@@ -160,6 +160,13 @@ METRICS_SCHEMA: dict[str, MetricSpec] = {
         "gauge", ("type",),
         "Per-RPC-kind error tallies (put.errors, rollup.errors, ...) "
         "by error type."),
+    "tsd.*.parser": _m(
+        "gauge", ("parser",),
+        "Write requests (HTTP bodies + telnet put blocks) served by "
+        "each ingest parser, per RPC kind: 'native' (the C++ columnar "
+        "parser, native/engine.cpp) or 'python' (the per-point "
+        "fallback — what every put takes when libtsdb_engine.so is "
+        "missing or the TSDB needs per-point hooks)."),
     "tsd.connectionmgr.connections": _m(
         "gauge", ("type",),
         "Connection manager totals: established/open/rejected."),

@@ -203,6 +203,35 @@ def update_device_gauges(tsdb) -> None:
         REGISTRY.gauge(name, "Device series cache (HBM) state").set(value)
 
 
+def device_report() -> dict:
+    """The devices this process computes on, as JAX reports them: the
+    default backend's platform, device kind and count, plus per-device
+    memory in use / peak / limit where the backend keeps
+    ``memory_stats()`` (the CPU backend reports none -> nulls).
+
+    Initializes the backend; a backend that cannot come up raises
+    (tsd_main calls this once at start so a daemon never serves from a
+    platform nobody chose).  Served as the ``device`` section of the
+    full /api/diag view."""
+    import jax
+    devices = jax.devices()
+    per_device = []
+    for d in devices:
+        stats = d.memory_stats() or {}
+        per_device.append({
+            "id": d.id,
+            "bytesInUse": stats.get("bytes_in_use"),
+            "peakBytesInUse": stats.get("peak_bytes_in_use"),
+            "bytesLimit": stats.get("bytes_limit"),
+        })
+    return {
+        "platform": devices[0].platform,
+        "kind": devices[0].device_kind,
+        "count": len(devices),
+        "memory": per_device,
+    }
+
+
 # --------------------------------------------------------------------- #
 # Costmodel predicted-vs-actual                                         #
 # --------------------------------------------------------------------- #

@@ -8,9 +8,9 @@ device time.  This module solves the inverse problem: regress the
 measured seconds onto the feature vectors by non-negative least
 squares, and install the solution as the costmodel's live override
 layer.  A daemon serving traffic thereby converges its `choose_*`
-argmins to whatever its own hardware measures — reproducing the
-offline chip-A/B winners (BENCH_WINNERS.json) without a bench session,
-and beating them on shapes the A/B never visited.  The hash- vs
+argmins to whatever its own hardware measures — reproducing an
+offline chip A/B's winners without a bench session, and beating them
+on shapes the A/B never visited.  The hash- vs
 sort-style group-by crossover this tunes is the one the focused
 empirical study measures (PAPERS.md, arXiv:2411.13245); the shared-
 aggregation adaptivity mirrors Enthuse (arXiv:2405.18168).

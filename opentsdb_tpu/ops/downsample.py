@@ -234,18 +234,17 @@ def window_edges(ts_dtype, spec: WindowSpec, wargs: dict):
 # full-length scan at all: exact f64 sums of 32-point sub-blocks (a tree
 # reduce — one cheap pass), a cumsum over the [S, N/32] sub-block sums
 # (1/32 the scan work), and per-edge remainders as 32-wide masked dots.
-# Rationale (r4 chip attribution, tools/stage_bench.py): a full-length
-# f64 cumsum costs 95ms/67M pts on the chip while an f64 elementwise
-# pass costs 14ms — the emulated-f64 SCAN is the bottleneck, not the
-# data traffic, so the subblock form does 1/32 of it.
-# Measured on the real chip (BENCH_CONFIGS_r03.json bench_prefix stage):
-# flat 0.568s vs blocked 0.600s per 67M-pt dispatch at int32 — XLA's
-# native cumsum lowering beats the hand-blocked form on TPU.
+# Rationale (per-stage attribution from an earlier chip session, not
+# re-measured on this installation — ROADMAP A6): a full-length f64
+# cumsum cost 95ms/67M pts on the chip while an f64 elementwise pass
+# cost 14ms — the emulated-f64 SCAN is the bottleneck, not the data
+# traffic, so the subblock form does 1/32 of it.  Same session: flat
+# 0.568s vs blocked 0.600s per 67M-pt dispatch at int32 — XLA's native
+# cumsum lowering beat the hand-blocked form on TPU.
 #
 # Env overrides (TSDB_SCAN_MODE / TSDB_SEARCH_MODE / TSDB_EXTREME_MODE,
-# read once at import): lets the one-command measurement session feed
-# bench_prefix's A/B winners into the later stages without editing
-# source mid-run.  Invalid values are ignored (defaults win).
+# read once at import): lets a measurement run race the modes without
+# editing source.  Invalid values are ignored (defaults win).
 _SCAN_MODES = ("auto", "flat", "blocked", "subblock", "subblock2")
 _SCAN_MODE = (_os.environ.get("TSDB_SCAN_MODE")
               if _os.environ.get("TSDB_SCAN_MODE") in _SCAN_MODES

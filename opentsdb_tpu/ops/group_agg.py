@@ -70,11 +70,12 @@ def _identity(x):
 # contiguous row run; group sums and extremes are short segmented
 # reset-scans along the tiny [S, W] grid's row axis — no scatter, no
 # one-hot, cost independent
-# of the group count (r4 chip attribution: the segment tail cost 219ms
-# and the matmul tail ~100ms on a 0.5M-cell grid that one pass covers
-# in ~1ms).  All are float64 (Java-double contract); the sum order
-# differs so results can drift in the last ulp.  The chip A/B
-# (bench_prefix) picks the default via TSDB_GROUP_REDUCE_MODE.
+# of the group count (from an earlier chip session, not re-measured on
+# this installation — ROADMAP A6: the segment tail cost 219ms and the
+# matmul tail ~100ms on a 0.5M-cell grid that one pass covers in ~1ms).
+# All are float64 (Java-double contract); the sum order differs so
+# results can drift in the last ulp.  TSDB_GROUP_REDUCE_MODE forces a
+# mode for an A/B (bench_prefix.py).
 import os as _os
 
 _GROUP_REDUCE_MODES = ("auto", "segment", "matmul", "sorted", "sorted2")

@@ -211,10 +211,11 @@ def _segment_chunk_moments(ts, val, mask, spec: WindowSpec, wargs: dict,
 
 # Segment-vs-dense routing threshold for streamed chunks: the segment
 # form engages when W > ratio * N.  1.0 is the analytic crossover (per-
-# edge search work vs per-point scatter work); the chip session's
-# stream_chunk_segment / stream_chunk_dense rows (tools/stage_bench.py)
-# measure the real one — TPU scatters serialize, so the measured ratio
-# may sit well above 1.  Env override pending a chip-crowned default.
+# edge search work vs per-point scatter work); tools/stage_bench.py's
+# stream_chunk_segment / stream_chunk_dense stages measure the real one
+# and have not run on this installation (ROADMAP A2/A6) — TPU scatters
+# serialize, so the measured ratio may sit well above 1.  Env override
+# pending a chip-measured default.
 import os as _os
 
 _SEGMENT_CHUNK_RATIO = float(_os.environ.get(

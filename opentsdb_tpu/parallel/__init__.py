@@ -6,7 +6,7 @@ RowKey.prefixKeyWithSalt :141); its distributed backend is asynchbase RPC +
 ZooKeeper (SURVEY.md §2.7).  The TPU-native equivalent: a
 `jax.sharding.Mesh` with a *series* axis (the salt-bucket analog — each chip
 owns a shard of series) and a *time* axis (sequence-parallel analog — long
-series split across chips), with XLA collectives (`psum`/`pmax`/`pmin`)
+series split across chips), with XLA collectives (`psum`, `all_gather`)
 combining partial window moments over ICI.
 """
 

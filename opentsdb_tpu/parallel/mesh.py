@@ -8,7 +8,8 @@ Axes:
     (the 3600s row-chunking analog, Const.java:95).
 
 Collectives ride ICI within a slice: additive window moments combine with
-`psum` over both axes; min/max with `pmin`/`pmax`.
+`psum` over both axes; min/max by all-gather + local reduce
+(parallel/sharded.py `_pextreme`).
 """
 
 from __future__ import annotations

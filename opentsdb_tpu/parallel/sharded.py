@@ -7,8 +7,9 @@ scatter/gather of SaltScanner (/root/reference/src/core/SaltScanner.java:269).
 Each HBase salt bucket scanned concurrently becomes a series shard owned by
 one chip; the TreeMap merge of per-bucket results becomes XLA collectives:
 window moments (count/sum/sumsq/min/max) are computed per chip with segment
-reductions, then combined over ICI with `psum`/`pmax`/`pmin` inside
-`shard_map`.  The time axis is additionally sharded (sequence parallelism)
+reductions, then combined over ICI inside `shard_map`: sums with `psum`,
+extremes by gather + local reduce (`_pextreme` — the f64 `pmax`/`pmin`
+all-reduce does not lower on TPU).  The time axis is additionally sharded (sequence parallelism)
 — window moments are associative over time, so time shards combine with the
 same collectives, no halo exchange needed.
 
@@ -38,6 +39,19 @@ from opentsdb_tpu.ops.downsample import (
 from opentsdb_tpu.parallel.mesh import AXIS_SERIES, AXIS_TIME
 
 _BOTH = (AXIS_SERIES, AXIS_TIME)
+
+
+def _pextreme(x, axes, reduce):
+    """Cross-chip min/max (`reduce` = jnp.min / jnp.max) of float64
+    partials — in place of lax.pmin / lax.pmax, which XLA:TPU refuses for
+    64-bit element types ("UNIMPLEMENTED: Supported lowering only of Sum
+    all reduce": f64 is an emulated u32 pair there and only the sum
+    combiner is lowered; first met by `max:1m-max` on a 2x2 v5e mesh).
+    Every chip gathers the partials and reduces them locally: the same
+    values in, the same extreme out, replicated like the all-reduce was.
+    The partials are already-reduced [G, W] / [S, W] grids, so the
+    gather moves n_chips small grids."""
+    return reduce(lax.all_gather(x, axes), axis=0)
 
 # Cross-chip aggregators expressible as psum/pmax/pmin-combinable moments.
 # Scopes sharded_group_downsample (the offline rollup pass, which only ever
@@ -103,11 +117,11 @@ def _finish(agg_name, seg, ok_flat, flat_v, count, total, num,
     elif agg_name in ("min", "mimmin"):
         lo = jax.ops.segment_min(jnp.where(ok_flat, flat_v, jnp.inf), seg,
                                  num_segments=num)[:-1]
-        out = lax.pmin(lo, _BOTH).reshape(g, w)
+        out = _pextreme(lo, _BOTH, jnp.min).reshape(g, w)
     elif agg_name in ("max", "mimmax"):
         hi = jax.ops.segment_max(jnp.where(ok_flat, flat_v, -jnp.inf), seg,
                                  num_segments=num)[:-1]
-        out = lax.pmax(hi, _BOTH).reshape(g, w)
+        out = _pextreme(hi, _BOTH, jnp.max).reshape(g, w)
     elif agg_name == "dev":
         # Second pass with the *global* mean (ICI round-trip already paid by
         # the psum of count/total): numerically the two-pass scheme the
@@ -176,7 +190,7 @@ def sharded_rollup(mesh: Mesh, spec: WindowSpec):
     rollup rows for the series it owns, the write-path analog of
     TSDB.addAggregatePoint (/root/reference/src/core/TSDB.java:1359-1457)
     batched over every interval at once.  Time shards combine with psum /
-    pmin / pmax over the time axis only.
+    _pextreme over the time axis only.
     """
     w = spec.count
 
@@ -205,8 +219,8 @@ def sharded_rollup(mesh: Mesh, spec: WindowSpec):
                                  num_segments=num)[:-1]
         cnt = lax.psum(cnt, AXIS_TIME).reshape(s, w)
         tot = lax.psum(tot, AXIS_TIME).reshape(s, w)
-        lo = lax.pmin(lo, AXIS_TIME).reshape(s, w)
-        hi = lax.pmax(hi, AXIS_TIME).reshape(s, w)
+        lo = _pextreme(lo, AXIS_TIME, jnp.min).reshape(s, w)
+        hi = _pextreme(hi, AXIS_TIME, jnp.max).reshape(s, w)
         wts = window_timestamps(spec, wargs)
         return wts, tot, cnt, lo, hi
 
@@ -225,7 +239,7 @@ def _local_grid_tail(spec, num_groups: int, wts, v, m, gid):
     (rate ->) grouped cross-series aggregation on a row-sharded [S, W] grid.
 
     The mesh analog of ops.pipeline._grid_tail: moment-decomposable
-    aggregators combine per-chip partial moments with psum/pmin/pmax;
+    aggregators combine per-chip partial moments with psum/_pextreme;
     order/rank aggregators all-gather the reduced grid (gather-to-owner,
     W ≪ N) and reduce replicated.  Shared by the materialized serving path
     (sharded_query_pipeline) and the streamed finish (sharded stream
@@ -251,8 +265,8 @@ def _local_grid_tail(spec, num_groups: int, wts, v, m, gid):
         out, _ = moment_group_reduce(
             agg.name, contrib, participate, gid, g,
             combine_sum=lambda x: lax.psum(x, _BOTH),
-            combine_min=lambda x: lax.pmin(x, _BOTH),
-            combine_max=lambda x: lax.pmax(x, _BOTH),
+            combine_min=lambda x: _pextreme(x, _BOTH, jnp.min),
+            combine_max=lambda x: _pextreme(x, _BOTH, jnp.max),
             # contiguous row sharding + end-padding preserve the
             # planner's non-decreasing gid on every shard
             rows_sorted=spec.rows_sorted)
@@ -448,7 +462,7 @@ class ShardedStreamAccumulator:
     per-batch callbacks (:463-740).  Series rows are sharded over every
     chip of the mesh; each host chunk is device_put row-sharded and folded
     into per-chip [S_local, W] moments (associative, collective-free); the
-    finish runs the sharded grid tail (psum/pmin/pmax for moment
+    finish runs the sharded grid tail (psum/_pextreme for moment
     aggregators, gather-to-owner for order/rank) so the answer matches the
     single-device StreamAccumulator + run_grid_tail bit-for-bit up to
     psum reassociation.

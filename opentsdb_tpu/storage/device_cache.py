@@ -4,7 +4,7 @@ The TPU-native analog of the reference's storage-side block caching (the
 HBase BlockCache that made repeated scans of hot rows memory-speed; the
 reference leans on it implicitly — every SaltScanner pass re-reads the
 same regions, SaltScanner.java:269).  Here the roles are inverted: the
-store is host RAM, the accelerator is across a PCIe/tunnel link, and the
+store is host RAM, the accelerator is across a PCIe link, and the
 dominant cost of a repeated `/api/query` is re-uploading the same raw
 points every dispatch.  This cache pins each hot metric's columnar data
 in device HBM once; subsequent queries gather their [S, N] window batch

@@ -112,12 +112,38 @@ def write_sanitizer_report(config) -> None:
             "could not write sanitizer report to %s: %s", path, e)
 
 
+def log_device() -> bool:
+    """Touch the backend ONCE, before the store opens or the port binds,
+    and say where this daemon computes.  False when the backend cannot come up: the daemon
+    then exits instead of discovering it on the first query (or, worse,
+    serving from a platform nobody chose)."""
+    # the ops package fixes x64, the platform set and the compile-cache
+    # directory at import — all of which must precede backend init
+    from opentsdb_tpu import ops  # noqa: F401
+    from opentsdb_tpu.obs import jaxprof
+    log = logging.getLogger("tsd.device")
+    try:
+        report = jaxprof.device_report()
+    except RuntimeError as e:
+        log.error("JAX backend failed to initialize; not serving: %s", e)
+        return False
+    log.info(
+        "computing on platform=%s device_kind=%s count=%d "
+        "bytes_in_use=%s peak_bytes_in_use=%s",
+        report["platform"], report["kind"], report["count"],
+        [m["bytesInUse"] for m in report["memory"]],
+        [m["peakBytesInUse"] for m in report["memory"]])
+    return True
+
+
 def main(argv: list[str] | None = None) -> int:
     args = build_arg_parser().parse_args(argv)
     logging.basicConfig(
         level=logging.DEBUG if args.verbose else logging.INFO,
         format="%(asctime)s %(levelname)s [%(threadName)s] "
                "%(name)s: %(message)s")
+    if not log_device():
+        return 1
     tsdb = make_tsdb_from_args(args)
     if tsdb.config.enable_compactions:
         # The compaction-thread analog (CompactionQueue.java:95-107): dirty

@@ -223,7 +223,10 @@ class DiagRpc(HttpRpc):
     slow-query store, and the health-engine verdicts
     (obs/flightrec.py, obs/health.py; docs/observability.md).
 
-      * ``/api/diag``              the event ring, oldest first.
+      * ``/api/diag``              the event ring, oldest first, plus
+        (full view only) the ``tenants`` fair-share audit and the
+        ``device`` report — platform, device kind, count, per-device
+        memory in use / peak.
         ``?since=<seq>`` returns only events newer than that sequence
         number — poll with the last ``seq`` you saw for an incremental
         feed.  ``?trace_id=<id>`` narrows to one request's ring slice
@@ -319,6 +322,10 @@ class DiagRpc(HttpRpc):
             gate = getattr(tsdb, "_admission_gate", None)
             if gate is not None:
                 reply["tenants"] = gate.tenant_snapshot()
+            # where this daemon computes: platform / device kind / count
+            # and live per-device memory (obs/jaxprof.py device_report)
+            from opentsdb_tpu.obs import jaxprof
+            reply["device"] = jaxprof.device_report()
         query.send_reply(reply)
 
 

@@ -655,8 +655,8 @@ def _scratch_store(tsdb):
         "tsd.diag.enable": "false",
         "tsd.health.enable": "false",
         # the final fold runs on THIS box: a coordinator whose operator
-        # disabled the mesh (e.g. a JAX without shard_map) must not have
-        # the scratch re-enable it behind their back
+        # disabled the mesh must not have the scratch re-enable it
+        # behind their back
         "tsd.query.mesh.enable": tsdb.config.get_string(
             "tsd.query.mesh.enable"),
     }))

@@ -79,6 +79,11 @@ class PutDataPointRpc(TelnetRpc, HttpRpc):
         self.illegal_arguments = 0  # guarded-by: _lock
         self.unknown_metrics = 0  # guarded-by: _lock
         self.writes_blocked = 0  # guarded-by: _lock
+        # write requests (HTTP bodies / telnet blocks) by the parser
+        # that served them — a missing native library shows up here
+        # instead of only as a slower daemon
+        self.native_parsed = 0  # guarded-by: _lock
+        self.python_parsed = 0  # guarded-by: _lock
         self._lock = threading.Lock()
 
     def _count(self, attr: str) -> None:
@@ -131,6 +136,7 @@ class PutDataPointRpc(TelnetRpc, HttpRpc):
                 is PutDataPointRpc.import_telnet_point:
             native = tsdb.add_telnet_batch_native(block)
         if native is None:
+            self._count("python_parsed")
             return self._telnet_lines_one_by_one(conn, block, manager)
         from opentsdb_tpu.storage.native_engine import LINE_FALLBACK
         tb, point_errors = native
@@ -160,6 +166,7 @@ class PutDataPointRpc(TelnetRpc, HttpRpc):
                 storage += 1
                 out.append("put: %s: %s\n" % (type(exc).__name__, exc))
         with self._lock:
+            self.native_parsed += 1
             self.requests += requests
             self.unknown_metrics += unknown
             self.illegal_arguments += illegal
@@ -199,6 +206,7 @@ class PutDataPointRpc(TelnetRpc, HttpRpc):
             # the native parser fuses decode + columnar ingest: the
             # write path's device-equivalent work counts as dispatch
             latattr.mark("dispatch")
+            self._count("native_parsed")
             success, errors, spans = native
             if success == 0 and not errors:
                 raise BadRequestError("No datapoints found in content")
@@ -223,6 +231,7 @@ class PutDataPointRpc(TelnetRpc, HttpRpc):
 
             self._respond_put(tsdb, query, success, errors, dp_at)
             return
+        self._count("python_parsed")
         dps = query.serializer.parse_put_v1()
         latattr.mark("parse")
         self.process_data_points(tsdb, query, dps)
@@ -334,6 +343,10 @@ class PutDataPointRpc(TelnetRpc, HttpRpc):
                          "type=illegal_arguments")
         collector.record("%s.errors" % self.kind, self.unknown_metrics,
                          "type=unknown_metrics")
+        collector.record("%s.parser" % self.kind, self.native_parsed,
+                         "parser=native")
+        collector.record("%s.parser" % self.kind, self.python_parsed,
+                         "parser=python")
 
 
 class RollupDataPointRpc(PutDataPointRpc):

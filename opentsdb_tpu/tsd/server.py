@@ -106,6 +106,9 @@ class TSDServer:
         # only on the event-loop thread, like _inflight_rpcs; stop()
         # (also on the loop) force-cancels them at drain expiry.
         self._active_handles: set = set()
+        # writers of open connections; loop-thread only, like the above.
+        # stop() closes whatever is still open once the drain is done.
+        self._open_writers: set = set()
         self._executor = ThreadPoolExecutor(
             max_workers=worker_threads, thread_name_prefix="tsd-responder")
         self._server: asyncio.AbstractServer | None = None
@@ -178,10 +181,11 @@ class TSDServer:
         await self.stop()
 
     async def stop(self) -> None:
-        if self._server is not None:
-            self._server.close()
-            await self._server.wait_closed()
-            self._server = None
+        server, self._server = self._server, None
+        if server is not None:
+            # stop accepting; connections already open keep being served
+            # through the drain below and are closed after it
+            server.close()
         # Drain in-flight responder work BEFORE tearing down the TSDB:
         # handlers may still be mid-write (a put landing, a query
         # serializing), and shutdown(wait=False) + tsdb.shutdown() would
@@ -231,6 +235,18 @@ class TSDServer:
             deadline = loop.time() + 5.0
             while self._inflight_rpcs and loop.time() < deadline:
                 await asyncio.sleep(0.02)
+            # What is left are idle keep-alive connections.  Python 3.12's
+            # Server.wait_closed() waits for every connection handler to
+            # return, so an idle client would otherwise hold the daemon's
+            # SIGTERM hostage until its idle timeout.
+            for writer in list(self._open_writers):
+                writer.close()
+            if server is not None:
+                try:
+                    await asyncio.wait_for(server.wait_closed(), timeout=5.0)
+                except asyncio.TimeoutError:
+                    LOG.warning("%d connection handler(s) still open at "
+                                "shutdown", len(self._open_writers))
         finally:
             # A cancelled drain must still release the process-global
             # installs — a CancelledError here would otherwise pin the
@@ -267,6 +283,7 @@ class TSDServer:
                 return
             self._open_connections += 1
             self.connections_established += 1
+        self._open_writers.add(writer)
         peer = writer.get_extra_info("peername")
         remote = "%s:%s" % (peer[0], peer[1]) if peer else "unknown"
         try:
@@ -287,6 +304,7 @@ class TSDServer:
             self.exceptions_caught += 1
             LOG.exception("Unhandled connection error from %s", remote)
         finally:
+            self._open_writers.discard(writer)
             with self._conn_lock:
                 self._open_connections -= 1
             try:

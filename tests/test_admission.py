@@ -439,8 +439,9 @@ class TestDegradationLadder:
 # --------------------------------------------------------------------- #
 
 def _manager(**cfg):
-    # mesh pinned off: this environment's jax has no shard_map (the
-    # known tier-1 mesh failure set) and grouped plans probe the mesh
+    # mesh pinned off: admission prices the single-device plan here,
+    # and the suite's 8 virtual devices would turn grouped plans with
+    # >= 8 series into mesh plans
     props = {"tsd.core.auto_create_metrics": True,
              "tsd.query.mesh.enable": "false"}
     props.update({k: str(v) for k, v in cfg.items()})

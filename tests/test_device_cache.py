@@ -18,8 +18,17 @@ from opentsdb_tpu.utils.config import Config
 BASE = 1_356_998_400
 
 
+# The cache under test serves the RESIDENT route.  Two arms sit in front
+# of it in plan_decision and would take these small queries first: the
+# fused-dispatch batcher (decided before the device-cache consult, by
+# design) and, on the suite's 8 virtual devices, nothing else — 2 series
+# stay under tsd.query.mesh.min_series.
+BASE_CONF = {"tsd.core.auto_create_metrics": True,
+             "tsd.query.batch.enable": "false"}
+
+
 def make_tsdb(**cfg):
-    conf = {"tsd.core.auto_create_metrics": True}
+    conf = dict(BASE_CONF)
     conf.update(cfg)
     t = TSDB(Config(conf))
     for i in range(40):
@@ -252,7 +261,7 @@ class TestBudget:
         # raw store and a rollup lane share the metric-uid space: each
         # gets its own entry, and rollup queries hit from HBM too
         tsdb = TSDB(Config({
-            "tsd.core.auto_create_metrics": True,
+            **BASE_CONF,
             "tsd.rollups.enable": True,
             "tsd.rollups.config": json.dumps({
                 "intervals": [{"interval": "1h", "table": "tsdb-rollup-1h",
@@ -282,7 +291,7 @@ class TestBudget:
         # on a raw segment: the cache must key on that lane, never build
         # (and then stale-mark) a raw-store entry for it (review r3)
         tsdb = TSDB(Config({
-            "tsd.core.auto_create_metrics": True,
+            **BASE_CONF,
             "tsd.rollups.enable": True,
             "tsd.rollups.config": json.dumps({
                 "intervals": [{"interval": "1h", "table": "t",

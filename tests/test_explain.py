@@ -17,8 +17,9 @@ what-if grammar, the /api/diag ``?trace_id=`` resolution satellite,
 and the PLAN_CORPUS.json byte-pin (subprocess — routing changes must
 surface as reviewed corpus diffs).
 
-No mesh/shard_map anywhere — those fail at HEAD in this environment,
-so every TSDB here pins tsd.query.mesh.enable=false.
+Every TSDB here pins tsd.query.mesh.enable=false: PLAN_CORPUS.json pins
+the single-device routes, and the suite's 8 virtual devices would
+otherwise turn every >= 8-series profile into a mesh plan.
 """
 
 from __future__ import annotations

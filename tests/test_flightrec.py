@@ -8,8 +8,8 @@ contract — ONE trace id carried through the admission queue, the
 degradation ladder, the flight-recorder events, and the peer_fetch
 child of a cluster fan-out.
 
-No mesh/shard_map anywhere — those fail at HEAD in this environment,
-so every TSDB here pins tsd.query.mesh.enable=false.
+Every TSDB here pins tsd.query.mesh.enable=false: the plan events under
+test are the single-device routes'.
 """
 
 from __future__ import annotations
@@ -169,7 +169,16 @@ class TestEndpoints:
         status, payload, _ = ask(mgr, "/api/diag")
         assert status == 200
         assert set(payload) == {"seq", "ringSize", "events", "tenants",
-                                "dropped", "droppedTotal"}
+                                "dropped", "droppedTotal", "device"}
+        # where the daemon computes (obs/jaxprof.py device_report): what
+        # chip_smoke.py reads to refuse a silent CPU
+        device = payload["device"]
+        assert set(device) == {"platform", "kind", "count", "memory"}
+        assert device["platform"] == "cpu" and device["kind"]
+        assert device["count"] == len(device["memory"]) >= 1
+        for mem in device["memory"]:
+            assert set(mem) == {"id", "bytesInUse", "peakBytesInUse",
+                                "bytesLimit"}
         assert payload["seq"] >= 1
         kinds = {e["kind"] for e in payload["events"]}
         assert {"admission", "plan"} <= kinds
@@ -178,6 +187,9 @@ class TestEndpoints:
             assert isinstance(e["tMs"], int)
         status, tail, _ = ask(mgr, "/api/diag?since=%d" % payload["seq"])
         assert status == 200 and tail["events"] == []
+        # a trace-scoped fetch is one request's evidence, not the daemon's
+        status, scoped, _ = ask(mgr, "/api/diag?trace_id=nonesuch")
+        assert status == 200 and "device" not in scoped
         status, _, _ = ask(mgr, "/api/diag?since=bogus")
         assert status == 400
 
@@ -443,10 +455,9 @@ class TestTraceContinuity:
         """A query that WAITS in the admission queue, degrades via the
         ladder, and fans out to a peer carries ONE trace id through
         the admission span, the flight-recorder events, and the
-        peer_fetch child (mesh off per the known shard_map HEAD
-        failure — including the clustered scratch store's runner,
-        whose default-config mesh consult is exactly the known
-        tier-1 failure mode)."""
+        peer_fetch child (mesh off — including the clustered scratch
+        store's runner — so the plan events are the single-device
+        routes' on the suite's 8 virtual devices)."""
         monkeypatch.setattr(TSDB, "query_mesh", lambda self: None)
         tsdb, mgr = _manager(**{
             "tsd.network.cluster.peers": peer.address,

@@ -1,8 +1,10 @@
 """tsdbobs surface tests: span trees, Prometheus exposition, histogram
 quantiles, the self-report loop, and the stats-collector fixes.
 
-No mesh/shard_map anywhere — those fail at HEAD in this environment, so
-every TSDB here pins tsd.query.mesh.enable=false.
+Every TSDB here pins tsd.query.mesh.enable=false: the span trees and
+the calibration ring under test describe the single-device dispatch
+(the mesh route has its own suites, tests/test_mesh_query.py and
+tests/test_parallel.py).
 """
 
 from __future__ import annotations

@@ -6,7 +6,8 @@ and failover that keeps answering with FULL results.
 Topology under test: two REAL TSDServer daemons on live sockets, each
 with its own storage directory, shard.enable on, rf=2 — every shard has
 both nodes in its preference list, so any single death is survivable.
-Mesh is off throughout (no shard_map at HEAD).
+Mesh is off throughout: each daemon stands for a one-chip node, and two
+daemons sharing the suite's 8 virtual devices would each build a mesh.
 
 Deterministic failure machinery: servers stop via their own shutdown
 event (graceful) or by closing the listening socket hard; breaker

@@ -10,7 +10,10 @@ byte-budget selection, the over-budget window-striped serve path
 (spill-pool replay reuse), admission pricing of warm lanes, and the
 tree-level lint pin that gutting the lane invalidator fails the build.
 
-Mesh disabled throughout (no shard_map at HEAD).
+Mesh disabled throughout: a rollup lane serves the single-device route
+(plan_decision consults lanes only for non-mesh plans), and the suite's
+8 virtual devices would otherwise turn every >= 8-series query into a
+mesh plan.
 """
 
 import os
@@ -498,7 +501,7 @@ def test_bench_rollup_ratio_pinned():
         proc = subprocess.run(
             [sys.executable, os.path.join(REPO, "tools",
                                           "bench_rollup.py"),
-             "--out", out],
+             "--platform", "cpu", "--out", out],
             capture_output=True, text=True, timeout=900, cwd=REPO,
             env=dict(os.environ, JAX_PLATFORMS="cpu"))
         assert proc.returncode == 0, proc.stdout[-4000:] \

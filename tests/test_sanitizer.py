@@ -22,8 +22,8 @@ report_blocked_past_deadline) is staged inline rather than from file
 fixtures: its inputs are real contended acquires under an ambient
 request Deadline, which a test thread pair produces directly.
 
-CPU-only (conftest pins JAX_PLATFORMS=cpu); nothing here touches mesh
-or shard_map paths, which fail at HEAD in this environment.
+CPU-only (conftest pins JAX_PLATFORMS=cpu); nothing here touches the
+mesh route.
 
 Works standalone AND under a TSDBSAN=1 session: when the pytest plugin
 already installed the sanitizer these tests borrow it, snapshotting and

@@ -40,8 +40,8 @@ def _timed_run(sanitized: bool) -> float:
          *SLICE],
         cwd=REPO, env=env, capture_output=True, text=True, timeout=600)
     elapsed = time.monotonic() - start
-    # the slice carries pre-existing environment failures (shard_map);
-    # the guard compares wall time, not verdicts — but a crash/usage
+    # the guard compares wall time, not verdicts (a failing test in the
+    # slice is its own suite's business) — but a crash/usage
     # error (rc >= 2 without the plugin's findings-exit 3) would make
     # the timing meaningless
     assert proc.returncode in (0, 1, 3), proc.stdout + proc.stderr

@@ -9,8 +9,8 @@ day a hot path quietly recompiles or syncs per request.
 Runs in plain tier-1 (self-contained: it arms its own JaxSanitizer
 instance, no TSDBSAN env needed) and doubles as the jax leg of the
 `tools/sanitize/run.py --subset tier1` sanitized run.  CPU-only; the
-mesh path is disabled (shard_map is unavailable at HEAD in this
-environment).
+mesh path is disabled so the steady-state compile counts describe one
+single-device program per shape.
 """
 
 from __future__ import annotations
@@ -38,8 +38,8 @@ BASE = 1_356_998_400
 def tsdb():
     t = TSDB(Config({
         "tsd.core.auto_create_metrics": True,
-        # shard_map is unavailable at HEAD in this environment; the
-        # mesh path would die on import, not on a sanitizer finding
+        # one single-device program per shape: the recompile detector
+        # counts those, not the mesh route's per-shard programs
         "tsd.query.mesh.enable": False,
     }))
     for host in ("web01", "web02", "web03", "web04"):

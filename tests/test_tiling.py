@@ -9,8 +9,9 @@ span; the costmodel's new spill terms obey the linearity contract; and
 tiled executions are deliberately excluded from the calibration ring
 (the PR 9 rewrite precedent).
 
-Mesh/shard_map stays DISABLED in every query test here (known-failing
-at HEAD: this JAX has no shard_map).
+Mesh stays DISABLED in every query test here: tiling is the
+single-device answer to an over-budget grid (a mesh plan divides the
+grid budget by its chips instead).
 """
 
 import json
@@ -30,7 +31,7 @@ def _mk_tsdb(state_mb, spill="true", extra=None, seed=7, hosts=24,
              pts=60, metric="til.m", float_vals=False):
     cfg = {
         "tsd.core.auto_create_metrics": True,
-        "tsd.query.mesh.enable": "false",          # no shard_map at HEAD
+        "tsd.query.mesh.enable": "false",          # single-device route
         "tsd.query.device_cache.enable": "false",
         "tsd.query.cache.enable": "false",
         "tsd.query.streaming.point_threshold": "10",

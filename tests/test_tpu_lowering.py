@@ -1,0 +1,140 @@
+"""The mesh programs must LOWER for a TPU, checked without one.
+
+libtpu can compile for a described topology with no chip attached
+(jax.experimental.topologies), so a collective or dtype XLA:TPU refuses
+shows up here, on the CPU, instead of as a 500 on the four-chip host.
+First catch: `lax.pmax`/`lax.pmin` over float64 partials — "UNIMPLEMENTED:
+Supported lowering only of Sum all reduce" on a 2x2 v5e mesh, while the
+virtual CPU mesh the rest of the suite uses lowers it happily.
+
+The compiles run in ONE child process (this file run as a script): libtpu
+takes a machine-wide lock, the TPU compiler must not meet whatever kernel
+modes or calibrations earlier tests left in the session (inside a full
+tier-1 session the same compile once ran for minutes), and a compile that
+does not come back is a timeout here, not a hung suite.
+
+Tiny shapes: this pins what lowers, not how fast or into how much vmem.
+The default backend is the CPU, so `auto` kernel modes are priced from
+the CPU table — collectives and dtypes are what is under test.
+"""
+
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+S, N, START = 32, 256, 1_451_606_400_000
+
+# (aggregator, downsample fn, rate)
+QUERY_CASES = [
+    ("max", "max", False),      # the 500 on the four-chip host
+    ("min", "min", False),
+    ("sum", "avg", False),
+    ("sum", "avg", True),
+    ("avg", "avg", False),
+    ("dev", "avg", False),
+    ("p99", "avg", False),      # gather-to-owner branch
+]
+CASE_NAMES = ["query:%s:%s%s" % (a, d, ":rate" if r else "")
+              for a, d, r in QUERY_CASES] + [
+    "rollup", "group_downsample:min", "group_downsample:max",
+    "group_downsample:sum"]
+
+
+def _compile_all() -> None:
+    """Child: compile every case for v5e 2x2, one JSON line each."""
+    sys.path.insert(0, REPO)
+    import numpy as np
+
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from opentsdb_tpu.ops.downsample import FixedWindows
+    from opentsdb_tpu.ops.pipeline import DownsampleStep, PipelineSpec
+    from opentsdb_tpu.ops.rate import RateOptions
+    from opentsdb_tpu.parallel import make_mesh, sharded
+    from opentsdb_tpu.parallel.mesh import AXIS_SERIES, AXIS_TIME
+
+    try:
+        topo = topologies.get_topology_desc(topology_name="v5e:2x2",
+                                            platform="tpu")
+    except Exception as e:      # noqa: BLE001 — no libtpu, no check
+        print(json.dumps({"skip": str(e)[:300]}), flush=True)
+        return
+    mesh = make_mesh(4, devices=topo.devices)
+
+    def sds(shape, dtype, spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    def windows(interval_ms):
+        wspec, wargs = FixedWindows.for_range(
+            START, START + N * 10_000 - 1, interval_ms).split()
+        return wspec, {k: jax.ShapeDtypeStruct(np.shape(v),
+                                               np.asarray(v).dtype)
+                       for k, v in wargs.items()}
+
+    def batch(spec):
+        return (sds((S, N), jnp.int64, spec), sds((S, N), jnp.float64, spec),
+                sds((S, N), jnp.bool_, spec))
+
+    jobs = {}
+    rows = P(sharded._BOTH, None)
+    wspec, wargs = windows(60_000)
+    for name, (agg, ds_fn, rate) in zip(CASE_NAMES, QUERY_CASES):
+        spec = PipelineSpec(
+            aggregator=agg,
+            downsample=DownsampleStep(ds_fn, wspec, "none", 0.0),
+            rate=RateOptions() if rate else None, int_mode=False,
+            rows_sorted=True)
+        jobs[name] = (sharded.sharded_query_pipeline(mesh, spec, 8),
+                      (*batch(rows), sds((S,), jnp.int64,
+                                         P(sharded._BOTH)), wargs))
+    hspec, hargs = windows(3_600_000)
+    grid = P(AXIS_SERIES, AXIS_TIME)
+    jobs["rollup"] = (sharded.sharded_rollup(mesh, hspec),
+                      (*batch(grid), hargs))
+    for agg in ("min", "max", "sum"):
+        jobs["group_downsample:" + agg] = (
+            sharded.sharded_group_downsample(mesh, agg, hspec, 4),
+            (*batch(grid), sds((S,), jnp.int64, P(AXIS_SERIES)), hargs))
+    assert list(jobs) == CASE_NAMES
+    for name, (fn, args) in jobs.items():
+        try:
+            fn.lower(*args).compile()
+            print(json.dumps({"case": name, "ok": True}), flush=True)
+        except Exception as e:  # noqa: BLE001 — the verdict under test
+            print(json.dumps({"case": name, "ok": False,
+                              "error": str(e)[:400]}), flush=True)
+
+
+@pytest.fixture(scope="module")
+def verdicts():
+    try:
+        proc = subprocess.run(
+            [sys.executable, os.path.abspath(__file__)], cwd=REPO,
+            capture_output=True, text=True, timeout=600,
+            env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    except subprocess.TimeoutExpired:
+        pytest.fail("the TPU compiles did not come back in 600 s")
+    out = [json.loads(line) for line in proc.stdout.splitlines()
+           if line.startswith("{")]
+    if out and "skip" in out[0]:
+        pytest.skip("no TPU compiler for a described topology: %s"
+                    % out[0]["skip"])
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    return {v["case"]: v for v in out}
+
+
+@pytest.mark.parametrize("case", CASE_NAMES)
+def test_mesh_program_lowers_for_v5e_2x2(verdicts, case):
+    assert verdicts[case]["ok"], verdicts[case].get("error")
+
+
+if __name__ == "__main__":
+    _compile_all()

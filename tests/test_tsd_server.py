@@ -191,3 +191,38 @@ class TestMalformedHttp:
                 pass
         assert out.startswith(b"HTTP/1.1 400")
         assert b"Malformed request line" in out
+
+
+class TestShutdownWithIdleClients:
+    def test_idle_keepalive_connection_does_not_hold_shutdown(self):
+        """A supervisor's stop must not wait out a dashboard's idle
+        keep-alive socket: since Python 3.12 Server.wait_closed() waits
+        for every connection handler, so stop() closes what the drain
+        left open (chip_smoke.py's failing path found a 120 s hang)."""
+        import http.client
+
+        tsdb = TSDB(Config({"tsd.core.auto_create_metrics": True}))
+        srv = TSDServer(tsdb, port=0, bind="127.0.0.1", worker_threads=2)
+        took = {}
+
+        async def main():
+            await srv.start()
+            port = srv._server.sockets[0].getsockname()[1]
+            loop = asyncio.get_running_loop()
+
+            def client():
+                conn = http.client.HTTPConnection("127.0.0.1", port,
+                                                  timeout=10)
+                conn.request("GET", "/api/version")
+                resp = conn.getresponse()
+                resp.read()
+                return conn, resp.status
+            conn, status = await loop.run_in_executor(None, client)
+            assert status == 200 and len(srv._open_writers) == 1
+            t0 = time.monotonic()
+            await srv.stop()                # the connection is still open
+            took["s"] = time.monotonic() - t0
+            conn.close()
+        asyncio.run(main())
+        assert took["s"] < 3.0
+        assert not srv._open_writers

@@ -16,7 +16,7 @@ BENCH_AGG_CACHE.json at the repo root.  The acceptance gate is
 tests/test_agg_cache.py::test_cache_hit_speedup_at_scale pins the same
 ratio in-tree at the same shape.
 
-Usage: JAX_PLATFORMS=cpu python tools/bench_agg_cache.py [--series N]
+Usage: python tools/bench_agg_cache.py --platform cpu [--series N]
        [--points N] [--interval-s N] [--repeats N] [--no-artifact]
 """
 
@@ -102,7 +102,10 @@ def main() -> None:
     ap.add_argument("--interval-s", type=int, default=500)
     ap.add_argument("--repeats", type=int, default=5)
     ap.add_argument("--no-artifact", action="store_true")
+    from bench import add_platform_arg, require_device
+    add_platform_arg(ap)
     args = ap.parse_args()
+    device = require_device(args.platform)
 
     # aligned repeat range: whole 32-window blocks (and the final
     # window's full ms coverage — a seconds-granularity `end` lands on
@@ -169,7 +172,8 @@ def main() -> None:
             med(plains, 0) / max(med(warms, 0), 1e-9), 2),
         "sliding_speedup": round(
             med(plains, 0) / max(med(slides, 0), 1e-9), 2),
-        "platform": os.environ.get("JAX_PLATFORMS", "default"),
+        "platform": device["platform"],
+        "device": device,
         "cache_stats": {k: v for k, v in
                         tsdb.agg_cache.collect_stats().items()},
     }

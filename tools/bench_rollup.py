@@ -8,11 +8,12 @@ BENCH_TILING (64 series x 16384 windows, state_mb=4), time axis scaled
 to 1h windows so the 1h lane serves it; integer-valued data so the
 lane-served and tiled-exact answers must match BITWISE.
 
-    JAX_PLATFORMS=cpu python tools/bench_rollup.py [--out BENCH_ROLLUP.json]
+    python tools/bench_rollup.py --platform cpu [--out BENCH_ROLLUP.json]
 
-Writes one JSON document (committed at the repo root as
-BENCH_ROLLUP.json; a chip session re-runs this on real HBM).  The
->= 10x ratio is pinned by tests/test_rollup_lanes.py (slow).
+Writes one JSON document naming the device it ran on (the one committed
+at the repo root as BENCH_ROLLUP.json is a CPU run; not measured on the
+chip yet).  Without `--platform cpu` a run that finds no TPU fails.  The
+>= 10x ratio is pinned on CPU by tests/test_rollup_lanes.py (slow).
 """
 
 from __future__ import annotations
@@ -84,10 +85,10 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default=os.path.join(REPO,
                                                   "BENCH_ROLLUP.json"))
+    from bench import add_platform_arg, require_device
+    add_platform_arg(ap)
     args = ap.parse_args()
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    import jax
-    platform = jax.devices()[0].platform
+    device = require_device(args.platform)
     dp = HOSTS * PTS
 
     # the tiled exact path (PR 10): lanes disabled, same over-limit plan
@@ -123,7 +124,8 @@ def main() -> None:
         "metric": "lane-served vs tiled-exact wall at the over-limit "
                   "long-range group-by shape (tsd.query.streaming."
                   "state_mb=%dMB, 1h lane)" % STATE_MB,
-        "platform": platform,
+        "platform": device["platform"],
+        "device": device,
         "shape": {"series": HOSTS, "windows": WINDOWS, "groups": 8,
                   "datapoints": dp, "lane": "1h",
                   "range_days": SPAN_S // 86400},

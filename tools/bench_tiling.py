@@ -8,10 +8,11 @@ records both sides plus a resident reference run of the SAME plan
 under an uncapped budget, and pins zero answer divergence between the
 tiled and resident executions.
 
-    JAX_PLATFORMS=cpu python tools/bench_tiling.py [--out BENCH_TILING.json]
+    python tools/bench_tiling.py --platform cpu [--out BENCH_TILING.json]
 
-Writes one JSON document (committed at the repo root as
-BENCH_TILING.json; a chip session re-runs this on real HBM).
+Writes one JSON document naming the device it ran on (the one committed
+at the repo root as BENCH_TILING.json is a CPU run; not measured on the
+chip yet).  Without `--platform cpu` a run that finds no TPU fails.
 """
 
 from __future__ import annotations
@@ -73,10 +74,10 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default=os.path.join(REPO,
                                                   "BENCH_TILING.json"))
+    from bench import add_platform_arg, require_device
+    add_platform_arg(ap)
     args = ap.parse_args()
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    import jax
-    platform = jax.devices()[0].platform
+    device = require_device(args.platform)
     dp = HOSTS * PTS
 
     # HEAD behavior: the same plan with the tiled path disabled
@@ -108,7 +109,8 @@ def main() -> None:
         "metric": "answered-vs-refused throughput at an over-limit "
                   "[S, W] group-by shape (tsd.query.streaming."
                   "state_mb=%dMB)" % STATE_MB,
-        "platform": platform,
+        "platform": device["platform"],
+        "device": device,
         "shape": {"series": HOSTS, "windows": 32768, "groups": 8,
                   "datapoints": dp,
                   "streaming_state_mb_needed": 32},

@@ -78,6 +78,8 @@ def spawn_tsd(port, extra_cfg: dict, san: bool = False, role: str = "tsd"):
             fh.write("%s = %s\n" % (k, v))
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO
+    # a CPU fleet by design: several daemons run at once, and a chip
+    # belongs to one process — this harness checks contracts, not speed
     env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.Popen(
         [sys.executable, "-m", "opentsdb_tpu.tools.tsd_main",
@@ -365,8 +367,8 @@ def run_autotune_stage(port: int, rounds: int) -> None:
         "tsd.costmodel.autotune.min_samples": "8",
         "tsd.costmodel.autotune.epsilon": "0.5",
         "tsd.costmodel.autotune.calibration_file": calib,
-        # grouped queries probe the mesh; shard_map is absent at HEAD
-        # (the known tier-1 mesh failure set), so pin it off here
+        # only monolithic single-device dispatches enter the
+        # calibration ring, so the mesh route stays off here
         "tsd.query.mesh.enable": "false",
         # the fitter needs ring entries from MONOLITHIC dispatches;
         # partial-aggregate rewrites skip the predicted-vs-actual
@@ -1059,7 +1061,7 @@ def run_overload_stage(port: int, rounds: int) -> None:
         "tsd.query.timeout": str(timeout_ms),
         "tsd.query.degrade": "allow",
         "tsd.faults.config": fault,
-        # grouped queries probe the mesh; shard_map is absent at HEAD
+        # a CPU fleet: every daemon is a one-device node
         "tsd.query.mesh.enable": "false",
         # fast health cadence so the post-heal diag gate converges
         "tsd.health.interval": "2",

@@ -59,6 +59,8 @@ def spawn_tsd(port, storage_dir, native: bool):
                  "tsd.storage.directory = %s\n" % storage_dir)
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO
+    # CPU by design: kill -9 of a process holding a chip is not what
+    # this harness tests (durability of acked writes is)
     env["JAX_PLATFORMS"] = "cpu"
     if not native:
         env["TSDB_NATIVE_LIB"] = "/nonexistent/forces-python-path.so"

@@ -5,7 +5,7 @@ Input is any two latency windows, from either source:
 
   * ``GET /api/diag/latency`` captures (obs/latattr.py) — the whole
     capture is one window (cumulative since daemon start);
-  * ``BENCH_QPS.json`` artifacts (tools/bench_qps.py) — each embeds a
+  * ``bench_qps.json`` artifacts (tools/bench_qps.py) — each embeds a
     proper timed-window decomposition per phase
     (``endToEnd.{off,on}.phaseDecomposition``).
 
@@ -19,10 +19,10 @@ phase's share of the after-window.
     python tools/latency_report.py a.json b.json
 
     # two bench artifacts (e.g. before/after an optimisation)
-    python tools/latency_report.py BENCH_QPS.old.json BENCH_QPS.json
+    python tools/latency_report.py bench_qps.old.json bench_qps.json
 
     # one bench artifact: batching off vs on
-    python tools/latency_report.py BENCH_QPS.json
+    python tools/latency_report.py bench_qps.json
 """
 
 from __future__ import annotations
@@ -93,7 +93,7 @@ def _normalize(payload: dict, label: str) -> dict:
 
 
 def _bench_windows(artifact: dict, path: str) -> list[dict]:
-    """The windows a BENCH_QPS.json artifact carries (off/on arms)."""
+    """The windows a bench_qps.json artifact carries (off/on arms)."""
     out = []
     e2e = artifact.get("endToEnd", {})
     for arm in ("off", "on"):
@@ -157,7 +157,7 @@ def render(before: dict, after: dict) -> str:
 def main(argv: list[str] | None = None) -> int:
     ap = argparse.ArgumentParser(
         description="Diff two latency-attribution windows "
-                    "(/api/diag/latency captures or BENCH_QPS.json "
+                    "(/api/diag/latency captures or bench_qps.json "
                     "artifacts) into a per-phase delta table.")
     ap.add_argument("before", help="first capture/artifact")
     ap.add_argument("after", nargs="?",

@@ -39,7 +39,9 @@ CORPUS_PATH = os.path.join(REPO, "PLAN_CORPUS.json")
 BASE = 1_356_998_400            # seconds; fixed epoch, never now()
 
 # One profile = one deterministic daemon config + seeded dataset.
-# mesh stays off everywhere (no shard_map at HEAD).
+# mesh stays off everywhere: the corpus pins the single-device routes,
+# and its fingerprints must not depend on how many devices the machine
+# that regenerates it happens to show.
 _COMMON = {
     "tsd.core.auto_create_metrics": "true",
     "tsd.query.mesh.enable": "false",

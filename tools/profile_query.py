@@ -1,12 +1,15 @@
-"""jax.profiler harness for the production query pipeline (VERDICT r2 #2),
-rebased onto the EXPLAIN engine for its decision reporting.
+"""jax.profiler harness for the production query pipeline, rebased onto
+the EXPLAIN engine for its decision reporting.
 
 Captures an XLA trace of the headline bench dispatch so the hot ops
 (cumsum, searchsorted, gathers, segment reductions) can be attributed:
 
-    python tools/profile_query.py [--outdir /tmp/tsdb_profile] [--passes 3]
+    python tools/profile_query.py [--outdir chiprun_out/profile] [--passes 3]
     python tools/profile_query.py --what-if calibration=default \\
                                   --what-if force_scan=flat
+
+Only the process that holds the chip can trace it, and a run that finds
+no TPU exits non-zero unless `--platform cpu` says the dry run is meant.
 
 Before tracing, the tool prints the per-axis kernel-strategy decision
 for the bench shape — chosen mode, per-candidate predicted ms,
@@ -18,10 +21,10 @@ explain grammar's costmodel keys (``platform``, ``calibration``,
 ``force_search/scan/extreme/group``) and prints the repriced view
 beside the live one.
 
-View traces with TensorBoard's profile plugin or xprof.  Each profiled
-pass uses a unique window origin and ends in a host drain (same honesty
-rules as bench.py — `block_until_ready` does not wait on this platform,
-so traces bounded by it would be empty).
+View traces with TensorBoard's profile plugin or xprof, or read them
+with `jax.profiler.ProfileData.from_file`.  Each profiled pass uses a
+unique window origin and ends in `jax.block_until_ready` (bench.py's
+timing rules), so the trace window holds the passes' device work.
 """
 
 from __future__ import annotations
@@ -69,8 +72,10 @@ def _decision_lines(what_if) -> list[str]:
 
 
 def main() -> None:
+    import bench
     ap = argparse.ArgumentParser()
-    ap.add_argument("--outdir", default="/tmp/tsdb_profile")
+    ap.add_argument("--outdir", default="chiprun_out/profile")
+    bench.add_platform_arg(ap)
     ap.add_argument("--passes", type=int, default=3)
     ap.add_argument("--what-if", action="append", default=[],
                     metavar="KEY=VAL",
@@ -95,6 +100,8 @@ def main() -> None:
         ap.error(str(e))
 
     from bench import _note
+    device = bench.require_device(args.platform)
+    _note("device: %s" % device)
     for line in _decision_lines(what_if):
         _note(line)
     if args.decisions_only:

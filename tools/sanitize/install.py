@@ -10,8 +10,7 @@
      write-interception layer (tools/sanitize/lockset);
   3. a meta-path hook instruments modules imported LATER the same way —
      lazy imports (the parallel/ mesh path, plugins) are covered
-     without importing anything eagerly (importing parallel/ on a
-     machine without shard_map must not become the sanitizer's fault);
+     without importing anything eagerly;
   4. the deadlock watchdog starts (tools/sanitize/deadlock) and the
      runtime ordering recorder arms (tools/sanitize/order) — the same
      module scan wraps the patch-table methods that realise tagged

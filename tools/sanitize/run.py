@@ -71,7 +71,7 @@ def run_subset(subset: list[str], sarif: str | None, report: str | None,
         "TSDBSAN": "1",
         "TSDBSAN_REPORT": report_path,
         "TSDBSAN_STATE": state_path,
-        "JAX_PLATFORMS": "cpu",
+        "JAX_PLATFORMS": "cpu",     # the sanitized subset is a CPU run
         "PYTHONPATH": _REPO + os.pathsep + env.get("PYTHONPATH", ""),
     })
     cmd = [sys.executable, "-m", "pytest", "-q", "-m", "not slow",

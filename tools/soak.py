@@ -16,6 +16,7 @@ _ap = argparse.ArgumentParser()
 _ap.add_argument("--seconds", type=int, default=90)
 _ap.add_argument("--port", type=int, default=14247)
 _args = _ap.parse_args()
+# CPU by design: a concurrency soak of the serving layer, not a device run
 os.environ["JAX_PLATFORMS"] = "cpu"
 import jax
 jax.config.update("jax_platforms", "cpu")
