@@ -466,6 +466,12 @@ METRICS_SCHEMA: dict[str, MetricSpec] = {
         "phase (parse, admission_wait, plan, batch_rendezvous, "
         "dispatch, device_wait, serialize, flush) across all "
         "requests."),
+    "tsd.latattr.phase_cpu_ms": _m(
+        "counter", ("phase",),
+        "Cumulative handler-thread CPU milliseconds (time.thread_time) "
+        "spent in each fixed request phase across all requests; over "
+        "tsd.latattr.phase_ms of the same phase it is the share of the "
+        "phase the thread worked on a core rather than waited."),
     "tsd.latattr.profiles": _m(
         "gauge", (),
         "Distinct (route, plan fingerprint, tenant) latency-"

@@ -9,10 +9,21 @@ for EVERY request, tracing on or off:
 * ``PhaseStamps`` — a per-request recorder the RPC layer attaches to
   every HTTP request.  Producers along the serving path call
   ``latattr.mark("plan")`` at phase boundaries; each mark attributes
-  the monotonic time since the previous mark to that phase.  A mark is
-  two perf_counter reads and a dict add — no locks, no registry, no
-  allocation beyond the first mark of a phase — so the always-on cost
-  stays under the tests/test_latattr.py overhead pin.
+  the monotonic time since the previous mark to that phase, and the
+  handler thread's CPU time (``time.thread_time()``) beside it:
+  cpu / wall of a phase is the share of it the thread spent on a core,
+  the rest it waited (for the interpreter, a lock, the device).  A mark
+  is two clock reads, two dict adds and one ``is_enabled()`` — no locks,
+  no registry, no allocation beyond the first mark of a phase — so the
+  always-on cost stays under the tests/test_latattr.py overhead pin.
+
+* Profiler annotations — while a ``jax.profiler`` trace runs, every
+  phase interval is also a ``tsd.phase`` event (stats ``phase``,
+  ``cpu_ms``, ``trace_id``) on the handler thread's ``/host:CPU`` line,
+  on the same clock as the device planes, and obs/trace.py's
+  stack-managed spans are ``tsd.span`` events (stat ``name``) inside
+  them.  tools/trace_gaps.py reads both: what the host did while the
+  chip idled.  With no profile running nothing is created.
 
 * ``LatencyAttribution`` — the aggregation engine.  Finished stamps
   fold into bounded streaming per-phase ``LogHistogram``s keyed by
@@ -63,37 +74,97 @@ PHASES = ("parse", "admission_wait", "plan", "batch_rendezvous",
 # mix mints.
 OVERFLOW_KEY = ("overflow", "-", "-")
 
+# --------------------------------------------------------------------- #
+# Profiler annotations (shared with obs/trace.py)                       #
+# --------------------------------------------------------------------- #
+
+_TraceAnnotation = None     # jax.profiler.TraceAnnotation, on first use
+
+
+def open_annotation(event: str, **metadata):
+    """An entered profiler annotation named ``event`` on the calling
+    thread's line, or None (and nothing created) while no profile
+    runs.  Close it with ``close_annotation`` on the SAME thread."""
+    global _TraceAnnotation
+    cls = _TraceAnnotation
+    if cls is None:
+        from jax.profiler import TraceAnnotation as cls
+        _TraceAnnotation = cls
+    if not cls.is_enabled():
+        return None
+    ann = cls(event, **metadata)
+    ann.__enter__()
+    return ann
+
+
+def close_annotation(ann, **metadata) -> None:
+    """End ``ann`` (None is fine), adding ``metadata`` to its stats:
+    latattr names an interval at its end, the profiler at its start."""
+    if ann is None:
+        return
+    if metadata:
+        ann.set_metadata(**metadata)
+    ann.__exit__(None, None, None)
+
 
 class PhaseStamps:
     """Per-request phase recorder.  Owned and touched by the request's
     handler thread only (the batcher's rendezvous and the admission
-    wait both block that same thread), so no lock."""
+    wait both block that same thread), so no lock — and so that ONE
+    thread's CPU clock is the right one: a mark from another thread
+    would read the wrong ``thread_time()``, as it would already miss
+    the ambient ``_tls`` stamps."""
 
-    __slots__ = ("t0", "_prev", "deltas", "phase", "route",
-                 "fingerprint", "tenant", "trace_id")
+    __slots__ = ("t0", "_prev", "_prev_cpu", "deltas", "cpu", "phase",
+                 "route", "fingerprint", "tenant", "trace_id", "_ann")
 
     def __init__(self, trace_id: str | None = None):
         now = time.perf_counter()
         self.t0 = now
         self._prev = now
-        self.deltas: dict[str, float] = {}      # phase -> seconds
+        self._prev_cpu = time.thread_time()
+        self.deltas: dict[str, float] = {}      # phase -> wall seconds
+        self.cpu: dict[str, float] = {}         # phase -> CPU seconds
         self.phase = "recv"                     # last completed mark
         self.route = "other"
         self.fingerprint: str | None = None     # set by the planner
         self.tenant: str | None = None          # set by admission
         self.trace_id = trace_id
+        # the open tsd.phase annotation; None while no profile runs
+        self._ann = open_annotation("tsd.phase")
 
-    def mark(self, phase: str) -> None:
-        """Attribute time since the previous mark to ``phase``."""
+    def mark(self, phase: str, last: bool = False) -> None:
+        """Attribute time since the previous mark to ``phase``.  The
+        ``last`` mark of a request leaves no annotation open."""
         now = time.perf_counter()
+        cpu = time.thread_time()
+        spent = cpu - self._prev_cpu
         self.deltas[phase] = (self.deltas.get(phase, 0.0)
                               + (now - self._prev))
+        self.cpu[phase] = self.cpu.get(phase, 0.0) + spent
         self._prev = now
+        self._prev_cpu = cpu
         self.phase = phase
+        if self._ann is not None:
+            self.end_annotation(phase, spent)
+        if not last:
+            self._ann = open_annotation("tsd.phase")
+
+    def end_annotation(self, phase: str, cpu_s: float = 0.0) -> None:
+        """End the open tsd.phase event under its name."""
+        meta = {"phase": phase, "cpu_ms": cpu_s * 1e3}
+        if self.trace_id is not None:
+            meta["trace_id"] = self.trace_id
+        close_annotation(self._ann, **meta)
+        self._ann = None
 
     def phase_ms(self) -> dict[str, float]:
         """The full ordered phase set in milliseconds, zero-filled."""
         return {p: self.deltas.get(p, 0.0) * 1e3 for p in PHASES}
+
+    def cpu_ms(self) -> dict[str, float]:
+        """The handler thread's CPU milliseconds per phase, zero-filled."""
+        return {p: self.cpu.get(p, 0.0) * 1e3 for p in PHASES}
 
     def total_ms(self) -> float:
         return (self._prev - self.t0) * 1e3
@@ -149,13 +220,15 @@ def phase_in_flight() -> str | None:
 class _Profile:
     """One (route, fingerprint, tenant) key's streaming summary."""
 
-    __slots__ = ("key", "count", "last_seq", "hists")
+    __slots__ = ("key", "count", "last_seq", "hists", "cpu_ms")
 
     def __init__(self, key: tuple[str, str, str]):
         self.key = key
         self.count = 0
         self.last_seq = 0
         self.hists = {p: LogHistogram() for p in PHASES}
+        # cumulative CPU ms per phase, written under the engine's lock
+        self.cpu_ms = {p: 0.0 for p in PHASES}
 
     def to_json(self) -> dict:
         route, fingerprint, tenant = self.key
@@ -164,9 +237,7 @@ class _Profile:
         for p in PHASES:
             h = self.hists[p]
             _counts, count, total = h.snapshot()
-            phases[p] = {"count": count, "totalMs": total,
-                         "p50Ms": _finite(h.quantile(0.5)),
-                         "p99Ms": _finite(h.quantile(0.99))}
+            phases[p] = _phase_json(h, self.cpu_ms[p])
             tail = [{"traceId": label, "ms": value}
                     for _bound, label, value in h.exemplar_entries()]
             if tail:
@@ -182,6 +253,15 @@ class _Profile:
 
 def _finite(value: float) -> float:
     return value if value == value else 0.0      # NaN (empty) -> 0
+
+
+def _phase_json(hist: LogHistogram, cpu_ms: float) -> dict:
+    """One phase object of the report: wall time from its histogram,
+    CPU time cumulative like ``totalMs`` (no CPU percentiles)."""
+    _counts, count, total = hist.snapshot()
+    return {"count": count, "totalMs": total, "cpuMs": cpu_ms,
+            "p50Ms": _finite(hist.quantile(0.5)),
+            "p99Ms": _finite(hist.quantile(0.99))}
 
 
 class LatencyAttribution:
@@ -200,6 +280,7 @@ class LatencyAttribution:
         # cumulative per-phase milliseconds — the health engine's
         # phase-share window deltas read this  # guarded-by: _lock
         self._phase_total_ms = {p: 0.0 for p in PHASES}
+        self._phase_cpu_ms = {p: 0.0 for p in PHASES}  # guarded-by: _lock
         # global per-phase histograms (LogHistogram locks itself)
         self._overall = {p: LogHistogram() for p in PHASES}
         self._requests_cell = REGISTRY.counter(
@@ -217,11 +298,20 @@ class LatencyAttribution:
             "Cumulative milliseconds attributed to each request phase")
         self._phase_cells = {p: phase_fam.labels(phase=p)
                              for p in PHASES}
+        cpu_fam = REGISTRY.counter(
+            "tsd.latattr.phase_cpu_ms",
+            "Cumulative handler-thread CPU milliseconds spent in each "
+            "request phase")
+        self._cpu_cells = {p: cpu_fam.labels(phase=p) for p in PHASES}
 
     def observe(self, stamps: PhaseStamps) -> None:
         """Fold one finished request.  Called by RpcManager.handle_http
         after the trailing flush mark, on the handler thread."""
+        if stamps._ann is not None:
+            # no trailing mark(last=True) closed it: time no phase names
+            stamps.end_annotation("unmarked")
         deltas = stamps.phase_ms()
+        cpu = stamps.cpu_ms()
         key = (stamps.route, stamps.fingerprint or "-",
                stamps.tenant or "default")
         overflowed = False
@@ -244,12 +334,15 @@ class LatencyAttribution:
             profile.last_seq = seq
             for p in PHASES:
                 self._phase_total_ms[p] += deltas[p]
+                self._phase_cpu_ms[p] += cpu[p]
+                profile.cpu_ms[p] += cpu[p]
             live = len(self._profiles)
         exemplar = stamps.trace_id
         for p in PHASES:
             profile.hists[p].observe(deltas[p], exemplar=exemplar)
             self._overall[p].observe(deltas[p])
             self._phase_cells[p].inc(deltas[p])
+            self._cpu_cells[p].inc(cpu[p])
         self._requests_cell.inc()
         if overflowed:
             self._overflow_cell.inc()
@@ -275,6 +368,7 @@ class LatencyAttribution:
             requests = self._requests
             overflow = self._overflow
             profiles = list(self._profiles.values())
+            cpu_ms = dict(self._phase_cpu_ms)
         selected = []
         for profile in profiles:
             _route, key_fp, key_tenant = profile.key
@@ -286,13 +380,8 @@ class LatencyAttribution:
                 continue
             selected.append(profile)
         selected.sort(key=lambda pr: (-pr.count, pr.key))
-        overall: dict[str, dict] = {}
-        for p in PHASES:
-            h = self._overall[p]
-            _counts, count, total = h.snapshot()
-            overall[p] = {"count": count, "totalMs": total,
-                          "p50Ms": _finite(h.quantile(0.5)),
-                          "p99Ms": _finite(h.quantile(0.99))}
+        overall = {p: _phase_json(self._overall[p], cpu_ms[p])
+                   for p in PHASES}
         return {"seq": seq, "requests": requests,
                 "phases": list(PHASES),
                 "profileOverflow": overflow,

@@ -27,6 +27,14 @@ This module is a registered tsdbsan SANCTIONED_SITES entry: the
 device_wait sync is the trace path's one deliberate device->host
 rendezvous, and it must never count as a hidden hot-path sync.
 
+While a ``jax.profiler`` trace runs, every stack-managed span
+(``Trace.span()``/``stage()``, ``begin()``/``end()``) is also a
+``tsd.span`` event with a ``name`` stat on the handler thread's
+``/host:CPU`` line, inside latattr's ``tsd.phase`` events
+(obs/latattr.py; tools/trace_gaps.py reads both).  Cross-thread
+``Span.child()`` handles are not: an annotation begins and ends on one
+thread.
+
 Trace ids propagate across the cluster fan-out via the
 ``X-TSDB-Trace-Id`` header (tsd/cluster.py attaches it; handle_http
 adopts an incoming one), so one clustered query is one trace id across
@@ -41,6 +49,8 @@ import threading
 import time
 from contextlib import contextmanager
 
+from opentsdb_tpu.obs.latattr import close_annotation, open_annotation
+
 TRACE_HEADER = "x-tsdb-trace-id"
 
 
@@ -52,7 +62,7 @@ class Span:
     """One named stage; a node in the trace tree."""
 
     __slots__ = ("name", "tags", "children", "start", "wall_ms",
-                 "device_ms")
+                 "device_ms", "_ann")
 
     def __init__(self, name: str, **tags):
         self.name = name
@@ -61,6 +71,7 @@ class Span:
         self.start = time.perf_counter()
         self.wall_ms: float | None = None
         self.device_ms = 0.0
+        self._ann = None        # begin()'s open tsd.span annotation
 
     def finish(self) -> None:
         if self.wall_ms is None:
@@ -119,9 +130,11 @@ class Trace:
     def span(self, name: str, **tags):
         sp = self.current().child(name, **tags)
         self._stack.append(sp)
+        ann = open_annotation("tsd.span", name=name)
         try:
             yield sp
         finally:
+            close_annotation(ann)
             self._stack.pop()
             sp.finish()
 
@@ -133,6 +146,9 @@ class Trace:
         must stop accruing elapsed-so-far here — not render a
         forever-climbing wallMs at every later scrape."""
         self._finish_open(self.root)
+        for sp in reversed(self._stack):
+            close_annotation(sp._ann)
+            sp._ann = None
         del self._stack[1:]
 
     @staticmethod
@@ -194,6 +210,7 @@ def begin(name: str, **tags) -> Span | None:
         return None
     sp = tr.current().child(name, **tags)
     tr._stack.append(sp)
+    sp._ann = open_annotation("tsd.span", name=name)
     return sp
 
 
@@ -201,6 +218,8 @@ def end(span: Span | None) -> None:
     tr = active()
     if span is None or tr is None:
         return
+    close_annotation(span._ann)
+    span._ann = None
     if tr._stack and tr._stack[-1] is span:
         tr._stack.pop()
     span.finish()

@@ -125,13 +125,23 @@ def _load_library():
                      or (os.path.exists(src)
                          and os.path.getmtime(src) > os.path.getmtime(path)))
             if stale:
+                # build under a name of this process's own and rename it
+                # into place: several processes (pytest -n 6 on a fresh
+                # checkout) build at once, and a reader must never
+                # dlopen a file the linker is still writing
+                tmp = "%s.%d.tmp" % (_LIB_NAME, os.getpid())
+                tmp_path = os.path.join(_NATIVE_DIR, tmp)
                 try:
-                    subprocess.run(["make", "-C", _NATIVE_DIR, "-B"],
+                    subprocess.run(["make", "-C", _NATIVE_DIR, "-B",
+                                    "lib=" + tmp],
                                    capture_output=True, timeout=120,
                                    check=True)
+                    os.replace(tmp_path, path)
                 except (OSError, subprocess.SubprocessError) as e:
                     LOG.warning("native engine build failed (%s); falling "
                                 "back to the pure-Python snapshot codec", e)
+                    if os.path.exists(tmp_path):
+                        os.remove(tmp_path)
                     if not os.path.exists(path):
                         return None
         try:

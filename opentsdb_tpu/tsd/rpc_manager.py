@@ -270,7 +270,7 @@ class RpcManager:
             # the trailing mark absorbs the handler tail (reply
             # buffering, error envelope) so the phase deltas sum to
             # the handler wall time
-            stamps.mark("flush")
+            stamps.mark("flush", last=True)
             stamps.route = route
             self.tsdb.latattr.observe(stamps)
         status = query.response.status if query.response is not None else 0

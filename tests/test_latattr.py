@@ -273,6 +273,190 @@ class TestRingDropAccounting:
             assert event["phase"] in PHASES
 
 
+def _spin(seconds: float) -> None:
+    end = time.perf_counter() + seconds
+    while time.perf_counter() < end:
+        pass
+
+
+CPU_CASES = {"spin": _spin, "sleep": time.sleep}
+
+
+def _check_cpu_share(case: str, measure) -> None:
+    """`measure(work)` runs `work` inside a phase and returns that
+    phase's (cpu ms, wall ms).  A sleeping phase reads under 20 % CPU;
+    a spinning one reads what the clocks themselves say of the same
+    work — 100 % on an idle box, less where pytest -n 6 takes the core
+    away, which is why the share is not held to 1.0 outright."""
+    by_the_clocks = []
+
+    def work():
+        cpu0, wall0 = time.thread_time(), time.perf_counter()
+        CPU_CASES[case](0.05)
+        by_the_clocks.append((time.thread_time() - cpu0)
+                             / (time.perf_counter() - wall0))
+
+    cpu_ms, wall_ms = measure(work)
+    assert wall_ms >= 49.0
+    share = cpu_ms / wall_ms
+    if case == "sleep":
+        assert share < 0.2
+    else:
+        assert share == pytest.approx(by_the_clocks[0], abs=0.1)
+        assert share > 0.2
+
+
+class TestCpuTime:
+    """CPU time beside wall time: did the handler thread work or wait."""
+
+    @pytest.mark.parametrize("case", sorted(CPU_CASES))
+    def test_a_phase_reads_its_cpu_time_beside_its_wall_time(self, case):
+        def measure(work):
+            stamps = PhaseStamps()
+            stamps.mark("parse")
+            work()
+            stamps.mark("dispatch")
+            return stamps.cpu_ms()["dispatch"], \
+                stamps.phase_ms()["dispatch"]
+        _check_cpu_share(case, measure)
+
+    @pytest.mark.parametrize("case", sorted(CPU_CASES))
+    def test_a_handler_that_waits_reads_less_cpu_than_wall(
+            self, served, case):
+        """Through handle_http: a route that stamps nothing lands in
+        flush, cpuMs beside totalMs."""
+        tsdb, manager = served
+        inner = manager.http_commands["api/version"]
+
+        class Slow:
+            def execute_http(self, tsdb, query):
+                self.work()
+                inner.execute_http(tsdb, query)
+
+        slow = manager.http_commands["api/version"] = Slow()
+
+        def measure(work):
+            slow.work = work
+            before = tsdb.latattr.report()["overall"]["flush"]
+            assert ask(manager, "/api/version").status == 200
+            after = tsdb.latattr.report()["overall"]["flush"]
+            return (after["cpuMs"] - before["cpuMs"],
+                    after["totalMs"] - before["totalMs"])
+        _check_cpu_share(case, measure)
+
+    @pytest.mark.parametrize("phase", PHASES)
+    def test_cpu_ms_in_overall_and_every_profile(self, served, phase):
+        tsdb, manager = served
+        assert ask(manager, QUERY_URI).status == 200
+        report = latency_report(manager)
+        assert report["profiles"]
+        for summary in [report["overall"][phase]] + [
+                p["phases"][phase] for p in report["profiles"]]:
+            assert 0.0 <= summary["cpuMs"]
+            if summary["totalMs"] == 0.0:
+                assert summary["cpuMs"] == 0.0
+        # cumulative, like totalMs: a second request only adds
+        assert ask(manager, QUERY_URI).status == 200
+        again = latency_report(manager)["overall"][phase]
+        assert again["cpuMs"] >= report["overall"][phase]["cpuMs"]
+        if phase in ("parse", "plan", "serialize"):
+            assert again["cpuMs"] > 0.0
+
+    @pytest.mark.parametrize("phase", PHASES)
+    def test_prometheus_exports_the_cpu_counter_per_phase(
+            self, served, phase):
+        _tsdb, manager = served
+        assert ask(manager, QUERY_URI).status == 200
+        text = ask(manager, "/api/stats/prometheus").body.decode()
+        (line,) = [ln for ln in text.splitlines() if ln.startswith(
+            'tsd_latattr_phase_cpu_ms_total{phase="%s"}' % phase)]
+        cpu_ms = float(line.rsplit(" ", 1)[1])
+        assert cpu_ms > 0.0 if phase in ("parse", "plan", "serialize") \
+            else cpu_ms >= 0.0
+
+
+class _NoProfile:
+    """Stands where jax.profiler.TraceAnnotation does, and counts."""
+    made = 0
+
+    def __init__(self, *_a, **_kw):
+        type(self).made += 1
+
+    @staticmethod
+    def is_enabled() -> bool:
+        return False
+
+
+class TestProfilerAnnotations:
+    def test_nothing_is_created_while_no_profile_runs(
+            self, served, monkeypatch):
+        _tsdb, manager = served
+        monkeypatch.setattr(latattr, "_TraceAnnotation", _NoProfile)
+        _NoProfile.made = 0
+        assert ask(manager, QUERY_URI).status == 200
+        assert ask(manager, "/api/diag/latency").status == 200
+        assert _NoProfile.made == 0
+        assert latattr.open_annotation("tsd.phase") is None
+
+    def test_a_profile_holds_the_requests_phases_and_spans(
+            self, served, tmp_path):
+        """Under jax.profiler.start_trace a served /api/query leaves
+        one tsd.phase event per mark, named and in order, on the
+        handler thread's line, with the tracer's tsd.span events
+        inside the request."""
+        import glob
+
+        import jax
+        from jax.profiler import ProfileData
+        tsdb, manager = served
+        assert ask(manager, QUERY_URI).status == 200        # warm
+        seen = []
+        observe = tsdb.latattr.observe
+        tsdb.latattr.observe = lambda st: (seen.append(st), observe(st))
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            assert latattr.open_annotation("tsd.phase") is not None
+            response = ask(manager, QUERY_URI,
+                           headers={"x-tsdb-trace-id": "la-prof-1"})
+        finally:
+            jax.profiler.stop_trace()
+        assert response.status == 200
+        assert latattr.open_annotation("tsd.phase") is None
+        (stamps,) = seen
+        assert stamps._ann is None      # the last mark left none open
+        (path,) = glob.glob(str(
+            tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+        (host,) = [plane for plane in ProfileData.from_file(path).planes
+                   if plane.name == "/host:CPU"]
+        lines = [sorted((ev.start_ns, ev.start_ns + ev.duration_ns,
+                         ev.name, dict(ev.stats)) for ev in ln.events
+                        if ev.name in ("tsd.phase", "tsd.span"))
+                 for ln in host.lines]
+        (events,) = [mine for mine in lines if any(
+            e[3].get("trace_id") == "la-prof-1" for e in mine)]
+        # one thread, one line
+        phases = [e for e in events if e[2] == "tsd.phase"
+                  and e[3].get("trace_id") == "la-prof-1"]
+        spans = [e for e in events if e[2] == "tsd.span"]
+        names = [e[3]["phase"] for e in phases]
+        # every mark of the request, in serving order
+        assert set(names) == set(stamps.deltas)
+        assert names == sorted(names, key=PHASES.index)
+        assert {p for p, s in stamps.deltas.items() if s > 0} \
+            <= set(names)
+        for (_s0, e0, *_), (s1, *_rest) in zip(phases, phases[1:]):
+            assert e0 <= s1             # no two overlap
+        cpu = sum(e[3]["cpu_ms"] for e in phases)
+        assert cpu == pytest.approx(sum(stamps.cpu_ms().values()),
+                                    abs=1e-6)
+        assert {"scan", "pipeline", "serialize"} <= {
+            e[3]["name"] for e in spans}
+        lo, hi = phases[0][0], phases[-1][1]
+        assert all(lo <= s and e <= hi for s, e, *_ in spans)
+
+
 MAX_RATIO = 1.03
 NOISE_FLOOR_S = 0.25
 QUERIES_PER_BATCH = 30

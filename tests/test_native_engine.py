@@ -9,6 +9,9 @@ binary save/load, and the DiskPersistence native-codec snapshot.
 
 import json
 import os
+import shutil
+import subprocess
+import sys
 
 import numpy as np
 import pytest
@@ -189,3 +192,31 @@ class TestSnapshotIntegration:
         assert not os.path.exists(tmp_path / "series.tsdb")
         fresh = self._tsdb(tmp_path, native=False)
         assert fresh.store.num_series == 1
+
+
+_CHILD = """
+import sys
+from opentsdb_tpu.storage import native_engine
+native_engine._NATIVE_DIR = sys.argv[1]
+print(native_engine.available())
+"""
+
+
+def test_concurrent_first_build_never_loads_a_half_written_library(tmp_path):
+    """pytest -n 6 on a fresh checkout: every worker finds no .so and
+    builds.  Each builds under its own name and renames into place, so
+    all of them load a whole library."""
+    native = tmp_path / "native"
+    native.mkdir()
+    for name in ("Makefile", "engine.cpp"):
+        shutil.copy(os.path.join(native_engine._NATIVE_DIR, name), native)
+    env = dict(os.environ)
+    env.pop("TSDB_NATIVE_LIB", None)
+    env["PYTHONPATH"] = os.path.dirname(native_engine._NATIVE_DIR)
+    procs = [subprocess.Popen([sys.executable, "-c", _CHILD, str(native)],
+                              stdout=subprocess.PIPE, env=env, text=True)
+             for _ in range(4)]
+    outs = [p.communicate(timeout=300)[0].strip() for p in procs]
+    assert outs == ["True"] * 4
+    assert sorted(os.listdir(native)) == [
+        "Makefile", "engine.cpp", native_engine._LIB_NAME]
