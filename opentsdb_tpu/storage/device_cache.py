@@ -7,15 +7,22 @@ same regions, SaltScanner.java:269).  Here the roles are inverted: the
 store is host RAM, the accelerator is across a PCIe link, and the
 dominant cost of a repeated `/api/query` is re-uploading the same raw
 points every dispatch.  This cache pins each hot metric's columnar data
-in device HBM once; subsequent queries gather their [S, N] window batch
+in device HBM once; subsequent queries assemble their [S, N] window batch
 ON DEVICE in a single dispatch — zero host->device traffic for the data
-itself (only the tiny per-series start/length vectors travel).
+itself (only the tiny per-series start/length vectors travel).  A row of
+the batch is one contiguous run of the buffer, so the dispatch copies
+whole 128-element tile rows and shifts each row to its start; it takes
+no index per stored point (`_gather_windows`).
 
 Design:
 
   * One entry per metric: every series' normalized (ts, val) columns
-    concatenated into two 1-D device buffers (padded to pow2 length to
-    bound gather recompiles), plus host-side row offsets.
+    concatenated whole into two 1-D device buffers (padded to pow2
+    length, >= 1024, to bound the batch program's recompiles and to keep
+    the buffer a whole number of tile rows), plus host-side row offsets.
+    The tail padding guarantees nothing to a reader: a row that runs
+    past the data's or the buffer's end does so beyond its length, under
+    the mask.
   * Consistency is by content-version, not locks: `Series.snapshot()`
     captures (data, version) atomically; at query time
     `Series.window_bounds()` returns (lo, hi, version) atomically.  A
@@ -434,10 +441,77 @@ def _to_device(arr: np.ndarray):
     return jax.device_put(arr)
 
 
+# One TPU tile row.  The 1-D [P] buffer viewed [P/128, 128] is a bitcast
+# on the chip (a 256-wide or wider view compiles to a copy of the whole
+# buffer), and a row of that view is the unit the chip copies in one
+# piece.  Origin: PR 25's race on a v5e (PERF.md §6) — whole tile rows
+# plus a shift won or tied at every shape against one index per point
+# (4000 x 8192: 42 ms against 2930 ms) and against one loop step per
+# series row, so there is no second form and no crossover.
+_TILE = 128
+
 # compiled gather programs keyed by (padded N, compaction flag) — the
-# closure reads only module constants (PAD_TS / I32_PAD_TS), so there
-# is nothing to invalidate  # cache: gather-programs invalidated-by: none
+# closure reads only module constants (PAD_TS / I32_PAD_TS / _TILE), so
+# there is nothing to invalidate
+# cache: gather-programs invalidated-by: none
 _GATHER_CACHE: dict = {}
+
+
+def _gather_program(n: int, compact: bool):
+    """The jitted (tb, vb, starts, lengths, base) -> (ts, val, mask)
+    batch assembly for padded row length `n`, memoized per (n, compact);
+    jit itself specializes it per buffer length and row count."""
+    import jax
+    import jax.numpy as jnp
+
+    key = (n, compact)
+    fn = _GATHER_CACHE.get(key)
+    if fn is not None:
+        return fn
+    # tile rows that cover [start, start + n) wherever start falls in
+    # its first tile: lane offset <= _TILE - 1
+    tiles = (n + 2 * _TILE - 2) // _TILE
+
+    def gather(tb, vb, st, ln, base):
+        m = jnp.arange(n, dtype=jnp.int64)[None, :] < ln[:, None]
+        st = st.astype(jnp.int32 if tb.shape[0] < 2**31 else jnp.int64)
+        lane = st % _TILE
+        # laid [tiles, S]: the [S, tiles] matrix's flatten takes XLA:TPU
+        # tens of seconds to compile at S = 100 000
+        rows = jnp.arange(tiles, dtype=st.dtype)[:, None] \
+            + (st // _TILE)[None, :]
+
+        def copy_rows(buf):
+            if buf.shape[0] % _TILE:
+                # never a cache entry (pow2 >= 1024 long): a caller's own
+                # odd-length buffer, padded by a copy of it
+                buf = jnp.pad(buf, (0, -buf.shape[0] % _TILE))
+            # a row index past the buffer's end clamps on its own: what
+            # it brings lies at or past start + length, under the mask
+            x = jnp.take(buf.reshape(-1, _TILE), rows, axis=0, mode="clip")
+            x = x.transpose(1, 0, 2).reshape(st.shape[0], tiles * _TILE)
+            # out[i, j] = x[i, lane[i] + j]: a barrel shift, one bit of
+            # the lane offset a step
+            k = _TILE // 2
+            while k:
+                x = jnp.where(((lane & k) != 0)[:, None],
+                              x[:, k:], x[:, :-k])
+                k //= 2
+            return x[:, :n]
+
+        if compact:
+            off = jnp.clip(copy_rows(tb) - base, 0, I32_PAD_TS) \
+                .astype(jnp.int32)
+            ts = jnp.where(m, off, I32_PAD_TS)
+        else:
+            ts = jnp.where(m, copy_rows(tb), PAD_TS)
+        val = jnp.where(m, copy_rows(vb), 0.0)
+        return ts, val, m
+    # memoized per (N, compaction) in _GATHER_CACHE just above — the
+    # wrapper is constructed once per padded batch shape, not per call
+    fn = jax.jit(gather)  # tsdblint: disable=jax-jit-per-call
+    _GATHER_CACHE[key] = fn
+    return fn
 
 
 def _gather_windows(ts_buf, val_buf, starts, lengths, n: int,
@@ -446,38 +520,20 @@ def _gather_windows(ts_buf, val_buf, starts, lengths, n: int,
 
     out[i, j] = buf[starts[i] + j] masked to j < lengths[i]; pads mirror
     build_batch (PAD_TS timestamps keep rows sorted for the prefix path).
-    Compiled once per (buffer length, N) — both pow2-padded.
+    Every row is one contiguous run of the buffer (series are
+    concatenated whole at build), so it is copied as whole 128-element
+    tile rows and shifted to its start — no per-point index.  `starts`
+    may be anything where `lengths` is 0, and a row may run past the
+    buffer's end beyond its length: neither is read under the mask.
+    Compiled once per (buffer length, S, N) — buffer and N pow2-padded.
 
     With `ts_base`, timestamps come back as int32 offsets from the base
-    (the compaction fused into this gather — the query dispatch already
+    (the compaction fused into this program — the query dispatch already
     paying for this data pass makes the sub+cast free, r4 attribution):
     pads sit at the int32 clip ceiling, past every window edge.
     """
-    import jax
     import jax.numpy as jnp
 
-    key = (n, ts_base is not None)
-    fn = _GATHER_CACHE.get(key)
-    if fn is None:
-        i32_ceiling = I32_PAD_TS
-
-        def gather(tb, vb, st, ln, base):
-            j = jnp.arange(n, dtype=jnp.int64)
-            idx = st[:, None] + j[None, :]
-            m = j[None, :] < ln[:, None]
-            safe = jnp.clip(idx, 0, tb.shape[0] - 1)
-            if ts_base is None:
-                ts = jnp.where(m, tb[safe], PAD_TS)
-            else:
-                off = jnp.clip(tb[safe] - base, 0, i32_ceiling) \
-                    .astype(jnp.int32)
-                ts = jnp.where(m, off, i32_ceiling)
-            val = jnp.where(m, vb[safe], 0.0)
-            return ts, val, m
-        # memoized per (N, compaction) in _GATHER_CACHE just above — the
-        # wrapper is constructed once per padded batch shape, not per call
-        fn = jax.jit(gather)  # tsdblint: disable=jax-jit-per-call
-        _GATHER_CACHE[key] = fn
     base = jnp.asarray(0 if ts_base is None else ts_base, jnp.int64)
-    return fn(ts_buf, val_buf, jnp.asarray(starts), jnp.asarray(lengths),
-              base)
+    return _gather_program(n, ts_base is not None)(
+        ts_buf, val_buf, jnp.asarray(starts), jnp.asarray(lengths), base)

@@ -340,3 +340,78 @@ class TestGatherCorrectness:
         np.testing.assert_array_equal(ts_d[mask_d], ts_h[mask_h])
         np.testing.assert_array_equal(val_d[mask_d], val_h[mask_h])
         assert (ts_d[~mask_d] == PAD_TS).all()
+
+
+def _gather_cases(p=8192):
+    """name -> (buffer length, data length, N, starts, lengths).  The
+    buffer holds `data` real points and pads behind them, as an entry's
+    does; 2500 and 3000 are no multiple of the 128-element tile."""
+    return {
+        "zero_length_row": (p, 6000, 64, [100, -7, p + 900, 3000],
+                            [40, 0, 0, 64]),
+        "lengths_equal_n": (p, 6000, 256, [0, 513, 2900], [256, 256, 256]),
+        "one_row": (p, 6000, 512, [1234], [300]),
+        "n_8": (p, 6000, 8, [5, 1021, 127, 4090], [8, 3, 1, 7]),
+        "n_4096": (p, 8000, 4096, [0, 3001, 3904], [4096, 2500, 4096]),
+        "unequal_lengths": (p, 6000, 128, [0, 129, 700, 2049, 5000],
+                            [1, 128, 77, 5, 100]),
+        "row_passes_the_data_end": (p, 6000, 1024, [5900, 5000, 100],
+                                    [100, 1000, 1024]),
+        "row_passes_the_buffer_end": (p, p, 1024, [p - 1, p - 300, p - 1024],
+                                      [1, 300, 1024]),
+        "starts_off_the_tile": (p, 6000, 256, [1, 127, 129, 255, 383, 2500],
+                                [256, 200, 256, 31, 256, 255]),
+        "starts_on_the_tile": (p, 6000, 256, [0, 128, 1024, 4096],
+                               [256, 256, 100, 1]),
+        "buffer_off_the_tile": (3000, 3000, 512, [0, 2999, 2600, 1777],
+                                [512, 1, 400, 512]),
+    }
+
+
+GATHER_CASES = _gather_cases()
+
+
+class TestGatherParity:
+    """_gather_windows against a plain per-element numpy reference,
+    bit for bit on ts, val and mask, pads included."""
+
+    @pytest.mark.parametrize("ts_base", [None, 1_356_998_400_000 + 1_234],
+                             ids=["int64", "ts_base"])
+    @pytest.mark.parametrize("case", sorted(GATHER_CASES))
+    def test_gather_equals_the_per_element_reference(self, case, ts_base):
+        import jax.numpy as jnp
+        from opentsdb_tpu.storage.device_cache import (
+            I32_PAD_TS, PAD_TS, _gather_windows)
+        p, data, n, starts, lengths = GATHER_CASES[case]
+        starts = np.asarray(starts, np.int64)
+        lengths = np.asarray(lengths, np.int64)
+        rng = np.random.default_rng(sorted(GATHER_CASES).index(case))
+        ts_buf = np.full(p, PAD_TS, np.int64)
+        # some stamps lie before the base and some 2^31 ms past it: the
+        # int32 clip is hit at both ends
+        ts_buf[:data] = 1_356_998_400_000 + np.cumsum(
+            rng.integers(0, 2_000_000, data))
+        val_buf = np.zeros(p)
+        val_buf[:data] = rng.normal(size=data) * 1e6
+        val_buf[:data:97] = -0.0
+
+        j = np.arange(n)
+        mask = j[None, :] < lengths[:, None]
+        at = np.clip(starts[:, None] + j[None, :], 0, p - 1)
+        if ts_base is None:
+            ts = np.where(mask, ts_buf[at], PAD_TS)
+        else:
+            ts = np.where(mask, np.clip(ts_buf[at] - ts_base, 0, I32_PAD_TS),
+                          I32_PAD_TS).astype(np.int32)
+        val = np.where(mask, val_buf[at], 0.0)
+
+        got_ts, got_val, got_mask = (np.asarray(a) for a in _gather_windows(
+            jnp.asarray(ts_buf), jnp.asarray(val_buf), starts, lengths, n,
+            ts_base))
+        assert got_ts.dtype == ts.dtype and got_ts.shape == ts.shape
+        assert got_val.dtype == np.float64 and got_mask.dtype == np.bool_
+        np.testing.assert_array_equal(got_mask, mask)
+        np.testing.assert_array_equal(got_ts, ts)
+        # bit-equal, signed zeros included
+        np.testing.assert_array_equal(got_val.view(np.int64),
+                                      val.view(np.int64))

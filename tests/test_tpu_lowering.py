@@ -16,6 +16,14 @@ does not come back is a timeout here, not a hung suite.
 Tiny shapes: this pins what lowers, not how fast or into how much vmem.
 The default backend is the CPU, so `auto` kernel modes are priced from
 the CPU table — collectives and dtypes are what is under test.
+
+The device cache's gather is the exception: it compiles on ONE described
+chip at the benchmark cells' own shapes (2^26-point buffers, 4000 rows,
+N = 256 / 2048 / 8192, both timestamp layouts), and the compiled text is
+read for its structure — whole 128-element tile rows, no gather that
+takes one index per stored point (PR 25: that form held the chip 83-97 %
+of its busy time).  A CPU run cannot time the chip; it can read what the
+chip's compiler emitted.
 """
 
 import json
@@ -43,22 +51,33 @@ CASE_NAMES = ["query:%s:%s%s" % (a, d, ":rate" if r else "")
     "rollup", "group_downsample:min", "group_downsample:max",
     "group_downsample:sum"]
 
+# the device cache's gather at the benchmark cells' shapes
+GATHER_BUFFER, GATHER_ROWS = 1 << 26, 4000
+GATHER_CASES = [(n, compact) for n in (256, 2048, 8192)
+                for compact in (False, True)]
+GATHER_NAMES = ["gather:n%d:%s" % (n, "ts_base" if compact else "int64")
+                for n, compact in GATHER_CASES]
+
 
 def _compile_all() -> None:
     """Child: compile every case for v5e 2x2, one JSON line each."""
     sys.path.insert(0, REPO)
+    import re
+
     import numpy as np
 
     import jax
     import jax.numpy as jnp
     from jax.experimental import topologies
-    from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.sharding import (NamedSharding, PartitionSpec as P,
+                              SingleDeviceSharding)
 
     from opentsdb_tpu.ops.downsample import FixedWindows
     from opentsdb_tpu.ops.pipeline import DownsampleStep, PipelineSpec
     from opentsdb_tpu.ops.rate import RateOptions
     from opentsdb_tpu.parallel import make_mesh, sharded
     from opentsdb_tpu.parallel.mesh import AXIS_SERIES, AXIS_TIME
+    from opentsdb_tpu.storage.device_cache import _gather_program
 
     try:
         topo = topologies.get_topology_desc(topology_name="v5e:2x2",
@@ -112,6 +131,28 @@ def _compile_all() -> None:
             print(json.dumps({"case": name, "ok": False,
                               "error": str(e)[:400]}), flush=True)
 
+    chip = SingleDeviceSharding(topo.devices[0])
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    for name, (n, compact) in zip(GATHER_NAMES, GATHER_CASES):
+        try:
+            text = _gather_program(n, compact).lower(
+                on_chip((GATHER_BUFFER,), jnp.int64),
+                on_chip((GATHER_BUFFER,), jnp.float64),
+                on_chip((GATHER_ROWS,), jnp.int64),
+                on_chip((GATHER_ROWS,), jnp.int64),
+                on_chip((), jnp.int64)).compile().as_text()
+            sizes = re.findall(r" gather\(.*slice_sizes=\{([0-9,]*)\}", text)
+            print(json.dumps({"case": name, "ok": True,
+                              "gather_slice_sizes": sorted(sizes),
+                              "whiles": len(re.findall(r" while\(", text))}),
+                  flush=True)
+        except Exception as e:  # noqa: BLE001 — the verdict under test
+            print(json.dumps({"case": name, "ok": False,
+                              "error": str(e)[:400]}), flush=True)
+
 
 @pytest.fixture(scope="module")
 def verdicts():
@@ -134,6 +175,17 @@ def verdicts():
 @pytest.mark.parametrize("case", CASE_NAMES)
 def test_mesh_program_lowers_for_v5e_2x2(verdicts, case):
     assert verdicts[case]["ok"], verdicts[case].get("error")
+
+
+@pytest.mark.parametrize("case", GATHER_NAMES)
+def test_cache_gather_copies_tile_rows_on_a_v5e(verdicts, case):
+    """Four gathers (int64 and float64 buffers, two 32-bit halves each),
+    every one of whole 128-element tile rows; none with one index per
+    point, and no loop with a step per series row."""
+    verdict = verdicts[case]
+    assert verdict["ok"], verdict.get("error")
+    assert verdict["gather_slice_sizes"] == ["1,128"] * 4, verdict
+    assert verdict["whiles"] == 0, verdict
 
 
 if __name__ == "__main__":
