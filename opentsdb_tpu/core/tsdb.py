@@ -73,6 +73,10 @@ class TSDB:
         self.store = MemStore(
             salt_buckets=self.config.salt_buckets,
             fix_duplicates=self.config.fix_duplicates)
+        # the planner's memo of resolved and grouped series selections
+        # (query/planner.py::_Selection), valid per store generation
+        from opentsdb_tpu.query.planner import SelectionMemo
+        self.selections = SelectionMemo()
         from opentsdb_tpu.storage.device_cache import DeviceSeriesCache
         self.device_cache = (
             DeviceSeriesCache(

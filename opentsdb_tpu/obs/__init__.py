@@ -70,6 +70,26 @@ METRICS_SCHEMA: dict[str, MetricSpec] = {
     "tsd.query.count": _m(
         "counter", ("status",),
         "/api/query requests served, by response status."),
+    "tsd.query.series": _m(
+        "counter", (),
+        "Rows (member series) dispatched by grouped downsample queries: "
+        "over tsd.http.requests of api/query, the width of a request."),
+    "tsd.query.groups": _m(
+        "counter", (),
+        "Groups answered by grouped downsample queries."),
+    "tsd.query.stage_ms": _m(
+        "counter", ("stage",),
+        "Cumulative wall milliseconds of the planner's host stages, "
+        "tracing on or off: scan (resolve + group, or their memo), "
+        "count (per-row point counts, budget), extract, assemble."),
+    "tsd.query.group_reduce": _m(
+        "counter", ("mode",),
+        "Grouped dispatches of the monolithic pipeline, by the "
+        "group-reduce form the chooser took for their shape (segment, "
+        "sorted, matmul, rows)."),
+    "tsd.http.response_bytes": _m(
+        "counter", ("route",),
+        "Response body bytes written, by registered route."),
     "tsd.query.latency_ms": _m(
         "histogram", ("tenant",),
         "End-to-end /api/query latency in milliseconds, by clamped "

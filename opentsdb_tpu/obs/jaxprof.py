@@ -245,7 +245,8 @@ _segments: deque = deque(maxlen=SEGMENT_RING)
 
 def segment_decisions(platform: str, s: int, n: int, w: int, g: int,
                       ds_function: str | None,
-                      aggregator: str | None = None) -> dict[str, dict]:
+                      aggregator: str | None = None,
+                      row_groups: bool = False) -> dict[str, dict]:
     """The kernel strategy decisions one grouped dispatch of shape
     [s series, n points] -> [w windows, g groups] makes, per kernel
     axis — recomputed through the SAME `_effective_*` choosers the
@@ -277,7 +278,8 @@ def segment_decisions(platform: str, s: int, n: int, w: int, g: int,
     else:
         out["scan"] = ds.scan_decision(s, n, e, platform)
     out["group"] = ga.group_decision(s, w, g, platform,
-                                     extremes=group_extremes)
+                                     extremes=group_extremes,
+                                     row_groups=row_groups)
     return out
 
 

@@ -50,6 +50,7 @@ import time
 from contextlib import contextmanager
 
 from opentsdb_tpu.obs.latattr import close_annotation, open_annotation
+from opentsdb_tpu.obs.registry import REGISTRY
 
 TRACE_HEADER = "x-tsdb-trace-id"
 
@@ -192,6 +193,23 @@ def stage(name: str, **tags):
         return
     with tr.span(name, **tags) as sp:
         yield sp
+
+
+@contextmanager
+def timed_stage(name: str, **tags):
+    """A `stage()` whose wall time is also summed, traced or not, into
+    the counter tsd.query.stage_ms{stage=name}: what the planner's
+    host stages cost per series and per group is read from two scrapes
+    of /api/stats/prometheus."""
+    t0 = time.perf_counter()
+    try:
+        with stage(name, **tags) as sp:
+            yield sp
+    finally:
+        REGISTRY.counter(
+            "tsd.query.stage_ms", "Cumulative wall milliseconds of the "
+            "planner's host stages").labels(stage=name).inc(
+                (time.perf_counter() - t0) * 1e3)
 
 
 def annotate(span: Span | None, **tags) -> None:

@@ -81,9 +81,25 @@ import threading
 #   elem_f64      0.018s / (S*N=6.71e7)              raw f64 elementwise
 #   win_gather    (0.130-0.100)s / (S*E=5.26e5)      flat windowed-sum
 #                                                    minus its cumsum
-#   seg_scatter   0.219s / (S*W=5.24e5)              group segment stage
-#   mxu_cell      0.100s / (G*S*W=5.24e9)            group matmul stage
-#   sorted_grid   0.090s / (S*W=5.24e5)              group sorted stage
+#   seg_scatter, mxu_cell, sorted_grid: re-anchored on this
+#   installation's v5e by PR 27's race of ops/group_agg.py's
+#   grid_group_aggregate (PERF.md section 6; median ms of 7 calls, the
+#   whole tail at the shape, rows sorted by group):
+#     [S, W] -> G (padded)       segment   sorted   matmul
+#     [100 000, 16] -> 9 (16)     347.5     11.9     17.7   sum
+#     [4000, 128]   -> 9 (16)      81.9      1.23     5.94  sum
+#     [100 000, 8]  -> 10^5 (2^17) 128.3    38.6      -     avg
+#     [4000, 16]    -> 4000 (2^12)  11.4     2.41     -     avg
+#   seg_scatter 1.6-2.2e-7 per S*W at all four; mxu_cell 6.9-7.3e-10
+#   per G*S*W at both; sorted 4.8e-8 and 3.8e-8 per S*W where G ~ S
+#   and 7.5e-9 / 2.4e-9 at G = 9.  sorted beats segment at every raced
+#   shape (3-67x) and, in run time, matmul at G = 9 (1.5x / 4.8x) —
+#   but in the served pipeline its program took XLA:TPU ~10 minutes to
+#   compile at [100 000, 16] (a cold cell's set-up 1339 s against 431
+#   with matmul there, which compiles in 7 s), for 6 ms of a 250 ms
+#   request.  So sorted_grid is anchored at its worst raced shape
+#   ([100 000, 8] -> 131 072: 38.6 ms), which ranks it under segment
+#   everywhere and keeps matmul below G ~ 68, as before.
 #   ext_scan      0.52s/dispatch vs ext_segment 7.09s — modeled per
 #                 grid element over S*N
 # CPU anchors are this dev box (differential suite timings): searchsorted
@@ -105,9 +121,12 @@ DEFAULT_COSTS: dict[str, dict[str, float]] = {
         # CPU prefix pass is 8x elem-cost — the chip may disappoint too)
         "sub2_elem": 3.5e-10,
         "win_gather": 5.7e-8,
-        "seg_scatter": 4.2e-7,
-        "mxu_cell": 1.9e-9,
-        "sorted_grid": 1.7e-7,
+        "seg_scatter": 1.8e-7,
+        "mxu_cell": 7.0e-10,
+        "sorted_grid": 4.8e-8,
+        # one member a group (group_agg form "rows"): 1.19 ms at
+        # [100 000, 8] in the same race, fixed costs included
+        "rows_grid": 1.5e-9,
         # blocked level-masked fold (mode "sorted2"): ESTIMATE (~0.4x
         # sorted — half the full-width levels, no pair-op selects/bool
         # channel) until a chip race records it; deliberately not an
@@ -166,6 +185,7 @@ DEFAULT_COSTS: dict[str, dict[str, float]] = {
         "mxu_cell": 1.0e-9,      # no MXU: dense [G,S]x[S,W] is real FLOPs
         "sorted_grid": 1.0e-8,
         "sorted2_grid": 1.0e-8,  # estimate; not an auto candidate yet
+        "rows_grid": 1.0e-9,     # a copy: elementwise class
 
         "ext_scan_elem": 4.0e-9,
         "ext_seg_elem": 2.0e-9,
@@ -511,6 +531,8 @@ def features_group(mode: str, s: int, w: int, g: int
         return {"sorted_grid": float(s * w)}
     if mode == "sorted2":
         return {"sorted2_grid": float(s * w)}
+    if mode == "rows":      # one member a group: a copy of the grid
+        return {"rows_grid": float(s * w)}
     raise ValueError("unknown group mode: " + mode)
 
 

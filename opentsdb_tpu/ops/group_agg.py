@@ -76,6 +76,12 @@ def _identity(x):
 # All are float64 (Java-double contract); the sum order differs so
 # results can drift in the last ulp.  TSDB_GROUP_REDUCE_MODE forces a
 # mode for an A/B (bench_prefix.py).
+# "rows" is no mode to choose: where the caller guarantees that row i IS
+# group i (one member a group, the whole batch in one dispatch: a
+# group-by over every host of a fleet), the reduction is a copy of the
+# [S, W] grid into [G, W].  Raced on a v5e at [100 000, 8] -> G 131 072:
+# 1.2 ms against sorted 38.6 and segment 128.3; at [4000, 16] -> G 4096:
+# 1.1 against 2.4 and 11.4 (PERF.md section 6, PR 27).
 import os as _os
 
 _GROUP_REDUCE_MODES = ("auto", "segment", "matmul", "sorted", "sorted2")
@@ -123,7 +129,8 @@ def _group_candidates(s: int, g: int, extremes: bool) -> list[str]:
 
 def _effective_group_reduce_mode(s: int, w: int, g: int,
                                  extremes: bool = False,
-                                 platform: str | None = None) -> str:
+                                 platform: str | None = None,
+                                 row_groups: bool = False) -> str:
     """The group-combine strategy for this shape: 'auto' (default) ranks
     segment/sorted/(feasible) matmul with the calibrated cost model
     (ops.costmodel — chip anchors: segment scatter 219ms, matmul ~100ms
@@ -135,6 +142,8 @@ def _effective_group_reduce_mode(s: int, w: int, g: int,
     mode = _GROUP_REDUCE_MODE
     if mode != "auto":
         return mode
+    if row_groups:
+        return "rows"
     from opentsdb_tpu.ops.hostlane import execution_platform
     from opentsdb_tpu.ops import costmodel
     return costmodel.choose_group(s, w, g, platform
@@ -143,7 +152,8 @@ def _effective_group_reduce_mode(s: int, w: int, g: int,
 
 
 def group_decision(s: int, w: int, g: int, platform: str,
-                   extremes: bool = False) -> dict:
+                   extremes: bool = False,
+                   row_groups: bool = False) -> dict:
     """The group-reduce strategy decision for one dispatch shape, as
     the trace annotates it (same report shape as
     downsample.search_decision).  An explicit matmul on an infeasible
@@ -151,12 +161,15 @@ def group_decision(s: int, w: int, g: int, platform: str,
     dispatched form."""
     from opentsdb_tpu.ops import costmodel
     from opentsdb_tpu.ops.downsample import _decision_report
-    mode = _effective_group_reduce_mode(s, w, g, extremes, platform)
+    mode = _effective_group_reduce_mode(s, w, g, extremes, platform,
+                                        row_groups)
     if mode == "matmul" and (extremes or not _matmul_feasible(s, g)):
         mode = "segment"    # the call-site feasibility fallback
     cands = _group_candidates(s, g, extremes)
     if _GROUP_REDUCE_MODE == "sorted2":
         cands = cands + ["sorted2"]     # explicit-only mode: price it
+    if mode == "rows":
+        cands = ["rows"] + cands        # a guarantee, not a ranking
     return _decision_report(
         "group", mode, _GROUP_REDUCE_MODE, cands, platform,
         lambda m: costmodel.predict_group(m, s, w, g, platform))
@@ -263,6 +276,29 @@ class _SortedGroups:
                                        self.s, jnp.maximum, -jnp.inf)
         return _blocked_group_fold(xs, self.flags, self.bounds, self.s,
                                    jnp.minimum, jnp.inf)
+
+
+class _RowGroups:
+    """_SortedGroups where row i IS group i (form "rows"): every fold of
+    a run is the row itself, so each is a copy of [S, W] into [G, W]
+    (zero rows up to G: they carry no count and are masked out)."""
+
+    def __init__(self, num_groups: int):
+        self.g = num_groups
+
+    def sum(self, x2d):
+        s = x2d.shape[0]
+        return x2d[:self.g] if s >= self.g \
+            else jnp.pad(x2d, ((0, self.g - s), (0, 0)))
+
+    def extreme(self, x2d, want_max: bool):
+        return self.sum(x2d)
+
+
+def _run_folds(mode: str, gid, num_groups: int, s: int, rows_sorted: bool):
+    """The fold machinery of the scatter-free forms."""
+    return _RowGroups(num_groups) if mode == "rows" \
+        else _SortedGroups(gid, num_groups, s, rows_sorted)
 
 
 _SORTED2_K = 8          # rows per block in the blocked reset-scan
@@ -421,7 +457,8 @@ def _flat_segments(contrib, participate, gid, num_groups: int):
 def moment_group_reduce(agg_name: str, contrib, participate, gid,
                         num_groups: int, combine_sum=_identity,
                         combine_min=_identity, combine_max=_identity,
-                        rows_sorted: bool = False):
+                        rows_sorted: bool = False,
+                        row_groups: bool = False):
     """[S, W] -> ([G, W] out, [G, W] count) for moment-decomposable aggs.
 
     `combine_*` inject the cross-chip collectives (psum/pmin/pmax over the
@@ -435,15 +472,16 @@ def moment_group_reduce(agg_name: str, contrib, participate, gid,
     g = num_groups
     num = g * w
     extremes = agg_name in ("min", "mimmin", "max", "mimmax")
-    mode = _effective_group_reduce_mode(s, w, g, extremes=extremes)
+    mode = _effective_group_reduce_mode(s, w, g, extremes=extremes,
+                                        row_groups=row_groups)
 
     if extremes:
         want_max = agg_name in ("max", "mimmax")
-        if mode in ("sorted", "sorted2"):
+        if mode in ("sorted", "sorted2", "rows"):
             # contiguous-run reset-scan over group-sorted rows: no
             # scatter.  sorted2 = the blocked fold, with native-int32
-            # counts (exact: counts <= S).
-            sg = _SortedGroups(gid, g, s, rows_sorted)
+            # counts (exact: counts <= S).  rows = every run is one row.
+            sg = _run_folds(mode, gid, g, s, rows_sorted)
             fold = sg.sum2 if mode == "sorted2" else sg.sum
             cdt = jnp.int32 if mode == "sorted2" else jnp.float64
             vf0 = contrib.astype(jnp.float64)
@@ -485,8 +523,8 @@ def moment_group_reduce(agg_name: str, contrib, participate, gid,
     ok2 = participate & ~jnp.isnan(vf)
     v2 = jnp.where(ok2, vf, 0.0)
     use_matmul = mode == "matmul" and _matmul_feasible(s, g)
-    if mode in ("sorted", "sorted2"):
-        sg = _SortedGroups(gid, g, s, rows_sorted)
+    if mode in ("sorted", "sorted2", "rows"):
+        sg = _run_folds(mode, gid, g, s, rows_sorted)
         fold = sg.sum2 if mode == "sorted2" else sg.sum
 
         def gsum(x2d):   # [S, W] -> [G, W], cross-chip combined
@@ -640,7 +678,8 @@ def ordered_group_reduce(agg_name: str, contrib, participate, gid,
 
 # shape: grid_ts[W] i64, val[S,W] any, mask[S,W] bool, gid[S] any
 def grid_group_aggregate(grid_ts, val, mask, gid, num_groups: int,
-                         agg: Aggregator, rows_sorted: bool = False):
+                         agg: Aggregator, rows_sorted: bool = False,
+                         row_groups: bool = False):
     """All-groups-at-once grid aggregation (single-device form).
 
     [S, W] batch + gid[S] -> (grid_ts[W], out[G, W], out_mask[G, W]).
@@ -651,13 +690,17 @@ def grid_group_aggregate(grid_ts, val, mask, gid, num_groups: int,
     rows_sorted=True is a CALLER GUARANTEE that gid is non-decreasing
     (the planner always builds it that way, planner.py:403) — the sorted
     modes then skip the argsort and the [S, W] permute gathers.  A false
-    claim silently misassigns rows to groups.
+    claim silently misassigns rows to groups.  row_groups=True is the
+    stronger guarantee that gid[i] == i for every row that carries data
+    (one member a group, the whole batch in this one dispatch): the
+    moment reductions and the mask pass are then copies.
     """
     vf = val.astype(jnp.float64)
     contrib, participate = grid_contributions(grid_ts, vf, mask, agg)
     if is_moment_agg(agg.name):
         out, _ = moment_group_reduce(agg.name, contrib, participate, gid,
-                                     num_groups, rows_sorted=rows_sorted)
+                                     num_groups, rows_sorted=rows_sorted,
+                                     row_groups=row_groups)
     else:
         out, _ = ordered_group_reduce(agg.name, contrib, participate, gid,
                                       num_groups)
@@ -670,12 +713,13 @@ def grid_group_aggregate(grid_ts, val, mask, gid, num_groups: int,
     extreme_agg = agg.name in ("min", "mimmin", "max", "mimmax")
     mask_mode = _effective_group_reduce_mode(
         s, w, num_groups,
-        extremes=is_moment_agg(agg.name) and extreme_agg)
-    if mask_mode in ("sorted", "sorted2"):
+        extremes=is_moment_agg(agg.name) and extreme_agg,
+        row_groups=row_groups)
+    if mask_mode in ("sorted", "sorted2", "rows"):
         # same fold machinery as the reduce (XLA CSEs the repeated
         # argsort/bounds); sorted2 presence rides native int32 adds.
         # Both fold exact integer counts, so > 0 is the same test.
-        sg = _SortedGroups(gid, num_groups, s, rows_sorted)
+        sg = _run_folds(mask_mode, gid, num_groups, s, rows_sorted)
         present = (sg.sum2(mask.astype(jnp.int32))
                    if mask_mode == "sorted2"
                    else sg.sum(mask.astype(jnp.float64)))

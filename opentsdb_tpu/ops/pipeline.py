@@ -49,6 +49,10 @@ class PipelineSpec:
     # always emits groups as concatenated runs, planner.py:403) — the
     # sorted group-reduce modes then skip argsort + permute gathers
     rows_sorted: bool = False
+    # caller guarantee: row i of the batch IS group i (one member a
+    # group) and the whole batch travels in this one dispatch — the
+    # group reduce is then a copy (ops/group_agg.py, form "rows")
+    row_groups: bool = False
 
 
 def _pipeline(spec: PipelineSpec, ts, val, mask, wargs):
@@ -175,7 +179,8 @@ def _grid_tail(spec: PipelineSpec, num_groups: int, wts, v, m, gid):
         grid_b = jnp.broadcast_to(grid[None, :], v.shape)
         _, v, m = rate(grid_b, v, m, spec.rate, all_int=False)
     return grid_group_aggregate(grid, v, m, gid, num_groups, agg,
-                                rows_sorted=spec.rows_sorted)
+                                rows_sorted=spec.rows_sorted,
+                                row_groups=spec.row_groups)
 
 
 def _downsample_grid(step: DownsampleStep, ts, val, mask, wargs):
@@ -318,7 +323,8 @@ def _group_rollup_avg(spec: PipelineSpec, num_groups: int, ts_s, val_s,
         grid_b = jnp.broadcast_to(grid[None, :], v.shape)
         _, v, m = rate(grid_b, v, m, spec.rate, all_int=False)
     return grid_group_aggregate(grid, v, m, gid, num_groups, agg,
-                                rows_sorted=spec.rows_sorted)
+                                rows_sorted=spec.rows_sorted,
+                                row_groups=spec.row_groups)
 
 
 _jitted_group_rollup_avg = jax.jit(_group_rollup_avg, static_argnums=(0, 1))

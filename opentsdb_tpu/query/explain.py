@@ -491,12 +491,11 @@ def _explain_segment(tsdb, runner, query, sub, seg, what_if: WhatIf,
             store = pre if pre is not None else store
     else:
         store = seg.lane
-    series_tags = runner._resolve_series(sub, store)
-    groups = runner._group(series_tags, sub)
+    sel = runner._selection(sub, store)
     windows = runner._windows_for(sub, query)
     base = {"kind": seg.kind, "startMs": seg.start_ms,
-            "endMs": seg.end_ms, "series": len(series_tags),
-            "groups": len(groups)}
+            "endMs": seg.end_ms, "series": len(sel.series_tags),
+            "groups": len(sel.groups)}
     if windows is None:
         # union-timestamp aggregation: per-group fused dispatches, no
         # downsample grid — not routed through plan_decision
@@ -505,28 +504,21 @@ def _explain_segment(tsdb, runner, query, sub, seg, what_if: WhatIf,
                          "are not routed through plan_decision")
         return base
     fix = tsdb.config.fix_duplicates
-    kept = []
-    for group_key in sorted(groups, key=lambda k: tuple(map(str, k))):
-        members = groups[group_key]
-        counts = [s.window_count(seg.start_ms, seg.end_ms, fix)
-                  for s, _ in members]
-        points = sum(counts)
-        if points:
-            budget.charge(points)
-            kept.append((group_key, members, counts))
-    if not kept:
+    scan = runner._scan(sel, seg, store, observe=False)
+    if scan is None:
         base.update(path="empty", note="no datapoints in range")
         return base
+    total_points = int(scan.counts.sum())
+    budget.charge(total_points)
     budget.check_deadline()
     ds = sub.downsample_spec
     ds_fn = seg.ds_function or ds.function
-    series_list = [s for _, members, _ in kept for s, _t in members]
+    series_list, n_groups = scan.series, len(scan.groups)
     n_rows = len(series_list)
-    total_points = sum(sum(c) for _, _, c in kept)
-    n_max = max(max(c) for _, _, c in kept)
-    g_pad = pad_pow2(len(kept))
-    sketchable, hazard = runner._sketch_eligible(seg, ds_fn, windows,
-                                                 kept, n_rows, fix)
+    n_max = int(scan.counts.max())
+    g_pad = pad_pow2(n_groups)
+    sketchable, hazard = runner._sketch_eligible(
+        seg, ds_fn, windows, series_list, scan.counts, fix)
     from opentsdb_tpu.ops.streaming import STREAMABLE_DS
     stream_ok = (seg.kind != "rollup_avg"
                  and (ds_fn in STREAMABLE_DS or sketchable))
@@ -550,7 +542,7 @@ def _explain_segment(tsdb, runner, query, sub, seg, what_if: WhatIf,
     ctx = pdn.RouteContext(
         seg_kind=seg.kind, ds_fn=ds_fn, aggregator=sub.aggregator,
         has_rate=bool(sub.rate), s=n_rows, n_max=int(n_max), wp=wp,
-        groups=len(kept), g_pad=g_pad, total_points=int(total_points),
+        groups=n_groups, g_pad=g_pad, total_points=total_points,
         sketchable=sketchable, stream_ok=stream_ok, use_mesh=use_mesh,
         n_chips=n_chips, windows_fixed=isinstance(windows, FixedWindows),
         store_is_raw=store is tsdb.store, has_store=store is not None,

@@ -48,6 +48,10 @@ from opentsdb_tpu.query.limits import GridBudgetDecision, grid_budget
 MONOLITHIC_PATHS = frozenset(
     {"streamed", "resident", "host_lane", "mesh", "rollup_avg",
      "batched"})
+# the paths that hand the whole [S, N] batch with its own gid to ONE
+# grouped dispatch: where every group has one member, row i is group i
+# there and the group reduce is a copy (PipelineSpec.row_groups)
+ROW_GROUP_PATHS = frozenset({"resident", "host_lane"})
 
 
 @dataclass(frozen=True)
@@ -399,8 +403,9 @@ def plan_decision(tsdb, ctx: RouteContext, consults) -> PlanDecision:
 
     dec_platform = "cpu" if host_small else ctx.platform
     decisions = None
+    row_groups = ctx.groups == ctx.s and path in ROW_GROUP_PATHS
     if path in MONOLITHIC_PATHS:
-        if batch_decisions is not None \
+        if batch_decisions is not None and not row_groups \
                 and dec_platform == price_platform:
             # the coalesce-pricing recomputation already produced this
             # platform's reports — reuse them on the batched arm AND
@@ -416,7 +421,7 @@ def plan_decision(tsdb, ctx: RouteContext, consults) -> PlanDecision:
             # warm fast paths the caches exist to shrink
             decisions = jaxprof.segment_decisions(
                 dec_platform, ctx.s, n_pad, ctx.wp, g_dec, ctx.ds_fn,
-                aggregator=ctx.aggregator)
+                aggregator=ctx.aggregator, row_groups=row_groups)
     pd = PlanDecision(
         path=path, would_stream=would_stream, use_mesh=ctx.use_mesh,
         host_small=host_small, lane_small=lane_small, gbd=gbd,

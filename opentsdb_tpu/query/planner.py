@@ -13,13 +13,16 @@ downsample/rate/union kernels.
 
 from __future__ import annotations
 
-from dataclasses import dataclass, field
+import json
+import threading
+from dataclasses import dataclass, replace
 
 import numpy as np
 
 from opentsdb_tpu.models.tsquery import TSQuery, TSSubQuery
 from opentsdb_tpu.obs import latattr
 from opentsdb_tpu.obs import trace as obs_trace
+from opentsdb_tpu.obs.registry import REGISTRY
 from opentsdb_tpu.ops.downsample import (
     FixedWindows, EdgeWindows, AllWindow, pad_pow2)
 from opentsdb_tpu.ops.pipeline import (
@@ -29,12 +32,14 @@ from opentsdb_tpu.ops.pipeline import (
     build_batch_direct, PAD_TS)
 from opentsdb_tpu.ops.streaming import (
     StreamAccumulator, STREAMABLE_DS, is_sketch_ds, lanes_for)
+from opentsdb_tpu.query import filters as query_filters
 from opentsdb_tpu.rollup.config import NoSuchRollupForInterval, RollupQuery
 from opentsdb_tpu.storage.memstore import Series, SeriesKey
 from opentsdb_tpu.uid import NoSuchUniqueName
 from opentsdb_tpu.utils import datetime_util as DT
 
 _NO_MATCH = object()  # sentinel: a literal filter can never match
+_INF = float("inf")
 
 # Downsample function -> (rollup lane, function applied over lane cells).
 # Counts re-reduce with SUM; min/max/sum re-reduce with themselves
@@ -67,30 +72,87 @@ class Segment:
     rollup_query: RollupQuery | None = None
 
 
-@dataclass
 class QueryResult:
-    """One output object of /api/query (HttpJsonSerializer.java:742-815)."""
-    metric: str
-    tags: dict[str, str]
-    aggregate_tags: list[str]
-    tsuids: list[str]
-    dps: list[tuple[int, object]]  # (ts_ms, value) value int or float or NaN
-    annotations: list = field(default_factory=list)
-    global_annotations: list = field(default_factory=list)
-    index: int = 0
+    """One output object of /api/query (HttpJsonSerializer.java:742-815).
+
+    The points are (ts_ms, value) pairs, value int or float or NaN, read
+    as `dps`; a downsampled answer holds them as two columns instead —
+    `stamps`, one list shared by every group of the answer, and this
+    group's `values` — and pairs them up only for a reader that asks.
+    `tags`, `aggregate_tags` and `tsuids` may be a memoised selection's
+    own objects: replace them, never write into them.
+    """
+
+    __slots__ = ("metric", "tags", "aggregate_tags", "tsuids",
+                 "annotations", "global_annotations", "index", "head",
+                 "stamps", "values", "_dps")
+
+    def __init__(self, metric: str, tags: dict[str, str],
+                 aggregate_tags: list[str], tsuids: list[str],
+                 dps: list[tuple[int, object]] | None = None,
+                 annotations=(), global_annotations=(), index: int = 0,
+                 head: str | None = None, stamps: list | None = None,
+                 values: list | None = None):
+        self.metric = metric
+        self.tags = tags
+        self.aggregate_tags = aggregate_tags
+        self.tsuids = tsuids
+        self.annotations = annotations
+        self.global_annotations = global_annotations
+        self.index = index
+        # json.dumps of {"metric", "tags", "aggregateTags"} less its
+        # closing brace, where the planner has it from a memoised
+        # selection; whoever replaces one of the three sets it to None
+        self.head = head
+        self.stamps, self.values, self._dps = stamps, values, dps
+
+    @property
+    def dps(self) -> list[tuple[int, object]]:
+        if self._dps is None:
+            self._dps = list(zip(self.stamps, self.values))
+        return self._dps
+
+    @dps.setter
+    def dps(self, pairs: list[tuple[int, object]]) -> None:
+        self.stamps = self.values = None
+        self._dps = pairs
+
+    def _columns(self) -> tuple:
+        if self.stamps is None:
+            return tuple(zip(*self._dps)) if self._dps else ((), ())
+        return self.stamps, self.values
+
+    @staticmethod
+    def _memo(keys: dict | None, what: str, stamps, make):
+        """`make(stamps)`, remembered in `keys` under `what` for the
+        other results of the response: by identity for a shared column,
+        else by value."""
+        if keys is None:
+            return make(stamps)
+        key = stamps if isinstance(stamps, tuple) else id(stamps)
+        hit = keys.get((what, key))
+        if hit is None:     # the column is kept: its id stays its own
+            hit = keys[(what, key)] = (stamps, make(stamps))
+        return hit[1]
 
     def to_json(self, ms_resolution: bool = False, show_tsuids: bool = False,
                 fill_policy: str = "none", show_query: bool = False,
                 sub_query: TSSubQuery | None = None,
                 no_annotations: bool = False,
-                global_annotations: bool = False) -> dict:
-        dps = {}
-        for ts_ms, value in self.dps:
-            key = str(ts_ms if ms_resolution else ts_ms // 1000)
-            if isinstance(value, float) and value != value:  # NaN
-                dps[key] = None if fill_policy == "null" else float("nan")
-            else:
-                dps[key] = value
+                global_annotations: bool = False,
+                keys: dict | None = None) -> dict:
+        """`keys` is a memo of the timestamps' key strings that the
+        caller shares between the results of one response: the groups
+        of a downsampled answer all carry the same timestamps."""
+        stamps, values = self._columns()
+        scale = 1 if ms_resolution else 1000
+        names = self._memo(keys, "names", stamps,
+                           lambda col: [str(t // scale) for t in col])
+        total = sum(values)
+        if total != total:      # a NaN among them (or inf - inf)
+            null = None if fill_policy == "null" else float("nan")
+            values = [null if isinstance(v, float) and v != v else v
+                      for v in values]
         out = {
             "metric": self.metric,
             "tags": self.tags,
@@ -105,8 +167,108 @@ class QueryResult:
         if global_annotations and self.global_annotations:
             out["globalAnnotations"] = [a.to_json()
                                         for a in self.global_annotations]
-        out["dps"] = dps
+        out["dps"] = dict(zip(names, values))
         return out
+
+    def json_text(self, keys: dict, ms_resolution: bool = False
+                  ) -> str | None:
+        """to_json() of a plain answer (no tsuids, query echo or
+        annotations asked for) as the text json.dumps would make of it,
+        from the pre-encoded head and one format call for the points —
+        or None where that text cannot be made so (no head, no points,
+        a NaN or an infinity among the values): the caller then takes
+        to_json()."""
+        stamps, values = self._columns()
+        if self.head is None or not stamps:
+            return None
+        total = sum(values)
+        if total != total or total in (_INF, -_INF):
+            return None
+        scale = 1 if ms_resolution else 1000
+        form = self._memo(keys, "form", stamps, lambda col: ', "dps": {%s}}' % (
+            ", ".join('"%d": %%r' % (t // scale) for t in col)))
+        return self.head + form % tuple(values)
+
+
+class _Selection:
+    """What one (store, metric, filters, group-by) selects: resolved and
+    grouped once, and served again until a series is born or deleted in
+    the store or a UID is renamed (`stamp`).  Nothing here depends on a
+    query's time range; a request reads it and never writes to it."""
+
+    def __init__(self, store, stamp: tuple, series_tags: list,
+                 groups: dict):
+        self.store = store      # the strong ref keeps id(store) stable
+        self.stamp = stamp
+        self.series_tags = series_tags
+        self.groups = groups    # as QueryRunner._group returns it
+        # the groups in the order they are answered, and their rows
+        self.keys = sorted(groups, key=lambda k: tuple(map(str, k)))
+        self.members = [groups[k] for k in self.keys]
+        self.str_keys = [tuple(map(str, k)) for k in self.keys]
+        sizes = np.fromiter(map(len, self.members), np.int64,
+                            len(self.members))
+        self.starts = np.concatenate([[0], np.cumsum(sizes)])
+        self.series = [s for m in self.members for s, _ in m]
+        self.gid = np.repeat(np.arange(len(sizes), dtype=np.int64), sizes)
+        self._lock = threading.Lock()
+        self._meta = None  # guarded-by: _lock
+
+    def meta(self, tsdb, metric: str) -> list:
+        """Per group (tags, aggregateTags, tsuids, head): what an answer
+        says of a group besides its points, and — for a memoised
+        selection, which is asked again — QueryResult.head, the three
+        of them as JSON text."""
+        with self._lock:
+            if self._meta is None:
+                self._meta = []
+                for members in self.members:
+                    tags, agg_tags = QueryRunner._compute_tags(members)
+                    head = None
+                    if self.stamp is not None:
+                        head = json.dumps({"metric": metric, "tags": tags,
+                                           "aggregateTags": agg_tags})[:-1]
+                    self._meta.append(
+                        (tags, agg_tags,
+                         [tsdb.tsuid(s.key) for s, _ in members], head))
+            return self._meta
+
+
+class SelectionMemo:
+    """The last few selections of one TSDB, by what was asked; shared by
+    its handler threads."""
+
+    SIZE = 32
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._by_key: dict = {}  # guarded-by: _lock
+
+    def get(self, key: tuple, store, stamp: tuple) -> _Selection | None:
+        with self._lock:
+            sel = self._by_key.get(key)
+        if sel is not None and sel.stamp == stamp and sel.store is store:
+            return sel
+        return None
+
+    def put(self, key: tuple, sel: _Selection) -> None:
+        with self._lock:
+            self._by_key.pop(key, None)
+            while len(self._by_key) >= self.SIZE:
+                self._by_key.pop(next(iter(self._by_key)))
+            self._by_key[key] = sel
+
+
+@dataclass
+class _Scan:
+    """One segment's scan of a selection: the groups that hold points in
+    the segment's range, row by row (a row is one member series)."""
+    groups: np.ndarray      # [G] index into the selection's groups
+    series: list            # [S] rows, group by group
+    gid: np.ndarray         # [S] int64 position in `groups` of each row
+    counts: np.ndarray      # [S] int64 points of each row in the range
+    bounds: object          # the device cache's WindowBounds of the rows,
+    #                         where a valid entry answered the counts
 
 
 class QueryRunner:
@@ -119,6 +281,7 @@ class QueryRunner:
         # scanner-level stats of QueryStats.java:132, re-expressed for
         # batch execution: points scanned, streamed chunks, mesh devices)
         self.exec_stats: dict[str, float] = {}
+        self._fetch_notes = True    # set per sub query by run_sub
 
     def _bump(self, key: str, value: float) -> None:
         self.exec_stats[key] = self.exec_stats.get(key, 0.0) + value
@@ -213,6 +376,78 @@ class QueryRunner:
         conflicting keys -> aggregateTags."""
         from opentsdb_tpu.expression.series import compute_tags
         return compute_tags([tags for _, tags in members])
+
+    def _selection(self, sub: TSSubQuery, store) -> _Selection:
+        """Resolve and group, or the memo of it: a dashboard asks the
+        same (metric, filters, group-by) over and over, and at 10^5
+        series the walk costs seconds.  Memoised per store generation
+        for the built-in filter types (a plugin's match may read state
+        this cannot see) and never for tsuid queries."""
+        tsdb = self.tsdb
+        memo = key = stamp = None
+        if (not sub.tsuids and hasattr(store, "series_generation")
+                and all(type(f).__module__ == query_filters.__name__
+                        for f in sub.filters)):
+            memo = tsdb.selections
+            key = (id(store), sub.metric, tuple(map(repr, sub.filters)),
+                   sub.explicit_tags, tuple(sub.group_by_tags()),
+                   sub.aggregator == "none")
+            # read BEFORE the walk: a series born meanwhile leaves the
+            # memo a generation behind, and the next request walks again
+            stamp = (store.series_generation, tsdb.metrics.renames,
+                     tsdb.tag_names.renames, tsdb.tag_values.renames)
+            sel = memo.get(key, store, stamp)
+            if sel is not None:
+                return sel
+        with obs_trace.stage("scan.resolve"):
+            series_tags = self._resolve_series(sub, store)
+        with obs_trace.stage("scan.group"):
+            sel = _Selection(store, stamp, series_tags,
+                             self._group(series_tags, sub))
+        if memo is not None:
+            memo.put(key, sel)
+        return sel
+
+    def _scan(self, sel: _Selection, seg: Segment, store,
+              observe: bool = True) -> _Scan | None:
+        """Count every row's points in the segment's range and keep the
+        groups that hold any (no datapoints in range -> no SpanGroup at
+        all: the scanner returns no spans, TsdbQuery.findSpans -> empty
+        group map); the caller charges its budget with the counts.  A
+        valid device-cache entry answers the counts for all rows in one
+        pass; else each series is asked (lock + binary search, no copy).
+        `observe` off is the explain engine's read-only walk."""
+        tsdb = self.tsdb
+        fix = tsdb.config.fix_duplicates
+        series, bounds = sel.series, None
+        if not series:
+            return None
+        cache = tsdb.device_cache
+        if cache is not None and store is not None:
+            ask = cache.bounds_for if observe else cache.peek_bounds
+            bounds = ask(store, series[0].key.metric, series,
+                         seg.start_ms, seg.end_ms)
+        if bounds is not None:
+            counts = bounds.lengths
+        else:
+            counts = np.fromiter(
+                (s.window_count(seg.start_ms, seg.end_ms, fix)
+                 for s in series), np.int64, len(series))
+        live = np.add.reduceat(counts, sel.starts[:-1]) > 0
+        if live.all():
+            return _Scan(np.arange(len(live)), series, sel.gid, counts,
+                         bounds)
+        if not live.any():
+            return None
+        rows = np.repeat(live, np.diff(sel.starts))
+        groups = np.flatnonzero(live)
+        if bounds is not None:
+            bounds = bounds.take(rows)
+        return _Scan(groups,
+                     [s for s, k in zip(series, rows.tolist()) if k],
+                     np.repeat(np.arange(len(groups), dtype=np.int64),
+                               np.diff(sel.starts)[live]),
+                     counts[rows], bounds)
 
     # -- execution -------------------------------------------------------
 
@@ -333,29 +568,36 @@ class QueryRunner:
                 store = pre if pre is not None else store
         else:
             store = seg.lane
-        with obs_trace.stage("scan", kind=seg.kind) as sp:
-            series_tags = self._resolve_series(sub, store)
-            groups = self._group(series_tags, sub)
-            obs_trace.annotate(sp, series=len(series_tags),
-                               groups=len(groups))
+        with obs_trace.timed_stage("scan", kind=seg.kind) as sp:
+            sel = self._selection(sub, store)
+            obs_trace.annotate(sp, series=len(sel.series_tags),
+                               groups=len(sel.groups))
         windows = self._windows_for(sub, query)
         if windows is not None:
-            return self._run_segment_grouped(query, sub, seg, groups,
+            return self._run_segment_grouped(query, sub, seg, sel,
                                              windows, global_notes, budget,
                                              store)
-        return self._run_segment_union(query, sub, seg, groups, global_notes,
-                                       budget)
+        return self._run_segment_union(query, sub, seg, sel.groups,
+                                       global_notes, budget)
 
     def _assemble_result(self, query: TSQuery, sub: TSSubQuery, members,
-                         dps, global_notes) -> QueryResult:
+                         dps, global_notes, meta=None, stamps=None,
+                         values=None) -> QueryResult:
+        """`meta` is the selection's (tags, aggregateTags, tsuids, head)
+        of the group where it has them: the answer shares them.  The
+        points are `dps`, or the columns `stamps` and `values`."""
         tsdb = self.tsdb
-        group_tags, agg_tags = self._compute_tags(members)
-        tsuids = [tsdb.tsuid(s.key) for s, _ in members]
-        annotations = []
-        if not query.no_annotations:
-            for t in tsuids:
-                annotations.extend(tsdb.store.get_annotations(
-                    t, query.start_time, query.end_time))
+        if meta is None:
+            group_tags, agg_tags = self._compute_tags(members)
+            tsuids = [tsdb.tsuid(s.key) for s, _ in members]
+            head = None
+        else:
+            group_tags, agg_tags, tsuids, head = meta
+        annotations = ()
+        if self._fetch_notes:
+            annotations = [
+                note for t in tsuids for note in tsdb.store.get_annotations(
+                    t, query.start_time, query.end_time)]
         return QueryResult(
             metric=sub.metric or (
                 tsdb.metrics.get_name(members[0][0].key.metric)
@@ -367,10 +609,11 @@ class QueryRunner:
             annotations=annotations,
             global_annotations=global_notes,
             index=sub.index,
+            head=head, stamps=stamps, values=values,
         )
 
     def _run_segment_grouped(self, query: TSQuery, sub: TSSubQuery,
-                             seg: Segment, groups, windows,
+                             seg: Segment, sel: _Selection, windows,
                              global_notes: list, budget,
                              store=None) -> dict[tuple, QueryResult]:
         """All group-by buckets in ONE device dispatch (downsample queries).
@@ -385,23 +628,18 @@ class QueryRunner:
         ds = sub.downsample_spec
 
         fix = tsdb.config.fix_duplicates
-        # Counts first (lock + binary search, no copy): budget charging and
-        # the streaming decision must not force the whole range into host
-        # memory — a 1B-pt query would otherwise materialize twice (full
-        # window copies AND chunk buffers).
-        kept = []  # (group_key, members, per-member point counts)
-        for group_key in sorted(groups, key=lambda k: tuple(map(str, k))):
-            members = groups[group_key]
-            counts = [s.window_count(seg.start_ms, seg.end_ms, fix)
-                      for s, _ in members]
-            # No datapoints in range -> no SpanGroup at all (the scanner
-            # returns no spans, TsdbQuery.findSpans -> empty group map).
-            points = sum(counts)
-            if points:
-                budget.charge(points)
-                kept.append((group_key, members, counts))
-        if not kept:
+        # Counts first (no copy): budget charging and the streaming
+        # decision must not force the whole range into host memory — a
+        # 1B-pt query would otherwise materialize twice (full window
+        # copies AND chunk buffers).
+        with obs_trace.timed_stage("count"):
+            scan = self._scan(sel, seg, store)
+        if scan is None:
             return {}
+        series_list, gid, counts = scan.series, scan.gid, scan.counts
+        n_groups = len(scan.groups)
+        total_points = int(counts.sum())
+        budget.charge(total_points)
         budget.check_deadline()
         # one "pipeline" span covers batch build + the fused dispatch;
         # begin/end (not a with-block) keeps the 5-path dispatch chain
@@ -425,10 +663,7 @@ class QueryRunner:
         # it.
         window_spec, wargs = windows.split()
 
-        gid = np.concatenate([
-            np.full(len(members), i, np.int64)
-            for i, (_, members, _) in enumerate(kept)])
-        g_pad = pad_pow2(len(kept))
+        g_pad = pad_pow2(n_groups)
         spec = PipelineSpec(
             aggregator=sub.aggregator,
             downsample=DownsampleStep(
@@ -440,10 +675,10 @@ class QueryRunner:
             # construction; lets sorted reduce modes skip the permute
             rows_sorted=True)
 
-        total_points = sum(sum(c) for _, _, c in kept)
+        n_max = int(counts.max())
         ds_fn = seg.ds_function or ds.function
         sketchable, hazard = self._sketch_eligible(seg, ds_fn, windows,
-                                                   kept, len(gid), fix)
+                                                   series_list, counts, fix)
         if hazard:
             self.exec_stats["sketchHazardExact"] = 1.0
         stream_ok = (seg.kind != "rollup_avg"
@@ -457,7 +692,6 @@ class QueryRunner:
         if use_mesh:
             from opentsdb_tpu.parallel.sharded import n_devices
             n_chips = n_devices(mesh)
-        series_list = [s for _, members, _ in kept for s, _t in members]
         # ONE routing verdict for the whole fast-path arbitration
         # (rollup lane -> tiled -> agg rewrite -> device cache ->
         # streamed/mesh/host-lane/resident), computed by the SAME pure
@@ -472,12 +706,11 @@ class QueryRunner:
         from opentsdb_tpu.query import plandecision as pdn
         ts_base = precompact_base(
             window_spec, getattr(windows, "first_window_ms", None))
-        n_max = max(max(c) for _, _, c in kept)
         batcher = getattr(tsdb, "dispatch_batcher", None)
         ctx = pdn.RouteContext(
             seg_kind=seg.kind, ds_fn=ds_fn, aggregator=sub.aggregator,
-            has_rate=bool(sub.rate), s=len(gid), n_max=int(n_max),
-            wp=window_spec.count, groups=len(kept), g_pad=g_pad,
+            has_rate=bool(sub.rate), s=len(gid), n_max=n_max,
+            wp=window_spec.count, groups=n_groups, g_pad=g_pad,
             total_points=int(total_points), sketchable=sketchable,
             stream_ok=stream_ok, use_mesh=use_mesh, n_chips=n_chips,
             windows_fixed=isinstance(windows, FixedWindows),
@@ -496,12 +729,16 @@ class QueryRunner:
                 "tsd.query.batch.amortize_factor"))
         pd = pdn.plan_decision(
             tsdb, ctx, _ExecConsults(tsdb, ctx, seg, sub, windows,
-                                     store, series_list, fix))
+                                     store, series_list, fix, scan.bounds))
         if pd.lane_note is not None:
             obs_trace.annotate(psp, rollup=pd.lane_note)
         if pd.agg_note is not None:
             obs_trace.annotate(psp, agg_cache=pd.agg_note)
         obs_trace.annotate(psp, fingerprint=pd.fingerprint)
+        if pd.decisions is not None:
+            REGISTRY.counter(
+                "tsd.query.group_reduce", "Dispatches by group-reduce "
+                "form").labels(mode=pd.decisions["group"]["mode"]).inc()
         # phase boundary: scan + batch shaping + the routing verdict
         # all land in "plan"; the fingerprint keys this request's
         # latency-attribution profile (first segment wins)
@@ -589,25 +826,23 @@ class QueryRunner:
             # Beyond the threshold the batch never materializes: bounded
             # chunks are copied straight out of the store into the device
             # accumulator (SaltScanner overlap analog, VERDICT r1 #4).
-            max_len = max(max(c) for _, _, c in kept)
             out_ts, out_val, out_mask = self._stream_grouped(
-                spec, seg, series_list, max_len, gid, g_pad, window_spec,
+                spec, seg, series_list, n_max, gid, g_pad, window_spec,
                 wargs, sketch=sketchable)
         elif seg.kind == "rollup_avg":
-            all_windows = self._materialize_windows(kept, seg, fix)
+            all_windows = self._materialize_windows(series_list, seg, fix)
             ts, val, mask, _ = build_batch(all_windows)
             cnt_windows = []
-            for _, members, _ in kept:
-                for s, _tags in members:
-                    cs = seg.count_lane.get_series(s.key)
-                    if cs is None:
-                        cnt_windows.append(
-                            (np.empty(0, np.int64), np.empty(0, np.float64),
-                             np.empty(0, np.int64), np.empty(0, bool)))
-                    else:
-                        cnt_windows.append(cs.window(
-                            seg.start_ms, seg.end_ms,
-                            tsdb.config.fix_duplicates))
+            for s in series_list:
+                cs = seg.count_lane.get_series(s.key)
+                if cs is None:
+                    cnt_windows.append(
+                        (np.empty(0, np.int64), np.empty(0, np.float64),
+                         np.empty(0, np.int64), np.empty(0, bool)))
+                else:
+                    cnt_windows.append(cs.window(
+                        seg.start_ms, seg.end_ms,
+                        tsdb.config.fix_duplicates))
             tc, vc, mc, _ = build_batch(cnt_windows)
             with host_lane(host_small):
                 out_ts, out_val, out_mask = run_group_rollup_avg_pipeline(
@@ -620,8 +855,7 @@ class QueryRunner:
                 # (build_batch_direct): a 1M-pt query's window()+pack
                 # double copy was ~30% of the host-lane query time
                 ts, val, mask, _ = build_batch_direct(
-                    [s for _, members, _ in kept for s, _t in members],
-                    seg.start_ms, seg.end_ms, fix)
+                    series_list, seg.start_ms, seg.end_ms, fix)
             if use_mesh:
                 from opentsdb_tpu.parallel import (
                     sharded_query_pipeline, shard_rows)
@@ -641,6 +875,10 @@ class QueryRunner:
                 out_ts, out_val, out_mask = fn(d_ts, d_val, d_mask, d_gid,
                                                wargs)
             else:
+                if n_groups == len(gid) and pd.path in pdn.ROW_GROUP_PATHS:
+                    # one member a group, the whole batch in this one
+                    # dispatch: row i is group i
+                    spec = replace(spec, row_groups=True)
                 with host_lane(host_small):
                     out_ts, out_val, out_mask = run_group_pipeline(
                         spec, ts, val, mask, gid, g_pad, wargs)
@@ -660,9 +898,8 @@ class QueryRunner:
                 # execution, and pairing its prediction with a partial
                 # (or shared) actual would poison the calibration ring
                 self._trace_pipeline_stages(
-                    psp, sub, seg, len(gid),
-                    max(max(c) for _, _, c in kept), window_spec.count,
-                    len(kept), host_small, policy_epoch,
+                    psp, sub, seg, len(gid), n_max, window_spec.count,
+                    n_groups, host_small, policy_epoch,
                     decisions=pd.decisions)
         obs_trace.end(psp)
         recorder = getattr(tsdb, "flightrec", None)
@@ -675,7 +912,7 @@ class QueryRunner:
             # explain-vs-actual parity handle (query/plandecision.py).
             fields = {"path": pd.path, "metric": sub.metric,
                       "series": len(gid), "windows": window_spec.count,
-                      "groups": len(kept), "points": int(total_points),
+                      "groups": n_groups, "points": total_points,
                       "deviceCacheHit": cached is not None,
                       "fingerprint": pd.fingerprint}
             if tsdb.rollup_lanes is not None:
@@ -686,7 +923,7 @@ class QueryRunner:
             if batch_info is not None:
                 fields["batch"] = batch_info
             recorder.record("plan", **fields)
-        with obs_trace.stage("extract"):
+        with obs_trace.timed_stage("extract"):
             out_ts = np.asarray(out_ts)
             out_val = np.asarray(out_val)
             out_mask = np.asarray(out_mask)
@@ -694,14 +931,25 @@ class QueryRunner:
             # actually blocks (tracing syncs earlier via device_wait,
             # in which case this delta is ~0)
             latattr.mark("device_wait")
+            stamps, rows = extract_grid(
+                out_ts, out_val[:n_groups], out_mask[:n_groups],
+                seg.start_ms, seg.end_ms,
+                keep_nans=sub.fill_policy != "none")
+        with obs_trace.timed_stage("assemble"):
+            members = sel.members
+            meta = sel.meta(tsdb, sub.metric or (
+                tsdb.metrics.get_name(series_list[0].key.metric)))
             results: dict[tuple, QueryResult] = {}
-            for i, (group_key, members, _) in enumerate(kept):
-                dps = extract_dps(out_ts, out_val[i], out_mask[i],
-                                  seg.start_ms, seg.end_ms, False,
-                                  keep_nans=sub.fill_policy != "none")
-                results[tuple(map(str, group_key))] = \
-                    self._assemble_result(query, sub, members, dps,
-                                          global_notes)
+            for g, ts_col, values in zip(scan.groups.tolist(), stamps, rows):
+                results[sel.str_keys[g]] = self._assemble_result(
+                    query, sub, members[g], None, global_notes, meta[g],
+                    ts_col, values)
+        REGISTRY.counter(
+            "tsd.query.series", "Rows (member series) dispatched by "
+            "grouped downsample queries").inc(len(gid))
+        REGISTRY.counter(
+            "tsd.query.groups", "Groups answered by grouped downsample "
+            "queries").inc(n_groups)
         return results
 
     def _trace_pipeline_stages(self, span, sub: TSSubQuery, seg: Segment,
@@ -723,7 +971,6 @@ class QueryRunner:
         actual) tuple lands in obs.jaxprof's segment ring — the corpus
         the online calibrator (ops/calibrate.py) fits from."""
         from opentsdb_tpu.obs import jaxprof
-        from opentsdb_tpu.obs.registry import REGISTRY
         from opentsdb_tpu.ops.hostlane import execution_platform
         ds = sub.downsample_spec
         ds_fn = seg.ds_function or (ds.function if ds is not None else None)
@@ -809,10 +1056,9 @@ class QueryRunner:
         return np.zeros(len(tsb), np.int64)    # AllWindow: one cell
 
     @staticmethod
-    def _materialize_windows(kept, seg, fix):
+    def _materialize_windows(series_list, seg, fix):
         """Full window copies for the sub-threshold (one-batch) paths."""
-        return [s.window(seg.start_ms, seg.end_ms, fix)
-                for _, members, _ in kept for s, _t in members]
+        return [s.window(seg.start_ms, seg.end_ms, fix) for s in series_list]
 
     @staticmethod
     def _materialize_agg_piece(v, m, count: int):
@@ -948,8 +1194,9 @@ class QueryRunner:
             self.exec_stats["aggCacheHit"] = 1.0
         return out
 
-    def _sketch_eligible(self, seg: Segment, ds_fn: str, windows, kept,
-                         n_rows: int, fix: bool) -> tuple[bool, bool]:
+    def _sketch_eligible(self, seg: Segment, ds_fn: str, windows,
+                         series_list, counts: np.ndarray, fix: bool
+                         ) -> tuple[bool, bool]:
         """(sketchable, hazard_fallback) for one grouped segment —
         shared by the executor and the explain engine (read-only store
         walk, no dispatch).
@@ -977,18 +1224,16 @@ class QueryRunner:
             return True, False
         chunk_points = max(tsdb.config.get_int(
             "tsd.query.streaming.chunk_points"), 1)
-        n_chunk = pad_pow2(max(1024, chunk_points // max(n_rows, 1)))
+        n_chunk = pad_pow2(max(1024, chunk_points // max(len(counts), 1)))
         worst = 0
-        for _, members, counts in kept:
-            for (s, _t), c in zip(members, counts):
-                if c <= n_chunk:
-                    continue        # single chunk: no merges at all
-                tsb = s.window_stride_timestamps(
-                    seg.start_ms, seg.end_ms, n_chunk, fix)
-                wids = self._host_window_ids(windows, tsb)
-                if len(wids):
-                    worst = max(worst, int(np.max(
-                        np.unique(wids, return_counts=True)[1])))
+        # a row of a single chunk has no merges at all
+        for row in np.flatnonzero(counts > n_chunk).tolist():
+            tsb = series_list[row].window_stride_timestamps(
+                seg.start_ms, seg.end_ms, n_chunk, fix)
+            wids = self._host_window_ids(windows, tsb)
+            if len(wids):
+                worst = max(worst, int(np.max(
+                    np.unique(wids, return_counts=True)[1])))
         if worst + 1 > max_merges:
             return False, True
         return True, False
@@ -1616,6 +1861,10 @@ class QueryRunner:
 
     def run_sub(self, query: TSQuery, sub: TSSubQuery) -> list[QueryResult]:
         budget = self._new_budget(sub)
+        # nothing annotated, nothing to look up: one lookup a tsuid is
+        # seconds at 10^5 groups
+        self._fetch_notes = (not query.no_annotations
+                             and self.tsdb.store.has_annotations())
         if sub.percentiles or sub.show_histogram_buckets:
             return self._run_histogram_sub(query, sub, budget)
         segments = self._plan_segments(query, sub)
@@ -1633,12 +1882,15 @@ class QueryRunner:
                     continue
                 # Split stitch (SplitRollupSpanGroup): segments are time-
                 # disjoint, so concatenation in segment order is sorted.
+                # (replaced, not extended: what a result holds may be a
+                # memoised selection's own list)
                 cur.dps = cur.dps + qr.dps
-                new_tsuids = [t for t in qr.tsuids if t not in cur.tsuids]
-                cur.tsuids.extend(new_tsuids)
+                cur.head = None
+                cur.tsuids = cur.tsuids + [t for t in qr.tsuids
+                                           if t not in cur.tsuids]
                 seen_notes = {id(a) for a in cur.annotations}
-                cur.annotations.extend(
-                    a for a in qr.annotations if id(a) not in seen_notes)
+                cur.annotations = list(cur.annotations) + [
+                    a for a in qr.annotations if id(a) not in seen_notes]
                 cur.tags = {k: v for k, v in cur.tags.items()
                             if qr.tags.get(k) == v}
                 cur.aggregate_tags = sorted(
@@ -1662,8 +1914,9 @@ class _ExecConsults:
     per-segment context onto the subsystem calls."""
 
     def __init__(self, tsdb, ctx, seg, sub, windows, store,
-                 series_list, fix):
+                 series_list, fix, bounds=None):
         self.tsdb = tsdb
+        self.bounds = bounds    # the scan's, from a valid cache entry
         self.ctx = ctx
         self.seg = seg
         self.sub = sub
@@ -1714,12 +1967,35 @@ class _ExecConsults:
         return self.tsdb.device_cache.batch_for(
             self.store, self._metric(), self.series_list,
             self.seg.start_ms, self.seg.end_ms, self.fix, build=build,
-            ts_base=ts_base)
+            ts_base=ts_base, bounds=self.bounds)
 
 
 def _fmt_pct(p: float) -> str:
     """Float.toString parity: 99 -> "99.0", 99.9 -> "99.9"."""
     return "%s" % float(p)
+
+
+def extract_grid(out_ts: np.ndarray, out_val: np.ndarray,
+                 out_mask: np.ndarray, start_ms: int, end_ms: int,
+                 keep_nans: bool = False) -> tuple[list, list]:
+    """extract_dps for every row of a [G, W] float grid over one [W]
+    timestamp vector, as columns: ([G] timestamp lists, [G] value
+    lists).  Where every row keeps the same columns (a grid with no
+    gap: the common case) all rows share ONE timestamp list and the
+    values convert at C speed in one call; rows that differ are
+    extracted one by one."""
+    ts = out_ts.ravel()
+    val = out_val.astype(np.float64, copy=False)
+    keep = out_mask & ((ts >= start_ms) & (ts <= end_ms))[None, :]
+    if not keep_nans:
+        keep = keep & ~np.isnan(val)
+    cols = keep.any(axis=0)
+    if not keep[:, cols].all():
+        pairs = [extract_dps(ts, val[i], out_mask[i], start_ms, end_ms,
+                             False, keep_nans) for i in range(len(val))]
+        return ([[t for t, _ in row] for row in pairs],
+                [[v for _, v in row] for row in pairs])
+    return [ts[cols].tolist()] * len(val), val[:, cols].tolist()
 
 
 def extract_dps(out_ts: np.ndarray, out_val: np.ndarray, out_mask: np.ndarray,

@@ -24,9 +24,12 @@ Design:
     past the data's or the buffer's end does so beyond its length, under
     the mask.
   * Consistency is by content-version, not locks: `Series.snapshot()`
-    captures (data, version) atomically; at query time
-    `Series.window_bounds()` returns (lo, hi, version) atomically.  A
-    version mismatch on ANY requested series is a miss — the planner
+    captures (data, version) atomically; at query time every requested
+    series' version is read and compared with the snapshot's, and the
+    window bounds of all of them come from the snapshot's own
+    timestamps (a host copy) in one vectorised binary search — so what
+    is served is what each series held at one instant of the request.
+    A version mismatch on ANY requested series is a miss — the planner
     falls back to the host build path, and the entry is queued for a
     background refresh (the maintenance thread calls `refresh()`), so
     ingest-heavy metrics never pay rebuild costs on the query path.
@@ -83,13 +86,92 @@ class _Entry:
     #                    part of validity — a deleted+recreated series has an
     #                    equal key and a restarted version counter, and must
     #                    not validate against the old snapshot
-    versions: list     # row -> version at snapshot
+    versions: np.ndarray  # [S] int64, row -> version at snapshot
     offsets: np.ndarray  # [S+1] int64 start offsets into the buffers
+    ts_host: np.ndarray  # host [P] int64: the timestamps as pinned, for
+    #                      the window bounds of every row in one pass
     ts_dev: object     # device [P] int64 (pow2-padded, pads PAD_TS)
     val_dev: object    # device [P] float64
     nbytes: int = 0
     tick: int = 0      # LRU clock
     stale: bool = field(default=False)
+    # last few (series list, its rows): a planner that resolves a
+    # selection once asks with the same list object every time
+    rows_memo: list = field(default_factory=list)
+
+    def find_rows(self, series_list) -> np.ndarray | None:
+        """Row of every series, or None when one was born after the
+        snapshot or deleted and recreated under its key (a fresh object
+        with a restarted version counter): the snapshot is then invalid."""
+        for known, rows in self.rows_memo:
+            if known is series_list:
+                return rows
+        get, objs = self.row.get, self.series_objs
+        rows = np.fromiter((get(s.key, -1) for s in series_list), np.int64,
+                           len(series_list))
+        if (rows < 0).any() or any(
+                objs[r] is not s for r, s in zip(rows.tolist(), series_list)):
+            return None
+        return rows
+
+    def rows_of(self, series_list) -> np.ndarray | None:
+        """find_rows, remembered for the next request with this list."""
+        rows = self.find_rows(series_list)
+        if rows is not None and not any(
+                known is series_list for known, _ in self.rows_memo):
+            self.rows_memo = [(series_list, rows)] + self.rows_memo[:3]
+        return rows
+
+
+@dataclass
+class WindowBounds:
+    """Where each asked series' window lies in one entry's buffers."""
+    entry: _Entry
+    starts: np.ndarray   # [S] int64 buffer offsets
+    lengths: np.ndarray  # [S] int64 points in the window
+
+    def take(self, rows: np.ndarray) -> "WindowBounds":
+        """The bounds of the rows picked by a mask or an index vector."""
+        return WindowBounds(self.entry, self.starts[rows],
+                            self.lengths[rows])
+
+
+# effects: reads-only
+def _bounds(entry: _Entry, rows: np.ndarray | None, series_list,
+            start_ms: int, end_ms: int) -> WindowBounds | None:
+    """The windows of `series_list` (entry rows `rows`) in the entry's
+    buffers, or None when the entry cannot serve them.  Consistency is
+    by content version: the entry serves a series only while that
+    series' version is the snapshot's, and then the bounds come from the
+    snapshot's own timestamps — what is served is what the series held
+    at one instant of this request."""
+    if rows is None:
+        return None
+    now = np.fromiter((s.version for s in series_list), np.int64,
+                      len(series_list))
+    if not np.array_equal(now, entry.versions[rows]):
+        return None
+    seg_lo, seg_hi = entry.offsets[rows], entry.offsets[rows + 1]
+    lo = _search_segments(entry.ts_host, seg_lo, seg_hi, start_ms, False)
+    hi = _search_segments(entry.ts_host, seg_lo, seg_hi, end_ms, True)
+    return WindowBounds(entry, lo, hi - lo)
+
+
+def _search_segments(ts: np.ndarray, lo: np.ndarray, hi: np.ndarray,
+                     key: int, right: bool) -> np.ndarray:
+    """np.searchsorted(ts[lo[i]:hi[i]], key, side) + lo[i] for every i at
+    once: a binary search whose steps run over all segments together."""
+    lo, hi = lo.copy(), hi.copy()
+    last = len(ts) - 1
+    while True:
+        open_ = lo < hi
+        if not open_.any():
+            return lo
+        mid = (lo + hi) >> 1
+        at = ts[np.minimum(mid, last)]
+        up = open_ & ((at <= key) if right else (at < key))
+        lo = np.where(up, mid + 1, lo)
+        hi = np.where(open_ & ~up, mid, hi)
 
 
 class DeviceSeriesCache:
@@ -140,7 +222,8 @@ class DeviceSeriesCache:
 
     def batch_for(self, store, metric: int, series_list, start_ms: int,
                   end_ms: int, fix_duplicates: bool = True,
-                  build: bool = True, ts_base: int | None = None):
+                  build: bool = True, ts_base: int | None = None,
+                  bounds: WindowBounds | None = None):
         """Device [S, N] (ts, val, mask) for the series' windows, or None.
 
         A None return means cold/stale/over-budget — the caller uses its
@@ -157,44 +240,34 @@ class DeviceSeriesCache:
         caller guarantees the window grid spans < 2^31 ms from the base;
         pads land at the int32 clip ceiling (sorted past every edge,
         mirroring the int64 PAD_TS contract).
+
+        `bounds`, where the caller already asked `bounds_for` for this
+        very request, is served as it stands: the entry it names is an
+        immutable snapshot that was valid when the bounds were taken.
         """
+        del fix_duplicates      # the snapshot was normalized at build
         ekey = (id(store), metric)
-        with self._lock:
-            entry = self._entries.get(ekey)
-        if entry is None:
-            if not build:
-                with self._lock:
-                    self._stale[ekey] = store
-                self._count("misses")
-                return None
-            entry = self._build(store, metric)
+        if bounds is None:
+            with self._lock:
+                entry = self._entries.get(ekey)
             if entry is None:
+                if not build:
+                    with self._lock:
+                        self._stale[ekey] = store
+                    self._count("misses")
+                    return None
+                entry = self._build(store, metric)
+                if entry is None:
+                    self._count("misses")
+                    return None
+            bounds = _bounds(entry, entry.rows_of(series_list), series_list,
+                             start_ms, end_ms)
+            if bounds is None:
+                self._mark_stale(ekey, entry)
                 self._count("misses")
                 return None
+        entry, starts, lengths = bounds.entry, bounds.starts, bounds.lengths
         s = len(series_list)
-        starts = np.empty(s, np.int64)
-        lengths = np.empty(s, np.int64)
-        for i, series in enumerate(series_list):
-            row = entry.row.get(series.key)
-            if row is None or entry.series_objs[row] is not series:
-                # a series born after the snapshot — or deleted and
-                # recreated under the same key (fresh object, restarted
-                # version counter): either way the snapshot is invalid
-                self._mark_stale(ekey, entry)
-                self._count("misses")
-                return None
-            try:
-                lo, hi, version = series.window_bounds(start_ms, end_ms,
-                                                       fix_duplicates)
-            except ValueError:
-                self._count("misses")
-                return None     # unresolved duplicates: host path raises
-            if version != entry.versions[row]:
-                self._mark_stale(ekey, entry)
-                self._count("misses")
-                return None
-            starts[i] = entry.offsets[row] + lo
-            lengths[i] = hi - lo
         n = _pad_pow2(max(int(lengths.max(initial=0)), 1))
         # ts8+val8+mask1, or ts4+val8+mask1 for int32 pre-compacted
         # batches — the budget must not decline batches the smaller
@@ -210,6 +283,35 @@ class DeviceSeriesCache:
         self._emit_hit()
         return _gather_windows(entry.ts_dev, entry.val_dev,
                                starts, lengths, n, ts_base)
+
+    def bounds_for(self, store, metric: int, series_list, start_ms: int,
+                   end_ms: int) -> WindowBounds | None:
+        """Every asked series' window in a valid entry's buffers, in one
+        vectorised pass — or None (no entry, or one that went stale and
+        is now queued for a rebuild: the caller walks the series).  No
+        hit or miss is counted here."""
+        ekey = (id(store), metric)
+        with self._lock:
+            entry = self._entries.get(ekey)
+        if entry is None:
+            return None
+        bounds = _bounds(entry, entry.rows_of(series_list), series_list,
+                         start_ms, end_ms)
+        if bounds is None:
+            self._mark_stale(ekey, entry)
+        return bounds
+
+    # effects: reads-only
+    def peek_bounds(self, store, metric: int, series_list, start_ms: int,
+                    end_ms: int) -> WindowBounds | None:
+        """bounds_for for the explain engine: nothing is remembered
+        and a stale entry is not queued."""
+        with self._lock:
+            entry = self._entries.get((id(store), metric))
+        if entry is None:
+            return None
+        return _bounds(entry, entry.find_rows(series_list), series_list,
+                       start_ms, end_ms)
 
     # effects: reads-only
     def peek(self, store, metric: int, series_list, start_ms: int,
@@ -231,7 +333,13 @@ class DeviceSeriesCache:
         with self._lock:
             entry = self._entries.get(ekey)
             building = ekey in self._building
-        if entry is None:
+        if entry is not None:
+            bounds = _bounds(entry, entry.find_rows(series_list),
+                             series_list, start_ms, end_ms)
+            if bounds is None:
+                return False
+            max_len = int(bounds.lengths.max(initial=0))
+        else:
             if not build or building:
                 return False
             # the _build_guarded preconditions, probed without copying
@@ -244,24 +352,16 @@ class DeviceSeriesCache:
             if total > self.build_max_points or nbytes > self.max_bytes:
                 return False
             rows = {s.key: s for s in series_objs}
-            resolve = rows.get
-        else:
-            def resolve(key, _row=entry.row, _objs=entry.series_objs):
-                row = _row.get(key)
-                return None if row is None else _objs[row]
-        max_len = 0
-        for i, series in enumerate(series_list):
-            if resolve(series.key) is not series:
-                return False
-            try:
-                lo, hi, version = series.window_bounds(
-                    start_ms, end_ms, fix_duplicates)
-            except ValueError:
-                return False        # unresolved duplicates: host path
-            if entry is not None \
-                    and version != entry.versions[entry.row[series.key]]:
-                return False
-            max_len = max(max_len, hi - lo)
+            max_len = 0
+            for series in series_list:
+                if rows.get(series.key) is not series:
+                    return False
+                try:
+                    lo, hi, _ = series.window_bounds(
+                        start_ms, end_ms, fix_duplicates)
+                except ValueError:
+                    return False    # unresolved duplicates: host path
+                max_len = max(max_len, hi - lo)
         n = _pad_pow2(max(int(max_len), 1))
         per_point = 13 if ts_base is not None else 17
         return len(series_list) * n * per_point <= self.batch_max_bytes
@@ -366,7 +466,8 @@ class DeviceSeriesCache:
             val_buf[:total] = np.concatenate(parts_val)
         entry = _Entry(store=store, metric=metric, row=row,
                        series_objs=series_list,
-                       versions=versions, offsets=offsets,
+                       versions=np.asarray(versions, np.int64),
+                       offsets=offsets, ts_host=ts_buf,
                        ts_dev=_to_device(ts_buf), val_dev=_to_device(val_buf),
                        nbytes=p * _BYTES_PER_POINT)
         ekey = (id(store), metric)

@@ -497,6 +497,9 @@ class MemStore:
         # guarded-by: _lock
         self._series: dict[SeriesKey, Series] = {}
         self._by_metric: dict[int, set[SeriesKey]] = {}  # guarded-by: _lock
+        # bumped whenever a series is born or deleted: the planner's
+        # memoised series resolution is valid for one generation
+        self.series_generation = 0  # guarded-by: _lock
         self._lock = threading.RLock()
         self.compaction_queue = CompactionQueue(fix_duplicates)
         # annotations: tsuid-keyed and global lists  # guarded-by: _lock
@@ -546,6 +549,7 @@ class MemStore:
             series = Series(key, shard=key.salt(self.salt_buckets))
             self._series[key] = series
             self._by_metric.setdefault(key.metric, set()).add(key)
+            self.series_generation += 1
         return series
 
     def add_point(self, key: SeriesKey, ts_ms: int, value: float,
@@ -616,6 +620,12 @@ class MemStore:
     def add_annotation(self, note: Annotation) -> None:
         with self._lock:
             self._annotations.setdefault(note.tsuid, []).append(note)
+
+    def has_annotations(self) -> bool:
+        """False while nothing was ever annotated: a wide query then
+        skips its one lookup per tsuid."""
+        with self._lock:
+            return bool(self._annotations)
 
     def get_annotations(self, tsuid: str, start_ms: int, end_ms: int,
                         include_global: bool = False) -> list[Annotation]:
@@ -688,6 +698,7 @@ class MemStore:
         with self._lock:
             series = self._series.pop(key, None)     # order-event: memstore-write
             if series is not None:
+                self.series_generation += 1
                 keys = self._by_metric.get(key.metric)
                 if keys is not None:
                     keys.discard(key)

@@ -277,6 +277,10 @@ class RpcManager:
         REGISTRY.counter(
             "tsd.http.requests", "HTTP requests served").labels(
                 route=route, status=str(status)).inc()
+        if query.response is not None and query.response.body:
+            REGISTRY.counter(
+                "tsd.http.response_bytes", "Response body bytes").labels(
+                    route=route).inc(len(query.response.body))
         REGISTRY.histogram(
             "tsd.http.latency_ms", "HTTP request latency (ms)").labels(
                 route=route).observe(

@@ -14,7 +14,7 @@ from opentsdb_tpu.models.tsquery import (
     TSQuery, TSSubQuery, parse_m_subquery, parse_tsuid_subquery,
     parse_rate_options, parse_percentiles)
 from opentsdb_tpu.query.filters import build_filter, tags_to_filters
-from opentsdb_tpu.tsd.http import BadRequestError, HttpQuery
+from opentsdb_tpu.tsd.http import BadRequestError, HttpQuery, RawJson
 
 
 class HttpSerializer:
@@ -175,8 +175,17 @@ class HttpJsonSerializer(HttpSerializer):
                         globals_list: list | None = None) -> list[dict]:
         """The /api/query result array (formatQueryAsyncV1 :516)."""
         out = []
+        keys: dict = {}     # timestamps -> their key strings, shared
+        plain = not (data_query.show_tsuids or data_query.show_query
+                     or data_query.global_annotations)
         for r in results:
+            text = (r.json_text(keys, data_query.ms_resolution)
+                    if plain and not r.annotations else None)
+            if text is not None:
+                out.append(RawJson(text))
+                continue
             out.append(r.to_json(
+                keys=keys,
                 ms_resolution=data_query.ms_resolution,
                 show_tsuids=data_query.show_tsuids,
                 fill_policy=(data_query.queries[r.index].fill_policy

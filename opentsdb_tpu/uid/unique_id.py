@@ -83,6 +83,9 @@ class UniqueId:
         self.cache_misses = 0  # guarded-by: _lock
         self.assigned = 0  # guarded-by: _lock
         self._id_filter = None  # UniqueIdFilterPlugin hook  # guarded-by: _lock
+        # bumped by rename/delete: what a name resolved to before may no
+        # longer hold (the planner's memoised series resolution reads it)
+        self.renames = 0  # guarded-by: _lock
         self.on_create = None   # callable(name, uid) on new assignment
 
     @property
@@ -181,6 +184,7 @@ class UniqueId:
             validate_uid_name(self.kind.value, new_name)
             self._name_to_id[new_name] = uid
             self._id_to_name[uid] = new_name
+            self.renames += 1
 
     def delete(self, name: str) -> int:
         with self._lock:
@@ -188,6 +192,7 @@ class UniqueId:
             if uid is None:
                 raise NoSuchUniqueName(self.kind.value, name)
             self._id_to_name.pop(uid, None)
+            self.renames += 1
             return uid
 
     # -- codec helpers --

@@ -203,19 +203,29 @@ class TestPredictionSanity:
     def test_headline_predictions_near_measurements(self):
         """The calibrated model must land within 3x of the chip anchors
         it was fitted to (a grossly wrong formula would still 'choose'
-        something — this pins the magnitudes)."""
+        something — this pins the magnitudes).  The group anchors are
+        PR 27's race on a v5e at fleet-replay-100k's and heavy-replay's
+        shapes (PERF.md section 6); sorted at its worst two."""
         s, n, e = 1024, 65_536, 514
         anchors = [
             (costmodel.predict_search("scan", s, n, e, "tpu"), 0.154),
             (costmodel.predict_search("compare_all", s, n, e, "tpu"),
              0.116),
             (costmodel.predict_search("hier", s, n, e, "tpu"), 0.020),
-            (costmodel.predict_group("segment", 1024, 512, 100, "tpu"),
-             0.219),
-            (costmodel.predict_group("sorted", 1024, 512, 100, "tpu"),
-             0.090),
-            (costmodel.predict_group("matmul", 1024, 512, 100, "tpu"),
-             0.100),
+            (costmodel.predict_group("segment", 100_000, 16, 16, "tpu"),
+             0.3475),
+            (costmodel.predict_group("sorted", 100_000, 8, 131_072, "tpu"),
+             0.0386),
+            (costmodel.predict_group("matmul", 100_000, 16, 16, "tpu"),
+             0.0177),
+            (costmodel.predict_group("segment", 4000, 128, 16, "tpu"),
+             0.0819),
+            (costmodel.predict_group("sorted", 4000, 16, 4096, "tpu"),
+             0.00241),
+            (costmodel.predict_group("matmul", 4000, 128, 16, "tpu"),
+             0.00594),
+            (costmodel.predict_group("rows", 100_000, 8, 131_072, "tpu"),
+             0.00119),
             (costmodel.predict_extreme("scan", s, n, e, "tpu"), 0.40),
         ]
         for got, want in anchors:
