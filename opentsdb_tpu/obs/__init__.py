@@ -87,6 +87,15 @@ METRICS_SCHEMA: dict[str, MetricSpec] = {
         "Grouped dispatches of the monolithic pipeline, by the "
         "group-reduce form the chooser took for their shape (segment, "
         "sorted, matmul, rows)."),
+    "tsd.query.contrib_lane": _m(
+        "counter", ("lane",),
+        "Grouped dispatches answered by one device program, by the "
+        "contribution lane that program took (ops/group_agg.py "
+        "grid_contributions): dense = no row of the [S, W] grid had a "
+        "hole between two present windows, so interpolation was "
+        "skipped; full = at least one had.  On the mesh dense means "
+        "every shard's rows.  Tiled and lane-folded executions run "
+        "one contribution program a tile and count nothing here."),
     "tsd.http.response_bytes": _m(
         "counter", ("route",),
         "Response body bytes written, by registered route."),

@@ -388,6 +388,18 @@ def _blocked_group_fold(xs, flags, bounds, s_orig: int, op, identity):
     return jnp.where(empty, jnp.asarray(identity, xs.dtype), out)
 
 
+def _no_interior_hole(mask):
+    """mask[S, W] -> bool[]: every row is one contiguous run of True, or
+    all False.  A run starts where a True follows a False (or sits in
+    column 0); a row with at most one start has no hole between two
+    present windows.  One fused elementwise pass and a row reduction —
+    no scan, no gather, no 64-bit arithmetic."""
+    starts = mask[:, 1:] & ~mask[:, :-1]
+    rises = mask[:, 0].astype(jnp.int32) \
+        + jnp.sum(starts, axis=1, dtype=jnp.int32)
+    return jnp.all(rises <= 1)
+
+
 def grid_contributions(grid_ts, val, mask, agg: Aggregator):
     """Per-series contribution + participation at every grid slot.
 
@@ -395,14 +407,24 @@ def grid_contributions(grid_ts, val, mask, agg: Aggregator):
     (nextDoubleValue :735): a series missing window w contributes the
     interpolated value per the aggregator's policy, participating only
     between its first and last present window.  Row-local — valid across
-    any row sharding.  Returns (contrib[S, W], participate[S, W]).
+    any row sharding.  Returns (contrib[S, W], participate[S, W], dense[]).
 
-    Hole-free grids (every series has every window — the common
-    downsampled dense shape, and the headline benchmark's) take a
-    lax.cond fast lane that skips the prev/next scans, the four gathers,
-    and the interpolation entirely: with mask all-true, contrib == val
-    and participate == mask exactly.  Data with holes runs the full
-    branch; the cond costs one jnp.all reduce.
+    Grids without an INTERIOR hole — every row one contiguous run of
+    present windows, or empty (`_no_interior_hole`) — take a lax.cond
+    fast lane that skips the prev/next scans, the four gathers and the
+    interpolation: a missing slot before a row's first value or after
+    its last has no previous or no next, so participate == mask there,
+    and every consumer masks contrib by participate first.  The lane's
+    answer (val, mask) is therefore the full branch's on every slot
+    anyone reads.  That covers the regular-cadence store whatever its
+    padding: windows past the live count, rate's masked first column,
+    series born late or ended early.  A row with a real hole (a host
+    down mid-range) sends the grid through the full branch; the cond
+    costs one elementwise pass and a row reduce over bool[S, W].
+
+    The third return is the predicate itself, a bool scalar: the lane
+    is decided on the device, and the served path hands it back beside
+    the answer for `tsd.query.contrib_lane{lane}`.
     """
     from jax import lax
 
@@ -438,7 +460,10 @@ def grid_contributions(grid_ts, val, mask, agg: Aggregator):
         _, val_, mask_ = operand
         return val_.astype(out_dtype), mask_
 
-    return lax.cond(jnp.all(mask), _dense, _full, (grid_ts, val, mask))
+    dense = _no_interior_hole(mask)
+    contrib, participate = lax.cond(dense, _dense, _full,
+                                    (grid_ts, val, mask))
+    return contrib, participate, dense
 
 
 def _flat_segments(contrib, participate, gid, num_groups: int):
@@ -682,10 +707,11 @@ def grid_group_aggregate(grid_ts, val, mask, gid, num_groups: int,
                          row_groups: bool = False):
     """All-groups-at-once grid aggregation (single-device form).
 
-    [S, W] batch + gid[S] -> (grid_ts[W], out[G, W], out_mask[G, W]).
-    out_mask marks (group, window) cells where at least one member holds an
-    actual (non-interpolated) value — the union-timestamp rule restricted to
-    the shared grid.
+    [S, W] batch + gid[S] -> (grid_ts[W], out[G, W], out_mask[G, W],
+    dense[]).  out_mask marks (group, window) cells where at least one
+    member holds an actual (non-interpolated) value — the union-timestamp
+    rule restricted to the shared grid.  dense is grid_contributions'
+    lane predicate (True: the grid had no interior hole).
 
     rows_sorted=True is a CALLER GUARANTEE that gid is non-decreasing
     (the planner always builds it that way, planner.py:403) — the sorted
@@ -696,7 +722,7 @@ def grid_group_aggregate(grid_ts, val, mask, gid, num_groups: int,
     moment reductions and the mask pass are then copies.
     """
     vf = val.astype(jnp.float64)
-    contrib, participate = grid_contributions(grid_ts, vf, mask, agg)
+    contrib, participate, dense = grid_contributions(grid_ts, vf, mask, agg)
     if is_moment_agg(agg.name):
         out, _ = moment_group_reduce(agg.name, contrib, participate, gid,
                                      num_groups, rows_sorted=rows_sorted,
@@ -732,4 +758,4 @@ def grid_group_aggregate(grid_ts, val, mask, gid, num_groups: int,
             mask.reshape(-1).astype(jnp.int32), seg,
             num_segments=num_groups * w)
         out_mask = present.reshape(num_groups, w) > 0
-    return grid_ts, out, out_mask
+    return grid_ts, out, out_mask, dense

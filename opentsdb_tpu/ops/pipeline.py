@@ -169,7 +169,10 @@ def _group_pipeline(spec: PipelineSpec, num_groups: int, ts, val, mask, gid,
 def _grid_tail(spec: PipelineSpec, num_groups: int, wts, v, m, gid):
     """Shared pipeline tail: (rate ->) grouped cross-series aggregation on
     an already-downsampled [S, W] grid.  Also the finish stage of the
-    streaming executor (ops.streaming hands it the accumulated grid)."""
+    streaming executor (ops.streaming hands it the accumulated grid).
+    Like every grouped program here it returns grid_group_aggregate's
+    four: the answer's triple and `dense`, the contribution lane the
+    device took (ops/group_agg.py::grid_contributions)."""
     from opentsdb_tpu.ops.group_agg import grid_group_aggregate
     agg = get_agg(spec.aggregator)
     if spec.rate is not None:
@@ -238,7 +241,7 @@ def _stacked_group_pipeline(spec: PipelineSpec, num_groups: int, ts, val,
     (stacked along axis 0), and inside the vmap the kernels trace on
     the per-member [S, N] shapes, so the mode choosers pick exactly
     what a solo dispatch of the same member would.  Per-member results
-    come back batched ([Q, W], [Q, G, W], [Q, G, W]) for host-side
+    come back batched ([Q, W], [Q, G, W], [Q, G, W], [Q]) for host-side
     unpack; on integer data a member's slice is bitwise what its solo
     dispatch would produce (integer-exact f64 accumulation is
     reassociation-proof — the same contract the rollup lanes pin).
@@ -258,7 +261,8 @@ _jitted_lane_partials = jax.jit(_lane_partials, static_argnums=0)
 
 
 def run_grid_tail(spec: PipelineSpec, wts, v, m, gid, num_groups: int):
-    """Finish a streamed query: grid [S, W] -> (wts, out[G, W], mask[G, W])."""
+    """Finish a streamed query: grid [S, W] -> (wts, out[G, W],
+    mask[G, W], dense[])."""
     return _jitted_grid_tail(spec, num_groups, wts, v, m, gid)
 
 
@@ -266,8 +270,10 @@ def run_grid_tail(spec: PipelineSpec, wts, v, m, gid, num_groups: int):
 def run_stacked_group_pipeline(spec: PipelineSpec, ts, val, mask, gid,
                                num_groups: int, wargs: dict):
     """Q stacked grouped pipelines -> (wts[Q, W], out[Q, G, W],
-    mask[Q, G, W]) — the batcher's one-launch form of
-    run_group_pipeline; `wargs` values carry a leading member axis."""
+    mask[Q, G, W], dense[Q]) — the batcher's one-launch form of
+    run_group_pipeline; `wargs` values carry a leading member axis.
+    Under the vmap grid_contributions' cond is a select (both branches
+    run); dense[q] still says which answer member q got."""
     if spec.downsample is None:
         raise ValueError("grouped pipeline requires a downsample step")
     return _jitted_stacked_group(spec, num_groups, ts, val, mask, gid,
@@ -290,7 +296,8 @@ def run_lane_partials(spec: WindowSpec, ts, val, mask, wargs: dict):
 # shape: ts[S,N] any, val[S,N] any, mask[S,N] bool, gid[S] any
 def run_group_pipeline(spec: PipelineSpec, ts, val, mask, gid,
                        num_groups: int, wargs: dict | None = None):
-    """Execute the grouped pipeline -> (wts[W], out[G, W], out_mask[G, W]).
+    """Execute the grouped pipeline -> (wts[W], out[G, W], out_mask[G, W],
+    dense[]).
 
     Requires a downsample step (the shared grid is what makes the segmented
     cross-series reduce possible); union-timestamp queries keep the
@@ -333,7 +340,8 @@ _jitted_group_rollup_avg = jax.jit(_group_rollup_avg, static_argnums=(0, 1))
 def run_group_rollup_avg_pipeline(spec: PipelineSpec, ts_s, val_s, mask_s,
                                   ts_c, val_c, mask_c, gid, num_groups: int,
                                   wargs: dict | None = None):
-    """Grouped rollup-avg pipeline -> (wts[W], out[G, W], out_mask[G, W])."""
+    """Grouped rollup-avg pipeline -> (wts[W], out[G, W], out_mask[G, W],
+    dense[])."""
     return _jitted_group_rollup_avg(spec, num_groups, ts_s, val_s, mask_s,
                                     ts_c, val_c, mask_c, gid, wargs or {})
 

@@ -34,9 +34,11 @@ instead, in the spilled-window-aggregation stance (arXiv:2007.10385):
      [S_total, W] grid never materializes anywhere, host or device.
      Replaying contributions through ``grid_contributions`` is exact:
      participation regions are contiguous per row, so the recomputation
-     is the identity on every participating cell, and group-by
-     reduction over a stripe equals the same reduction over the full
-     grid restricted to those columns (associative per cell).  The
+     is the identity on every participating cell (and takes that
+     function's dense lane: no row of a stripe has an interior hole),
+     and group-by reduction over a stripe equals the same reduction
+     over the full grid restricted to those columns (associative per
+     cell).  The
      out-mask comes from the spilled ACTUAL mask (a cell is present
      only where a member holds a real value, not an interpolated one —
      the same rule the resident tail applies).
@@ -202,7 +204,7 @@ def _tile_contrib(spec, wts, v, m):
         agg = Aggregator(agg.name, PREV, agg.reduce)
         grid_b = jnp.broadcast_to(grid[None, :], v.shape)
         _, v, m = rate(grid_b, v, m, spec.rate, all_int=False)
-    contrib, participate = grid_contributions(
+    contrib, participate, _dense = grid_contributions(
         grid, v.astype(jnp.float64), m, agg)
     return contrib, participate, m
 
@@ -473,9 +475,9 @@ def run_tiled(tsdb, spec, seg, series_list, gid, g_pad: int, window_spec,
             wts_s[:n] = wts_full[w0:w1]
             if n < ws:
                 wts_s[n:] = wts_full[w1 - 1]
-            _, ov, _om = run_grid_tail(spec_tail, jnp.asarray(wts_s),
-                                       jnp.asarray(V), jnp.asarray(P),
-                                       gid_dev, g_pad)
+            _, ov, _om, _dense = run_grid_tail(
+                spec_tail, jnp.asarray(wts_s), jnp.asarray(V),
+                jnp.asarray(P), gid_dev, g_pad)
             pres = _jitted_presence(g_pad, jnp.asarray(A), gid_dev)
             out_val[:, w0:w1] = np.asarray(ov)[:, :n]
             out_mask[:, w0:w1] = np.asarray(pres)[:, :n]

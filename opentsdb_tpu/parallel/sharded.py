@@ -260,7 +260,10 @@ def _local_grid_tail(spec, num_groups: int, wts, v, m, gid):
         grid_b = jnp.broadcast_to(grid[None, :], v.shape)
         _, v, m = rate(grid_b, v, m, spec.rate, all_int=False)
     vf = v.astype(jnp.float64)
-    contrib, participate = grid_contributions(grid, vf, m, agg)
+    contrib, participate, dense = grid_contributions(grid, vf, m, agg)
+    # each shard took the lane its own rows allow; the answer's lane is
+    # dense iff every shard's was
+    dense = lax.psum((~dense).astype(jnp.int32), _BOTH) == 0
     if is_moment_agg(agg.name):
         out, _ = moment_group_reduce(
             agg.name, contrib, participate, gid, g,
@@ -285,7 +288,7 @@ def _local_grid_tail(spec, num_groups: int, wts, v, m, gid):
     present = jax.ops.segment_sum(m.reshape(-1).astype(jnp.int32), seg,
                                   num_segments=g * w)
     out_mask = lax.psum(present, _BOTH).reshape(g, w) > 0
-    return wts, out, out_mask
+    return wts, out, out_mask, dense
 
 
 @lru_cache(maxsize=128)
@@ -294,8 +297,9 @@ def sharded_query_pipeline(mesh: Mesh, spec, num_groups: int):
 
     fn(ts, val, mask, gid, wargs) with rows sharded over every chip
     (dim 0 split across both mesh axes, time dim intact so downsample/rate
-    stay row-local); returns replicated (wts[W], out[G, W], out_mask[G, W])
-    identical to ops.pipeline.run_group_pipeline's single-device answer.
+    stay row-local); returns replicated (wts[W], out[G, W], out_mask[G, W],
+    dense[]) identical to ops.pipeline.run_group_pipeline's single-device
+    answer (dense: every shard's rows took the dense contribution lane).
 
     `spec` is a PipelineSpec (hashable) — the builder is lru_cached so a
     dashboard re-issuing the same query shape reuses the compiled program.
@@ -313,7 +317,7 @@ def sharded_query_pipeline(mesh: Mesh, spec, num_groups: int):
         local, mesh=mesh,
         in_specs=(P(_BOTH, None), P(_BOTH, None), P(_BOTH, None), P(_BOTH),
                   P()),
-        out_specs=(P(), P(), P()),
+        out_specs=(P(), P(), P(), P()),
         check_vma=False)
     return jax.jit(mapped)
 
@@ -435,7 +439,8 @@ def _stream_update_sliced_fn(mesh: Mesh, window_spec, wc: int,
 def _stream_finish_fn(mesh: Mesh, window_spec, pipeline_spec,
                       num_groups: int):
     """Jitted shard_map'd stream finish: per-chip moment state -> replicated
-    (wts[W], out[G, W], out_mask[G, W]) via the collective grid tail."""
+    (wts[W], out[G, W], out_mask[G, W], dense[]) via the collective grid
+    tail."""
     from opentsdb_tpu.ops import streaming
 
     step = pipeline_spec.downsample
@@ -449,7 +454,7 @@ def _stream_finish_fn(mesh: Mesh, window_spec, pipeline_spec,
     mapped = shard_map(
         fin, mesh=mesh,
         in_specs=(P(_BOTH, None), P(_BOTH), P()),
-        out_specs=(P(), P(), P()),
+        out_specs=(P(), P(), P(), P()),
         check_vma=False)
     return jax.jit(mapped)
 
@@ -533,7 +538,8 @@ class ShardedStreamAccumulator:
         return int(np.asarray(self.state["oob"]))
 
     def finish_tail(self, pipeline_spec, gid: np.ndarray, num_groups: int):
-        """Replicated (wts[W], out[G, W], out_mask[G, W]) for the query."""
+        """Replicated (wts[W], out[G, W], out_mask[G, W], dense[]) for the
+        query."""
         fn = _stream_finish_fn(self.mesh, self.window_spec, pipeline_spec,
                                num_groups)
         pad_gid = np.full(self.s_pad, num_groups, np.int64)

@@ -168,7 +168,7 @@ class DispatchBatcher:
     def submit(self, spec, ts, val, mask, gid, g_pad: int, wargs: dict,
                host_small: bool, policy_epoch: int, deadline=None):
         """Execute one batch-routed plan; returns ((out_ts, out_val,
-        out_mask), info) where the outputs are the member's own
+        out_mask, dense), info) where the outputs are the member's own
         host-unpacked slice (np arrays when stacked, device arrays on
         the solo fallback) and ``info`` carries the batch verdict for
         span annotation.  Raises the member's own deadline error if it
@@ -216,7 +216,7 @@ class DispatchBatcher:
         # planner's own "dispatch" mark right after submit() returns
         # then reads ~0 for stacked members
         latattr.mark("batch_rendezvous")
-        q = result[3]
+        q = result[4]
         outcome = "stacked" if q > 1 else "solo"
         REGISTRY.counter(
             "tsd.query.batch.queries",
@@ -226,7 +226,7 @@ class DispatchBatcher:
             "tsd.query.batch.wait_ms",
             "Coalesce wait before the stacked/solo dispatch "
             "(ms)").observe(waited_ms)
-        return result[:3], {"q": q, "stacked": q > 1,
+        return result[:4], {"q": q, "stacked": q > 1,
                             "waitMs": round(waited_ms, 3)}
 
     def _follow(self, bucket: _Bucket, member: _Member,
@@ -321,7 +321,7 @@ class DispatchBatcher:
                                          m.gid, g_pad, m.wargs)
             with self._lock:
                 self.solo_dispatches += 1
-            return [(out[0], out[1], out[2], 1)]
+            return [(*out, 1)]
         # The member axis pads to a power of FOUR (replicating the
         # first member; its extra slices are dropped after unpack), so
         # the stacked program compiles once per (bucket key, quantum)
@@ -343,12 +343,13 @@ class DispatchBatcher:
         wargs = {k: np.stack([np.asarray(m.wargs[k]) for m in padded])
                  for k in live[0].wargs}
         with host_lane(host_small):
-            wts, out_val, out_mask = run_stacked_group_pipeline(
+            wts, out_val, out_mask, dense = run_stacked_group_pipeline(
                 spec, ts, val, mask, gid, g_pad, wargs)
         # host-side unpack: one transfer per output, then row views
         wts = np.asarray(wts)
         out_val = np.asarray(out_val)
         out_mask = np.asarray(out_mask)
+        dense = np.asarray(dense)
         with self._lock:
             self.stacked_dispatches += 1
             self.stacked_members += q
@@ -365,7 +366,7 @@ class DispatchBatcher:
                             points=int(ts.shape[2]),
                             groups=int(g_pad),
                             hostSmall=bool(host_small))
-        return [(wts[i], out_val[i], out_mask[i], q)
+        return [(wts[i], out_val[i], out_mask[i], dense[i], q)
                 for i in range(q)]
 
     # -- stats ----------------------------------------------------------- #

@@ -17,6 +17,7 @@ import json
 import threading
 from dataclasses import dataclass, replace
 
+import jax
 import numpy as np
 
 from opentsdb_tpu.models.tsquery import TSQuery, TSSubQuery
@@ -772,7 +773,7 @@ class QueryRunner:
             # calibration ring skips lane-served executions like
             # rewrites/tiled runs (the monolithic stage breakdown does
             # not describe them).
-            out_ts, out_val, out_mask = self._run_lane_serve(
+            out_ts, out_val, out_mask, dense = self._run_lane_serve(
                 spec, seg, lane_plan, series_list, gid, g_pad, windows,
                 window_spec, budget, fix, psp)
             self.exec_stats["rollupLane"] = 1.0
@@ -789,12 +790,13 @@ class QueryRunner:
                 tsdb, spec, seg, series_list, gid, g_pad, window_spec,
                 wargs, ds_fn, lanes_for([ds_fn]), sketchable, fix,
                 tiled_plan, budget, store=store)
+            dense = None    # no one lane: a contribution program a tile
             obs_trace.annotate(psp, tiling=tile_stats)
             self.exec_stats["tiledExecution"] = 1.0
             self._bump("spillBytes", float(tile_stats["spillBytes"]))
             self._bump("tiledTiles", float(tile_stats["tiles"]))
         elif agg_plan is not None:
-            out_ts, out_val, out_mask = self._run_agg_rewrite(
+            out_ts, out_val, out_mask, dense = self._run_agg_rewrite(
                 spec, agg_plan, series_list, gid, g_pad, windows,
                 window_spec, host_small, budget)
         elif pd.path == "batched":
@@ -810,7 +812,7 @@ class QueryRunner:
             from opentsdb_tpu.query.limits import active_deadline
             ts, val, mask, _ = build_batch_direct(
                 series_list, seg.start_ms, seg.end_ms, fix)
-            (out_ts, out_val, out_mask), batch_info = \
+            (out_ts, out_val, out_mask, dense), batch_info = \
                 tsdb.dispatch_batcher.submit(
 
                     spec, ts, val, mask, gid, g_pad, wargs,
@@ -826,7 +828,7 @@ class QueryRunner:
             # Beyond the threshold the batch never materializes: bounded
             # chunks are copied straight out of the store into the device
             # accumulator (SaltScanner overlap analog, VERDICT r1 #4).
-            out_ts, out_val, out_mask = self._stream_grouped(
+            out_ts, out_val, out_mask, dense = self._stream_grouped(
                 spec, seg, series_list, n_max, gid, g_pad, window_spec,
                 wargs, sketch=sketchable)
         elif seg.kind == "rollup_avg":
@@ -845,8 +847,10 @@ class QueryRunner:
                         tsdb.config.fix_duplicates))
             tc, vc, mc, _ = build_batch(cnt_windows)
             with host_lane(host_small):
-                out_ts, out_val, out_mask = run_group_rollup_avg_pipeline(
-                    spec, ts, val, mask, tc, vc, mc, gid, g_pad, wargs)
+                out_ts, out_val, out_mask, dense = \
+                    run_group_rollup_avg_pipeline(
+                        spec, ts, val, mask, tc, vc, mc, gid, g_pad,
+                        wargs)
         else:
             if cached is not None:
                 ts, val, mask = cached
@@ -872,15 +876,15 @@ class QueryRunner:
                 else:
                     d_ts, d_val, d_mask, d_gid = shard_rows(
                         mesh, ts, val, mask, gid, pad_gid_value=g_pad)
-                out_ts, out_val, out_mask = fn(d_ts, d_val, d_mask, d_gid,
-                                               wargs)
+                out_ts, out_val, out_mask, dense = fn(
+                    d_ts, d_val, d_mask, d_gid, wargs)
             else:
                 if n_groups == len(gid) and pd.path in pdn.ROW_GROUP_PATHS:
                     # one member a group, the whole batch in this one
                     # dispatch: row i is group i
                     spec = replace(spec, row_groups=True)
                 with host_lane(host_small):
-                    out_ts, out_val, out_mask = run_group_pipeline(
+                    out_ts, out_val, out_mask, dense = run_group_pipeline(
                         spec, ts, val, mask, gid, g_pad, wargs)
 
         # the arm above returned (dispatch enqueued; results may still
@@ -924,9 +928,15 @@ class QueryRunner:
                 fields["batch"] = batch_info
             recorder.record("plan", **fields)
         with obs_trace.timed_stage("extract"):
-            out_ts = np.asarray(out_ts)
-            out_val = np.asarray(out_val)
-            out_mask = np.asarray(out_mask)
+            out_ts, out_val, out_mask, dense = self._materialize_answer(
+                out_ts, out_val, out_mask, dense)
+            if dense is not None:
+                # which contribution lane the device took
+                # (ops/group_agg.py grid_contributions)
+                REGISTRY.counter(
+                    "tsd.query.contrib_lane", "Grouped dispatches by "
+                    "the contribution lane the device took").labels(
+                        lane="dense" if dense else "full").inc()
             # device->host materialization is where an async dispatch
             # actually blocks (tracing syncs earlier via device_wait,
             # in which case this delta is ~0)
@@ -1066,6 +1076,17 @@ class QueryRunner:
         (`_materialize` prefix: this is a sanctioned device->host
         result materialization, like the extract stage's)."""
         return (np.asarray(v)[:, :count], np.asarray(m)[:, :count])
+
+    @staticmethod
+    def _materialize_answer(out_ts, out_val, out_mask, dense):
+        """Host copies of a grouped dispatch's answer and of the lane
+        scalar its program made, in ONE fetch: device_get starts every
+        copy before it waits for the first, where an np.asarray each in
+        turn pays a blocking transfer's latency (~0.4 ms on the chip,
+        PERF.md section 6, PR 28).  Host arrays and None pass through.
+        (`_materialize` prefix: the sanctioned device->host result
+        materialization of the extract stage.)"""
+        return jax.device_get((out_ts, out_val, out_mask, dense))
 
     def _run_agg_rewrite(self, spec, plan, series_list, gid, g_pad,
                          windows, window_spec, host_small, budget):
@@ -1371,10 +1392,12 @@ class QueryRunner:
         fold_dev_ok = foldable and fold_rows >= 1
         if foldable and spec.rate is None \
                 and bool(np.all(m_full[:, :w])):
-            # DENSE rate-free grid (every interior cell populated —
-            # the regular-cadence common case): grid_contributions is
-            # the identity (contrib == values, participate == mask,
-            # exactly — its own lax.cond fast lane) and there is no
+            # DENSE rate-free grid (every live cell populated — the
+            # regular-cadence common case): grid_contributions is the
+            # identity (contrib == values, participate == mask,
+            # exactly).  Its own lax.cond fast lane asks less (no row
+            # with a hole BETWEEN two present windows), which this
+            # test implies.  There is no
             # rate pass, so the per-tile device work degenerates to
             # group-partial sums the host computes directly at memcpy
             # speed.  Rate queries take the device fold below, whose
@@ -1414,7 +1437,7 @@ class QueryRunner:
             if agg_name != "count":
                 out_val = np.where(cnt > 0, out_val, np.nan)
             obs_trace.annotate(psp, rollup_fold="host_dense")
-            return wts, out_val, present > 0
+            return wts, out_val, present > 0, None
         if fold_dev_ok:
             # holes in the grid: interpolation/participation must run
             # (row-local, full-width) — fold tile by tile on device
@@ -1457,7 +1480,7 @@ class QueryRunner:
             if agg_name != "count":
                 out_val = np.where(cnt > 0, out_val, np.nan)
             obs_trace.annotate(psp, rollup_fold=True)
-            return wts, out_val, present > 0
+            return wts, out_val, present > 0, None
 
         def tile_grid(row_lo: int, row_hi: int):
             return (wts, v_full[row_lo:row_hi], m_full[row_lo:row_hi])
@@ -1468,7 +1491,7 @@ class QueryRunner:
             store=tsdb.store, tile_grid_fn=tile_grid)
         obs_trace.annotate(psp, tiling=tile_stats)
         self._bump("spillBytes", float(tile_stats["spillBytes"]))
-        return out_ts, out_val, out_mask
+        return out_ts, out_val, out_mask, None
 
     def _stream_grouped(self, spec: PipelineSpec, seg, series_list,
                         max_len: int, gid, g_pad: int, window_spec, wargs,

@@ -71,7 +71,7 @@ def main() -> None:
         downsample=DownsampleStep("avg", window_spec, "none", 0.0))
 
     # single-host reference on this process's local devices
-    ref_ts, ref_val, ref_mask = run_group_pipeline(
+    ref_ts, ref_val, ref_mask, _ = run_group_pipeline(
         spec, ts, val, mask, gid, g_pad, wargs)
     ref_ts, ref_val, ref_mask = (np.asarray(ref_ts), np.asarray(ref_val),
                                  np.asarray(ref_mask))
@@ -82,7 +82,7 @@ def main() -> None:
     fn = sharded_query_pipeline(mesh, spec, g_pad)
     d_ts, d_val, d_mask, d_gid = shard_rows(mesh, ts, val, mask, gid,
                                             pad_gid_value=g_pad)
-    out_ts, out_val, out_mask = fn(d_ts, d_val, d_mask, d_gid, wargs)
+    out_ts, out_val, out_mask, _ = fn(d_ts, d_val, d_mask, d_gid, wargs)
     out_ts, out_val, out_mask = (np.asarray(out_ts), np.asarray(out_val),
                                  np.asarray(out_mask))
 

@@ -1,12 +1,16 @@
-"""grid_contributions: the dense (hole-free) lax.cond fast lane must be
-exactly the full interpolation branch's answer at the all-true boundary,
-and the full branch must be unchanged for holey masks."""
+"""grid_contributions: the dense lax.cond fast lane — taken by every
+grid without an INTERIOR hole — must be exactly the full interpolation
+branch's answer on every slot a consumer reads, and the full branch must
+be unchanged for grids with a real hole."""
 
 import numpy as np
 import pytest
 
-from opentsdb_tpu.ops.aggregators import get_agg
-from opentsdb_tpu.ops.group_agg import grid_contributions
+from opentsdb_tpu.ops import group_agg
+from opentsdb_tpu.ops.aggregators import PREV, Aggregator, get_agg
+from opentsdb_tpu.ops.group_agg import (_no_interior_hole,
+                                        grid_contributions,
+                                        grid_group_aggregate)
 from opentsdb_tpu.ops.rate import _prev_valid_index
 from opentsdb_tpu.ops.union_agg import interpolate, _next_valid
 
@@ -44,7 +48,8 @@ def test_cond_matches_full_reference(aggname, holey):
     else:
         mask = jnp.ones((s, w), bool)
     agg = get_agg(aggname)
-    got_c, got_p = grid_contributions(grid_ts, val, mask, agg)
+    got_c, got_p, dense = grid_contributions(grid_ts, val, mask, agg)
+    assert bool(dense) == (not holey)
     want_c, want_p = _full_reference(grid_ts, val, mask, agg)
     np.testing.assert_array_equal(np.asarray(got_p), np.asarray(want_p))
     gp = np.asarray(want_p)
@@ -67,9 +72,201 @@ def test_f32_values_keep_working():
         agg = get_agg(aggname)
         for mask in (jnp.ones((s, w), bool),
                      jnp.asarray(rng.random((s, w)) > 0.5)):
-            c, p = grid_contributions(grid_ts, val, mask, agg)
+            c, p, _ = grid_contributions(grid_ts, val, mask, agg)
             assert c.dtype == want_dtype, (aggname, c.dtype)
             assert p.shape == (s, w)
+
+
+# ---- grids whose only missing slots are at the EDGES of their rows ---- #
+
+S_EDGE, W_EDGE, LIVE = 12, 16, 12
+AGGS = ("sum", "avg", "min", "max", "zimsum", "mimmax", "dev", "count",
+        "p99", "median", "first", "last")
+
+
+def _edge_mask(case: str) -> np.ndarray:
+    """bool[S_EDGE, W_EDGE] with no hole between two present windows."""
+    m = np.zeros((S_EDGE, W_EDGE), bool)
+    if case == "all_false":
+        return m
+    m[:, :LIVE] = True                      # padded tail: live < W
+    if case == "rate_first_column":
+        m[:, 0] = False
+    elif case == "born_late_ended_early":
+        m[2, :5] = False                    # born late
+        m[7, 9:] = False                    # ended early
+        m[9, :3] = False                    # both
+        m[9, 10:] = False
+    elif case == "empty_row":
+        m[4] = False
+    elif case == "everything_at_once":
+        m[:, 0] = False
+        m[1, :6] = False
+        m[4] = False
+        m[10, 7:] = False
+        m[11] = False
+        m[11, 3] = True                     # a run of one window
+    else:
+        assert case == "padded_tail", case
+    return m
+
+
+EDGE_CASES = ("padded_tail", "rate_first_column", "born_late_ended_early",
+              "empty_row", "all_false", "everything_at_once")
+
+
+def _edge_grid(case: str):
+    import jax.numpy as jnp
+    rng = np.random.default_rng(28)
+    grid_ts = jnp.asarray(1_451_606_400_000
+                          + np.arange(W_EDGE, dtype=np.int64) * 60_000)
+    val = rng.normal(50, 20, (S_EDGE, W_EDGE))
+    mask = _edge_mask(case)
+    # what a downsample leaves under a False mask is not a value
+    val = np.where(mask, val, np.nan)
+    gid = jnp.asarray(np.arange(S_EDGE, dtype=np.int64) // 4)
+    return grid_ts, jnp.asarray(val), jnp.asarray(mask), gid
+
+
+def _agg(name: str, interp: str) -> Aggregator:
+    agg = get_agg(name)
+    return agg if interp == "own" else Aggregator(agg.name, PREV,
+                                                  agg.reduce)
+
+
+@pytest.mark.parametrize("interp", ["own", "prev"])
+@pytest.mark.parametrize("aggname", AGGS)
+@pytest.mark.parametrize("case", EDGE_CASES)
+def test_edge_holes_take_the_dense_lane_exactly(case, aggname, interp):
+    grid_ts, val, mask, _ = _edge_grid(case)
+    agg = _agg(aggname, interp)
+    got_c, got_p, dense = grid_contributions(grid_ts, val, mask, agg)
+    assert bool(dense)
+    want_c, want_p = _full_reference(grid_ts, val, mask, agg)
+    assert got_c.dtype == want_c.dtype
+    np.testing.assert_array_equal(np.asarray(got_p), np.asarray(want_p))
+    gp = np.asarray(want_p)
+    np.testing.assert_array_equal(np.asarray(got_c)[gp],
+                                  np.asarray(want_c)[gp])
+
+
+def _forced_full(monkeypatch):
+    import jax.numpy as jnp
+    monkeypatch.setattr(group_agg, "_no_interior_hole",
+                        lambda mask: jnp.asarray(False))
+
+
+def _same_bits(got, want):
+    for a, b in zip(got, want):
+        a, b = np.asarray(a), np.asarray(b)
+        assert a.dtype == b.dtype and a.shape == b.shape
+        np.testing.assert_array_equal(a, b)     # NaN == NaN here
+
+
+@pytest.mark.parametrize("interp", ["own", "prev"])
+@pytest.mark.parametrize("aggname", AGGS)
+@pytest.mark.parametrize("case", EDGE_CASES)
+def test_group_aggregate_equals_the_full_branch_forced(
+        case, aggname, interp, monkeypatch):
+    grid_ts, val, mask, gid = _edge_grid(case)
+    agg = _agg(aggname, interp)
+    got = grid_group_aggregate(grid_ts, val, mask, gid, 4, agg)
+    assert bool(got[3])
+    _forced_full(monkeypatch)
+    want = grid_group_aggregate(grid_ts, val, mask, gid, 4, agg)
+    assert not bool(want[3])
+    _same_bits(got[:3], want[:3])
+
+
+@pytest.mark.parametrize("interp", ["own", "prev"])
+@pytest.mark.parametrize("aggname", AGGS)
+def test_one_interior_hole_takes_the_full_lane(aggname, interp,
+                                               monkeypatch):
+    """One missing window between two present ones, in one row: the
+    whole grid goes through the full branch and answers as it did."""
+    import jax.numpy as jnp
+    grid_ts, val, mask, gid = _edge_grid("everything_at_once")
+    mask = np.asarray(mask).copy()
+    mask[6, 5] = False
+    val = jnp.asarray(np.where(mask, np.asarray(val), np.nan))
+    mask = jnp.asarray(mask)
+    agg = _agg(aggname, interp)
+    got_c, got_p, dense = grid_contributions(grid_ts, val, mask, agg)
+    assert not bool(dense)
+    want_c, want_p = _full_reference(grid_ts, val, mask, agg)
+    _same_bits((got_c, got_p), (want_c, want_p))
+    assert bool(np.asarray(got_p)[6, 5])        # the hole is interpolated
+    got = grid_group_aggregate(grid_ts, val, mask, gid, 4, agg)
+    _forced_full(monkeypatch)
+    _same_bits(got, grid_group_aggregate(grid_ts, val, mask, gid, 4, agg))
+
+
+@pytest.mark.parametrize("case", EDGE_CASES + (
+    "interior_hole", "two_runs_at_the_edges", "one_column", "no_rows"))
+def test_predicate_is_the_row_run_count(case):
+    import jax.numpy as jnp
+    if case in EDGE_CASES:
+        m = _edge_mask(case)
+    elif case == "interior_hole":
+        m = _edge_mask("padded_tail")
+        m[3, 6] = False
+    elif case == "two_runs_at_the_edges":
+        m = np.zeros((3, 8), bool)
+        m[1, 0] = m[1, 7] = True
+    elif case == "one_column":
+        m = np.array([[True], [False]])
+    else:
+        m = np.zeros((0, 8), bool)
+    runs = [len(np.flatnonzero(np.diff(np.r_[False, row].astype(int)) == 1))
+            for row in m]
+    assert bool(_no_interior_hole(jnp.asarray(m))) == all(
+        r <= 1 for r in runs)
+
+
+def _primitives(jaxpr) -> set:
+    out = set()
+    for eqn in jaxpr.eqns:
+        out.add(eqn.primitive.name)
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    out |= _primitives(inner)
+    return out
+
+
+def test_predicate_holds_no_gather_scan_or_sort():
+    """What the lane's condition costs a grid WITH a hole: one
+    elementwise pass and a row reduction over bool[S, W], in 32 bits."""
+    import jax
+    import jax.numpy as jnp
+    jaxpr = jax.make_jaxpr(_no_interior_hole)(
+        jnp.zeros((4000, 128), bool))
+    prims = _primitives(jaxpr.jaxpr)
+    for p in prims:
+        assert not any(word in p for word in (
+            "gather", "scatter", "scan", "while", "sort", "cum")), prims
+    for v in jaxpr.jaxpr.eqns:
+        for o in v.outvars:
+            assert o.aval.dtype.itemsize <= 4, (v.primitive.name,
+                                                o.aval.dtype)
+
+
+def test_one_cond_and_the_lane_under_jit():
+    """Still one lax.cond whose predicate is computed from the mask
+    alone; jitted, the same program serves both kinds of grid."""
+    import jax
+    grid_ts, val, mask, _ = _edge_grid("padded_tail")
+    agg = get_agg("sum")
+    fn = jax.jit(lambda g, v, m: grid_contributions(g, v, m, agg))
+    jaxpr = jax.make_jaxpr(
+        lambda g, v, m: grid_contributions(g, v, m, agg))(
+            grid_ts, val, mask)
+    assert [e.primitive.name for e in jaxpr.jaxpr.eqns].count("cond") == 1
+    assert bool(fn(grid_ts, val, mask)[2])
+    holed = np.asarray(mask).copy()
+    holed[0, 3] = False
+    assert not bool(fn(grid_ts, val, holed)[2])
 
 
 class TestSubblock2Boundaries:

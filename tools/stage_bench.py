@@ -222,7 +222,7 @@ def main() -> int:
     contrib_fn = jax.jit(lambda g, v, m: grid_contributions(
         g, v.astype(jnp.float64), m, agg_sum))
     record("group_contrib", time_fn(contrib_fn, (wts0, dval, dmask)))
-    contrib, participate = contrib_fn(wts0, dval, dmask)
+    contrib, participate, _dense = contrib_fn(wts0, dval, dmask)
     drain((contrib, participate))
 
     def reduce_under(mode):
