@@ -130,8 +130,7 @@ def make_batch(precompacted: bool = True):
     grids (storage/device_cache.py `ts_base`), so the measured dispatch
     is the production cache-hit dispatch: no per-point compaction pass.
     `precompacted=False` keeps absolute int64 timestamps (the host-build
-    path's layout) — bench_prefix uses it to race the per-dispatch
-    compaction against the pre-compacted layout honestly.
+    path's layout).
     """
     import opentsdb_tpu.ops  # noqa: F401  (enables jax x64 mode)
     import jax

@@ -64,7 +64,6 @@ class MaintenanceThread(threading.Thread):
         self.self_reports = 0
         self.self_report_errors = 0
         self.self_report_points = 0
-        self.autotune_passes = 0
         self.health_passes = 0
 
     # ------------------------------------------------------------------ #
@@ -78,7 +77,6 @@ class MaintenanceThread(threading.Thread):
                 self._maybe_snapshot(now)
                 self._maybe_refresh_device_cache()
                 self._maybe_self_report(now)
-                self._maybe_autotune(now)
                 self._maybe_rollup(now)
                 self._maybe_health(now)
             except Exception:
@@ -150,15 +148,6 @@ class MaintenanceThread(threading.Thread):
             self.self_report_errors += 1
             LOG.exception("self-report pass failed")
 
-    def _maybe_autotune(self, now: float) -> None:
-        """tsd.costmodel.autotune.* cadence: one OnlineCalibrator tick
-        (fit from the segment ring, install live constants, maybe
-        explore — ops/calibrate.py).  The calibrator rate-limits
-        itself; this just forwards the heartbeat."""
-        calibrator = getattr(self.tsdb, "autotuner", None)
-        if calibrator is not None and calibrator.tick(now):
-            self.autotune_passes += 1
-
     def _maybe_rollup(self, now: float) -> None:
         """tsd.rollup.interval cadence: one rollup-lane maintenance
         pass (storage/rollup.py refresh — Storyboard selection under
@@ -207,7 +196,6 @@ class MaintenanceThread(threading.Thread):
             "tsd.maintenance.self_reports": self.self_reports,
             "tsd.maintenance.self_report_errors": self.self_report_errors,
             "tsd.maintenance.self_report_points": self.self_report_points,
-            "tsd.maintenance.autotune_passes": self.autotune_passes,
             "tsd.maintenance.health_passes": self.health_passes,
             "tsd.maintenance.rollup_passes": self.rollup_passes,
             "tsd.maintenance.rollup_blocks_built":

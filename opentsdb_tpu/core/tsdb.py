@@ -44,7 +44,8 @@ class TSDB:
 
     def __init__(self, config: Config | None = None):
         self.config = config or Config()
-        self._query_mesh = _UNSET
+        self._mesh_lock = threading.Lock()
+        self._query_mesh = _UNSET  # guarded-by: _mesh_lock
         self._query_limits = None
         self.maintenance = None
         # extra stats sources keyed by owner (RpcManager registers the
@@ -54,7 +55,6 @@ class TSDB:
         # register its own hook during startup.
         self.stats_hooks: dict = {}
         self._apply_precision_config()
-        self._apply_kernel_modes()
         # chaos/failure-testing hooks (tsd.faults.config; no-op unless
         # armed) — installed before any storage or network touchpoint so
         # WAL-replay faults inject from the very first restore
@@ -132,7 +132,7 @@ class TSDB:
                     metric, lo, hi))
         # flight recorder (obs/flightrec.py): the always-on diagnostics
         # ring every query-path subsystem feeds — admission verdicts,
-        # cache/rollup consults, spills, autotune flips, breaker
+        # cache/rollup consults, spills, breaker
         # transitions, deadline expiries, recompiles — served at
         # /api/diag and dumped at shutdown so a wedged session leaves
         # a black box
@@ -215,14 +215,6 @@ class TSDB:
         self.authentication = None
         self.startup_plugin = None
         self.mode = self.config.get_string("tsd.mode")  # rw / ro / wo
-        # online costmodel calibration (ops/calibrate.py): fits the
-        # kernel-strategy constants from the live segment ring on the
-        # maintenance cadence; ticked by MaintenanceThread, persisted
-        # at shutdown
-        self.autotuner = None
-        if self.config.get_bool("tsd.costmodel.autotune.enable"):
-            from opentsdb_tpu.ops.calibrate import OnlineCalibrator
-            self.autotuner = OnlineCalibrator(self)
         # health engine (obs/health.py): declared invariants evaluated
         # on the maintenance cadence into per-subsystem verdicts at
         # /api/diag/health — the chaos_soak post-heal gate.  Needs
@@ -305,52 +297,6 @@ class TSDB:
                 "downsample planners refuse int64 window math "
                 "(ops.downsample.require_x64) rather than truncate "
                 "ms timestamps")
-
-    def _apply_kernel_modes(self) -> None:
-        """Apply tsd.query.kernel.* hot-path strategy config (operator
-        counterpart of the TSDB_*_MODE env toggles; empty = leave the
-        module default / env choice alone).
-
-        PROCESS-GLOBAL: the strategies are trace-time module state (a
-        per-instance form would thread through every jitted pipeline's
-        static args), so the last constructed TSDB with a NON-EMPTY key
-        wins for the whole process — matching the one-TSDB-per-process
-        production shape.  Embedders running several TSDBs must config
-        them identically or leave the keys empty.  No-op when the value
-        already matches (the setters flush every dependent jit cache)."""
-        from opentsdb_tpu.ops import downsample as _ds
-        from opentsdb_tpu.ops import group_agg as _ga
-        for key, setter, current in (
-                ("tsd.query.kernel.scan_mode", _ds.set_scan_mode,
-                 lambda: _ds._SCAN_MODE),
-                ("tsd.query.kernel.search_mode", _ds.set_search_mode,
-                 lambda: _ds._SEARCH_MODE),
-                ("tsd.query.kernel.extreme_mode", _ds.set_extreme_mode,
-                 lambda: _ds._EXTREME_MODE),
-                ("tsd.query.kernel.group_reduce_mode",
-                 _ga.set_group_reduce_mode,
-                 lambda: _ga._GROUP_REDUCE_MODE)):
-            value = self.config.get_string(key)
-            if value and value != current():
-                setter(value)   # invalid values raise at startup, loudly
-        ratio = self.config.get_string(
-            "tsd.query.kernel.stream_segment_ratio")
-        if ratio:
-            from opentsdb_tpu.ops import streaming as _st
-            _st.set_segment_chunk_ratio(float(ratio))  # bad float: loud
-        raw = self.config.get_string("tsd.query.kernel.platform_guard")
-        if raw:   # empty keeps the module default (on) / test override
-            token = raw.strip().lower()
-            if token in ("true", "1", "yes"):
-                guard = True
-            elif token in ("false", "0", "no"):
-                guard = False
-            else:   # a typo must not silently disable the CPU guard
-                raise ValueError(
-                    "tsd.query.kernel.platform_guard must be "
-                    "true/false (got %r)" % raw)
-            if guard != _ds._PLATFORM_MODE_GUARD:
-                _ds.set_platform_mode_guard(guard)
 
     def check_timestamp_and_tags(self, metric: str, timestamp: int | float,
                                  value, tags: dict[str, str]) -> None:
@@ -959,13 +905,18 @@ class TSDB:
         if not self.config.get_bool("tsd.query.mesh.enable"):
             return None
         if self._query_mesh is _UNSET:
-            from opentsdb_tpu.parallel import make_mesh
-            from opentsdb_tpu.parallel.distributed import (
-                maybe_init_distributed, host_major_devices)
-            maybe_init_distributed(self.config)
-            devices = host_major_devices()
-            self._query_mesh = (make_mesh(len(devices), devices=devices)
-                                if len(devices) > 1 else None)
+            # built once: two first queries arriving together must not
+            # both build (and publish) a mesh
+            with self._mesh_lock:
+                if self._query_mesh is _UNSET:
+                    from opentsdb_tpu.parallel import make_mesh
+                    from opentsdb_tpu.parallel.distributed import (
+                        maybe_init_distributed, host_major_devices)
+                    maybe_init_distributed(self.config)
+                    devices = host_major_devices()
+                    self._query_mesh = (
+                        make_mesh(len(devices), devices=devices)
+                        if len(devices) > 1 else None)
         return self._query_mesh
 
     # ------------------------------------------------------------------ #
@@ -1132,15 +1083,6 @@ class TSDB:
         if self.maintenance is not None:
             self.maintenance.stop(final_flush=False)
             self.maintenance = None
-        if self.autotuner is not None:
-            # detach FIRST: a maintenance pass that outlived the 5s
-            # join timeout must find no autotuner to tick, or it could
-            # re-force a kernel mode after the restore below (and a
-            # second shutdown() must not re-run persist/teardown)
-            autotuner, self.autotuner = self.autotuner, None
-            # restore any exploration override and persist the fitted
-            # constants so calibration survives the restart
-            autotuner.shutdown()
         if self.replication is not None:
             # before the snapshot: no pull may apply (and journal) a
             # peer record while the WAL is being reset

@@ -279,8 +279,7 @@ METRICS_SCHEMA: dict[str, MetricSpec] = {
         "gauge", ("peer",),
         "Per-replica acknowledged position in this node's WAL stream "
         "(ship acks + tail since marks)."),
-    # -- JAX / costmodel (obs/jaxprof.py, ops/calibrate.py,             #
-    #    query/planner.py) -------------------------------------------- #
+    # -- JAX / costmodel (obs/jaxprof.py, query/planner.py) ------------- #
     "tsd.jax.compiles": _m(
         "counter", ("kernel",), "XLA compilations per jitted kernel."),
     "tsd.costmodel.segments": _m(
@@ -295,39 +294,7 @@ METRICS_SCHEMA: dict[str, MetricSpec] = {
     "tsd.costmodel.infeasible": _m(
         "counter", ("axis",),
         "Strategy decisions outside the feasible candidate set "
-        "(must stay 0 — chaos_soak --autotune gates on it)."),
-    "tsd.costmodel.calibration.fits": _m(
-        "counter", ("platform",), "Online costmodel fits installed."),
-    "tsd.costmodel.calibration.samples": _m(
-        "gauge", ("platform",),
-        "Ring entries consumed by the last fit."),
-    "tsd.costmodel.calibration.residual": _m(
-        "gauge", ("platform",),
-        "Relative residual of the last fit."),
-    "tsd.costmodel.calibration.constant": _m(
-        "gauge", ("platform", "term"),
-        "Live-fitted per-unit cost, seconds."),
-    "tsd.costmodel.calibration.explorations": _m(
-        "counter", ("axis",),
-        "Epsilon-exploration intervals dispatched."),
-    "tsd.costmodel.calibration.*": _m(
-        "gauge", ("term",),
-        "The installed live calibration constants, per platform "
-        "(tsd.costmodel.calibration.cpu / .tpu), term-tagged."),
-    # -- autotune loop counters (ops/calibrate.py collect_stats,        #
-    #    re-emitted through the stats-hook forwarder) ------------------ #
-    "tsd.costmodel.autotune.fits": _m(
-        "gauge", (), "Autotune fits installed since startup."),
-    "tsd.costmodel.autotune.fit_errors": _m(
-        "gauge", (), "Autotune passes that raised (caught + counted)."),
-    "tsd.costmodel.autotune.samples_used": _m(
-        "gauge", (), "Ring entries consumed by the last fit."),
-    "tsd.costmodel.autotune.explorations": _m(
-        "gauge", (), "Epsilon-exploration intervals started."),
-    "tsd.costmodel.autotune.residual": _m(
-        "gauge", (), "Relative residual of the last fit."),
-    "tsd.costmodel.autotune.exploring": _m(
-        "gauge", (), "1 while a losing mode is being explored."),
+        "(must stay 0)."),
     # -- query caches: shared tier-labeled families (tier values:      #
     #    device_series = storage/device_cache.py HBM columns,          #
     #    agg_host / agg_device = storage/agg_cache.py partial-         #
@@ -468,7 +435,7 @@ METRICS_SCHEMA: dict[str, MetricSpec] = {
     "tsd.diag.events": _m(
         "counter", ("kind",),
         "Flight-recorder events recorded, by event kind (admission, "
-        "plan, tiling, breaker, deadline, compile, autotune, health, "
+        "plan, tiling, breaker, deadline, compile, health, "
         "...)."),
     "tsd.diag.slow_captures": _m(
         "counter", (),

@@ -3,7 +3,7 @@
 The r03-r05 chip-bench blackout stayed undiagnosable for three sessions
 because nothing RETAINED what the daemon was doing when it mattered —
 every decision the query-path subsystems make (admission verdicts,
-cache/rollup consults, tile spills, autotune flips, breaker
+cache/rollup consults, tile spills, breaker
 transitions, deadline expiries, steady-state recompiles) was visible
 only to a query that opted into showStats or an operator scraping at
 the right instant.  This module is the retained-evidence layer:

@@ -18,9 +18,6 @@ last pass, never a point-in-time glance — and folds each into an
   * **agg_cache** — hit-rate collapse: consults in the window with a
     hit fraction under ``tsd.health.cache_hit_floor`` (volume-gated:
     a handful of cold misses is not a collapse).
-  * **costmodel** — predicted-vs-actual drift: the window's summed
-    predicted vs measured device ms off by more than
-    ``tsd.health.costmodel_drift`` x in either direction (volume-gated).
   * **spill** — pool saturation: resident bytes vs the combined
     host+disk budget past ``tsd.health.spill_saturation``.
   * **cluster** — breaker flap: open transitions in the window past
@@ -79,7 +76,6 @@ _LEVEL_NUM = {lvl: i for i, lvl in enumerate(LEVELS)}
 # Volume gates: below these per-window totals a ratio check abstains.
 _CACHE_MIN_CONSULTS = 16
 _CACHE_FAIL_CONSULTS = 64
-_COSTMODEL_MIN_ACTUAL_MS = 50.0
 _TENANT_MIN_DEMAND = 16.0
 _LATENCY_MIN_REQUESTS = 32.0
 _LATENCY_MIN_TOTAL_MS = 50.0
@@ -89,21 +85,12 @@ def _worst(a: str, b: str) -> str:
     return a if _LEVEL_NUM[a] >= _LEVEL_NUM[b] else b
 
 
-def _counter_total(name: str) -> float:
-    """Sum of a registry counter family across label cells (0.0 when
-    the family never registered)."""
-    # forwarder: callers pass names already declared in METRICS_SCHEMA
-    # (tsd.costmodel.predicted_ms/actual_ms); nothing is minted here
-    fam = REGISTRY.counter(name)  # tsdblint: disable=metrics-dynamic-name
-    return sum(cell.get() for _labels, cell in fam.children())
-
-
 class HealthEngine:
     """Evaluates the declared invariants against one TSDB instance."""
 
-    SUBSYSTEMS = ("admission", "compile", "agg_cache", "costmodel",
-                  "spill", "cluster", "tenant", "replication",
-                  "latency", "diag")
+    SUBSYSTEMS = ("admission", "compile", "agg_cache", "spill",
+                  "cluster", "tenant", "replication", "latency",
+                  "diag")
 
     def __init__(self, tsdb):
         cfg = tsdb.config
@@ -113,7 +100,6 @@ class HealthEngine:
         self.recompile_warmup = cfg.get_int("tsd.health.recompile_warmup")
         self.recompile_limit = cfg.get_int("tsd.health.recompile_limit")
         self.cache_hit_floor = cfg.get_float("tsd.health.cache_hit_floor")
-        self.costmodel_drift = cfg.get_float("tsd.health.costmodel_drift")
         self.spill_saturation = cfg.get_float(
             "tsd.health.spill_saturation")
         self.breaker_flap = cfg.get_int("tsd.health.breaker_flap")
@@ -227,31 +213,6 @@ class HealthEngine:
                          and consults >= _CACHE_FAIL_CONSULTS
                          else "degraded")
         verdicts["agg_cache"] = {"level": level, "detail": detail}
-
-        # costmodel: predicted-vs-actual drift.  Volume-gated AND
-        # calibration-gated: an uncalibrated daemon (no autotune loop,
-        # or none of its fits installed yet) predicts from another
-        # platform's constants — orders-of-magnitude "drift" there is
-        # the expected state autotune exists to fix, not ill health.
-        predicted = delta("cm_predicted",
-                          _counter_total("tsd.costmodel.predicted_ms"))
-        actual = delta("cm_actual",
-                       _counter_total("tsd.costmodel.actual_ms"))
-        calibrator = getattr(tsdb, "autotuner", None)
-        fitted = calibrator is not None and calibrator.fits > 0
-        level, detail = "ok", (
-            "insufficient device time in window" if fitted
-            else "uncalibrated (no live fit installed)")
-        if fitted and actual >= _COSTMODEL_MIN_ACTUAL_MS \
-                and predicted > 0:
-            ratio = max(predicted / actual, actual / predicted)
-            detail = "predicted %.0fms vs actual %.0fms (x%.1f drift, " \
-                "limit x%.1f)" % (predicted, actual, ratio,
-                                  self.costmodel_drift)
-            if ratio > self.costmodel_drift > 0:
-                level = "failing" if ratio > 4 * self.costmodel_drift \
-                    else "degraded"
-        verdicts["costmodel"] = {"level": level, "detail": detail}
 
         # spill: pool saturation
         pool = getattr(tsdb, "spill_pool", None)

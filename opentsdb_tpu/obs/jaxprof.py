@@ -10,14 +10,11 @@ JaxSanitizer (tools/sanitize/jax_san.py) subscribe to the same capture,
 so the profiler and the sanitizer can never disagree about what
 compiled — one regex, one handler, one event stream.
 
-Costmodel feedback — the loop is CLOSED (PR 6).  ops/costmodel.py
-predicts per-stage dispatch costs from calibrated per-unit constants;
-`record_segment()` keeps a ring of (shape, chosen modes, feature
-vector, predicted, actual) per query segment plus running totals in
-the metrics registry.  ops/calibrate.py consumes the ring: it solves
-the per-unit constants by non-negative least squares over the feature
-vectors and installs them as the costmodel's live override layer, so
-a daemon's strategy argmin converges to what its own traffic measures.
+Costmodel feedback.  ops/costmodel.py predicts per-stage dispatch
+costs from its static per-unit table; `record_segment()` keeps a ring
+of (shape, chosen modes, feature vector, predicted, actual) per query
+segment plus running totals in the metrics registry, for an operator
+to read at /api/stats/query (nothing in the daemon reads it back).
 `segment_decisions()` recomputes the per-axis strategy decisions
 through the same choosers the kernels consult (the trace annotates
 them per segment), and `stage_breakdown()` apportions a fused
@@ -254,7 +251,7 @@ def segment_decisions(platform: str, s: int, n: int, w: int, g: int,
     dispatched modes.  Keys: 'search', 'scan' OR 'extreme' (by the
     DOWNSAMPLE function — it picks the windowed-reduce kernel),
     'group'; values are decision reports (chosen mode, per-candidate
-    predicted ms, source — see downsample.search_decision).
+    predicted ms — see downsample.search_decision).
 
     The group axis's extremes flag comes from the CROSS-SERIES
     `aggregator` — that is what moment_group_reduce keys its kernel
@@ -289,8 +286,7 @@ def segment_features(platform: str, s: int, n: int, w: int, g: int,
     """The per-unit-cost feature vector of one dispatch under its CHOSEN
     modes: unit counts per costmodel term, summed across the pipeline
     stages.  `dot(features, costmodel.costs(platform))` is the
-    dispatch's predicted seconds; the fitter regresses measured device
-    seconds onto exactly these vectors (ops/calibrate.py)."""
+    dispatch's predicted seconds."""
     from opentsdb_tpu.ops import costmodel as cm
     s = max(int(s), 1)
     n = max(int(n), 1)
@@ -319,7 +315,7 @@ def stage_breakdown(platform: str, s: int, n: int, w: int, g: int,
                     decisions: dict[str, dict] | None = None
                     ) -> dict[str, float]:
     """Predicted seconds per logical pipeline stage for one grouped
-    dispatch, using the calibrated costmodel under the modes the
+    dispatch, using the costmodel's table under the modes the
     kernels actually chose (`decisions`; recomputed here when absent).
     Approximate by design — this is the PREDICTED side of the
     predicted-vs-actual ledger, not a timer."""
@@ -358,10 +354,9 @@ def record_segment(kind: str, s: int, n: int, w: int, g: int,
                    aggregator: str | None = None) -> None:
     """One executed query segment's predicted-vs-actual device cost.
     Lands in the in-process ring (`segments()`) and the registry
-    running totals; the ring is the calibration corpus.  Entries
-    carrying `platform` + `features` (the planner always sends both)
-    are FITTABLE: ops/calibrate.py regresses actualMs onto the feature
-    vector to re-solve the per-unit constants from live traffic."""
+    running totals.  Entries carrying `platform` + `features` (the
+    planner always sends both) hold what a re-anchoring of the table
+    would regress: actualMs against the feature vector."""
     entry = {
         "kind": kind, "series": int(s), "points": int(n),
         "windows": int(w), "groups": int(g),
@@ -371,8 +366,7 @@ def record_segment(kind: str, s: int, n: int, w: int, g: int,
     if platform is not None:
         entry["platform"] = platform
     if aggregator is not None:
-        # the group axis's extremes flag keys on this — the explorer
-        # needs it to recompute the entry's candidate sets faithfully
+        # the group axis's extremes flag keys on this
         entry["aggregator"] = aggregator
     if modes is not None:
         entry["modes"] = dict(modes)

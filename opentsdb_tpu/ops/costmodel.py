@@ -1,23 +1,22 @@
-"""Shape-driven kernel-mode selection.
+"""Shape-driven kernel-form selection: one static cost table.
 
-Replaces crowned-env-var-plus-reactive-guard mode policy with a small
-analytical cost model: for each kernel axis (edge search, prefix scan,
-extreme reduce, group reduce) predict the per-dispatch cost of every
-feasible mode from the dispatch shape and the execution platform, and
-take the argmin.  Feasibility (memory caps, divisibility, platform
-hazards) stays with the kernels in downsample.py/group_agg.py — this
-module only ranks the modes those guards admit, so a wrong prediction
-can cost a few x, never an OOM or a compile failure.
+For each kernel axis (edge search, prefix scan, extreme reduce, group
+reduce) predict the per-dispatch cost of every feasible form from the
+dispatch shape and the execution platform, and take the argmin.
+Feasibility (memory caps, divisibility, platform hazards) stays with the
+kernels in downsample.py/group_agg.py — this module only ranks the forms
+those guards admit, so a wrong prediction can cost a few x, never an OOM
+or a compile failure.
 
-The per-unit TPU constants are anchored to per-stage timings at the
-headline shape (1024 series x 65536 points, 514 window edges, f64
-contract) from an earlier chip session, not re-measured on this
-installation (ROADMAP A6).  A measurement session can re-calibrate
-without code edits by writing BENCH_CALIBRATION.json at the repo root
-({"tpu": {...}, "cpu": {...}} partial overrides); nothing requires the
-file to exist.
+Which form runs is a pure function of (platform, shape): the table below
+is the only input besides the shape, nothing overrides it at run time,
+and no compiled program ever has to be dropped because a choice moved.
+To change a constant: race the forms on the benchmark cells' own shapes
+on the chip, edit the constant here, and tests/test_kernel_choice.py
+says which cells' programs move (docs/costmodel.md).
 
-The decisions this model reproduces from that session's data:
+The decisions this table reproduces (chip sessions named per constant
+below; CPU anchors are the dev box):
   * search: hier (20ms) < compare_all (116ms) < binary scan (154ms) on
     the chip at the headline shape; binary everywhere on CPU (the dense
     compare materializes there — measured 18-70x slower).
@@ -38,23 +37,11 @@ The decisions this model reproduces from that session's data:
     reset-scan (~90ms, G-independent); matmul's cost grows linearly in
     G so large-G queries flip to sorted.  CPU keeps segment.
 
-Online calibration (PR 6, docs/costmodel.md).  Every `predict_*` is a
-LINEAR form: a dot product of a per-mode feature vector (unit counts —
-gather rounds, scanned elements, scattered cells; `features_*` below)
-with the per-unit cost table.  That linearity is what makes the model
-fittable from live traffic: obs/jaxprof.py records each executed query
-segment's feature vector next to its measured device time, and
-ops/calibrate.py solves for the per-unit constants by non-negative
-least squares, installing the result here as a LIVE override layer on
-top of the file calibration (`install_live_calibration`).  The three
-layers compose default -> BENCH_CALIBRATION.json -> live fit, and
-`calibration_source()` names the winning layer so every traced query
-can say where its mode decision came from.
-
-A hysteresis band (`set_hysteresis`) makes the argmin sticky per shape
-bucket: once a mode has won a bucket, a challenger must beat it by the
-band's margin to flip the choice — one noisy calibration batch cannot
-thrash modes (and the jit caches behind them) every query.
+Every `predict_*` is a LINEAR form: a dot product of a per-form feature
+vector (unit counts — gather rounds, scanned elements, scattered cells;
+`features_*` below) with the per-unit cost table.  obs/jaxprof.py
+records each executed query segment's prediction next to its measured
+device time (`tsd.costmodel.{predicted_ms, actual_ms}`).
 
 Reference being outperformed: the per-datapoint iterator stack
 (/root/reference/src/core/AggregationIterator.java:514,
@@ -65,15 +52,12 @@ shape-dependent.
 
 from __future__ import annotations
 
-import json
 import math
-import os
-import threading
 
 # --------------------------------------------------------------------- #
 # Calibrated per-unit costs, seconds.  Anchors (headline shape S=1024
 # N=65536 E=514 G=100 W=512; from an earlier chip session, not
-# re-measured on this installation — ROADMAP A6):
+# re-measured on this installation — ROADMAP C1):
 #   gather_round  0.154s / (S*E*log2(N)=8.42e6)      binary search stage
 #   cmp_cell      0.116s / (S*N*E=3.45e10)           compare_all stage
 #   hier_cell     0.020s / (S*(N/32)*E=1.08e9)       hier stage
@@ -104,8 +88,8 @@ import threading
 #                 grid element over S*N
 # CPU anchors are this dev box (differential suite timings): searchsorted
 # ~2e-8/unit, native cumsum ~1.5e-9/elem, scatters ~5e-9/elem; the
-# dense-compare materialization hazard is handled by feasibility (the
-# platform guard), not by the model.
+# dense-compare materialization hazard is handled by the search
+# chooser itself (binary search on CPU), not by the model.
 # --------------------------------------------------------------------- #
 
 DEFAULT_COSTS: dict[str, dict[str, float]] = {
@@ -116,9 +100,10 @@ DEFAULT_COSTS: dict[str, dict[str, float]] = {
         "scan_f64": 1.49e-9,
         "elem_f64": 2.7e-10,
         # within-block prefix pass: priced slightly ABOVE elem_f64 so
-        # the chip-race-crowned subblock stays the auto pick on TPU
-        # until a calibration actually measures subblock2 faster (its
-        # CPU prefix pass is 8x elem-cost — the chip may disappoint too)
+        # the chip-race-crowned subblock stays the pick on TPU wherever
+        # its [S, W, K] intermediate fits, until a chip race measures
+        # subblock2 faster (its CPU prefix pass is 8x elem-cost — the
+        # chip may disappoint too)
         "sub2_elem": 3.5e-10,
         "win_gather": 5.7e-8,
         "seg_scatter": 1.8e-7,
@@ -127,18 +112,12 @@ DEFAULT_COSTS: dict[str, dict[str, float]] = {
         # one member a group (group_agg form "rows"): 1.19 ms at
         # [100 000, 8] in the same race, fixed costs included
         "rows_grid": 1.5e-9,
-        # blocked level-masked fold (mode "sorted2"): ESTIMATE (~0.4x
-        # sorted — half the full-width levels, no pair-op selects/bool
-        # channel) until a chip race records it; deliberately not an
-        # auto candidate until then (group_agg._effective_group_reduce_mode)
-        "sorted2_grid": 7.0e-8,
         "ext_scan_elem": 6.0e-9,
         "ext_seg_elem": 1.06e-7,
         "ext_boundary_cell": 4.0e-8,
         # out-of-core tiling (ops/tiling.py): partial-grid spill-pool
         # write/read seconds per MB (host memcpy + the disk-overflow
-        # share at the default pool split — the fitter separates the
-        # real mix from live traffic) and the per-dispatch overhead of
+        # share at the default pool split) and the per-dispatch overhead of
         # a tiled plan's extra launches (chunk folds, finishes,
         # stripe tails).  ESTIMATES until a chip run records the
         # host<->device transfer reality; the tiled decision only ever
@@ -151,8 +130,8 @@ DEFAULT_COSTS: dict[str, dict[str, float]] = {
         # assembly+re-reduce seconds per MB of cells touched, and the
         # per-(series, cell) cost of a maintenance block build (the
         # Storyboard selection prices build amortization with it).
-        # ESTIMATES until the fitter sees lane traffic; a bad constant
-        # skews which lanes materialize, never an answer.
+        # ESTIMATES; a bad constant skews which lanes materialize, never
+        # an answer.
         "lane_assemble_mb": 2.5e-4,
         "lane_build_cell": 2.0e-9,
         # fused multi-query dispatch (query/batcher.py): the per-
@@ -160,9 +139,7 @@ DEFAULT_COSTS: dict[str, dict[str, float]] = {
         # (host->device round trip + XLA launch — the quantity the batcher
         # exists to stop paying Q times), and the per-cell host cost
         # of stacking a member's [S, N] batch in + unpacking its
-        # [G, W] slice out.  ESTIMATES until the fitter sees batch
-        # traffic; batched runs are EXCLUDED from the calibration ring
-        # (like rewrites/tiled runs), so a bad constant skews the
+        # [G, W] slice out.  ESTIMATES; a bad constant skews the
         # coalesce-vs-dispatch-now line, never an answer.
         "stacked_dispatch": 1.5e-3,
         "stacked_cell": 1.0e-9,
@@ -184,7 +161,6 @@ DEFAULT_COSTS: dict[str, dict[str, float]] = {
         "seg_scatter": 5.0e-9,   # CPU scatters are cheap
         "mxu_cell": 1.0e-9,      # no MXU: dense [G,S]x[S,W] is real FLOPs
         "sorted_grid": 1.0e-8,
-        "sorted2_grid": 1.0e-8,  # estimate; not an auto candidate yet
         "rows_grid": 1.0e-9,     # a copy: elementwise class
 
         "ext_scan_elem": 4.0e-9,
@@ -206,240 +182,28 @@ DEFAULT_COSTS: dict[str, dict[str, float]] = {
     },
 }
 
-# The per-unit cost TERMS — identical key set on every platform (the
-# fitter's design matrix columns; asserted at import so a new term
-# cannot be added to one table and silently stay un-fittable on the
-# other).
+# The per-unit cost TERMS — identical key set on every platform
+# (asserted at import so a new term cannot be priced on one platform
+# and silently missing on the other).
 COST_TERMS: tuple[str, ...] = tuple(sorted(DEFAULT_COSTS["tpu"]))
 assert tuple(sorted(DEFAULT_COSTS["cpu"])) == COST_TERMS
 
-_CALIBRATION_FILE = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__)))), "BENCH_CALIBRATION.json")
 
-_lock = threading.Lock()
-# the cached three-layer cost table; every jitted kernel bakes it in at
-# trace time  # guarded-by: _lock  # cache: cost-table invalidated-by: reload_calibration
-_COSTS: dict[str, dict[str, float]] | None = None
-# live-fit override layer (ops/calibrate.py installs; applied on top of
-# the file layer)  # guarded-by: _lock
-_LIVE: dict[str, dict[str, float]] = {}
-# platforms whose table took BENCH_CALIBRATION.json overrides — rebuilt
-# with the table  # cache: cost-table invalidated-by: reload_calibration
-_FILE_PLATFORMS: set[str] = set()    # guarded-by: _lock
-
-
-def _table_key(platform: str) -> str:
-    """The cost table pricing `platform`.  A platform without a table
-    is an error: pricing an unknown device with the TPU constants would
-    hide that the daemon is not running where it was deployed."""
+def costs(platform: str) -> dict[str, float]:
+    """Per-unit costs for a platform.  A platform without a table is an
+    error: pricing an unknown device with the TPU constants would hide
+    that the daemon is not running where it was deployed.  Callers must
+    treat the result as read-only."""
     if platform not in DEFAULT_COSTS:
         raise ValueError(
             "no cost table for platform %r (known: %s)"
             % (platform, ", ".join(sorted(DEFAULT_COSTS))))
-    return platform
+    return DEFAULT_COSTS[platform]
 
 
-def _apply_file_overrides(table: dict[str, dict[str, float]]) -> set[str]:
-    """Overlay BENCH_CALIBRATION.json onto a defaults table in place;
-    returns the platforms that took at least one override.  ONE parser
-    for the file layer — the serving table build and the what-if
-    repricer (`layer_table`) must never read the file differently."""
-    touched: set[str] = set()
-    try:
-        with open(_CALIBRATION_FILE) as fh:
-            for plat, over in json.load(fh).items():
-                if plat in table and isinstance(over, dict):
-                    for k, v in over.items():
-                        if k in table[plat]:
-                            table[plat][k] = float(v)
-                            touched.add(plat)
-    except (OSError, ValueError):
-        pass
-    return touched
-
-
-def _build_table_locked() -> dict[str, dict[str, float]]:
-    table = {p: dict(c) for p, c in DEFAULT_COSTS.items()}
-    _FILE_PLATFORMS.clear()
-    _FILE_PLATFORMS.update(_apply_file_overrides(table))
-    for plat, over in _LIVE.items():
-        if plat in table:
-            table[plat].update(over)
-    return table
-
-
-def costs(platform: str) -> dict[str, float]:
-    """Per-unit costs for a platform: defaults, then
-    BENCH_CALIBRATION.json overrides, then the live-fit layer — cached
-    until `reload_calibration()`.  Callers must treat the result as
-    read-only."""
-    global _COSTS
-    with _lock:
-        if _COSTS is None:
-            _COSTS = _build_table_locked()
-        return _COSTS[_table_key(platform)]
-
-
-def calibration_source(platform: str) -> str:
-    """Which layer last touched this platform's cost table: 'live'
-    (online fitter), 'file' (BENCH_CALIBRATION.json), or 'default'.
-    Traced queries stamp this on every strategy decision."""
-    global _COSTS
-    with _lock:
-        if _COSTS is None:
-            _COSTS = _build_table_locked()
-        key = _table_key(platform)
-        if _LIVE.get(key):
-            return "live"
-        if key in _FILE_PLATFORMS:
-            return "file"
-        return "default"
-
-
-def layer_table(platform: str, layer: str) -> dict[str, float]:
-    """A COPY of the per-unit cost table as a specific layer would
-    price it — the what-if repricer's view (query/explain.py):
-    'default' = the shipped constants, 'file' = defaults +
-    BENCH_CALIBRATION.json, 'auto' (or anything else) = the live
-    three-layer table ``costs()`` serves.  Never consulted by the
-    serving argmin, and never cached — explain is cold-path."""
-    key = _table_key(platform)
-    if layer == "default":
-        return dict(DEFAULT_COSTS[key])
-    if layer == "file":
-        table = {p: dict(c) for p, c in DEFAULT_COSTS.items()}
-        _apply_file_overrides(table)
-        return table[key]
-    return dict(costs(platform))
-
-
-def install_live_calibration(platform: str,
-                             constants: dict[str, float]) -> None:
-    """Install online-fitted per-unit constants for `platform` (merged
-    over any previous live values) and drop every cache that baked the
-    old table in.  Values must be finite and positive and every term
-    must exist — the fitter's guards should make a violation impossible,
-    so one here raises instead of installing a poisoned table."""
-    key = _table_key(platform)
-    clean: dict[str, float] = {}
-    for term, value in constants.items():
-        v = float(value)
-        if term not in DEFAULT_COSTS[key]:
-            raise ValueError("unknown cost term: %r" % term)
-        if not math.isfinite(v) or v <= 0.0:
-            raise ValueError("non-positive/NaN cost for %s: %r"
-                             % (term, value))
-        clean[term] = v
-    with _lock:
-        _LIVE.setdefault(key, {}).update(clean)
-    reload_calibration()
-
-
-def clear_live_calibration() -> None:
-    """Drop the live-fit layer (back to file/default constants)."""
-    with _lock:
-        _LIVE.clear()
-    reload_calibration()
-
-
-def live_calibration(platform: str) -> dict[str, float]:
-    """The currently-installed live overrides for a platform (empty when
-    the fitter has not run)."""
-    with _lock:
-        return dict(_LIVE.get(_table_key(platform), {}))
-
-
-def set_calibration_file(path: str) -> None:
-    """Point the file layer somewhere else (daemon config/tests) and
-    reload."""
-    global _CALIBRATION_FILE
-    _CALIBRATION_FILE = path
-    reload_calibration()
-
-
-def calibration_file() -> str:
-    return _CALIBRATION_FILE
-
-
-def reload_calibration() -> None:
-    """THE calibration-invalidation entry point: drops the cached cost
-    table, the sticky-choice memory, AND every dependent compiled
-    program (the downsample/group_agg pipelines bake mode choices in at
-    trace time — a reload that left them cached would keep serving
-    stale-mode kernels; that footgun used to be the caller's problem).
-    The hysteresis incumbent memory deliberately SURVIVES a reload:
-    it is what keeps one noisy calibration install from flipping modes
-    — every later choice re-prices the incumbent under the new table
-    and flips only past the band."""
-    global _COSTS
-    with _lock:
-        _COSTS = None
-    from opentsdb_tpu.ops.downsample import _clear_dependent_caches
-    _clear_dependent_caches()
-
-
-# --------------------------------------------------------------------- #
-# Sticky argmin: the hysteresis band                                    #
-# --------------------------------------------------------------------- #
-
-_HYSTERESIS = 0.0
-_MEMO_MAX = 1024
-# last winning mode per (kind, platform, candidates, shape bucket).
-# Deliberately SURVIVES reload_calibration (see its docstring);
-# set_hysteresis is the one entry point that drops it.
-# cache: choice-memo invalidated-by: set_hysteresis
-_choice_memo: dict[tuple, str] = {}    # guarded-by: _lock
-
-
-def set_hysteresis(band: float) -> None:
-    """Sticky-argmin band: a challenger mode must predict at least
-    ``band`` (fraction, e.g. 0.15) cheaper than a shape bucket's
-    incumbent before the choice flips.  0 (the default) keeps the pure
-    argmin — exactly the pre-autotune behavior.  Changing the band
-    clears the incumbent memory AND the dependent jit caches (the band
-    changes which mode _choose returns, and compiled programs bake
-    that in — same rule as every other mode-policy toggle)."""
-    global _HYSTERESIS
-    if band < 0.0 or not math.isfinite(band):
-        raise ValueError("hysteresis band must be finite and >= 0")
-    with _lock:
-        if _HYSTERESIS == band:
-            return      # idempotent: no policy change, nothing to drop
-        _HYSTERESIS = band
-        _choice_memo.clear()
-    from opentsdb_tpu.ops.downsample import _clear_dependent_caches
-    _clear_dependent_caches()
-
-
-def hysteresis() -> float:
-    return _HYSTERESIS
-
-
-def _choose(kind: str, mode_costs: dict[str, float], platform: str,
-            bucket: tuple) -> str:
-    """Argmin over mode_costs with the hysteresis band applied."""
-    best = min(mode_costs, key=mode_costs.get)
-    band = _HYSTERESIS
-    if band <= 0.0:
-        return best
-    key = (kind, _table_key(platform), tuple(sorted(mode_costs)), bucket)
-    with _lock:
-        prev = _choice_memo.get(key)
-        if (prev is not None and prev in mode_costs
-                and mode_costs[best] >= mode_costs[prev] / (1.0 + band)):
-            best = prev
-        if len(_choice_memo) >= _MEMO_MAX and key not in _choice_memo:
-            _choice_memo.clear()    # tiny table; wholesale reset is fine
-        _choice_memo[key] = best
-    return best
-
-
-def _bucket(*dims: int) -> tuple:
-    """Power-of-two shape bucket: hysteresis memory is per dispatch
-    SIZE CLASS, not per exact shape (the jit caches bucket the same
-    way via pad_pow2)."""
-    return tuple(max(int(d), 1).bit_length() for d in dims)
+def _argmin(mode_costs: dict[str, float]) -> str:
+    """The cheapest form; ties go to the earlier candidate."""
+    return min(mode_costs, key=mode_costs.get)
 
 
 def _log2(n: int) -> int:
@@ -454,9 +218,8 @@ def _dot(features: dict[str, float], platform: str) -> float:
 # --------------------------------------------------------------------- #
 # Feature vectors: unit counts per (kernel axis, mode).                 #
 #                                                                       #
-# predict_* == dot(features_*, costs) BY CONSTRUCTION — the fitter      #
-# (ops/calibrate.py) regresses measured device time onto these same     #
-# vectors, so a fitted constant means exactly what the predictor        #
+# predict_* == dot(features_*, costs) BY CONSTRUCTION: a constant       #
+# re-anchored from a chip race means exactly what the predictor         #
 # consumes.  Keep every form LINEAR in the constants.                   #
 # --------------------------------------------------------------------- #
 
@@ -481,10 +244,6 @@ def features_scan(mode: str, s: int, n: int, e: int) -> dict[str, float]:
     """Unit counts for one windowed-sum pass over [S, N]."""
     if mode == "flat":
         return {"scan_f64": float(s * n), "win_gather": float(s * e)}
-    if mode == "blocked":
-        # two-level scan: same element count, measured slightly slower
-        # than flat on both platforms (r3 chip: 0.600 vs 0.568)
-        return {"scan_f64": 1.06 * s * n, "win_gather": 1.06 * s * e}
     if mode == "subblock":
         k = _SUB_K
         return {"elem_f64": float(s * n + s * e * k),  # reduce + remainder
@@ -529,26 +288,9 @@ def features_group(mode: str, s: int, w: int, g: int
         return {"mxu_cell": float(g * s * w)}
     if mode == "sorted":
         return {"sorted_grid": float(s * w)}
-    if mode == "sorted2":
-        return {"sorted2_grid": float(s * w)}
     if mode == "rows":      # one member a group: a copy of the grid
         return {"rows_grid": float(s * w)}
     raise ValueError("unknown group mode: " + mode)
-
-
-def cost_features(kind: str, mode: str, s: int, n: int, e: int,
-                  g: int = 1) -> dict[str, float]:
-    """One entry point over the four axes ('search' | 'scan' |
-    'extreme' | 'group').  For 'group', `n` is the grid width W."""
-    if kind == "search":
-        return features_search(mode, s, n, e)
-    if kind == "scan":
-        return features_scan(mode, s, n, e)
-    if kind == "extreme":
-        return features_extreme(mode, s, n, e)
-    if kind == "group":
-        return features_group(mode, s, n, g)
-    raise ValueError("unknown kernel axis: " + kind)
 
 
 # -- edge search: idx[S, E] from [S, N] sorted timestamps -------------- #
@@ -560,10 +302,8 @@ def predict_search(mode: str, s: int, n: int, e: int,
 
 def choose_search(s: int, n: int, e: int, platform: str,
                   candidates: list[str]) -> str:
-    return _choose("search",
-                   {m: predict_search(m, s, n, e, platform)
-                    for m in candidates},
-                   platform, _bucket(s, n, e))
+    return _argmin({m: predict_search(m, s, n, e, platform)
+                    for m in candidates})
 
 
 # -- prefix scan: windowed sums over [S, N] ---------------------------- #
@@ -575,10 +315,8 @@ def predict_scan(mode: str, s: int, n: int, e: int,
 
 def choose_scan(s: int, n: int, e: int, platform: str,
                 candidates: list[str]) -> str:
-    return _choose("scan",
-                   {m: predict_scan(m, s, n, e, platform)
-                    for m in candidates},
-                   platform, _bucket(s, n, e))
+    return _argmin({m: predict_scan(m, s, n, e, platform)
+                    for m in candidates})
 
 
 # -- extreme (min/max) over [S, N] ------------------------------------- #
@@ -590,10 +328,8 @@ def predict_extreme(mode: str, s: int, n: int, e: int,
 
 def choose_extreme(s: int, n: int, e: int, platform: str,
                    candidates: list[str]) -> str:
-    return _choose("extreme",
-                   {m: predict_extreme(m, s, n, e, platform)
-                    for m in candidates},
-                   platform, _bucket(s, n, e))
+    return _argmin({m: predict_extreme(m, s, n, e, platform)
+                    for m in candidates})
 
 
 # -- group reduce: [S, W] + gid[S] -> [G, W] --------------------------- #
@@ -605,10 +341,8 @@ def predict_group(mode: str, s: int, w: int, g: int,
 
 def choose_group(s: int, w: int, g: int, platform: str,
                  candidates: list[str]) -> str:
-    return _choose("group",
-                   {m: predict_group(m, s, w, g, platform)
-                    for m in candidates},
-                   platform, _bucket(s, w, g))
+    return _argmin({m: predict_group(m, s, w, g, platform)
+                    for m in candidates})
 
 
 # -- out-of-core tiled execution (ops/tiling.py) ----------------------- #
@@ -620,9 +354,7 @@ def features_tiled(s: int, w: int, g: int, n_tiles: int, n_stripes: int,
     launches a tiled plan issues (per-tile chunk folds + finishes, per-
     stripe tail dispatches).  The streamed compute itself is priced by
     the same stage features a resident plan uses (obs.jaxprof) — this
-    vector is strictly the delta, so the fitter can regress the spill
-    constants from (tiled actual - resident prediction) residuals
-    without the compute terms aliasing them.  Linear in the constants
+    vector is strictly the delta.  Linear in the constants
     by construction: `predict_tiled == dot(features_tiled, costs)`.
     """
     mb = spill_bytes / 2.0**20
@@ -688,9 +420,8 @@ def features_stacked(q: int, s: int, n: int, w: int, g: int
     traffic (each member's [S, N] input cells copied into the stacked
     batch and its [G, W] output slice copied back out).  The members'
     compute itself is priced by the same stage features a solo plan
-    uses (obs.jaxprof) — this vector is strictly the delta, so the
-    fitter could regress the stacking constants from residuals without
-    the compute terms aliasing them.  Linear in the constants by
+    uses (obs.jaxprof) — this vector is strictly the delta.  Linear in
+    the constants by
     construction: ``predict_stacked == dot(features_stacked, costs)``.
     """
     return {"stacked_dispatch": 1.0,
@@ -707,7 +438,7 @@ def predict_stacked(q: int, s: int, n: int, w: int, g: int,
 def coalesce_worthwhile(compute_s: float, s: int, n: int, w: int,
                         g: int, platform: str, factor: float) -> bool:
     """The coalesce-vs-dispatch-now verdict for ONE plan, from the
-    fitted constants (the Factor-Windows cost-based-rewrite framing:
+    table's constants (the Factor-Windows cost-based-rewrite framing:
     price the rewrite, don't hardcode a batch size).  A plan is
     DISPATCH-BOUND — worth stacking — when its predicted monolithic
     compute plus its per-member stack/unpack overhead stays within

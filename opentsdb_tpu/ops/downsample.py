@@ -21,7 +21,6 @@ part (d).
 
 from __future__ import annotations
 
-import os as _os
 from dataclasses import dataclass
 
 import jax
@@ -190,29 +189,14 @@ PREFIX_AGGS = frozenset(
 
 # min/max ride a scatter-free segmented reset-scan (sorted rows make each
 # window a contiguous run; an associative_scan that resets at run starts
-# replaces the serializing segment scatter).  "segment" keeps the scatter
-# form — faster on CPU where scatters are cheap.  "subblock" removes the
-# full-length scan too (the r4 subblock-sum idea applied to extremes):
-# 32-point sub-block reduces, a reset-scan over the [S, N/32] sub-block
-# extremes for each window's interior, and 32-wide masked reduces over
-# the two boundary sub-blocks.  The chip A/B decides the default.
+# replaces the serializing segment scatter): form "scan".  "segment"
+# keeps the scatter — faster on CPU where scatters are cheap.  "subblock"
+# removes the full-length scan too (the subblock-sum idea applied to
+# extremes): 32-point sub-block reduces, a reset-scan over the [S, N/32]
+# sub-block extremes for each window's interior, and 32-wide masked
+# reduces over the two boundary sub-blocks.  _effective_extreme_mode
+# picks from platform and shape.
 EXTREME_AGGS = frozenset({"min", "mimmin", "max", "mimmax"})
-_EXTREME_MODES = ("auto", "scan", "segment", "subblock")
-_EXTREME_MODE = (_os.environ.get("TSDB_EXTREME_MODE")
-                 if _os.environ.get("TSDB_EXTREME_MODE")
-                 in _EXTREME_MODES else "auto")
-
-
-def set_extreme_mode(mode: str) -> None:
-    """'auto' | 'scan' | 'segment' | 'subblock' — min/max downsample
-    strategy ('auto' = shape/platform cost model, ops.costmodel); clears
-    caches."""
-    global _EXTREME_MODE
-    if mode not in _EXTREME_MODES:
-        raise ValueError("extreme mode must be one of %r"
-                         % (_EXTREME_MODES,))
-    _EXTREME_MODE = mode
-    _clear_dependent_caches()
 
 
 # shape: wargs.first[] i64, wargs.edges[*] i64 -> [W1] i64
@@ -228,28 +212,17 @@ def window_edges(ts_dtype, spec: WindowSpec, wargs: dict):
     raise ValueError("Unknown window kind: " + spec.kind)
 
 
-# Prefix-scan strategy for the hot path.  "flat" = one cumsum over the full
-# time axis; "blocked" = two-level scan (intra-block cumsum + tiny block-
-# offset scan) — shorter scan segments, same memory.  "subblock" = no
-# full-length scan at all: exact f64 sums of 32-point sub-blocks (a tree
-# reduce — one cheap pass), a cumsum over the [S, N/32] sub-block sums
-# (1/32 the scan work), and per-edge remainders as 32-wide masked dots.
-# Rationale (per-stage attribution from an earlier chip session, not
-# re-measured on this installation — ROADMAP A6): a full-length f64
-# cumsum cost 95ms/67M pts on the chip while an f64 elementwise pass
-# cost 14ms — the emulated-f64 SCAN is the bottleneck, not the data
-# traffic, so the subblock form does 1/32 of it.  Same session: flat
-# 0.568s vs blocked 0.600s per 67M-pt dispatch at int32 — XLA's native
-# cumsum lowering beat the hand-blocked form on TPU.
-#
-# Env overrides (TSDB_SCAN_MODE / TSDB_SEARCH_MODE / TSDB_EXTREME_MODE,
-# read once at import): lets a measurement run race the modes without
-# editing source.  Invalid values are ignored (defaults win).
-_SCAN_MODES = ("auto", "flat", "blocked", "subblock", "subblock2")
-_SCAN_MODE = (_os.environ.get("TSDB_SCAN_MODE")
-              if _os.environ.get("TSDB_SCAN_MODE") in _SCAN_MODES
-              else "auto")
-_SCAN_BLOCK = 512
+# Prefix-scan forms of the hot path.  "flat" = one cumsum over the full
+# time axis.  "subblock" = no full-length scan at all: exact f64 sums of
+# 32-point sub-blocks (a tree reduce — one cheap pass), a cumsum over
+# the [S, N/32] sub-block sums (1/32 the scan work), and per-edge
+# remainders as 32-wide masked dots.  "subblock2" = the same with
+# within-block prefixes and one element gather per edge.  Rationale
+# (per-stage attribution from an earlier chip session, not re-measured
+# on this installation): a full-length f64 cumsum cost 95ms/67M pts on
+# the chip while an f64 elementwise pass cost 14ms — the emulated-f64
+# SCAN is the bottleneck, not the data traffic, so the sub-block forms
+# do 1/32 of it.  _effective_scan_mode picks from platform and shape.
 _SUB_K = 32      # subblock scan / hier search granule (power of two)
 
 _I32_BIG = np.int64(2**31 - 2)
@@ -260,9 +233,7 @@ _I32_BIG = np.int64(2**31 - 2)
 _I32_PAD = np.int32(2**31 - 2)
 
 
-_COMPACT_ENABLED = True
-
-# Edge-position search strategy.  "scan" = jnp.searchsorted's binary
+# Edge-position search forms.  "scan" = jnp.searchsorted's binary
 # search: log2(N) rounds of gathers — TPU gathers serialize, so for the
 # [S, W+1]-edges-into-[S, N] search this is a chain of ~17 gather passes.
 # "compare_all" = one broadcasted compare + sum-reduce (idx[s, w] =
@@ -273,148 +244,18 @@ _COMPACT_ENABLED = True
 # then resolve the one boundary sub-block with a 32-wide compare — the
 # compare work drops from O(N*W) to O(N*W/32 + 32*W).  r3/r4 chip data:
 # scan 182ms, compare_all ~116ms for the 65536x513 headline search.
-_SEARCH_MODES = ("auto", "scan", "compare_all", "hier")
-_SEARCH_MODE = (_os.environ.get("TSDB_SEARCH_MODE")
-                if _os.environ.get("TSDB_SEARCH_MODE")
-                in _SEARCH_MODES else "auto")
-
-
-def set_search_mode(mode: str) -> None:
-    """'auto' | 'scan' | 'compare_all' | 'hier' — edge-search strategy
-    ('auto' = shape/platform cost model, ops.costmodel); clears
-    caches."""
-    global _SEARCH_MODE
-    if mode not in _SEARCH_MODES:
-        raise ValueError("search mode must be one of %r" % (_SEARCH_MODES,))
-    _SEARCH_MODE = mode
-    _clear_dependent_caches()
-
-# Value-accumulation precision for the prefix hot path.  "double" (default)
-# is the numeric contract — the reference accumulates in Java double
-# (Downsampler.java:257) and the golden tests pin 1e-9 agreement.  "single"
-# runs the cumsum in float32 (native TPU ALUs; f64 is emulated) at
-# ~n_points_per_window * 6e-8 relative error — a documented fast mode for
-# dashboards, never the default.
-_VALUE_PRECISION = "double"
-
-
-# bumped on every mode-policy change (all of them funnel through
-# _clear_dependent_caches): the planner snapshots it before a dispatch
-# and drops the calibration-ring entry if it moved mid-query — the
-# recomputed decision report could otherwise pair one mode's measured
-# time with another mode's feature vector
-_MODE_POLICY_EPOCH = 0
-
-
-def mode_policy_epoch() -> int:
-    return _MODE_POLICY_EPOCH
-
-
-def _clear_dependent_caches() -> None:
-    """Drop every compiled program that baked in the hot-path toggles.
-
-    The toggles are read at TRACE time; a cached program keeps its config
-    forever, so flipping a toggle without clearing these would silently
-    mix configs between already-seen and new query shapes.
-    """
-    global _MODE_POLICY_EPOCH
-    # the epoch must move BEFORE any compiled program is dropped: a
-    # planner that snapshots the epoch mid-splice sees it already
-    # bumped and discards its calibration entry, instead of pairing a
-    # stale program's timing with the new policy (checked contract)
-    # order: epoch-bump before jit-cache-splice
-    _MODE_POLICY_EPOCH += 1                          # order-event: epoch-bump
-    from opentsdb_tpu.ops import pipeline, streaming
-    for fn in (pipeline._jitted, pipeline._jitted_rollup_avg,
-               pipeline._jitted_group, pipeline._jitted_grid_tail,
-               pipeline._jitted_downsample_grid,
-               pipeline._jitted_group_rollup_avg,
-               pipeline._jitted_union_batch,
-               pipeline._jitted_stacked_group,
-               streaming._jitted_update,
-               streaming._jitted_update_sliced, streaming._jitted_finish):
-        fn.clear_cache()                             # order-event: jit-cache-splice
-    try:
-        from opentsdb_tpu.parallel import sharded
-        sharded.sharded_query_pipeline.cache_clear()  # order-event: jit-cache-splice
-        sharded._stream_update_fn.cache_clear()
-        sharded._stream_update_sliced_fn.cache_clear()
-        sharded._stream_finish_fn.cache_clear()
-    except ImportError:  # parallel extras absent in minimal installs
-        pass
-
-
-def set_scan_mode(mode: str) -> None:
-    """'auto' | 'flat' | 'blocked' | 'subblock' | 'subblock2' —
-    benchmarking/ops hook ('auto' = shape/platform cost model); clears
-    affected jit caches."""
-    global _SCAN_MODE
-    if mode not in _SCAN_MODES:
-        raise ValueError("scan mode must be one of %r" % (_SCAN_MODES,))
-    _SCAN_MODE = mode
-    _clear_dependent_caches()
-
-
-def set_ts_compaction(enabled: bool) -> None:
-    """Toggle int32 timestamp compaction — benchmarking hook; clears
-    affected jit caches."""
-    global _COMPACT_ENABLED
-    _COMPACT_ENABLED = bool(enabled)
-    _clear_dependent_caches()
-
-
-def set_value_precision(mode: str) -> None:
-    """'double' | 'single' — prefix-path accumulation dtype; clears
-    affected jit caches.  See _VALUE_PRECISION above for the contract."""
-    global _VALUE_PRECISION
-    if mode not in ("double", "single"):
-        raise ValueError("precision must be 'double' or 'single'")
-    _VALUE_PRECISION = mode
-    _clear_dependent_caches()
+# _effective_search_mode picks from platform and shape.
 
 
 def _edge_prefix_builder(s: int, n: int, idx):
     """Returns windowed(data): per-window sums via prefix evaluation at the
-    searched edge positions idx[S, W+1] (exclusive prefixes differenced).
-
-    flat: materialize cumsum[S, N+1], gather at idx.
-    blocked: intra-block cumsum (scan length _SCAN_BLOCK) + cumsum over the
-    [S, B] block totals; prefix(p) = block_offset[p // K] + intra[p-1 within
-    its block].  Same HBM traffic, much shorter scan dependency chains.
-    """
-    # only an EXPLICIT "blocked" takes the two-level form ("auto" never
-    # picks it: it lost the r3 chip race, 0.600 vs 0.568)
-    if _SCAN_MODE != "blocked" or n % _SCAN_BLOCK or n <= _SCAN_BLOCK:
-        def windowed(data):
-            csum = jnp.concatenate(
-                [jnp.zeros((s, 1), data.dtype),
-                 jnp.cumsum(data, axis=1)], axis=1)
-            at = jnp.take_along_axis(csum, idx, axis=1)
-            return at[:, 1:] - at[:, :-1]
-        return windowed
-
-    k = _SCAN_BLOCK
-    b = n // k
-    blk = idx // k               # block containing each edge position
-    off = idx - blk * k          # position within the block
-    # Exclusive intra-block prefix at `off` = inclusive intra cumsum at
-    # off-1; off==0 contributes nothing.  Flatten (block, slot) so one
-    # gather serves both lookups.
-    gather_pos = jnp.clip(blk * k + off - 1, 0, n - 1)
-    zero_intra = off == 0
-    safe_blk = jnp.clip(blk, 0, b)   # idx can be n -> blk == b (offset row)
-
+    searched edge positions idx[S, W+1] (exclusive prefixes differenced):
+    materialize cumsum[S, N+1], gather at idx (scan form "flat")."""
     def windowed(data):
-        blocks = data.reshape(s, b, k)
-        intra = jnp.cumsum(blocks, axis=2)
-        bsum = intra[:, :, -1]
-        boff = jnp.concatenate(
-            [jnp.zeros((s, 1), data.dtype), jnp.cumsum(bsum, axis=1)],
-            axis=1)                                      # [S, B+1]
-        base = jnp.take_along_axis(boff, safe_blk, axis=1)
-        part = jnp.take_along_axis(intra.reshape(s, n), gather_pos, axis=1)
-        part = jnp.where(zero_intra, jnp.zeros_like(part), part)
-        at = base + part
+        csum = jnp.concatenate(
+            [jnp.zeros((s, 1), data.dtype),
+             jnp.cumsum(data, axis=1)], axis=1)
+        at = jnp.take_along_axis(csum, idx, axis=1)
         return at[:, 1:] - at[:, :-1]
     return windowed
 
@@ -428,7 +269,7 @@ def _edge_subblock_builder(s: int, n: int, idx):
     32-wide masked dot over the boundary sub-block, gathered as ONE
     contiguous [1, K] slice per edge (vector loads, not 32 scalar
     gathers).  Chip rationale: the emulated-f64 full-length cumsum costs
-    ~7x an elementwise f64 pass (tools/stage_bench.py r4) — this form
+    ~7x an elementwise f64 pass (r4 chip session) — this form
     keeps the same f64 accumulation contract with 1/32 of the scan.
     """
     k = _SUB_K
@@ -503,8 +344,7 @@ def precompact_base(spec: WindowSpec, first_window_ms) -> int | None:
     query dispatch entirely (r4 chip attribution: 74ms of the headline
     dispatch was the ts - first sub+clip+cast over [S, N] int64).
     """
-    if (_COMPACT_ENABLED and spec.kind == "fixed"
-            and first_window_ms is not None
+    if (spec.kind == "fixed" and first_window_ms is not None
             and (spec.count + 1) * spec.interval_ms < 2**31 - 2):
         return int(first_window_ms)
     return None
@@ -532,7 +372,7 @@ def _compact_ts(ts, spec: WindowSpec, wargs: dict):
                            -_I32_BIG, _I32_BIG).astype(jnp.int32)
         return ts, edges32
     edges64 = window_edges(ts.dtype, spec, wargs)
-    if not _COMPACT_ENABLED or spec.kind != "fixed" or \
+    if spec.kind != "fixed" or \
             (spec.count + 1) * spec.interval_ms >= 2**31 - 2:
         return ts, edges64
     first = wargs["first"]
@@ -563,19 +403,17 @@ def _prefix_downsample(ts, val, mask, agg_name: str, spec: WindowSpec,
     w = spec.count
     vf, ok, cts, _idx, windowed, count = _window_scan_setup(ts, val, mask,
                                                             spec, wargs)
-    fdtype = vf.dtype
-    acc_dtype = jnp.float32 if _VALUE_PRECISION == "single" else fdtype
-    v0 = jnp.where(ok, vf, 0).astype(acc_dtype)
+    v0 = jnp.where(ok, vf, 0)
     if agg_name == "count":
-        return count.astype(fdtype), count
+        return count.astype(vf.dtype), count
     total = windowed(v0)
     safe = jnp.maximum(count, 1)
     if agg_name in ("sum", "zimsum", "pfsum"):
-        return total.astype(fdtype), count
+        return total, count
     if agg_name == "avg":
-        return (total / safe).astype(fdtype), count
+        return total / safe, count
     if agg_name == "squareSum":
-        return windowed(v0 * v0).astype(fdtype), count
+        return windowed(v0 * v0), count
     if agg_name == "dev":
         # Two-pass centered moment (matches the segment path's numerics):
         # per-point window mean via the same edge-search, then one more
@@ -583,11 +421,11 @@ def _prefix_downsample(ts, val, mask, agg_name: str, spec: WindowSpec,
         mean = total / safe
         win = jnp.clip(_window_ids_fast(ts, cts, spec, wargs), 0, w - 1)
         mean_pp = jnp.take_along_axis(mean, win, axis=1)
-        centered = jnp.where(ok, vf - mean_pp, 0).astype(acc_dtype)
+        centered = jnp.where(ok, vf - mean_pp, 0)
         m2 = windowed(centered * centered)
         return jnp.where(count >= 2,
-                         jnp.sqrt(m2 / jnp.maximum(count - 1, 1))
-                         .astype(fdtype), 0.0), count
+                         jnp.sqrt(m2 / jnp.maximum(count - 1, 1)),
+                         0.0), count
     raise KeyError("No prefix-sum path for: " + agg_name)
 
 
@@ -659,32 +497,11 @@ _COMPARE_ALL_CELL_CAP = 1 << 27
 _HIER_CELL_CAP = 1 << 23
 
 
-# The dense search forms are ACCELERATOR winners: on the chip their
-# compare+count fuses into vmem (r04b: hier 0.416s vs scan 0.590s on the
-# headline dispatch), but on CPU the backend materializes the compare
-# matrix — measured 70x slower than the binary search at [64, 65536] x
-# 514 edges, and 18x end-to-end on the config-1 host lane.  With this
-# guard on (production default), any trace executing on CPU — the
-# planner's small-query host lane, or a CPU-only process — takes the
-# binary search regardless of the configured/env mode.  Tests disable it
-# suite-wide (conftest) so CPU CI still exercises the dense kernels'
-# correctness at small shapes.
-_PLATFORM_MODE_GUARD = True
-
-
-def set_platform_mode_guard(on: bool) -> None:
-    """Enable/disable CPU demotion of dense search modes; clears caches."""
-    global _PLATFORM_MODE_GUARD
-    _PLATFORM_MODE_GUARD = bool(on)
-    _clear_dependent_caches()
-
-
 def _search_feasible(mode: str, n: int, w_edges: int) -> bool:
     """Hard feasibility for the dense search forms: memory caps on the
     compare intermediates and the per-edge compare-vs-gather cost ratio.
-    Shapes outside these bounds demote to the binary scan no matter what
-    crowned/auto policy says — a wrong choice here is an OOM or a
-    scoped-vmem compile failure, not a slowdown."""
+    Shapes outside these bounds take the binary scan — a wrong choice
+    here is an OOM or a scoped-vmem compile failure, not a slowdown."""
     logn = max(int(np.ceil(np.log2(max(n, 2)))), 1)
     if mode == "compare_all":
         return (n <= _SEARCH_DEMOTE_RATIO * logn
@@ -704,29 +521,25 @@ def _search_candidates(n: int, w_edges: int) -> list[str]:
 
 def _effective_search_mode(s: int, n: int, w_edges: int,
                            platform: str | None = None) -> str:
-    """The search mode for this shape: 'auto' (default) ranks the
-    feasible modes with the calibrated cost model (ops.costmodel);
-    an explicit mode (env/setter — measurement sessions) is honored but
-    still demoted to "scan" when infeasible for the shape or when the
-    trace executes on CPU (see _PLATFORM_MODE_GUARD — the dense forms'
-    compare matrices materialize there).  `platform` defaults to the
-    ambient execution platform; the planner's decision report passes
-    its per-segment platform explicitly."""
-    mode = _SEARCH_MODE
+    """The search form for this shape: the cheapest feasible one by the
+    cost table (ops.costmodel).  The dense forms are ACCELERATOR
+    winners: on the chip their compare+count fuses into vmem (r04b: hier
+    0.416s vs scan 0.590s on the headline dispatch), but on CPU the
+    backend materializes the compare matrix — measured 70x slower than
+    the binary search at [64, 65536] x 514 edges, and 18x end-to-end on
+    the config-1 host lane.  So any trace executing on CPU — the
+    planner's small-query host lane, or a CPU-only process — takes the
+    binary search.  `platform` defaults to the ambient execution
+    platform; the planner's decision report passes its per-segment
+    platform explicitly."""
     from opentsdb_tpu.ops.hostlane import execution_platform
     if platform is None:
         platform = execution_platform()
-    if mode == "auto":
-        if platform == "cpu":
-            return "scan"      # dense compares materialize on CPU
-        from opentsdb_tpu.ops import costmodel
-        return costmodel.choose_search(s, n, w_edges, platform,
-                                       _search_candidates(n, w_edges))
-    if _PLATFORM_MODE_GUARD and mode != "scan" and platform == "cpu":
+    if platform == "cpu":
         return "scan"
-    if not _search_feasible(mode, n, w_edges):
-        return "scan"
-    return mode
+    from opentsdb_tpu.ops import costmodel
+    return costmodel.choose_search(s, n, w_edges, platform,
+                                   _search_candidates(n, w_edges))
 
 
 def _scan_candidates(n: int, w_edges: int) -> list[str]:
@@ -741,14 +554,10 @@ def _scan_candidates(n: int, w_edges: int) -> list[str]:
 
 def _effective_scan_mode(s: int, n: int, w_edges: int,
                          platform: str | None = None) -> str:
-    """The prefix-scan strategy for this shape: 'auto' ranks the
-    feasible modes with the cost model (the sub-block forms need
-    K-divisible rows; "subblock" additionally needs the [S, W, K]
-    boundary intermediate to fit).  Explicit modes keep their existing
-    call-site eligibility fallbacks."""
-    mode = _SCAN_MODE
-    if mode != "auto":
-        return mode
+    """The prefix-scan form for this shape: the cheapest feasible one
+    by the cost table (the sub-block forms need K-divisible rows;
+    "subblock" additionally needs the [S, W, K] boundary intermediate
+    to fit)."""
     cands = _scan_candidates(n, w_edges)
     if len(cands) == 1:
         return "flat"
@@ -766,96 +575,59 @@ def _extreme_candidates(n: int, w_padded: int) -> list[str]:
 
 def _effective_extreme_mode(n: int, w_padded: int,
                             platform: str | None = None) -> str:
-    """The min/max strategy for this shape: 'auto' ranks scan vs segment
-    vs (when eligible) subblock with the cost model; an explicit
-    "subblock" falls back to "scan" on ineligible shapes — same rule on
-    the materialized and streaming paths (they must never drift)."""
-    mode = _EXTREME_MODE
-    sub_ok = (n % _SUB_K == 0 and n > _SUB_K
-              and _subblock_edges_fit(n, w_padded + 1))
-    if mode == "auto":
-        from opentsdb_tpu.ops.hostlane import execution_platform
-        from opentsdb_tpu.ops import costmodel
-        return costmodel.choose_extreme(
-            1, n, w_padded + 1, platform or execution_platform(),
-            _extreme_candidates(n, w_padded))
-    if mode == "subblock" and not sub_ok:
-        return "scan"
-    return mode
+    """The min/max form for this shape: scan vs segment vs (when
+    eligible) subblock, ranked by the cost table — one rule for the
+    materialized and streaming paths (they must never drift)."""
+    from opentsdb_tpu.ops.hostlane import execution_platform
+    from opentsdb_tpu.ops import costmodel
+    return costmodel.choose_extreme(
+        1, n, w_padded + 1, platform or execution_platform(),
+        _extreme_candidates(n, w_padded))
 
 
 def search_decision(s: int, n: int, w_edges: int, platform: str) -> dict:
-    """The edge-search strategy decision for one dispatch shape, as the
-    trace annotates it: chosen mode, per-candidate predicted ms, and
-    where the choice came from.  Recomputes exactly what the kernel's
-    trace-time `_effective_search_mode` picks for this platform."""
+    """The edge-search decision for one dispatch shape, as the trace
+    annotates it: chosen form and per-candidate predicted ms.
+    Recomputes exactly what the kernel's trace-time
+    `_effective_search_mode` picks for this platform."""
     from opentsdb_tpu.ops import costmodel
     return _decision_report(
         "search", _effective_search_mode(s, n, w_edges, platform),
-        _SEARCH_MODE, _search_candidates(n, w_edges), platform,
+        _search_candidates(n, w_edges),
         lambda m: costmodel.predict_search(m, s, n, w_edges, platform))
 
 
-def scan_dispatch_mode(smode: str, n: int, w_edges: int) -> str:
-    """The prefix form that ACTUALLY dispatches for an effective scan
-    mode: explicit sub-block/blocked picks fall back to flat on
-    ineligible shapes at the kernel call sites (_window_scan_setup /
-    _edge_prefix_builder) — the decision report and the calibration
-    ring must record the dispatched form, not the configured wish."""
-    sub_ok = n % _SUB_K == 0 and n > _SUB_K
-    if smode == "subblock" and sub_ok and _subblock_edges_fit(n, w_edges):
-        return "subblock"
-    if smode == "subblock2" and sub_ok:
-        return "subblock2"
-    if smode == "blocked" and n % _SCAN_BLOCK == 0 and n > _SCAN_BLOCK:
-        return "blocked"
-    return "flat"
-
-
 def scan_decision(s: int, n: int, w_edges: int, platform: str) -> dict:
-    """The prefix-scan strategy decision for one dispatch shape (see
+    """The prefix-scan decision for one dispatch shape (see
     `search_decision`)."""
     from opentsdb_tpu.ops import costmodel
-    dispatched = scan_dispatch_mode(
-        _effective_scan_mode(s, n, w_edges, platform), n, w_edges)
-    # every form dispatchable at this shape (blocked is explicit-only —
-    # it never wins auto — but it IS a legal dispatch, so the report
-    # prices it rather than flagging a forced 'blocked' as infeasible)
-    cands = _scan_candidates(n, w_edges)
-    if n % _SCAN_BLOCK == 0 and n > _SCAN_BLOCK:
-        cands = cands + ["blocked"]
     return _decision_report(
-        "scan", dispatched, _SCAN_MODE, cands, platform,
+        "scan", _effective_scan_mode(s, n, w_edges, platform),
+        _scan_candidates(n, w_edges),
         lambda m: costmodel.predict_scan(m, s, n, w_edges, platform))
 
 
 def extreme_decision(n: int, w_padded: int, platform: str) -> dict:
-    """The min/max strategy decision for one dispatch shape (see
+    """The min/max decision for one dispatch shape (see
     `search_decision`)."""
     from opentsdb_tpu.ops import costmodel
     return _decision_report(
         "extreme", _effective_extreme_mode(n, w_padded, platform),
-        _EXTREME_MODE, _extreme_candidates(n, w_padded), platform,
+        _extreme_candidates(n, w_padded),
         lambda m: costmodel.predict_extreme(m, 1, n, w_padded + 1,
                                             platform))
 
 
-def _decision_report(axis: str, chosen: str, configured: str,
-                     candidates: list[str], platform: str,
+def _decision_report(axis: str, chosen: str, candidates: list[str],
                      predict) -> dict:
-    """Shared decision-report shape (group_agg uses it too): `source`
-    says whether the mode came from the costmodel argmin ('auto') or an
-    explicit env/config override ('forced'); `calibration` names the
-    cost-table layer the argmin consulted (default/file/live);
-    `feasible` is False only if a mode outside the feasible candidate
-    set would dispatch — the kernels' guards make that unreachable, and
-    the planner counts any violation (tsd.costmodel.infeasible)."""
-    from opentsdb_tpu.ops import costmodel
+    """Shared decision-report shape (group_agg uses it too).
+    `feasible` is False only if a form outside the feasible candidate
+    set would dispatch — the choosers pick among the candidates, so
+    that is unreachable, and the planner counts any violation
+    (tsd.costmodel.infeasible)."""
     return {
         "axis": axis,
         "mode": chosen,
-        "source": "auto" if configured == "auto" else "forced",
-        "calibration": costmodel.calibration_source(platform),
         "candidates": {m: round(predict(m) * 1e3, 4)
                        for m in candidates},
         "feasible": chosen in candidates,
@@ -875,7 +647,7 @@ def _edge_search(cts, cedges):
     """
     s, n = cts.shape
     mode = _effective_search_mode(s, n, cedges.shape[0])
-    if mode == "hier" and n % _SUB_K == 0 and n > _SUB_K:
+    if mode == "hier":
         k = _SUB_K
         nb = n // k
         c3 = cts.reshape(s, nb, k)
@@ -906,9 +678,7 @@ def _window_scan_setup(ts, val, mask, spec: WindowSpec, wargs: dict):
     ok = mask & ~jnp.isnan(vf)
     cts, cedges = _compact_ts(ts, spec, wargs)
     idx = _edge_search(cts, cedges)
-    smode = scan_dispatch_mode(_effective_scan_mode(s, n,
-                                                    cedges.shape[0]),
-                               n, cedges.shape[0])
+    smode = _effective_scan_mode(s, n, cedges.shape[0])
     if smode == "subblock":
         windowed = _edge_subblock_builder(s, n, idx)
     elif smode == "subblock2":
@@ -999,9 +769,9 @@ def _extreme_downsample(ts, val, mask, spec: WindowSpec, wargs: dict,
 def _use_subblock_extreme(n: int, w_padded: int) -> bool:
     """ONE predicate for taking the subblock extreme form, shared by the
     materialized and streaming paths (they must never drift); ineligible
-    shapes fall back to the scan form on BOTH paths.  Eligibility (the
-    edge-fit guard bounding the [S, W, K] boundary-lane intermediates)
-    and auto-selection both live in _effective_extreme_mode."""
+    shapes never see it on either path.  Eligibility (the edge-fit
+    guard bounding the [S, W, K] boundary-lane intermediates) and the
+    ranking both live in _effective_extreme_mode."""
     return _effective_extreme_mode(n, w_padded) == "subblock"
 
 
@@ -1144,8 +914,6 @@ def downsample(ts, val, mask, agg_name: str, spec: WindowSpec, wargs: dict,
             out, count_grid = _prefix_downsample(ts, val, mask, agg_name,
                                                  spec, wargs)
         else:
-            # ineligible shapes under "subblock" fall back to the scan
-            # form (NOT the segment scatter) — same rule as streaming
             is_min = agg_name in ("min", "mimmin")
             extreme = _extreme_subblock if emode == "subblock" \
                 else _extreme_downsample

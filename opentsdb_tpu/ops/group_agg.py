@@ -69,46 +69,23 @@ def _identity(x):
 # device (argsort of gid — S elements, trivial) so every group is a
 # contiguous row run; group sums and extremes are short segmented
 # reset-scans along the tiny [S, W] grid's row axis — no scatter, no
-# one-hot, cost independent
-# of the group count (from an earlier chip session, not re-measured on
-# this installation — ROADMAP A6: the segment tail cost 219ms and the
-# matmul tail ~100ms on a 0.5M-cell grid that one pass covers in ~1ms).
+# one-hot, cost independent of the group count (PR 27's race on the
+# chip: ops/costmodel.py's table).
 # All are float64 (Java-double contract); the sum order differs so
-# results can drift in the last ulp.  TSDB_GROUP_REDUCE_MODE forces a
-# mode for an A/B (bench_prefix.py).
-# "rows" is no mode to choose: where the caller guarantees that row i IS
+# results can drift in the last ulp.  _effective_group_reduce_mode
+# picks from platform and shape.
+# "rows" is no form to rank: where the caller guarantees that row i IS
 # group i (one member a group, the whole batch in one dispatch: a
 # group-by over every host of a fleet), the reduction is a copy of the
 # [S, W] grid into [G, W].  Raced on a v5e at [100 000, 8] -> G 131 072:
 # 1.2 ms against sorted 38.6 and segment 128.3; at [4000, 16] -> G 4096:
 # 1.1 against 2.4 and 11.4 (PERF.md section 6, PR 27).
-import os as _os
-
-_GROUP_REDUCE_MODES = ("auto", "segment", "matmul", "sorted", "sorted2")
-_GROUP_REDUCE_MODE = (_os.environ.get("TSDB_GROUP_REDUCE_MODE")
-                      if _os.environ.get("TSDB_GROUP_REDUCE_MODE")
-                      in _GROUP_REDUCE_MODES else "auto")
 
 # Shape gate for the matmul form: the dense one-hot is [S, G] f64, so a
 # wide group-by (10k groups) would build GBs and burn O(S*G*W) FLOPs —
-# those shapes keep the scatter regardless of the A/B winner.
+# those shapes never rank it.
 _MATMUL_MAX_GROUPS = 512
 _MATMUL_MAX_ONEHOT_BYTES = 1 << 25        # 32 MB
-
-
-def set_group_reduce_mode(mode: str) -> None:
-    """Benchmarking/ops hook ('auto' = shape/platform cost model); clears
-    the jitted pipelines that baked the old strategy in (read at trace
-    time)."""
-    global _GROUP_REDUCE_MODE
-    if mode not in _GROUP_REDUCE_MODES:
-        raise ValueError("group reduce mode must be one of %r"
-                         % (_GROUP_REDUCE_MODES,))
-    _GROUP_REDUCE_MODE = mode
-    # one list of toggle-dependent compiled programs, owned by downsample
-    # (review r4: a hand-copied list here would drift)
-    from opentsdb_tpu.ops.downsample import _clear_dependent_caches
-    _clear_dependent_caches()
 
 
 def _matmul_feasible(s: int, g: int) -> bool:
@@ -116,12 +93,9 @@ def _matmul_feasible(s: int, g: int) -> bool:
 
 
 def _group_candidates(s: int, g: int, extremes: bool) -> list[str]:
-    # "sorted2" is deliberately NOT an auto candidate yet: its cost
-    # constant is an estimate until a chip race records it (r5 policy:
-    # no unraced mode can be auto-picked by a BASELINE config).
     cands = ["segment", "sorted"]
     # extremes have no matmul form (min/max don't distribute over the
-    # one-hot dot) — auto must rank only the forms that exist for them
+    # one-hot dot) — rank only the forms that exist for them
     if not extremes and _matmul_feasible(s, g):
         cands.append("matmul")
     return cands
@@ -131,17 +105,13 @@ def _effective_group_reduce_mode(s: int, w: int, g: int,
                                  extremes: bool = False,
                                  platform: str | None = None,
                                  row_groups: bool = False) -> str:
-    """The group-combine strategy for this shape: 'auto' (default) ranks
-    segment/sorted/(feasible) matmul with the calibrated cost model
-    (ops.costmodel — chip anchors: segment scatter 219ms, matmul ~100ms
-    at G=100, sorted ~90ms G-independent on the headline grid; CPU
-    scatters are cheap so segment wins there).  Explicit modes keep the
-    matmul feasibility gate at the call sites.  `platform` defaults to
-    the ambient execution platform; the planner's decision report
-    passes its per-segment platform explicitly."""
-    mode = _GROUP_REDUCE_MODE
-    if mode != "auto":
-        return mode
+    """The group-combine form for this shape: a copy where the caller
+    guarantees one member a group (`row_groups`), else segment / sorted /
+    (feasible) matmul ranked by the cost table (ops.costmodel — chip
+    anchors: PR 27's race, costmodel.py; CPU scatters are cheap so
+    segment wins there).  `platform` defaults to the ambient execution
+    platform; the planner's decision report passes its per-segment
+    platform explicitly."""
     if row_groups:
         return "rows"
     from opentsdb_tpu.ops.hostlane import execution_platform
@@ -154,24 +124,17 @@ def _effective_group_reduce_mode(s: int, w: int, g: int,
 def group_decision(s: int, w: int, g: int, platform: str,
                    extremes: bool = False,
                    row_groups: bool = False) -> dict:
-    """The group-reduce strategy decision for one dispatch shape, as
-    the trace annotates it (same report shape as
-    downsample.search_decision).  An explicit matmul on an infeasible
-    shape dispatches segment at the call sites — the report records the
-    dispatched form."""
+    """The group-reduce decision for one dispatch shape, as the trace
+    annotates it (same report shape as downsample.search_decision)."""
     from opentsdb_tpu.ops import costmodel
     from opentsdb_tpu.ops.downsample import _decision_report
     mode = _effective_group_reduce_mode(s, w, g, extremes, platform,
                                         row_groups)
-    if mode == "matmul" and (extremes or not _matmul_feasible(s, g)):
-        mode = "segment"    # the call-site feasibility fallback
     cands = _group_candidates(s, g, extremes)
-    if _GROUP_REDUCE_MODE == "sorted2":
-        cands = cands + ["sorted2"]     # explicit-only mode: price it
     if mode == "rows":
         cands = ["rows"] + cands        # a guarantee, not a ranking
     return _decision_report(
-        "group", mode, _GROUP_REDUCE_MODE, cands, platform,
+        "group", mode, cands,
         lambda m: costmodel.predict_group(m, s, w, g, platform))
 
 
@@ -255,29 +218,6 @@ class _SortedGroups:
         ends = jnp.clip(self.bounds[1:] - 1, 0, self.s - 1)
         return jnp.take(scanned, ends, axis=0)
 
-    # -- mode "sorted2": blocked level-masked folds (same answers) ---- #
-
-    def sum2(self, x2d):
-        """[S, W] -> [G, W] per-group column sums via the blocked
-        level-masked reset-scan (_blocked_group_fold) — dtype-preserving,
-        so int32 counts ride native TPU adds instead of emulated f64."""
-        xs = x2d if self.perm is None \
-            else jnp.take(x2d, self.perm, axis=0)
-        return _blocked_group_fold(xs, self.flags, self.bounds, self.s,
-                                   jnp.add, 0)
-
-    def extreme2(self, x2d, want_max: bool):
-        """[S, W] -> [G, W] per-group min/max via the blocked fold;
-        same identity-fill contract as extreme()."""
-        xs = x2d if self.perm is None \
-            else jnp.take(x2d, self.perm, axis=0)
-        if want_max:
-            return _blocked_group_fold(xs, self.flags, self.bounds,
-                                       self.s, jnp.maximum, -jnp.inf)
-        return _blocked_group_fold(xs, self.flags, self.bounds, self.s,
-                                   jnp.minimum, jnp.inf)
-
-
 class _RowGroups:
     """_SortedGroups where row i IS group i (form "rows"): every fold of
     a run is the row itself, so each is a copy of [S, W] into [G, W]
@@ -299,93 +239,6 @@ def _run_folds(mode: str, gid, num_groups: int, s: int, rows_sorted: bool):
     """The fold machinery of the scatter-free forms."""
     return _RowGroups(num_groups) if mode == "rows" \
         else _SortedGroups(gid, num_groups, s, rows_sorted)
-
-
-_SORTED2_K = 8          # rows per block in the blocked reset-scan
-
-
-def _blocked_group_fold(xs, flags, bounds, s_orig: int, op, identity):
-    """Per-group fold over group-sorted rows: a blocked, level-masked
-    segmented (reset) scan — the machinery behind group mode "sorted2".
-
-    Same answer as _SortedGroups' associative_scan reset-fold, ~3x less
-    device work on the value channel:
-
-      * the reset flags depend only on the [S] row axis, never on W, so
-        every level's carry mask is precomputed on [S] bools and the
-        heavy [S, W] channel pays ONE select+op per level instead of the
-        pair operator's add + two selects + a broadcast [S, W] bool OR;
-      * blocking at K rows halves the level count on the full-size
-        channel: log2(K) full-width levels + log2(S/K) levels on the
-        [S/K, W] block summaries (vs log2(S) full-width levels).
-
-    Like the reset-scan (and unlike a cumsum differenced at group
-    bounds), no addition ever combines values from two different groups
-    — error scales with each group's own magnitude, so the
-    1e15-next-to-1.0 skew contract holds (see _SortedGroups.sum).
-
-    xs: [S, W] group-sorted rows (any dtype with `op`/`identity`, f64
-    values or int32 counts); flags: [S] bool, True where a row starts a
-    new group run; bounds: [G+1] group row bounds; s_orig: valid row
-    count (xs rows past it are ignored).  Returns [G, W] per-group fold,
-    `identity` for empty groups.
-    """
-    k = _SORTED2_K
-    s, w = xs.shape
-    sp = -(-max(s, 1) // k) * k
-    if sp != s:
-        pad_rows = jnp.full((sp - s, w), identity, xs.dtype)
-        xs = jnp.concatenate([xs, pad_rows], axis=0)
-        flags = jnp.concatenate(
-            [flags, jnp.ones((sp - s,), bool)], axis=0)
-    nb = sp // k
-    pos_in_block = jnp.arange(sp, dtype=jnp.int32) % k
-
-    def shift_rows(a, d, fill):
-        return jnp.concatenate(
-            [jnp.full((d,) + a.shape[1:], fill, a.dtype), a[:-d]], axis=0)
-
-    # Within-block Hillis-Steele with per-level [S] carry masks: after
-    # log2(K) levels, row i holds the fold of its run restricted to its
-    # own block (runs reset at group starts).
-    fl = flags
-    v = xs
-    d = 1
-    while d < k:
-        in_block = pos_in_block >= d
-        carry = in_block & ~fl
-        v = jnp.where(carry[:, None], op(v, shift_rows(v, d, identity)), v)
-        fl = fl | (in_block & shift_rows(fl, d, False))
-        d *= 2
-
-    # Block summaries: Y[b] = fold of block b's trailing run; Fb[b] =
-    # block contains a run start (so carries stop at it).
-    y = v[k - 1::k]                                         # [nb, W]
-    fb = flags.reshape(nb, k).any(axis=1)                   # [nb]
-    zb = y
-    fbl = fb
-    bpos = jnp.arange(nb, dtype=jnp.int32)
-    d = 1
-    while d < nb:
-        carry_b = (bpos >= d) & ~fbl
-        zb = jnp.where(carry_b[:, None],
-                       op(zb, shift_rows(zb, d, identity)), zb)
-        fbl = fbl | ((bpos >= d) & shift_rows(fbl, d, False))
-        d *= 2
-
-    # Group g ends at row e: fold = intra[e], combined with the previous
-    # blocks' summary iff e's run reaches back past its block start
-    # (no flag in rows [block_start(e) .. e] — an OR-scan on [S] bools).
-    fcum = jnp.cumsum(flags.reshape(nb, k).astype(jnp.int32),
-                      axis=1).reshape(sp) > 0               # [S'] incl. OR
-    ends = jnp.clip(bounds[1:] - 1, 0, s_orig - 1)          # [G]
-    be = (ends // k).astype(jnp.int32)
-    intra_e = jnp.take(v, ends, axis=0)                     # [G, W]
-    z_prev = jnp.take(zb, jnp.clip(be - 1, 0, nb - 1), axis=0)
-    carry_e = ((~jnp.take(fcum, ends)) & (be > 0))[:, None]
-    out = jnp.where(carry_e, op(intra_e, z_prev), intra_e)
-    empty = (bounds[1:] == bounds[:-1])[:, None]
-    return jnp.where(empty, jnp.asarray(identity, xs.dtype), out)
 
 
 def _no_interior_hole(mask):
@@ -502,22 +355,18 @@ def moment_group_reduce(agg_name: str, contrib, participate, gid,
 
     if extremes:
         want_max = agg_name in ("max", "mimmax")
-        if mode in ("sorted", "sorted2", "rows"):
+        if mode in ("sorted", "rows"):
             # contiguous-run reset-scan over group-sorted rows: no
-            # scatter.  sorted2 = the blocked fold, with native-int32
-            # counts (exact: counts <= S).  rows = every run is one row.
+            # scatter.  rows = every run is one row.
             sg = _run_folds(mode, gid, g, s, rows_sorted)
-            fold = sg.sum2 if mode == "sorted2" else sg.sum
-            cdt = jnp.int32 if mode == "sorted2" else jnp.float64
             vf0 = contrib.astype(jnp.float64)
             ok0 = participate & ~jnp.isnan(vf0)
-            local_cnt = fold(ok0.astype(cdt))                   # [G, W]
+            local_cnt = sg.sum(ok0.astype(jnp.float64))         # [G, W]
             cnt_grid = combine_sum(local_cnt.reshape(-1)) \
                 .reshape(g, w).astype(jnp.int64)
             ident = -jnp.inf if want_max else jnp.inf
             filled = jnp.where(ok0, vf0, ident)
-            ext = (sg.extreme2(filled, want_max) if mode == "sorted2"
-                   else sg.extreme(filled, want_max))
+            ext = sg.extreme(filled, want_max)
             # a group empty on THIS shard must contribute the identity to
             # pmin/pmax, not the boundary gather's neighboring-run value
             ext = jnp.where(local_cnt > 0.5, ext, ident).reshape(-1)
@@ -525,7 +374,7 @@ def moment_group_reduce(agg_name: str, contrib, participate, gid,
                    else combine_min(ext)).reshape(g, w)
             out = jnp.where(cnt_grid > 0, ext, jnp.nan)
             return out, cnt_grid
-        # segment/matmul modes: extremes have no matmul form — scatter ops
+        # segment: extremes have no matmul form — scatter ops
         seg, ok, v = _flat_segments(contrib, participate, gid, g)
         cnt = combine_sum(jax.ops.segment_sum(ok.astype(jnp.int32), seg,
                                               num_segments=num))
@@ -539,22 +388,18 @@ def moment_group_reduce(agg_name: str, contrib, participate, gid,
         out = jnp.where(cnt_grid > 0, ext.reshape(g, w), jnp.nan)
         return out, cnt_grid
 
-    # One finish, two group-sum primitives.  The matmul form is gated to
-    # shapes where the dense one-hot is cheap (small G relative to S —
-    # the headline group-by shape); a 10k-group query would build a
-    # multi-GB [S, G] one-hot, so big-G shapes keep the scatter
-    # regardless of the A/B winner (review r4).
+    # One finish, three group-sum primitives.  The matmul form is a
+    # candidate only where the dense one-hot is cheap (small G relative
+    # to S — the headline group-by shape, _matmul_feasible).
     vf = contrib.astype(jnp.float64)
     ok2 = participate & ~jnp.isnan(vf)
     v2 = jnp.where(ok2, vf, 0.0)
-    use_matmul = mode == "matmul" and _matmul_feasible(s, g)
-    if mode in ("sorted", "sorted2", "rows"):
+    if mode in ("sorted", "rows"):
         sg = _run_folds(mode, gid, g, s, rows_sorted)
-        fold = sg.sum2 if mode == "sorted2" else sg.sum
 
         def gsum(x2d):   # [S, W] -> [G, W], cross-chip combined
-            return combine_sum(fold(x2d).reshape(-1)).reshape(g, w)
-    elif use_matmul:
+            return combine_sum(sg.sum(x2d).reshape(-1)).reshape(g, w)
+    elif mode == "matmul":
         # out[g, w] = Σ_s onehot[s, g] * grid[s, w] — dense MXU work, no
         # serializing scatter.  Counts are 0/1 sums (exact in f64 far
         # beyond any real S); value sums reassociate vs segment_sum, so
@@ -577,10 +422,7 @@ def moment_group_reduce(agg_name: str, contrib, participate, gid,
                 x2d.reshape(-1), seg, num_segments=num + 1)[:-1]) \
                 .reshape(g, w)
 
-    # sorted2 counts ride int32 (native TPU adds, exact — counts <= S;
-    # psum combines int32 fine); other modes keep their f64/scatter form
-    cnt_dtype = jnp.int32 if mode == "sorted2" else jnp.float64
-    cnt_grid = gsum(ok2.astype(cnt_dtype)).astype(jnp.int64)
+    cnt_grid = gsum(ok2.astype(jnp.float64)).astype(jnp.int64)
     safe = jnp.maximum(cnt_grid, 1)
 
     if agg_name in ("sum", "zimsum", "pfsum"):
@@ -732,7 +574,7 @@ def grid_group_aggregate(grid_ts, val, mask, gid, num_groups: int,
                                       num_groups)
     s, w = val.shape
     # same extremes flag as moment_group_reduce's own decision: the mask
-    # pass must ride the mode the reduce actually took, or an auto pick
+    # pass must ride the form the reduce actually took, or a pick
     # of matmul (excluded for extremes) would put the segment scatter
     # back into a dispatch the sorted mode was chosen to keep
     # scatter-free (review r5)
@@ -741,15 +583,11 @@ def grid_group_aggregate(grid_ts, val, mask, gid, num_groups: int,
         s, w, num_groups,
         extremes=is_moment_agg(agg.name) and extreme_agg,
         row_groups=row_groups)
-    if mask_mode in ("sorted", "sorted2", "rows"):
+    if mask_mode in ("sorted", "rows"):
         # same fold machinery as the reduce (XLA CSEs the repeated
-        # argsort/bounds); sorted2 presence rides native int32 adds.
-        # Both fold exact integer counts, so > 0 is the same test.
+        # argsort/bounds)
         sg = _run_folds(mask_mode, gid, num_groups, s, rows_sorted)
-        present = (sg.sum2(mask.astype(jnp.int32))
-                   if mask_mode == "sorted2"
-                   else sg.sum(mask.astype(jnp.float64)))
-        out_mask = present > 0
+        out_mask = sg.sum(mask.astype(jnp.float64)) > 0
     else:
         dt = _seg_dtype(num_groups * w + w)
         cols = jnp.arange(w, dtype=dt)[None, :]

@@ -235,7 +235,7 @@ def _stacked_group_pipeline(spec: PipelineSpec, num_groups: int, ts, val,
     """Q compatible grouped queries in ONE stacked [Q, S, N] dispatch.
 
     The fused multi-query batcher (query/batcher.py) buckets concurrent
-    small plans by (static spec, padded shapes, mode-policy epoch) and
+    small plans by (static spec, padded shapes) and
     vmaps the SAME _group_pipeline over a leading member axis — each
     member keeps its own gid row map and its own traced window args
     (stacked along axis 0), and inside the vmap the kernels trace on

@@ -211,24 +211,10 @@ def _segment_chunk_moments(ts, val, mask, spec: WindowSpec, wargs: dict,
 
 # Segment-vs-dense routing threshold for streamed chunks: the segment
 # form engages when W > ratio * N.  1.0 is the analytic crossover (per-
-# edge search work vs per-point scatter work); tools/stage_bench.py's
-# stream_chunk_segment / stream_chunk_dense stages measure the real one
-# and have not run on this installation (ROADMAP A2/A6) — TPU scatters
-# serialize, so the measured ratio may sit well above 1.  Env override
-# pending a chip-measured default.
-import os as _os
-
-_SEGMENT_CHUNK_RATIO = float(_os.environ.get(
-    "TSDB_STREAM_SEGMENT_RATIO", "1.0"))
-
-
-def set_segment_chunk_ratio(ratio: float) -> None:
-    """W/N threshold above which streamed chunks take the segment form;
-    clears dependent jit caches (read at trace time)."""
-    global _SEGMENT_CHUNK_RATIO
-    _SEGMENT_CHUNK_RATIO = float(ratio)
-    from opentsdb_tpu.ops.downsample import _clear_dependent_caches
-    _clear_dependent_caches()
+# edge search work vs per-point scatter work); no chip run has measured
+# the real one (no cell streams: PERF.md section 4) — TPU scatters
+# serialize, so it may sit well above 1.
+_SEGMENT_CHUNK_RATIO = 1.0
 
 
 def _use_segment_chunk(n: int, w: int, lanes: frozenset,

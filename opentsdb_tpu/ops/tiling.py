@@ -43,14 +43,14 @@ instead, in the spilled-window-aggregation stance (arXiv:2007.10385):
      only where a member holds a real value, not an interpolated one —
      the same rule the resident tail applies).
 
-The tiled-vs-refuse decision and its price come from the fitted
+The tiled-vs-refuse decision and its price come from the
 costmodel: ``costmodel.features_tiled`` / ``predict_tiled`` stay a dot
 product against ``COST_TERMS`` (spill write/read MB, per-tile dispatch
 overhead) per the linearity contract, `tsd/admission.py` prices the
 tiled plan with the same vector instead of shedding it, and every
 tiled pipeline span carries a ``tiling`` annotation (tile count, spill
-bytes, decision source).  Tiled executions are deliberately EXCLUDED
-from the calibration ring, like partial-aggregate rewrites: the
+bytes).  Tiled executions are deliberately EXCLUDED
+from the predicted-vs-actual ring, like partial-aggregate rewrites: the
 monolithic stage breakdown does not describe a tiled execution
 (pinned by tests/test_tiling.py).
 """
@@ -88,7 +88,6 @@ class TilePlan:
     spill_bytes: int     # total partial-grid bytes through the pool
     dispatches: int      # extra launches a tiled plan issues
     predicted_s: float   # tiled OVERHEAD prediction (costmodel)
-    source: str          # calibration layer that priced it
 
 
 def size_tiles(s: int, w: int, budget_bytes: int, acc_cell_bytes: int,
@@ -122,7 +121,7 @@ def size_tiles(s: int, w: int, budget_bytes: int, acc_cell_bytes: int,
     # + finish/contrib, per-stripe tail + presence
     dispatches = n_tiles * (chunks_per_tile + 2) + 2 * n_stripes
     return TilePlan(tile_rows, n_tiles, stripe_w, n_stripes, spill_bytes,
-                    dispatches, 0.0, "default")
+                    dispatches, 0.0)
 
 
 def count_refusal(reason: str) -> None:
@@ -179,8 +178,7 @@ def plan_tiled(tsdb, *, s: int, w: int, g_pad: int, acc_cell_bytes: int,
     predicted = cm.predict_tiled(s, w, g_pad, plan.n_tiles,
                                  plan.n_stripes, plan.spill_bytes,
                                  plan.dispatches, platform)
-    return replace(plan, predicted_s=predicted,
-                   source=cm.calibration_source(platform))
+    return replace(plan, predicted_s=predicted)
 
 
 # --------------------------------------------------------------------- #
@@ -484,8 +482,7 @@ def run_tiled(tsdb, spec, seg, series_list, gid, g_pad: int, window_spec,
         stats = {"tiles": plan.n_tiles, "stripes": plan.n_stripes,
                  "spillBytes": int(spilled_bytes),
                  "chunks": int(chunks_total),
-                 "predictedMs": round(plan.predicted_s * 1e3, 3),
-                 "source": plan.source}
+                 "predictedMs": round(plan.predicted_s * 1e3, 3)}
         recorder = getattr(tsdb, "flightrec", None)
         if recorder is not None:
             # retained spill evidence: tile/stripe split + bytes

@@ -14,10 +14,8 @@ unpack — Q queries, one launch floor.
 Compatibility = one jit program: plans share a bucket only when they
 would trace the SAME kernel — identical static ``PipelineSpec``,
 identical padded batch shapes and dtypes, identical window-arg
-structure, the same host-lane verdict, and the same **mode-policy
-epoch** (an autotune flip mid-coalesce must not splice two kernel
-generations into one launch; members on either side of the flip land
-in different buckets).  Within a bucket each member keeps its own
+structure and the same host-lane verdict.  Within a bucket each member
+keeps its own
 mask plane, its own gid row map, and its own traced window args
 (stacked along the member axis), so on integer data a member's
 unpacked slice is bitwise what its solo dispatch would produce
@@ -35,9 +33,9 @@ the admission gate shows no other query in flight: an uncontended
 query never pays coalesce latency), seals the bucket at
 ``tsd.query.batch.max_q`` members / ``tsd.query.batch.max_mb`` of
 stacked operands, dispatches once, and distributes the host-unpacked
-slices.  Batched executions are EXCLUDED from the calibration ring
-like rewrites/tiled runs (a stacked launch's measured time describes
-no single member's feature vector).
+slices.  Batched executions are EXCLUDED from the predicted-vs-actual
+ring like rewrites/tiled runs (a stacked launch's measured time
+describes no single member's prediction).
 
 Deadlines stay per-member: a member whose deadline expires or cancels
 while waiting leaves the bucket WITHOUT poisoning its siblings — the
@@ -120,15 +118,12 @@ def _wargs_signature(wargs: dict) -> tuple:
 
 
 def bucket_key(spec, g_pad: int, ts, val, gid, wargs: dict,
-               host_small: bool, policy_epoch: int) -> tuple:
+               host_small: bool) -> tuple:
     """The compatibility key: everything the stacked jit program bakes
     in at trace time.  PipelineSpec is frozen/hashable (it IS the
-    static argument); shapes/dtypes cover the operand layout; the
-    mode-policy epoch keeps an autotune flip from splicing kernel
-    generations into one launch."""
+    static argument); shapes/dtypes cover the operand layout."""
     return (spec, g_pad, ts.shape, val.dtype.str, gid.dtype.str,
-            _wargs_signature(wargs), bool(host_small),
-            int(policy_epoch))
+            _wargs_signature(wargs), bool(host_small))
 
 
 class DispatchBatcher:
@@ -166,7 +161,7 @@ class DispatchBatcher:
     # -- the rendezvous -------------------------------------------------- #
 
     def submit(self, spec, ts, val, mask, gid, g_pad: int, wargs: dict,
-               host_small: bool, policy_epoch: int, deadline=None):
+               host_small: bool, deadline=None):
         """Execute one batch-routed plan; returns ((out_ts, out_val,
         out_mask, dense), info) where the outputs are the member's own
         host-unpacked slice (np arrays when stacked, device arrays on
@@ -176,7 +171,7 @@ class DispatchBatcher:
         member = _Member(ts, val, mask, np.asarray(gid), wargs, deadline)
         t0 = time.monotonic()
         key = bucket_key(spec, g_pad, ts, val, member.gid, wargs,
-                         host_small, policy_epoch)
+                         host_small)
         with self._lock:
             bucket = self._buckets.get(key)
             leader = bucket is None

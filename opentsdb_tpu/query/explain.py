@@ -32,16 +32,15 @@ JSON object on POST:
   * ``state_mb=<int>``        hypothetical tsd.query.streaming.state_mb
   * ``rollup_mb=<int>``       hypothetical tsd.rollup.mb (0 = lanes off)
   * ``platform=cpu|tpu``      price for an alternate execution platform
-  * ``calibration=default|file|auto`` reprice candidates from a layer
   * ``deadline_ms=<int>``     admission preview against this budget
   * ``force_search|force_scan|force_extreme|force_group=<mode>``
                               forced kernel modes in the report
 
 Cache/budget/platform what-ifs feed the routing decision itself;
-forced modes and the calibration layer produce a repriced
-``costmodelWhatIf`` report beside the actual decision (per-candidate
-pricing is already part of every decision report, so a forced mode is
-a reporting question, not a global mode flip).
+forced modes produce a ``costmodelWhatIf`` report beside the actual
+decision (per-candidate pricing is already part of every decision
+report, so a forced mode is a reporting question: nothing the daemon
+serves with can be forced).
 """
 
 from __future__ import annotations
@@ -57,7 +56,6 @@ from opentsdb_tpu.query import plandecision as pdn
 from opentsdb_tpu.query.limits import QueryException, active_deadline
 
 _ASSUME = ("live", "cold", "warm")
-_CAL_LAYERS = ("auto", "default", "file")
 _FORCE_AXES = ("search", "scan", "extreme", "group")
 
 
@@ -74,7 +72,6 @@ class WhatIf:
     state_mb: int | None = None
     rollup_mb: int | None = None
     platform: str | None = None
-    calibration: str = "auto"
     deadline_ms: int | None = None
     force: dict = field(default_factory=dict)   # axis -> mode
 
@@ -86,7 +83,6 @@ class WhatIf:
                 or self.state_mb is not None
                 or self.rollup_mb is not None
                 or self.platform is not None
-                or self.calibration != "auto"
                 or self.deadline_ms is not None
                 or bool(self.force))
 
@@ -94,8 +90,7 @@ class WhatIf:
         out: dict = {}
         for key, live in (("assume_rollup", "live"),
                           ("assume_agg_cache", "live"),
-                          ("assume_device_cache", "live"),
-                          ("calibration", "auto")):
+                          ("assume_device_cache", "live")):
             value = getattr(self, key)
             if value != live:
                 out[key] = value
@@ -133,11 +128,6 @@ def parse_what_if(raw: dict) -> WhatIf:
             if value not in ("cpu", "tpu"):
                 raise WhatIfError("platform must be cpu|tpu")
             wi.platform = value
-        elif key == "calibration":
-            if value not in _CAL_LAYERS:
-                raise WhatIfError("calibration must be one of %s"
-                                  % "|".join(_CAL_LAYERS))
-            wi.calibration = value
         elif key.startswith("force_") and key[6:] in _FORCE_AXES:
             wi.force[key[6:]] = value
         else:
@@ -289,49 +279,20 @@ class _ExplainConsults:
 # What-if repricing                                                     #
 # --------------------------------------------------------------------- #
 
-def _reprice_decisions(decisions: dict, what_if: WhatIf, s: int,
-                       n_pad: int, wp: int, g_dec: int,
-                       platform: str) -> dict | None:
-    """Forced-mode / alternate-calibration view of the per-axis
-    decision reports: same candidate sets, repriced from the requested
-    layer's table via the same ``cost_features`` vectors the fitter
-    regresses on.  None when no costmodel what-if is active."""
-    from opentsdb_tpu.ops import costmodel as cm
-    if not what_if.force and what_if.calibration == "auto":
+def _forced_decisions(decisions: dict, what_if: WhatIf) -> dict | None:
+    """Forced-mode view of the per-axis decision reports: the same
+    candidates at the same prices, with the forced form as the pick
+    (`feasible` says whether the kernels could dispatch it at this
+    shape).  None when no force_* what-if is active."""
+    if not what_if.force:
         return None
-    table = cm.layer_table(platform, what_if.calibration)
-    e = wp + 1
     out: dict = {}
     for axis, report in decisions.items():
         rep = dict(report)
-        rep["calibration"] = what_if.calibration
-        # dims mirror what each *_decision report priced with
-        # (extreme_decision prices per-row: s=1)
-        dims = {"search": (s, n_pad, e),
-                "scan": (s, n_pad, e),
-                "extreme": (1, n_pad, e),
-                "group": (s, wp, e, g_dec)}[axis]
-        priced = {}
-        for mode in report["candidates"]:
-            if axis == "group":
-                fv = cm.cost_features("group", mode, dims[0], dims[1],
-                                      dims[2], dims[3])
-            else:
-                fv = cm.cost_features(axis, mode, *dims)
-            priced[mode] = round(sum(
-                units * table[term] for term, units in fv.items())
-                * 1e3, 4)
-        rep["candidates"] = priced
         forced = what_if.force.get(axis)
         if forced is not None:
             rep["mode"] = forced
-            rep["source"] = "what_if"
-            rep["feasible"] = forced in priced
-        elif priced:
-            # the argmin under the repriced table (no hysteresis — a
-            # what-if report must not touch the sticky-choice memory)
-            rep["mode"] = min(priced, key=priced.get)
-            rep["source"] = "what_if"
+            rep["feasible"] = forced in report["candidates"]
         out[axis] = rep
     return out
 
@@ -579,15 +540,12 @@ def _explain_segment(tsdb, runner, query, sub, seg, what_if: WhatIf,
     if pd.agg_note is not None:
         base["aggCache"] = pd.agg_note
     if pd.tiled_plan is not None:
-        from opentsdb_tpu.ops import costmodel as cm
         tp = pd.tiled_plan
         base["tiling"] = {
             "tiles": tp.n_tiles, "tileRows": tp.tile_rows,
             "stripes": tp.n_stripes, "stripeWindows": tp.stripe_w,
             "spillBytes": tp.spill_bytes, "dispatches": tp.dispatches,
-            "predictedOverheadMs": round(tp.predicted_s * 1e3, 3),
-            "calibration": tp.source or cm.calibration_source(
-                pd.dec_platform)}
+            "predictedOverheadMs": round(tp.predicted_s * 1e3, 3)}
     if pd.refusal is not None:
         base["refused"] = _refusal_json(pd.refusal.exception())
     # per-axis costmodel pricing for the report: plan_decision computes
@@ -599,9 +557,7 @@ def _explain_segment(tsdb, runner, query, sub, seg, what_if: WhatIf,
         decisions = jaxprof.segment_decisions(
             pd.dec_platform, ctx.s, pd.n_pad, ctx.wp, pd.g_dec,
             ctx.ds_fn, aggregator=ctx.aggregator)
-    whatif_decisions = _reprice_decisions(
-        decisions, what_if, ctx.s, pd.n_pad, ctx.wp, pd.g_dec,
-        pd.dec_platform)
+    whatif_decisions = _forced_decisions(decisions, what_if)
     if not include_candidates:
         decisions = {axis: {k: v for k, v in rep.items()
                             if k != "candidates"}

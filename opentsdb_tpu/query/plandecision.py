@@ -23,7 +23,7 @@ callers.
 
 Every decision carries a stable **plan fingerprint** — a hash over the
 discrete routing facts (path, shapes, chosen kernel modes, lane/cache
-verdicts, calibration layer; never raw milliseconds) — which the
+verdicts; never raw milliseconds) — which the
 executor stamps into the flight-recorder ``plan`` event and the
 pipeline span, so explain-vs-actual parity is mechanically checkable
 and ``PLAN_CORPUS.json`` can byte-pin the routing of a canonical query
@@ -191,7 +191,7 @@ def size_lane_stripes(tsdb, plan, s: int, wp: int, g_pad: int,
 def _fingerprint(fields: dict) -> str:
     """Stable hash over the discrete routing facts — canonical JSON,
     first 16 hex chars of sha256.  Deliberately excludes every raw
-    millisecond so a calibration-constant edit alone cannot churn a
+    millisecond so a cost-table edit alone cannot churn a
     fingerprint unless it actually flips a decision."""
     blob = json.dumps(fields, sort_keys=True, separators=(",", ":"))
     return "pf-" + hashlib.sha256(blob.encode("utf-8")).hexdigest()[:16]
@@ -199,7 +199,6 @@ def _fingerprint(fields: dict) -> str:
 
 def _finish(pd: PlanDecision, ctx: RouteContext) -> PlanDecision:
     """Fingerprint assembly shared by the refused and served arms."""
-    from opentsdb_tpu.ops import costmodel as cm
     fields = {
         "path": pd.path,
         "seg": ctx.seg_kind,
@@ -213,7 +212,6 @@ def _finish(pd: PlanDecision, ctx: RouteContext) -> PlanDecision:
         "mesh": pd.use_mesh,
         "hostSmall": pd.host_small,
         "deviceCache": bool(pd.cached),
-        "calibration": cm.calibration_source(pd.dec_platform),
     }
     if pd.decisions is not None:
         fields["modes"] = {axis: d["mode"]

@@ -648,14 +648,6 @@ class QueryRunner:
         # unfinished inside a request-scoped trace
         psp = obs_trace.begin("pipeline", aggregator=sub.aggregator,
                               downsample=seg.ds_function or ds.function)
-        # snapshot the mode-policy epoch BEFORE the dispatch: if the
-        # autotune loop flips a strategy (exploration start/end, live
-        # install) while this query executes, the post-dispatch
-        # decision recomputation would describe the NEW policy while
-        # the kernel ran the old one — such entries are dropped from
-        # the calibration ring (see _trace_pipeline_stages)
-        from opentsdb_tpu.ops.downsample import mode_policy_epoch
-        policy_epoch = mode_policy_epoch()
         # The window plan materializes ONLY after the budget accepted the
         # scan: EdgeWindows.split builds a [W+1] edge vector sized by the
         # query's range/interval (calendar grids over a year at fine
@@ -770,7 +762,7 @@ class QueryRunner:
             # rollup lane's mergeable partials (storage/rollup.py) —
             # the raw points are never fetched, never streamed.  Exact
             # by derivation; annotated on the span's `rollup` tag; the
-            # calibration ring skips lane-served executions like
+            # predicted-vs-actual ring skips lane-served executions like
             # rewrites/tiled runs (the monolithic stage breakdown does
             # not describe them).
             out_ts, out_val, out_mask, dense = self._run_lane_serve(
@@ -783,7 +775,7 @@ class QueryRunner:
             # Out-of-core: series-tiled streaming with partial-grid
             # spill, window-striped tail replay (ops/tiling.py).  The
             # decision + pool traffic ride the span's `tiling` tag; the
-            # calibration ring skips tiled executions like rewrites
+            # predicted-vs-actual ring skips tiled executions like rewrites
             # (the monolithic stage breakdown does not describe them).
             from opentsdb_tpu.ops import tiling
             (out_ts, out_val, out_mask), tile_stats = tiling.run_tiled(
@@ -805,7 +797,7 @@ class QueryRunner:
             # compatible plans and executes as one stacked [Q, S, N]
             # kernel with host-side unpack — the per-dispatch floor is
             # paid once per bucket instead of once per query.  The
-            # calibration ring skips batched executions like rewrites/
+            # predicted-vs-actual ring skips batched executions like rewrites/
             # tiled runs (a stacked launch's measured time describes
             # no single member), so the span carries the decisions
             # directly.
@@ -814,10 +806,8 @@ class QueryRunner:
                 series_list, seg.start_ms, seg.end_ms, fix)
             (out_ts, out_val, out_mask, dense), batch_info = \
                 tsdb.dispatch_batcher.submit(
-
                     spec, ts, val, mask, gid, g_pad, wargs,
-                    host_small, policy_epoch,
-                    deadline=active_deadline())
+                    host_small, deadline=active_deadline())
             obs_trace.annotate(psp, batch=batch_info,
                                costmodel=pd.decisions)
             self.exec_stats["batched"] = 1.0
@@ -900,11 +890,10 @@ class QueryRunner:
                 # stage breakdown does not describe a block-decomposed,
                 # tiled, lane-derived, or stacked-multi-member
                 # execution, and pairing its prediction with a partial
-                # (or shared) actual would poison the calibration ring
+                # (or shared) actual would poison the ring
                 self._trace_pipeline_stages(
                     psp, sub, seg, len(gid), n_max, window_spec.count,
-                    n_groups, host_small, policy_epoch,
-                    decisions=pd.decisions)
+                    n_groups, host_small, decisions=pd.decisions)
         obs_trace.end(psp)
         recorder = getattr(tsdb, "flightrec", None)
         if recorder is not None:
@@ -965,29 +954,26 @@ class QueryRunner:
     def _trace_pipeline_stages(self, span, sub: TSSubQuery, seg: Segment,
                                s: int, n: int, w: int, g: int,
                                host_small: bool = False,
-                               policy_epoch: int | None = None,
                                decisions: dict | None = None) -> None:
         """Logical stage children of the fused dispatch span + the
         costmodel predicted-vs-actual ledger entry.
 
         XLA fuses downsample/rate/groupby/aggregate into one kernel, so
         per-stage device truth does not exist at runtime; the measured
-        device wait is APPORTIONED across the stages by the calibrated
+        device wait is APPORTIONED across the stages by the
         costmodel's per-stage predictions and the children say so
         (`estimated` tag).  The span is also annotated with every
-        kernel-axis strategy DECISION (chosen mode, per-candidate
-        predicted ms, decision source — defaults / file calibration /
-        live fitter), and the (shape, modes, feature vector, predicted,
-        actual) tuple lands in obs.jaxprof's segment ring — the corpus
-        the online calibrator (ops/calibrate.py) fits from."""
+        kernel-axis DECISION (chosen form, per-candidate predicted ms),
+        and the (shape, modes, feature vector, predicted, actual) tuple
+        lands in obs.jaxprof's segment ring."""
         from opentsdb_tpu.obs import jaxprof
         from opentsdb_tpu.ops.hostlane import execution_platform
         ds = sub.downsample_spec
         ds_fn = seg.ds_function or (ds.function if ds is not None else None)
         # per-SEGMENT platform: the exec_stats hostLane flag is sticky
         # across a run's segments and would misattribute later
-        # device-dispatched segments as cpu, poisoning the calibration
-        # ring with cpu-predicted vs device-actual pairs
+        # device-dispatched segments as cpu, filling the ring with
+        # cpu-predicted vs device-actual pairs
         platform = "cpu" if host_small else execution_platform()
         # DISPATCH shapes: build_batch pads the point axis to pow2 and
         # the group count dispatches as g_pad — the kernels' mode
@@ -1001,8 +987,8 @@ class QueryRunner:
         if decisions is None:
             # direct callers without a PlanDecision in hand; the
             # grouped executor passes plan_decision()'s reports through
-            # so the span, the fingerprint, and the calibration ring
-            # all describe ONE recomputation
+            # so the span, the fingerprint, and the ring all describe
+            # ONE recomputation
             decisions = jaxprof.segment_decisions(
                 platform, s, n, w, g, ds_fn, aggregator=sub.aggregator)
         obs_trace.annotate(span, costmodel=decisions)
@@ -1010,8 +996,7 @@ class QueryRunner:
             if not report["feasible"]:
                 # the kernels' feasibility guards make this unreachable;
                 # a nonzero counter means a guard regressed and an
-                # OOM-class mode is about to dispatch — chaos_soak
-                # --autotune fails the run on it
+                # OOM-class mode is about to dispatch
                 REGISTRY.counter(
                     "tsd.costmodel.infeasible",
                     "Strategy decisions outside the feasible candidate "
@@ -1031,17 +1016,7 @@ class QueryRunner:
         if tr is None or not tr.device_time:
             # wall-only tracing: span.device_ms is 0 by CONFIG, not by
             # measurement — recording predicted>0/actual=0 pairs would
-            # poison the calibration ring
-            return
-        from opentsdb_tpu.ops.downsample import mode_policy_epoch
-        if policy_epoch is not None and policy_epoch \
-                != mode_policy_epoch():
-            # the mode policy flipped while this query executed
-            # (autotune exploration/install): the decisions above
-            # describe the NEW policy, the measured time came from the
-            # OLD kernels — the pair would poison the fit.  The span
-            # keeps its (best-effort) annotation; the ring skips it.
-            obs_trace.annotate(span, costmodel_stale=True)
+            # poison the ring
             return
         jaxprof.record_segment(
             seg.kind, s, n, w, g, sum(breakdown.values()), span.device_ms,
@@ -1102,7 +1077,6 @@ class QueryRunner:
         back (generation-guarded: a dirty mark that landed since
         planning discards the insert)."""
         import jax.numpy as jnp
-        from opentsdb_tpu.ops.downsample import mode_policy_epoch
         from opentsdb_tpu.ops.hostlane import host_lane
         from opentsdb_tpu.ops.pipeline import (
             DownsampleStep, build_batch_direct, run_downsample_grid,
@@ -1110,7 +1084,6 @@ class QueryRunner:
         tsdb = self.tsdb
         fix = tsdb.config.fix_duplicates
         step0 = spec.downsample
-        epoch = mode_policy_epoch()
         interval = windows.interval_ms
         s = len(series_list)
         pieces_v: list = []
@@ -1170,8 +1143,7 @@ class QueryRunner:
                     vn, mn = self._materialize_agg_piece(v, m,
                                                          piece.count)
                     tsdb.agg_cache.store_block(plan, piece,
-                                               series_list, vn, mn,
-                                               epoch)
+                                               series_list, vn, mn)
                 # edge pieces stay padded here; the host assembly
                 # slices to piece.count after materializing (an eager
                 # jnp slice would dispatch — and recompile — per call)

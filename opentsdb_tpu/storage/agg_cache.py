@@ -9,7 +9,7 @@ downsample grid — in alignable, reusable factors, in the Factor Windows
 stance (arXiv:2008.12379): decompose each fixed-interval downsample
 plan into aligned sub-window blocks, reuse every cached block, and
 dispatch only the uncovered delta ranges.  Which factors are worth
-materializing is decided per plan by the fitted costmodel
+materializing is decided per plan by the costmodel
 (`ops/costmodel.py` predict_* via obs.jaxprof.stage_breakdown) plus a
 repeat-count admission rule, the Storyboard placement question
 (arXiv:2002.03063) reduced to: populate once a plan family has proven
@@ -39,9 +39,9 @@ A cache hit must never change an answer: a warm query's result is
 bit-identical to the same query against the same data with the cache
 EMPTY, because a cold run executes the very same per-block compiled
 programs whose outputs a warm run replays — same shapes, same kernels,
-same platform (the execution platform is part of the block key, and the
-mode-policy epoch is too, so an autotune flip can never splice
-kernels).  tests/test_agg_cache.py pins cold == warm == invalidated-
+same platform (the execution platform is part of the block key, and
+which kernel form runs is a pure function of platform and shape).
+tests/test_agg_cache.py pins cold == warm == invalidated-
 and-recomputed bitwise on random float data, and cache-enabled ==
 cache-disabled bitwise on exactly-representable data; against the
 monolithic (cache-disabled) pipeline on arbitrary floats the decomposed
@@ -110,8 +110,8 @@ _MARK_RING = 512
 # rewrite-vs-recompute decision — the monolithic path copies every
 # point, the rewrite only the uncovered delta, and a warm hit none.
 # A rough memcpy+locking figure, deliberately conservative; the device
-# stages use the calibrated costmodel, this host stage has no
-# calibration channel (yet).
+# stages are priced by ops/costmodel.py's table, this host stage by
+# this constant.
 _HOST_BUILD_S_PER_POINT = 5e-9
 
 
@@ -406,8 +406,7 @@ class AggregateCache:
         BEFORE its increment, a dry-run at the same instant computes
         the identical decision (the explain-vs-actual parity pin)."""
         from opentsdb_tpu.obs import jaxprof
-        from opentsdb_tpu.ops.downsample import (mode_policy_epoch,
-                                                 pad_pow2)
+        from opentsdb_tpu.ops.downsample import pad_pow2
         interval = windows.interval_ms
         first = windows.first_window_ms
         w = windows.count
@@ -429,7 +428,6 @@ class AggregateCache:
             decision["reason"] = "no_full_blocks"
             return None, decision
 
-        epoch = mode_policy_epoch()
         sig = hash(tuple(sorted(id(srs) for srs in series_list)))
         family = (id(store), metric, ds_fn, interval, fill_policy,
                   float(fill_value), platform, sig)
@@ -467,7 +465,7 @@ class AggregateCache:
                     first_ms=k * bw * interval, count=bw,
                     fetch_lo=k * bw * interval,
                     fetch_hi=(k + 1) * bw * interval - 1, block=k)
-                key = family + (epoch, k)
+                key = family + (k,)
                 entry = self._blocks.get(key)
                 if entry is not None and self._valid_locked(entry) and \
                         all(srs in entry.rows for srs in series_list):
@@ -548,7 +546,7 @@ class AggregateCache:
                                decision=decision), decision
 
         # costmodel: price the rewrite vs the monolithic recompute.
-        # Both sides carry their device stages (the calibrated
+        # Both sides carry their device stages (ops/costmodel.py's
         # predict_* via stage_breakdown), their host batch-build cost
         # (proportional to the points each side copies), and one
         # dispatch-overhead charge per dispatch they issue.
@@ -636,8 +634,8 @@ class AggregateCache:
     # -- population ------------------------------------------------------
 
     def store_block(self, plan: RewritePlan, piece: PlanPiece,
-                    series_list, val: np.ndarray, mask: np.ndarray,
-                    epoch: int) -> None:
+                    series_list, val: np.ndarray,
+                    mask: np.ndarray) -> None:
         """Insert one computed block, unless a dirty mark younger than
         the plan's generation snapshot overlaps it (the mark could have
         landed after the block's points were read — conservatively
@@ -648,7 +646,7 @@ class AggregateCache:
                        lo_ms=piece.fetch_lo, hi_ms=piece.fetch_hi,
                        nbytes=val.shape[0] * val.shape[1]
                        * _BYTES_PER_CELL)
-        key = plan.family + (epoch, piece.block)
+        key = plan.family + (piece.block,)
         with self._lock:
             if not self._valid_locked(entry):
                 return

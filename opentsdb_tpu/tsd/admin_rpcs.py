@@ -152,8 +152,7 @@ class StatsRpc(TelnetRpc, HttpRpc):
                                       status=404)
             payload = self.stats_registry.snapshot()
             # the costmodel predicted-vs-actual segment ring rides the
-            # query-stats payload: a saved /api/stats/query response is
-            # a fittable calibration corpus (tools/fit_costmodel.py)
+            # query-stats payload
             from opentsdb_tpu.obs import jaxprof
             payload["costmodelSegments"] = jaxprof.segments()
             query.send_reply(query.serializer.format_query_stats_v1(

@@ -199,7 +199,7 @@ CONFIG_SCHEMA: dict[str, ConfigEntry] = {
     "tsd.diag.enable": _e(
         "bool", True, "Arm the always-on flight recorder: a bounded "
         "ring of structured diagnostic events (admission verdicts, "
-        "cache/rollup consults, spills, autotune flips, breaker "
+        "cache/rollup consults, spills, breaker "
         "transitions, deadline expiries, recompiles) served at "
         "/api/diag and dumped at shutdown.  Also gates /api/diag/slow."),
     "tsd.diag.ring_size": _e(
@@ -266,7 +266,7 @@ CONFIG_SCHEMA: dict[str, ConfigEntry] = {
     "tsd.health.enable": _e(
         "bool", True, "Evaluate the declared health invariants "
         "(shed burn, steady-state recompiles, cache hit collapse, "
-        "costmodel drift, spill saturation, breaker flap) into "
+        "spill saturation, breaker flap) into "
         "per-subsystem ok/degraded/failing verdicts at "
         "/api/diag/health and tsd.health.* gauges."),
     "tsd.health.interval": _e(
@@ -289,10 +289,6 @@ CONFIG_SCHEMA: dict[str, ConfigEntry] = {
         "float", "0.05", "Aggregate-cache hit fraction under which a "
         "busy window (>= 16 consults) reads degraded — the hit-rate-"
         "collapse invariant."),
-    "tsd.health.costmodel_drift": _e(
-        "float", "40", "Predicted-vs-actual device-ms ratio (either "
-        "direction) above which the costmodel subsystem reads "
-        "degraded (failing at 4x); volume-gated."),
     "tsd.health.spill_saturation": _e(
         "float", "0.9", "Spill-pool resident fraction of the combined "
         "host+disk budget above which the spill subsystem reads "
@@ -326,43 +322,6 @@ CONFIG_SCHEMA: dict[str, ConfigEntry] = {
         "diag subsystem reads degraded (failing at 4x) — a steadily "
         "overflowing ring means the next incident's history is "
         "already gone."),
-    # -- costmodel autotune (ops/calibrate.py, docs/costmodel.md) ------ #
-    "tsd.costmodel.autotune.enable": _e(
-        "bool", False, "Online costmodel calibration: fit the kernel-"
-        "strategy per-unit constants from the live predicted-vs-actual "
-        "segment ring (obs/jaxprof.py) on the maintenance cadence and "
-        "install them as a live override layer, so choose_* converges "
-        "to what this hardware measures.  Requires traced serving with "
-        "device timing (tsd.trace.enable + tsd.trace.device_time)."),
-    "tsd.costmodel.autotune.interval": _e(
-        "int", "30", "Seconds between calibration fits (and the length "
-        "of an epsilon-exploration interval)."),
-    "tsd.costmodel.autotune.min_samples": _e(
-        "int", "64", "Fittable ring entries required before a fit runs "
-        "— below this the window is too noisy to trust."),
-    "tsd.costmodel.autotune.hysteresis": _e(
-        "float", "0.15", "Sticky-argmin band: a challenger mode must "
-        "predict this fraction cheaper than a shape bucket's incumbent "
-        "before the strategy choice (and its jit caches) flips.  0 "
-        "restores the pure argmin."),
-    "tsd.costmodel.autotune.epsilon": _e(
-        "float", "0", "Probability per calibration pass of forcing one "
-        "losing-but-feasible mode for one interval so the fitter "
-        "observes actuals for strategies the argmin never picks.  Off "
-        "by default: exploration dispatches deliberately-slower "
-        "kernels."),
-    "tsd.costmodel.autotune.max_step": _e(
-        "float", "4", "Bound on how far one fit may move a per-unit "
-        "constant (multiplier clipped into [1/max_step, max_step]); "
-        "convergence stays geometric and one wild batch is bounded."),
-    "tsd.costmodel.autotune.persist": _e(
-        "bool", True, "Merge the live-fitted constants into the "
-        "calibration file at shutdown so calibration survives "
-        "restarts."),
-    "tsd.costmodel.autotune.calibration_file": _e(
-        "str", "", "Calibration file path for both the file override "
-        "layer and shutdown persistence; empty = BENCH_CALIBRATION."
-        "json at the repo root."),
     # -- core ---------------------------------------------------------- #
     "tsd.core.authentication.enable": _e(
         "bool", False, "Require telnet/HTTP authentication."),
@@ -545,23 +504,6 @@ CONFIG_SCHEMA: dict[str, ConfigEntry] = {
         "int", "150", "Per-dispatch overhead (microseconds) the "
         "rewrite-vs-recompute costmodel decision charges each "
         "dispatch either side issues."),
-    "tsd.query.kernel.scan_mode": _e(
-        "str", "", "Prefix-scan strategy: auto|flat|blocked|subblock|"
-        "subblock2 (empty keeps the module default / TSDB_SCAN_MODE "
-        "env)."),
-    "tsd.query.kernel.search_mode": _e(
-        "str", "", "Edge-search strategy: auto|scan|compare_all|hier."),
-    "tsd.query.kernel.extreme_mode": _e(
-        "str", "", "min/max downsample strategy: "
-        "auto|scan|segment|subblock."),
-    "tsd.query.kernel.group_reduce_mode": _e(
-        "str", "", "Group-reduce strategy: auto|segment|matmul|sorted."),
-    "tsd.query.kernel.platform_guard": _e(
-        "bool", "", "Demote dense search forms to the binary scan on "
-        "CPU execution (empty keeps the module default: on)."),
-    "tsd.query.kernel.stream_segment_ratio": _e(
-        "float", "", "Streamed chunks take the segment form when "
-        "W > ratio * N (empty keeps the module default)."),
     "tsd.query.multi_get.enable": _e(
         "bool", False, "Reference compat multigets toggle.", compat=True),
     "tsd.query.multi_get.limit": _e(

@@ -47,16 +47,6 @@ jax.config.update("jax_platforms", "cpu")
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
-# The platform guard demotes the dense (accelerator-winner) search forms
-# to the binary search whenever execution lands on CPU — which is every
-# test in this suite.  Disable it suite-wide so CPU CI keeps exercising
-# the dense kernels' correctness; tests of the guard itself re-enable it
-# locally (tests/test_prefix_downsample.py::TestPlatformModeGuard).
-from opentsdb_tpu.ops import downsample as _ds  # noqa: E402
-
-_ds.set_platform_mode_guard(False)
-
-
 def pytest_configure(config):
     config.addinivalue_line(
         "markers",
@@ -67,3 +57,52 @@ def pytest_configure(config):
 @pytest.fixture
 def rng():
     return np.random.default_rng(42)
+
+
+@pytest.fixture
+def kernel_forms(monkeypatch):
+    """Pin a kernel axis to one form for a test: `kernel_forms(search=
+    "hier", scan="subblock2", extreme="subblock", group="sorted")`.
+
+    Which form runs is a pure function of platform and shape
+    (ops/downsample.py, ops/group_agg.py), and on the CPU backend that
+    function never picks hier, compare_all, sorted, ... — yet every kept
+    form needs its numeric tests here.  So the pin is made from the test
+    side only, by patching the axis's chooser to a constant; a form the
+    shape cannot take falls back as the chooser's own candidates say
+    (scan / flat / scan / segment).  Compiled programs bake the form in
+    at trace time: they are dropped when a pin is made and again when
+    the test ends, so no program traced under one pin serves another
+    test."""
+    from opentsdb_tpu.ops import downsample as ds
+    from opentsdb_tpu.ops import group_agg as ga
+
+    def pin(search=None, scan=None, extreme=None, group=None):
+        if search is not None:
+            monkeypatch.setattr(
+                ds, "_effective_search_mode",
+                lambda s, n, w_edges, platform=None: search
+                if search in ds._search_candidates(n, w_edges) else "scan")
+        if scan is not None:
+            monkeypatch.setattr(
+                ds, "_effective_scan_mode",
+                lambda s, n, w_edges, platform=None: scan
+                if scan in ds._scan_candidates(n, w_edges) else "flat")
+        if extreme is not None:
+            monkeypatch.setattr(
+                ds, "_effective_extreme_mode",
+                lambda n, w_padded, platform=None: extreme
+                if extreme in ds._extreme_candidates(n, w_padded)
+                else "scan")
+        if group is not None:
+            monkeypatch.setattr(
+                ga, "_effective_group_reduce_mode",
+                lambda s, w, g, extremes=False, platform=None,
+                row_groups=False: group
+                if group in ga._group_candidates(s, g, extremes)
+                else "segment")
+        jax.clear_caches()
+
+    yield pin
+    monkeypatch.undo()
+    jax.clear_caches()

@@ -323,32 +323,6 @@ class TestPolicy:
         assert "aggCacheHitWindows" not in s   # plans recomputed
         assert tsdb.agg_cache.promote_pending(max_uploads=64) == 0
 
-    def test_mode_policy_epoch_keys_blocks(self):
-        """An autotune/kernel-mode flip bumps the mode-policy epoch;
-        cached blocks from the old epoch must never splice into
-        new-epoch answers (the block key carries the epoch)."""
-        from opentsdb_tpu.ops import downsample as ds
-        tsdb = make_tsdb()
-        feed_int(tsdb)
-        m = "sum:60s-sum:sys.i{host=*}"
-        run_q(tsdb, m)
-        _, s_warm = run_q(tsdb, m)
-        assert s_warm.get("aggCacheHitWindows", 0) > 0
-        prev = ds._SCAN_MODE
-        try:
-            ds.set_scan_mode("subblock" if prev != "subblock"
-                             else "flat")
-            _, s_flip = run_q(tsdb, m)
-            assert "aggCacheHitWindows" not in s_flip  # old epoch dead
-            got, s_warm2 = run_q(tsdb, m)
-            assert s_warm2.get("aggCacheHitWindows", 0) > 0
-            off = make_tsdb(**{"tsd.query.cache.enable": False})
-            feed_int(off)
-            want, _ = run_q(off, m)
-            assert got == want
-        finally:
-            ds.set_scan_mode(prev)
-
     def test_admission_estimate_prices_the_rewritten_plan(self):
         """ISSUE 9: estimate_plan_cost_ms must price the rewritten
         plan — a warm cache shrinks the predicted cost."""

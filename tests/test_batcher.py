@@ -5,9 +5,7 @@ Pins the batching contract end to end:
   * batched == solo BITWISE per member on integer data, across the
     kernel families the stacked program serves (downsample fns, rate,
     grouped), at Q > 1 through the real rendezvous;
-  * bucket keying: a mode-policy epoch flip mid-coalesce must not
-    splice kernel generations into one launch — members on either
-    side land in different buckets; shape/dtype mismatches likewise;
+  * bucket keying: shape/dtype mismatches land in different buckets;
   * one member's deadline expiry leaves the batch without poisoning
     its siblings;
   * weighted deficit-round-robin fairness in the admission gate
@@ -15,9 +13,7 @@ Pins the batching contract end to end:
     bounds, single-tenant FIFO preserved, audit snapshot);
   * explain parity + fingerprint for the `batched` routing arm (the
     corpus pin rides tests/test_explain.py over PLAN_CORPUS.json);
-  * batched executions stay OUT of the calibration ring;
-  * the stacked jit binding is under the cache-coherence contract
-    (gutting its entry in _clear_dependent_caches fails the tree);
+  * batched executions stay OUT of the predicted-vs-actual ring;
   * the health engine's cross-tenant starvation invariant;
   * tools/bench_qps.py: >= 2x dispatch-layer uplift (slow re-measure).
 
@@ -27,7 +23,6 @@ Mesh stays off throughout: the batcher serves the single-device route
 
 import json
 import os
-import shutil
 import subprocess
 import sys
 import threading
@@ -43,8 +38,7 @@ if REPO not in sys.path:
 from opentsdb_tpu.core import TSDB                       # noqa: E402
 from opentsdb_tpu.models.tsquery import (                # noqa: E402
     TSQuery, parse_m_subquery)
-from opentsdb_tpu.ops.downsample import (                # noqa: E402
-    FixedWindows, mode_policy_epoch)
+from opentsdb_tpu.ops.downsample import FixedWindows    # noqa: E402
 from opentsdb_tpu.ops.pipeline import (                  # noqa: E402
     DownsampleStep, PipelineSpec, run_group_pipeline)
 from opentsdb_tpu.query.batcher import (                 # noqa: E402
@@ -111,11 +105,9 @@ def spec_for(ds_fn, rate, w):
 
 
 def submit_concurrently(batcher, spec, members, g_pad, wargs,
-                        epoch=None, deadlines=None):
+                        deadlines=None):
     """Drive Q members through the rendezvous from Q threads; returns
     ([result | exception per member], infos)."""
-    if epoch is None:
-        epoch = mode_policy_epoch()
     results = [None] * len(members)
     infos = [None] * len(members)
 
@@ -124,7 +116,7 @@ def submit_concurrently(batcher, spec, members, g_pad, wargs,
         dl = deadlines[i] if deadlines else None
         try:
             out, info = batcher.submit(spec, ts, val, mask, gid,
-                                       g_pad, wargs, False, epoch, dl)
+                                       g_pad, wargs, False, dl)
             results[i] = tuple(np.asarray(x) for x in out)
             infos[i] = info
         except Exception as e:              # noqa: BLE001 — test capture
@@ -178,8 +170,7 @@ class TestStackedBitwise:
         batcher = make_batcher(demand=1)     # uncontended: no hold
         t0 = time.monotonic()
         out, info = batcher.submit(spec, m[0], m[1], m[2], m[3], 1,
-                                   wargs, False, mode_policy_epoch(),
-                                   None)
+                                   wargs, False, None)
         assert info == {"q": 1, "stacked": False,
                         "waitMs": info["waitMs"]}
         # zero hold for an uncontended query (well under the 100 ms
@@ -193,33 +184,6 @@ class TestStackedBitwise:
 
 
 class TestBucketKeying:
-    def test_mode_policy_epoch_splits_buckets(self):
-        """An autotune flip mid-coalesce must not splice kernel
-        generations: members carrying different epochs never share a
-        stacked launch."""
-        rng = np.random.default_rng(2)
-        spec, wargs = spec_for("avg", False, 16)
-        members = [member_operands(rng, 2, 128, 16) for _ in range(2)]
-        batcher = make_batcher(hold_ms=150)
-        epoch = mode_policy_epoch()
-        results = [None, None]
-        infos = [None, None]
-
-        def worker(i, ep):
-            m = members[i]
-            out, info = batcher.submit(spec, m[0], m[1], m[2], m[3],
-                                       1, wargs, False, ep, None)
-            results[i] = out
-            infos[i] = info
-
-        t1 = threading.Thread(target=worker, args=(0, epoch))
-        t2 = threading.Thread(target=worker, args=(1, epoch + 1))
-        t1.start()
-        t2.start()
-        t1.join(60)
-        t2.join(60)
-        assert infos[0]["q"] == 1 and infos[1]["q"] == 1, infos
-
     def test_shape_and_dtype_split_buckets(self):
         spec, wargs = spec_for("avg", False, 16)
         rng = np.random.default_rng(3)
@@ -227,9 +191,8 @@ class TestBucketKeying:
         b = member_operands(rng, 4, 128, 16)          # different S
         c = member_operands(rng, 2, 128, 16, int_vals=False)
         c = (a[0], a[1].astype(np.int64), a[2], a[3])  # different dtype
-        epoch = mode_policy_epoch()
         keys = {bucket_key(spec, 1, m[0], m[1], np.asarray(m[3]),
-                           wargs, False, epoch)
+                           wargs, False)
                 for m in (a, b, c)}
         assert len(keys) == 3
 
@@ -285,7 +248,6 @@ class TestDeadlines:
             spec, m[0], m[1], m[2], m[3], 1, wargs))
             for m in members]
         batcher = make_batcher(hold_ms=500)
-        epoch = mode_policy_epoch()
         results = [None] * 3
         infos = [None] * 3
 
@@ -293,7 +255,7 @@ class TestDeadlines:
             ts, val, mask, gid = members[i]
             try:
                 out, info = batcher.submit(spec, ts, val, mask, gid,
-                                           1, wargs, False, epoch, dl)
+                                           1, wargs, False, dl)
                 results[i] = tuple(np.asarray(x) for x in out)
                 infos[i] = info
             except Exception as e:          # noqa: BLE001 — test capture
@@ -557,7 +519,8 @@ class TestBatchedRouting:
     def test_batched_runs_skip_the_calibration_ring(self):
         """Like rewrites/tiled/lane serves: a stacked launch's
         measured time describes no single member's feature vector, so
-        batched executions never land in the fitter's corpus."""
+        batched executions never land in the predicted-vs-actual
+        ring."""
         from opentsdb_tpu.obs import jaxprof
         tsdb, mgr = _manager(**{"tsd.trace.enable": "true",
                                 "tsd.trace.device_time": "true"})
@@ -571,34 +534,6 @@ class TestBatchedRouting:
             assert len(jaxprof.segments()) == before
         finally:
             tsdb.shutdown()
-
-
-class TestCoherenceGutPin:
-    def test_removing_the_stacked_clear_fails_the_tree(self, tmp_path):
-        """ISSUE 14 hygiene: the stacked jit binding joins
-        _clear_dependent_caches under the `# cache:` coherence
-        contract — deleting its entry re-fires the cache-coherence
-        analyzer at every mode-policy mutation site."""
-        from tools.lint import cache_coherence
-        from tools.lint.core import LintContext
-        from tools.lint.run import run_lint
-        dst = tmp_path / "opentsdb_tpu"
-        shutil.copytree(os.path.join(REPO, "opentsdb_tpu"), dst)
-        mod = dst / "ops" / "downsample.py"
-        src = mod.read_text()
-        needle = "               pipeline._jitted_stacked_group,\n"
-        assert needle in src, "expected the stacked binding in the " \
-            "clear list"
-        mod.write_text(src.replace(needle, ""))
-        ctx = LintContext(str(tmp_path))
-        findings = run_lint(["opentsdb_tpu"], root=str(tmp_path),
-                            analyzers=[cache_coherence.ANALYZER],
-                            ctx=ctx)
-        assert any(f.rule == "cache-stale-mutation"
-                   and "_jitted_stacked_group" in f.message
-                   for f in findings), (
-            "gutting the stacked-kernel cache clear went undetected:\n"
-            + "\n".join(f.render() for f in findings))
 
 
 # --------------------------------------------------------------------- #

@@ -3,8 +3,6 @@ must reproduce the r4 chip-race winners at the headline shape, pick the
 safe host modes on CPU, and never hand an infeasible mode to a kernel —
 for ANY of the 7 BASELINE config shapes."""
 
-import json
-
 import numpy as np
 import pytest
 
@@ -27,9 +25,9 @@ CONFIG_SHAPES = {
 
 
 class TestChipAnchors:
-    """Auto must reproduce the winners at the headline shape that the
-    TPU cost constants were anchored to (from an earlier chip session,
-    not re-measured on this installation — ROADMAP A6)."""
+    """The chooser must reproduce the winners at the headline shape that
+    the TPU cost constants were anchored to (from an earlier chip
+    session, not re-measured on this installation — ROADMAP C1)."""
 
     def test_search_headline_tpu(self):
         s, n, e, _ = CONFIG_SHAPES["headline"]
@@ -39,8 +37,8 @@ class TestChipAnchors:
 
     def test_scan_headline_tpu(self):
         # subblock is the CHIP-MEASURED winner (r4 race, 88ms); the
-        # default constants must not flip auto to the unmeasured
-        # subblock2 — only a real calibration may do that
+        # constants must not flip the pick to the unmeasured
+        # subblock2 — only a chip race may do that
         s, n, e, _ = CONFIG_SHAPES["headline"]
         assert costmodel.choose_scan(
             s, n, e, "tpu", ["flat", "subblock", "subblock2"]) \
@@ -92,21 +90,15 @@ class TestChipAnchors:
 
 class TestFeasibilityComposition:
     """_effective_* must return a feasible mode for every BASELINE config
-    shape under auto AND under every globally-forced mode — the r4
-    failure (config 1 rc=1: hier forced onto a [1, 1M] x 3502 shape)
-    must be structurally impossible."""
+    shape on either platform — the r4 failure (config 1 rc=1: hier
+    forced onto a [1, 1M] x 3502 shape) must be structurally
+    impossible."""
 
     @pytest.mark.parametrize("shape", sorted(CONFIG_SHAPES))
-    @pytest.mark.parametrize("forced", ["auto", "scan", "compare_all",
-                                        "hier"])
-    def test_search_always_feasible(self, shape, forced):
+    @pytest.mark.parametrize("platform", ["tpu", "cpu"])
+    def test_search_always_feasible(self, shape, platform):
         s, n, e, _ = CONFIG_SHAPES[shape]
-        prior = ds._SEARCH_MODE
-        ds._SEARCH_MODE = forced    # direct: avoid cache-clear churn
-        try:
-            got = ds._effective_search_mode(s, n, e)
-        finally:
-            ds._SEARCH_MODE = prior
+        got = ds._effective_search_mode(s, n, e, platform)
         assert got in ("scan", "compare_all", "hier")
         assert ds._search_feasible(got, n, e)
 
@@ -123,7 +115,7 @@ class TestFeasibilityComposition:
     def test_scan_choice_valid(self, shape):
         s, n, e, _ = CONFIG_SHAPES[shape]
         got = ds._effective_scan_mode(s, n, e)
-        assert got in ("flat", "blocked", "subblock", "subblock2")
+        assert got in ("flat", "subblock", "subblock2")
         if got == "subblock":
             assert n % ds._SUB_K == 0 and ds._subblock_edges_fit(n, e)
 
@@ -145,41 +137,12 @@ class TestFeasibilityComposition:
         assert not ga._matmul_feasible(10_240, 10_240)
 
 
-class TestCalibrationOverride:
-    def test_calibration_file_overrides(self, tmp_path, monkeypatch):
-        cal = tmp_path / "BENCH_CALIBRATION.json"
-        # make the segment scatter free on TPU: chooser must flip to it
-        cal.write_text(json.dumps({"tpu": {"seg_scatter": 1e-15}}))
-        monkeypatch.setattr(costmodel, "_CALIBRATION_FILE", str(cal))
-        costmodel.reload_calibration()
-        try:
-            assert costmodel.choose_group(
-                1024, 512, 100, "tpu",
-                ["segment", "sorted", "matmul"]) == "segment"
-        finally:
-            monkeypatch.undo()
-            costmodel.reload_calibration()
-
-    def test_malformed_calibration_ignored(self, tmp_path, monkeypatch):
-        cal = tmp_path / "BENCH_CALIBRATION.json"
-        cal.write_text("{not json")
-        monkeypatch.setattr(costmodel, "_CALIBRATION_FILE", str(cal))
-        costmodel.reload_calibration()
-        try:
-            assert costmodel.choose_group(
-                1024, 512, 100, "tpu",
-                ["segment", "sorted", "matmul"]) == "sorted"
-        finally:
-            monkeypatch.undo()
-            costmodel.reload_calibration()
-
+class TestCostTable:
     def test_unknown_platform_is_an_error(self):
         # a device the table does not know is never priced as a TPU
         for platform in ("gpu", "rocm", "", "TPU"):
             with pytest.raises(ValueError, match="no cost table"):
                 costmodel.costs(platform)
-            with pytest.raises(ValueError, match="no cost table"):
-                costmodel.calibration_source(platform)
         assert costmodel.costs("tpu") is not costmodel.costs("cpu")
 
 
@@ -190,7 +153,7 @@ class TestPredictionSanity:
                 for m in ("scan", "compare_all", "hier"):
                     assert 0 < costmodel.predict_search(m, s, n, e, plat) \
                         < 1e6
-                for m in ("flat", "blocked", "subblock", "subblock2"):
+                for m in ("flat", "subblock", "subblock2"):
                     assert 0 < costmodel.predict_scan(m, s, n, e, plat) \
                         < 1e6
                 for m in ("segment", "matmul", "sorted"):
@@ -201,7 +164,7 @@ class TestPredictionSanity:
                                                          plat) < 1e6
 
     def test_headline_predictions_near_measurements(self):
-        """The calibrated model must land within 3x of the chip anchors
+        """The table must land within 3x of the chip anchors
         it was fitted to (a grossly wrong formula would still 'choose'
         something — this pins the magnitudes).  The group anchors are
         PR 27's race on a v5e at fleet-replay-100k's and heavy-replay's
@@ -233,11 +196,11 @@ class TestPredictionSanity:
 
 
 class TestAutoMatchesForcedResults:
-    """End-to-end: a grouped downsample under mode 'auto' answers
-    bit-identically to every forced mode (the chooser only changes WHICH
-    equivalence-tested kernel runs)."""
+    """End-to-end: a grouped downsample under the chooser's own picks
+    answers bit-identically to every pinned form (the chooser only
+    changes WHICH equivalence-tested kernel runs)."""
 
-    def test_auto_equals_forced(self):
+    def test_auto_equals_forced(self, kernel_forms):
         import jax.numpy as jnp
         from opentsdb_tpu.ops.downsample import FixedWindows, pad_pow2
         from opentsdb_tpu.ops.pipeline import (PipelineSpec,
@@ -260,35 +223,22 @@ class TestAutoMatchesForcedResults:
                 spec, jnp.asarray(ts), jnp.asarray(val),
                 jnp.asarray(mask), jnp.asarray(gid), pad_pow2(3), wargs)]
 
-        prior = (ds._SCAN_MODE, ds._SEARCH_MODE, ga._GROUP_REDUCE_MODE)
-        try:
-            ds.set_scan_mode("auto")
-            ds.set_search_mode("auto")
-            ga.set_group_reduce_mode("auto")
-            want = run()
-            for scan in ("flat", "subblock", "subblock2"):
-                for search in ("scan", "compare_all", "hier"):
-                    for group in ("segment", "matmul", "sorted"):
-                        ds.set_scan_mode(scan)
-                        ds.set_search_mode(search)
-                        ga.set_group_reduce_mode(group)
-                        got = run()
-                        for a, b in zip(want, got):
-                            np.testing.assert_allclose(
-                                a, b, rtol=1e-9, atol=1e-9,
-                                err_msg="%s/%s/%s" % (scan, search,
-                                                      group))
-        finally:
-            ds.set_scan_mode(prior[0])
-            ds.set_search_mode(prior[1])
-            ga.set_group_reduce_mode(prior[2])
+        want = run()
+        for scan in ("flat", "subblock", "subblock2"):
+            for search in ("scan", "compare_all", "hier"):
+                for group in ("segment", "matmul", "sorted"):
+                    kernel_forms(scan=scan, search=search, group=group)
+                    got = run()
+                    for a, b in zip(want, got):
+                        np.testing.assert_allclose(
+                            a, b, rtol=1e-9, atol=1e-9,
+                            err_msg="%s/%s/%s" % (scan, search, group))
 
 
 class TestFeatureDecomposition:
-    """predict_* must equal dot(features_*, costs) BY CONSTRUCTION —
-    the online fitter (ops/calibrate.py) regresses measured time onto
-    the feature vectors, so a predictor term the features don't carry
-    would be unfittable (and vice versa)."""
+    """predict_* must equal dot(features_*, costs) BY CONSTRUCTION — a
+    constant re-anchored from a chip race then means exactly what the
+    predictor consumes."""
 
     @pytest.mark.parametrize("plat", ["tpu", "cpu"])
     @pytest.mark.parametrize("shape", sorted(CONFIG_SHAPES))
@@ -302,14 +252,14 @@ class TestFeatureDecomposition:
         for m in ("scan", "compare_all", "hier"):
             assert costmodel.predict_search(m, s, n, e, plat) == \
                 pytest.approx(dot(costmodel.features_search(m, s, n, e)))
-        for m in ("flat", "blocked", "subblock", "subblock2"):
+        for m in ("flat", "subblock", "subblock2"):
             assert costmodel.predict_scan(m, s, n, e, plat) == \
                 pytest.approx(dot(costmodel.features_scan(m, s, n, e)))
         for m in ("scan", "segment", "subblock"):
             assert costmodel.predict_extreme(m, s, n, e, plat) == \
                 pytest.approx(dot(costmodel.features_extreme(m, s, n,
                                                              e)))
-        for m in ("segment", "matmul", "sorted", "sorted2"):
+        for m in ("segment", "matmul", "sorted", "rows"):
             assert costmodel.predict_group(m, s, e - 1, g, plat) == \
                 pytest.approx(dot(costmodel.features_group(m, s, e - 1,
                                                            g)))
@@ -320,25 +270,14 @@ class TestFeatureDecomposition:
             [costmodel.features_search(m, s, n, e)
              for m in ("scan", "compare_all", "hier")]
             + [costmodel.features_scan(m, s, n, e)
-               for m in ("flat", "blocked", "subblock", "subblock2")]
+               for m in ("flat", "subblock", "subblock2")]
             + [costmodel.features_extreme(m, s, n, e)
                for m in ("scan", "segment", "subblock")]
             + [costmodel.features_group(m, s, e - 1, g)
-               for m in ("segment", "matmul", "sorted", "sorted2")])
+               for m in ("segment", "matmul", "sorted", "rows")])
         for fv in vectors:
             for term in fv:
                 assert term in costmodel.COST_TERMS
-
-    def test_cost_features_entry_point(self):
-        s, n, e, g = CONFIG_SHAPES["headline"]
-        assert costmodel.cost_features("search", "hier", s, n, e) == \
-            costmodel.features_search("hier", s, n, e)
-        assert costmodel.cost_features("group", "sorted", s, 512,
-                                       e, g) == \
-            costmodel.features_group("sorted", s, 512, g)
-        with pytest.raises(ValueError):
-            costmodel.cost_features("nope", "x", s, n, e)
-
 
 class TestArgminFlips:
     """choose_* must flip where the model says the crossover is."""
@@ -368,125 +307,6 @@ class TestArgminFlips:
                                        cands) == "scan"
 
 
-class TestLiveCalibrationLayer:
-    """The online fitter's override layer: install -> argmin moves,
-    source tracks the winning layer, clear -> defaults return."""
-
-    def teardown_method(self):
-        costmodel.clear_live_calibration()
-
-    def test_install_flips_argmin_and_source(self):
-        assert costmodel.calibration_source("tpu") == "default"
-        assert costmodel.choose_group(
-            1024, 512, 100, "tpu",
-            ["segment", "sorted", "matmul"]) == "sorted"
-        costmodel.install_live_calibration("tpu", {"seg_scatter": 1e-15})
-        assert costmodel.calibration_source("tpu") == "live"
-        assert costmodel.choose_group(
-            1024, 512, 100, "tpu",
-            ["segment", "sorted", "matmul"]) == "segment"
-        costmodel.clear_live_calibration()
-        assert costmodel.calibration_source("tpu") == "default"
-        assert costmodel.choose_group(
-            1024, 512, 100, "tpu",
-            ["segment", "sorted", "matmul"]) == "sorted"
-
-    def test_live_layer_wins_over_file_layer(self, tmp_path,
-                                             monkeypatch):
-        cal = tmp_path / "BENCH_CALIBRATION.json"
-        cal.write_text(json.dumps({"tpu": {"seg_scatter": 1e-15}}))
-        monkeypatch.setattr(costmodel, "_CALIBRATION_FILE", str(cal))
-        costmodel.reload_calibration()
-        try:
-            assert costmodel.calibration_source("tpu") == "file"
-            assert costmodel.costs("tpu")["seg_scatter"] == 1e-15
-            costmodel.install_live_calibration("tpu",
-                                               {"seg_scatter": 1e-3})
-            assert costmodel.calibration_source("tpu") == "live"
-            assert costmodel.costs("tpu")["seg_scatter"] == 1e-3
-        finally:
-            costmodel.clear_live_calibration()
-            monkeypatch.undo()
-            costmodel.reload_calibration()
-
-    def test_install_rejects_poison(self):
-        with pytest.raises(ValueError):
-            costmodel.install_live_calibration("tpu",
-                                               {"seg_scatter": 0.0})
-        with pytest.raises(ValueError):
-            costmodel.install_live_calibration("tpu",
-                                               {"seg_scatter":
-                                                float("nan")})
-        with pytest.raises(ValueError):
-            costmodel.install_live_calibration("tpu", {"no_term": 1e-9})
-        assert costmodel.calibration_source("tpu") == "default"
-
-
-class TestReloadClearsDependentCaches:
-    """The reload_calibration footgun fix: ONE entry point drops the
-    cost table AND the compiled programs that baked the old modes in
-    (its old docstring admitted callers had to remember the second
-    half themselves)."""
-
-    def test_reload_clears_jit_caches(self, monkeypatch):
-        calls = []
-        monkeypatch.setattr(ds, "_clear_dependent_caches",
-                            lambda: calls.append(1))
-        costmodel.reload_calibration()
-        assert calls, "reload_calibration must clear dependent caches"
-
-    def test_install_live_clears_jit_caches(self, monkeypatch):
-        calls = []
-        monkeypatch.setattr(ds, "_clear_dependent_caches",
-                            lambda: calls.append(1))
-        costmodel.install_live_calibration("cpu", {"elem_f64": 2e-9})
-        try:
-            assert calls
-        finally:
-            monkeypatch.undo()
-            costmodel.clear_live_calibration()
-
-
-class TestHysteresis:
-    """The sticky argmin: one noisy batch must not flip modes."""
-
-    def teardown_method(self):
-        costmodel.set_hysteresis(0.0)
-        costmodel.clear_live_calibration()
-
-    def test_small_margin_keeps_incumbent(self):
-        costmodel.set_hysteresis(0.25)
-        bucket = costmodel._bucket(1024, 512, 100)
-        first = costmodel._choose("t", {"a": 1.0, "b": 1.2}, "tpu",
-                                  bucket)
-        assert first == "a"
-        # b now nominally cheaper, but within the band: sticks with a
-        assert costmodel._choose("t", {"a": 1.0, "b": 0.9}, "tpu",
-                                 bucket) == "a"
-        # decisively cheaper: flips
-        assert costmodel._choose("t", {"a": 1.0, "b": 0.5}, "tpu",
-                                 bucket) == "b"
-
-    def test_zero_band_is_pure_argmin(self):
-        bucket = costmodel._bucket(1024, 512, 100)
-        assert costmodel._choose("t", {"a": 1.0, "b": 0.99}, "tpu",
-                                 bucket) == "b"
-
-    def test_end_to_end_choice_sticks_through_noise(self):
-        costmodel.set_hysteresis(0.25)
-        cands = ["segment", "sorted", "matmul"]
-        assert costmodel.choose_group(1024, 512, 100, "tpu",
-                                      cands) == "sorted"
-        # a noisy fit nudges matmul 8% under sorted — inside the band,
-        # the incumbent survives
-        c = costmodel.costs("tpu")
-        nudged = c["sorted_grid"] * 512 * 1024 * 0.92 / (100 * 1024
-                                                         * 512)
-        costmodel.install_live_calibration("tpu", {"mxu_cell": nudged})
-        assert costmodel.choose_group(1024, 512, 100, "tpu",
-                                      cands) == "sorted"
-
-
 class TestMixedAggregatorDecisions:
     """The group axis keys its extremes flag on the CROSS-SERIES
     aggregator (what moment_group_reduce dispatches on), not the
@@ -513,32 +333,3 @@ class TestMixedAggregatorDecisions:
         from opentsdb_tpu.obs import jaxprof
         dec = jaxprof.segment_decisions("tpu", 64, 1024, 32, 8, "max")
         assert "matmul" not in dec["group"]["candidates"]
-
-
-class TestModePolicyEpoch:
-    """Every mode-policy change bumps the epoch (the planner snapshots
-    it around a dispatch and drops calibration-ring entries that span a
-    flip — decisions recomputed under the new policy must never pair
-    with device time measured under the old one)."""
-
-    def test_setters_and_reload_bump(self):
-        e0 = ds.mode_policy_epoch()
-        ds.set_scan_mode("flat")
-        try:
-            assert ds.mode_policy_epoch() > e0
-        finally:
-            ds.set_scan_mode("auto")
-        e1 = ds.mode_policy_epoch()
-        costmodel.reload_calibration()
-        assert ds.mode_policy_epoch() > e1
-
-    def test_set_hysteresis_is_idempotent(self):
-        costmodel.set_hysteresis(0.0)
-        e0 = ds.mode_policy_epoch()
-        costmodel.set_hysteresis(0.0)      # unchanged: no policy event
-        assert ds.mode_policy_epoch() == e0
-        costmodel.set_hysteresis(0.2)
-        try:
-            assert ds.mode_policy_epoch() > e0
-        finally:
-            costmodel.set_hysteresis(0.0)

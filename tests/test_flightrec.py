@@ -218,7 +218,7 @@ class TestEndpoints:
                                 "evaluatedMs"}
         assert payload["overall"] == "ok"
         assert set(payload["subsystems"]) == {
-            "admission", "compile", "agg_cache", "costmodel", "spill",
+            "admission", "compile", "agg_cache", "spill",
             "cluster", "tenant", "replication", "latency", "diag"}
         for verdict in payload["subsystems"].values():
             assert verdict["level"] in ("ok", "degraded", "failing")

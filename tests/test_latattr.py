@@ -256,11 +256,11 @@ class TestRingDropAccounting:
         tsdb.flightrec._events = __import__("collections").deque(
             tsdb.flightrec._events, maxlen=2)
         for _ in range(5):
-            tsdb.flightrec.record("autotune", flip="x")
+            tsdb.flightrec.record("tiling", flip="x")
         response = ask(manager, "/api/diag")
         payload = json.loads(response.body)
         assert payload["droppedTotal"] >= 3
-        assert payload["dropped"].get("autotune", 0) >= 3
+        assert payload["dropped"].get("tiling", 0) >= 3
 
     def test_events_carry_the_phase_in_flight(self, served):
         tsdb, manager = served

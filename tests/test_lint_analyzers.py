@@ -380,84 +380,86 @@ def test_shape_contracts_catch_reintroduced_narrowing(tmp_path):
 
 
 def test_removing_the_cache_drop_fails_the_tree(tmp_path):
-    """The cache_coherence analyzer's load-bearing checks, pinned on the
-    exact PR 6 bug class:
+    """The cache_coherence analyzer's load-bearing checks, pinned on two
+    contracts the served path lives by:
 
-    (a) deleting the jit-cache clear inside `reload_calibration` — THE
-        single-entry-point invalidator every calibration mutation routes
-        through — must turn those mutation sites
-        (install_live_calibration, set_calibration_file, ...) into
-        findings;
-    (b) deleting the live-layer uninstall inside
-        `OnlineCalibrator.shutdown` must re-fire the paired-install rule
-        at the annotated install site.
+    (a) deleting the wholesale block drop inside `AggCache.invalidate` —
+        THE registered invalidator of the partial-aggregate cache, which
+        /api/dropcaches and every mutation notice route through — must
+        fire cache-invalidator-gutted there;
+    (b) deleting the log-buffer uninstall inside `TSDServer.stop` must
+        re-fire the paired-install rule at the annotated install site.
 
     If this test fails, the analyzer has gone blind to the regression it
     exists to catch."""
     import shutil
     from tools.lint import cache_coherence
 
-    # (a) gut reload_calibration's dependent-cache clear
+    # (a) gut AggCache.invalidate's drop of its backing store
     dst = tmp_path / "a" / "opentsdb_tpu"
     shutil.copytree(os.path.join(REPO, "opentsdb_tpu"), dst)
-    cm = dst / "ops" / "costmodel.py"
-    src = cm.read_text()
-    needle = ("    with _lock:\n        _COSTS = None\n"
-              "    from opentsdb_tpu.ops.downsample import "
-              "_clear_dependent_caches\n    _clear_dependent_caches()\n")
+    ac = dst / "storage" / "agg_cache.py"
+    src = ac.read_text()
+    needle = ("                self._blocks = {}\n"
+              "                self._family_index.clear()\n")
     assert src.count(needle) == 1, \
-        "expected exactly one clear inside reload_calibration"
-    cm.write_text(src.replace(
-        needle, "    with _lock:\n        _COSTS = None\n"))
+        "expected exactly one wholesale drop inside AggCache.invalidate"
+    ac.write_text(src.replace(
+        needle, "                self._family_index.clear()\n"))
     ctx = LintContext(str(tmp_path / "a"))
     findings = run_lint(["opentsdb_tpu"], root=str(tmp_path / "a"),
                         analyzers=[cache_coherence.ANALYZER], ctx=ctx)
-    stale = [f for f in findings if f.rule == "cache-stale-mutation"]
-    assert stale, "gutting reload_calibration went undetected"
-    flagged = " ".join(f.message for f in stale)
-    assert "install_live_calibration" in flagged, (
-        "the live-layer install site should be among the stale "
-        "mutations:\n" + "\n".join(f.render() for f in findings))
+    assert any(f.rule == "cache-invalidator-gutted"
+               and f.path == "opentsdb_tpu/storage/agg_cache.py"
+               and "agg-blocks" in f.message
+               for f in findings), (
+        "gutting AggCache.invalidate went undetected:\n"
+        + "\n".join(f.render() for f in findings))
 
-    # (b) gut OnlineCalibrator.shutdown's live-layer uninstall
+    # (b) gut TSDServer.stop's log-buffer uninstall
     dst = tmp_path / "b" / "opentsdb_tpu"
     shutil.copytree(os.path.join(REPO, "opentsdb_tpu"), dst)
-    cal = dst / "ops" / "calibrate.py"
-    src = cal.read_text()
-    needle = "        costmodel.clear_live_calibration()\n"
-    assert needle in src
-    cal.write_text(src.replace(needle, ""))
+    srv = dst / "tsd" / "server.py"
+    src = srv.read_text()
+    needle = ("                from opentsdb_tpu.tsd.admin_rpcs import "
+              "uninstall_log_buffer\n"
+              "                uninstall_log_buffer()\n")
+    assert src.count(needle) == 1
+    srv.write_text(src.replace(needle, ""))
     ctx = LintContext(str(tmp_path / "b"))
     findings = run_lint(["opentsdb_tpu"], root=str(tmp_path / "b"),
                         analyzers=[cache_coherence.ANALYZER], ctx=ctx)
     assert any(f.rule == "install-missing-uninstall"
-               and f.path == "opentsdb_tpu/ops/calibrate.py"
+               and f.path == "opentsdb_tpu/tsd/server.py"
                for f in findings), (
-        "gutting shutdown's clear_live_calibration went undetected:\n"
+        "gutting stop's uninstall_log_buffer went undetected:\n"
         + "\n".join(f.render() for f in findings))
 
 
-def test_gutting_set_hysteresis_cache_clear_fails_the_tree(tmp_path):
-    """set_hysteresis not clearing the jit mode caches was a real PR 6
-    review bug; deleting its `_clear_dependent_caches()` call must
-    re-fire cache-stale-mutation at the band mutation."""
+def test_gutting_the_rollup_lane_invalidator_fails_the_tree(tmp_path):
+    """A lane block served after its points were rewritten is a wrong
+    answer, not a slow one: deleting the wholesale drop inside
+    `RollupLanes.invalidate` must fire cache-invalidator-gutted at the
+    registered invalidator of `rollup-lanes`."""
     import shutil
     from tools.lint import cache_coherence
     dst = tmp_path / "opentsdb_tpu"
     shutil.copytree(os.path.join(REPO, "opentsdb_tpu"), dst)
-    cm = dst / "ops" / "costmodel.py"
-    src = cm.read_text()
-    needle = ("        _choice_memo.clear()\n"
-              "    from opentsdb_tpu.ops.downsample import "
-              "_clear_dependent_caches\n    _clear_dependent_caches()\n")
-    assert needle in src, "expected the clear call inside set_hysteresis"
-    cm.write_text(src.replace(needle, "        _choice_memo.clear()\n"))
+    ru = dst / "storage" / "rollup.py"
+    src = ru.read_text()
+    needle = ("                self._blocks = {}\n"
+              "                self._marks.clear()\n")
+    assert src.count(needle) == 1, \
+        "expected the wholesale drop inside RollupLanes.invalidate"
+    ru.write_text(src.replace(needle,
+                              "                self._marks.clear()\n"))
     ctx = LintContext(str(tmp_path))
     findings = run_lint(["opentsdb_tpu"], root=str(tmp_path),
                         analyzers=[cache_coherence.ANALYZER], ctx=ctx)
-    hits = [f for f in findings if f.rule == "cache-stale-mutation"
-            and "set_hysteresis" in f.message]
-    assert hits, ("set_hysteresis without the cache clear went "
+    hits = [f for f in findings if f.rule == "cache-invalidator-gutted"
+            and f.path == "opentsdb_tpu/storage/rollup.py"
+            and "rollup-lanes" in f.message]
+    assert hits, ("RollupLanes.invalidate without its drop went "
                   "undetected:\n" + "\n".join(f.render()
                                               for f in findings))
 

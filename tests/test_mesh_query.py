@@ -198,57 +198,46 @@ def test_mesh_serves_from_device_cache(pair):
 
 
 class TestMatmulGroupReduce:
-    """group-reduce strategy toggle (r4 perf lever): the one-hot matmul
-    moments must answer exactly like the segment-scatter moments, on and
-    off the mesh, for every moment aggregator + movingAverage.  min/max
-    fall back to segment ops under the toggle and must keep working."""
+    """group-reduce form "matmul": the one-hot matmul moments must answer
+    exactly like the segment-scatter moments, on and off the mesh, for
+    every moment aggregator + movingAverage.  min/max have no matmul
+    form: pinned to it they take segment ops and must keep working."""
 
     QUERIES = MOMENT_QUERIES + [
         "movingAverage3:1m-sum:sys.cpu.user{dc=*}",
         "min:1m-max:sys.cpu.user{dc=*}",     # segment fallback path
     ]
 
-    @pytest.fixture()
-    def matmul_mode(self):
-        from opentsdb_tpu.ops import group_agg
-        group_agg.set_group_reduce_mode("matmul")
-        yield
-        group_agg.set_group_reduce_mode("segment")
-
     @pytest.mark.parametrize("m", QUERIES)
-    def test_matmul_equals_segment(self, matmul_mode, m):
+    def test_matmul_equals_segment(self, kernel_forms, m):
         t = _mk_tsdb(False)
         _ingest(t)
+        kernel_forms(group="matmul")
         got = _run(t, m)
-        from opentsdb_tpu.ops import group_agg
-        group_agg.set_group_reduce_mode("segment")
+        kernel_forms(group="segment")
         want = _run(t, m)
-        group_agg.set_group_reduce_mode("matmul")
         assert_equivalent(got, want)
 
-    def test_matmul_on_mesh(self, pair):
-        """Every matmul-mode aggregator (incl. dev's second gsum pass and
+    def test_matmul_on_mesh(self, pair, kernel_forms):
+        """Every matmul-form aggregator (incl. dev's second gsum pass and
         the min/max segment fallback) under the real mesh collectives —
-        ONE mode flip and one meshed store for the whole sweep (cache
-        clears + recompiles per flip are the expensive part)."""
-        from opentsdb_tpu.ops import group_agg
+        ONE pin and one meshed store for the whole sweep (cache
+        clears + recompiles per pin are the expensive part)."""
         meshed, plain = pair
-        wants = {m: _run(plain, m) for m in self.QUERIES}   # segment mode
-        group_agg.set_group_reduce_mode("matmul")
-        try:
-            for m in self.QUERIES:
-                assert_equivalent(_run(meshed, m), wants[m])
-        finally:
-            group_agg.set_group_reduce_mode("segment")
+        kernel_forms(group="segment")
+        wants = {m: _run(plain, m) for m in self.QUERIES}
+        kernel_forms(group="matmul")
+        for m in self.QUERIES:
+            assert_equivalent(_run(meshed, m), wants[m])
 
 
 class TestSortedGroupReduce:
-    """group-reduce mode "sorted" (r4 chip-attribution lever): rows are
+    """group-reduce form "sorted" (r4 chip-attribution lever): rows are
     argsort-permuted into contiguous group runs, sums become axis-0
     cumsum-diffs and extremes a segmented reset-scan — no scatter, no
     one-hot.  Must answer exactly like the segment scatter, on and off
     the mesh, for every moment aggregator including the extremes (which,
-    unlike matmul mode, have a native sorted form)."""
+    unlike matmul, have a native sorted form)."""
 
     QUERIES = MOMENT_QUERIES + [
         "movingAverage3:1m-sum:sys.cpu.user{dc=*}",
@@ -256,114 +245,25 @@ class TestSortedGroupReduce:
         "max:1m-min:sys.cpu.user{host=*}",
     ]
 
-    def test_sorted_equals_segment(self):
-        from opentsdb_tpu.ops import group_agg
+    def test_sorted_equals_segment(self, kernel_forms):
         t = _mk_tsdb(False)
         _ingest(t)
-        wants = {m: _run(t, m) for m in self.QUERIES}       # segment mode
-        group_agg.set_group_reduce_mode("sorted")
-        try:
-            for m in self.QUERIES:
-                assert_equivalent(_run(t, m), wants[m])
-        finally:
-            group_agg.set_group_reduce_mode("segment")
+        kernel_forms(group="segment")
+        wants = {m: _run(t, m) for m in self.QUERIES}
+        kernel_forms(group="sorted")
+        for m in self.QUERIES:
+            assert_equivalent(_run(t, m), wants[m])
 
-    def test_sorted_on_mesh(self, pair):
+    def test_sorted_on_mesh(self, pair, kernel_forms):
         """The sorted machinery runs per-shard inside shard_map (each chip
         sorts its local rows; psum/pmin/pmax combine across chips) — one
-        mode flip for the whole sweep."""
-        from opentsdb_tpu.ops import group_agg
+        pin for the whole sweep."""
         meshed, plain = pair
-        wants = {m: _run(plain, m) for m in self.QUERIES}   # segment mode
-        group_agg.set_group_reduce_mode("sorted")
-        try:
-            for m in self.QUERIES:
-                assert_equivalent(_run(meshed, m), wants[m])
-        finally:
-            group_agg.set_group_reduce_mode("segment")
-
-    def test_sorted2_equals_segment(self):
-        """Mode "sorted2" (r5): blocked level-masked reset-fold + int32
-        counts must answer exactly like the segment scatter for every
-        moment aggregator including extremes."""
-        from opentsdb_tpu.ops import group_agg
-        t = _mk_tsdb(False)
-        _ingest(t)
-        wants = {m: _run(t, m) for m in self.QUERIES}       # segment mode
-        group_agg.set_group_reduce_mode("sorted2")
-        try:
-            for m in self.QUERIES:
-                assert_equivalent(_run(t, m), wants[m])
-        finally:
-            group_agg.set_group_reduce_mode("segment")
-
-    def test_sorted2_on_mesh(self, pair):
-        """sorted2 per-shard under shard_map: int32 count psums + blocked
-        folds must match the plain-store segment answers."""
-        from opentsdb_tpu.ops import group_agg
-        meshed, plain = pair
-        wants = {m: _run(plain, m) for m in self.QUERIES}   # segment mode
-        group_agg.set_group_reduce_mode("sorted2")
-        try:
-            for m in self.QUERIES:
-                assert_equivalent(_run(meshed, m), wants[m])
-        finally:
-            group_agg.set_group_reduce_mode("segment")
-
-    def test_sorted2_sum_magnitude_skew(self):
-        """The blocked fold must keep the reset-scan's error contract:
-        additions never cross a group boundary, so a 1.0-magnitude group
-        survives next to a 1e15-magnitude neighbor (a cumsum differenced
-        at group bounds would lose it)."""
-        import jax.numpy as jnp
-        from opentsdb_tpu.ops import group_agg
-        s, w, g = 8, 4, 2
-        contrib = np.ones((s, w))
-        contrib[:4] = 1e15
-        contrib[4:] = 0.25
-        part = np.ones((s, w), bool)
-        gid = np.array([0, 0, 0, 0, 1, 1, 1, 1])
-        group_agg.set_group_reduce_mode("sorted2")
-        try:
-            out, cnt = group_agg.moment_group_reduce(
-                "sum", jnp.asarray(contrib), jnp.asarray(part),
-                jnp.asarray(gid), g)
-        finally:
-            group_agg.set_group_reduce_mode("segment")
-        np.testing.assert_allclose(np.asarray(out)[0], 4e15, rtol=1e-12)
-        np.testing.assert_allclose(np.asarray(out)[1], 1.0, rtol=1e-12)
-        np.testing.assert_array_equal(np.asarray(cnt), 4)
-
-    def test_blocked_fold_randomized(self):
-        """_blocked_group_fold vs numpy per-group folds across shapes
-        that exercise every block-boundary case: runs inside one block,
-        spanning blocks, block-aligned starts, empty groups, non-multiple
-        -of-K row counts, out-of-range gids, single rows."""
-        import jax.numpy as jnp
-        from opentsdb_tpu.ops.group_agg import (_SortedGroups,
-                                                _blocked_group_fold)
-        rng = np.random.default_rng(7)
-        for s, g in [(1, 1), (3, 2), (8, 2), (9, 4), (16, 1), (17, 5),
-                     (64, 7), (130, 13), (257, 40)]:
-            w = int(rng.integers(1, 6))
-            gid = np.sort(rng.integers(0, g, size=s))
-            if rng.random() < 0.3 and s > 2:    # out-of-range tail rows
-                gid[-1] = g + 1
-            x = rng.normal(size=(s, w)) * 10.0 ** float(rng.integers(-3, 4))
-            sg = _SortedGroups(jnp.asarray(np.sort(gid)), g, s)
-            got_sum = np.asarray(sg.sum2(jnp.asarray(x)))
-            got_min = np.asarray(sg.extreme2(jnp.asarray(x), False))
-            want_sum = np.zeros((g, w))
-            want_min = np.full((g, w), np.inf)
-            for gi in range(g):
-                rows = np.sort(gid) == gi
-                if rows.any():
-                    want_sum[gi] = x[rows].sum(axis=0)
-                    want_min[gi] = x[rows].min(axis=0)
-            np.testing.assert_allclose(got_sum, want_sum, rtol=1e-12,
-                                       err_msg="s=%d g=%d" % (s, g))
-            np.testing.assert_allclose(got_min, want_min, rtol=0,
-                                       err_msg="s=%d g=%d" % (s, g))
+        kernel_forms(group="segment")
+        wants = {m: _run(plain, m) for m in self.QUERIES}
+        kernel_forms(group="sorted")
+        for m in self.QUERIES:
+            assert_equivalent(_run(meshed, m), wants[m])
 
     def test_presorted_skips_permute_same_answers(self):
         """rows_sorted=True (the planner's layout guarantee) must answer
@@ -379,16 +279,14 @@ class TestSortedGroupReduce:
             b = _SortedGroups(gid, g, s, presorted=True)
             np.testing.assert_array_equal(np.asarray(a.sum(x)),
                                           np.asarray(b.sum(x)))
-            np.testing.assert_array_equal(np.asarray(a.sum2(x)),
-                                          np.asarray(b.sum2(x)))
             np.testing.assert_array_equal(
                 np.asarray(a.extreme(x, True)),
                 np.asarray(b.extreme(x, True)))
             np.testing.assert_array_equal(
-                np.asarray(a.extreme2(x, False)),
-                np.asarray(b.extreme2(x, False)))
+                np.asarray(a.extreme(x, False)),
+                np.asarray(b.extreme(x, False)))
 
-    def test_sorted_sum_magnitude_skew(self):
+    def test_sorted_sum_magnitude_skew(self, kernel_forms):
         """Cross-group cancellation regression (r4 review): a 1.0-magnitude
         group next to a 1e15-magnitude group must keep 1e-9 relative
         accuracy — the reset-scan form restarts accumulation per group,
@@ -402,13 +300,10 @@ class TestSortedGroupReduce:
         contrib[4:] = 0.25
         part = np.ones((s, w), bool)
         gid = np.array([0, 0, 0, 0, 1, 1, 1, 1])
-        group_agg.set_group_reduce_mode("sorted")
-        try:
-            out, cnt = group_agg.moment_group_reduce(
-                "sum", jnp.asarray(contrib), jnp.asarray(part),
-                jnp.asarray(gid), g)
-        finally:
-            group_agg.set_group_reduce_mode("segment")
+        kernel_forms(group="sorted")
+        out, cnt = group_agg.moment_group_reduce(
+            "sum", jnp.asarray(contrib), jnp.asarray(part),
+            jnp.asarray(gid), g)
         np.testing.assert_allclose(np.asarray(out)[0], 4e15, rtol=1e-12)
         np.testing.assert_allclose(np.asarray(out)[1], 1.0, rtol=1e-12)
         np.testing.assert_array_equal(np.asarray(cnt), 4)

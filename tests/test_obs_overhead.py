@@ -36,22 +36,6 @@ BATCHES = 4
 WARMUP = 5
 
 
-def test_autotune_and_exploration_are_off_by_default():
-    """The overhead pin below measures DEFAULT serving.  The costmodel
-    autotune loop — and especially epsilon exploration, which forces
-    deliberately-slower kernels — must be opt-in, or the 1.15x pin
-    would be measuring the explorer, not the tracer."""
-    from opentsdb_tpu.ops import costmodel
-    from opentsdb_tpu.utils.config import CONFIG_SCHEMA
-    assert CONFIG_SCHEMA["tsd.costmodel.autotune.enable"].default \
-        == "false"
-    assert float(CONFIG_SCHEMA["tsd.costmodel.autotune.epsilon"].default
-                 ) == 0.0
-    # no hysteresis / live layer leaks into this process's defaults
-    assert costmodel.hysteresis() == 0.0
-    assert costmodel.live_calibration("cpu") == {}
-
-
 @pytest.fixture
 def served():
     tsdb = TSDB(Config({"tsd.core.auto_create_metrics": True,

@@ -448,44 +448,3 @@ class TestCompileCapture:
         finally:
             jaxprof.stop_compile_counting()
             jaxprof.compile_capture.unsubscribe(cb)
-
-
-class TestPolicyEpochGuard:
-    def test_mid_query_policy_flip_drops_ring_entry(self, manager):
-        """A mode-policy flip between dispatch and decision
-        recomputation (autotune exploration/install on the maintenance
-        thread) must DROP the calibration-ring entry — the recomputed
-        feature vector describes the new policy, the measured time the
-        old kernels — and tag the span instead."""
-        from opentsdb_tpu.obs import jaxprof
-        from opentsdb_tpu.ops import downsample as ds
-
-        jaxprof.clear_segments()
-        real_epoch = ds.mode_policy_epoch
-        calls = [0]
-
-        def flipping_epoch():
-            calls[0] += 1
-            return real_epoch() + (0 if calls[0] == 1 else 1)
-
-        ds.mode_policy_epoch = flipping_epoch
-        try:
-            r = http(manager, "GET",
-                     "/api/query?start=%d&end=%d"
-                     "&m=sum:30s-avg:obs.cpu{host=*}&show_stats"
-                     % (BASE, BASE + 300))
-        finally:
-            ds.mode_policy_epoch = real_epoch
-        assert r.status == 200
-        assert jaxprof.segments() == [], \
-            "a policy-spanning segment must not land in the ring"
-        payload = json.loads(r.body)
-        trace = [e for e in payload
-                 if "statsSummary" in e][0]["statsSummary"]["trace"]
-
-        def find_tag(node):
-            if node.get("tags", {}).get("costmodel_stale"):
-                return True
-            return any(find_tag(c) for c in node.get("spans", []))
-
-        assert find_tag(trace), "span must say why the ring skipped it"

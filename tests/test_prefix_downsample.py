@@ -110,12 +110,13 @@ def test_all_window_matches_reference(agg):
 
 
 class TestScanModesAndCompaction:
-    """r3 hot-path rework: blocked two-level scan + int32 ts compaction.
+    """The prefix-scan and edge-search forms + int32 ts compaction.
 
-    The default batches above (N=64) fall back to the flat scan, so these
-    pin the blocked path (N divisible by the 512 block) and the int32 /
-    int64 timestamp compaction decision against the numpy reference and
-    each other.
+    Rows of N=1024 make every sub-block form eligible; these pin the
+    forms (each through the `kernel_forms` fixture: on the CPU backend
+    the chooser alone never reaches most of them) and the int32 / int64
+    timestamp compaction decision against the numpy reference and each
+    other.
     """
 
     def _big_batch(self, rng, s=4, n=1024, spread_ms=40_000_000,
@@ -149,25 +150,23 @@ class TestScanModesAndCompaction:
                                    rtol=1e-11, atol=1e-9)
 
     @pytest.mark.parametrize("agg", sorted(PREFIX_AGGS))
-    def test_scan_modes_agree_and_match_reference(self, agg):
-        """flat / blocked / subblock scan forms index and sum identically
-        (subblock replaces the full-length f64 cumsum with sub-block
-        reduces + 32-wide remainder dots — r4 chip attribution)."""
-        from opentsdb_tpu.ops import downsample as ds_mod
+    def test_scan_modes_agree_and_match_reference(self, agg,
+                                                  kernel_forms):
+        """flat / subblock / subblock2 scan forms index and sum
+        identically (subblock replaces the full-length f64 cumsum with
+        sub-block reduces + 32-wide remainder dots — r4 chip
+        attribution)."""
         rng = np.random.default_rng(11)
         ts, val, mask = self._big_batch(rng)
         windows = FixedWindows.for_range(START, START + 40_000_000, 3_600_000)
         spec, wargs = windows.split()
         outs = {}
-        for mode in ("flat", "blocked", "subblock", "subblock2"):
-            ds_mod.set_scan_mode(mode)
-            try:
-                _, out, omask = downsample(ts, val, mask, agg, spec, wargs,
-                                           FILL_NONE)
-            finally:
-                ds_mod.set_scan_mode("flat")  # restore the chip-won default
+        for mode in ("flat", "subblock", "subblock2"):
+            kernel_forms(scan=mode)
+            _, out, omask = downsample(ts, val, mask, agg, spec, wargs,
+                                       FILL_NONE)
             outs[mode] = (np.asarray(out), np.asarray(omask))
-        for mode in ("blocked", "subblock", "subblock2"):
+        for mode in ("subblock", "subblock2"):
             np.testing.assert_array_equal(outs["flat"][1], outs[mode][1])
             m = outs["flat"][1]
             np.testing.assert_allclose(outs[mode][0][m], outs["flat"][0][m],
@@ -217,24 +216,20 @@ class TestScanModesAndCompaction:
 
     @pytest.mark.parametrize("agg", ["avg", "sum", "count", "dev", "min",
                                      "max"])
-    def test_search_modes_agree(self, agg):
+    def test_search_modes_agree(self, agg, kernel_forms):
         """compare_all (fused compare+reduce) and hier (sub-block firsts +
         32-wide remainder compare) must index identically to the binary
         search — min/max included: the extreme reset-scan consumes the
         same edge positions."""
-        from opentsdb_tpu.ops import downsample as ds_mod
         rng = np.random.default_rng(23)
         ts, val, mask = self._big_batch(rng)
         windows = FixedWindows.for_range(START, START + 40_000_000, 3_600_000)
         spec, wargs = windows.split()
         outs = {}
         for mode in ("scan", "compare_all", "hier"):
-            ds_mod.set_search_mode(mode)
-            try:
-                _, out, omask = downsample(ts, val, mask, agg, spec, wargs,
-                                           FILL_NONE)
-            finally:
-                ds_mod.set_search_mode("scan")
+            kernel_forms(search=mode)
+            _, out, omask = downsample(ts, val, mask, agg, spec, wargs,
+                                       FILL_NONE)
             outs[mode] = (np.asarray(out), np.asarray(omask))
         for mode in ("compare_all", "hier"):
             np.testing.assert_array_equal(outs["scan"][1], outs[mode][1])
@@ -243,12 +238,11 @@ class TestScanModesAndCompaction:
                                        outs["scan"][0][m],
                                        rtol=1e-12, atol=1e-12)
 
-    def test_hier_search_tie_timestamps(self):
+    def test_hier_search_tie_timestamps(self, kernel_forms):
         """Duplicate timestamps straddling sub-block boundaries: the hier
         search's strict-< decomposition must agree with searchsorted
         'left' when runs of equal timestamps cross the 32-point granule
         and when edges land exactly on a timestamp."""
-        from opentsdb_tpu.ops import downsample as ds_mod
         s, n = 2, 128
         ts = np.full((s, n), np.iinfo(np.int64).max, np.int64)
         val = np.zeros((s, n), np.float64)
@@ -267,12 +261,9 @@ class TestScanModesAndCompaction:
         spec, wargs = windows.split()
         outs = {}
         for mode in ("scan", "hier"):
-            ds_mod.set_search_mode(mode)
-            try:
-                _, out, omask = downsample(ts, val, mask, "sum", spec,
-                                           wargs, FILL_NONE)
-            finally:
-                ds_mod.set_search_mode("scan")
+            kernel_forms(search=mode)
+            _, out, omask = downsample(ts, val, mask, "sum", spec,
+                                       wargs, FILL_NONE)
             outs[mode] = (np.asarray(out), np.asarray(omask))
         np.testing.assert_array_equal(outs["scan"][1], outs["hier"][1])
         np.testing.assert_allclose(outs["hier"][0][outs["scan"][1]],
@@ -316,48 +307,6 @@ class TestScanModesAndCompaction:
         assert bool((np.diff(np.asarray(cts), axis=1) >= 0).all())
 
 
-class TestSinglePrecisionMode:
-    """Opt-in f32 accumulation (set_value_precision): documented fast mode;
-    must stay within float32 tolerance of the double path and never be the
-    default."""
-
-    def test_default_is_double(self):
-        from opentsdb_tpu.ops import downsample as ds_mod
-        assert ds_mod._VALUE_PRECISION == "double"
-
-    @pytest.mark.parametrize("agg", ["sum", "avg", "dev", "squareSum"])
-    def test_single_within_f32_tolerance(self, agg):
-        from opentsdb_tpu.ops import downsample as ds_mod
-        rng = np.random.default_rng(17)
-        ts = np.full((3, 1024), np.iinfo(np.int64).max, np.int64)
-        val = np.zeros((3, 1024), np.float64)
-        mask = np.zeros((3, 1024), bool)
-        for i in range(3):
-            k = 1000
-            ts[i, :k] = START + np.sort(
-                rng.choice(10_000_000, size=k, replace=False))
-            val[i, :k] = rng.normal(100.0, 10.0, k)
-            mask[i, :k] = True
-        windows = FixedWindows.for_range(START, START + 10_000_000,
-                                         3_600_000)
-        spec, wargs = windows.split()
-        _, want, wmask = downsample(ts, val, mask, agg, spec, wargs,
-                                    FILL_NONE)
-        ds_mod.set_value_precision("single")
-        try:
-            _, got, gmask = downsample(ts, val, mask, agg, spec, wargs,
-                                       FILL_NONE)
-        finally:
-            ds_mod.set_value_precision("double")
-        want = np.asarray(want)
-        got = np.asarray(got)
-        m = np.asarray(wmask)
-        np.testing.assert_array_equal(np.asarray(gmask), m)
-        assert got.dtype == want.dtype == np.float64  # contract: f64 out
-        # ~350 points/window in f32: relative error bounded by ~n*eps
-        np.testing.assert_allclose(got[m], want[m], rtol=5e-4, atol=1e-3)
-
-
 class TestExtremeScanPath:
     """r3: min/max downsample rides a segmented reset-scan, no scatter."""
 
@@ -397,43 +346,38 @@ class TestExtremeScanPath:
                 else:
                     assert not omask[i, w]
 
-    def test_materialized_and_streamed_minmax_have_no_scatter(self):
+    def test_materialized_and_streamed_minmax_have_no_scatter(
+            self, kernel_forms):
         """The scan-form extreme kernel is scatter-free (TPU scatters
-        serialize).  Mode "scan" is forced: under the default "auto" the
-        cost model correctly picks the segment scatter on CPU — where
-        this suite runs and scatters are cheap — so the property being
-        pinned is the scan KERNEL's, not the chooser's."""
+        serialize).  The form is pinned: the chooser correctly picks the
+        segment scatter on CPU — where this suite runs and scatters are
+        cheap — so the property being pinned is the scan KERNEL's, not
+        the chooser's."""
         import jax
         import jax.numpy as jnp
-        from opentsdb_tpu.ops import downsample as ds_mod
         from opentsdb_tpu.ops import streaming
         windows = FixedWindows.for_range(0, 3_000_000, 60_000)
         spec, wargs = windows.split()
         ts = jnp.zeros((4, 128), jnp.int64)
         val = jnp.zeros((4, 128))
         mask = jnp.ones((4, 128), bool)
-        prior = ds_mod._EXTREME_MODE
-        ds_mod.set_extreme_mode("scan")
-        try:
-            hlo = jax.jit(downsample, static_argnums=(3, 4, 6)).lower(
-                ts, val, mask, "min", spec, wargs, FILL_NONE).as_text()
-            assert "scatter" not in hlo
-            state = streaming._zero_state(
-                4, spec.count, lanes=streaming.lanes_for(["min", "max"]))
-            hlo = jax.jit(streaming._update, static_argnums=0).lower(
-                spec, state, ts, val, mask, wargs).as_text()
-            assert "scatter" not in hlo
-        finally:
-            ds_mod.set_extreme_mode(prior)
+        kernel_forms(extreme="scan")
+        hlo = jax.jit(downsample, static_argnums=(3, 4, 6)).lower(
+            ts, val, mask, "min", spec, wargs, FILL_NONE).as_text()
+        assert "scatter" not in hlo
+        state = streaming._zero_state(
+            4, spec.count, lanes=streaming.lanes_for(["min", "max"]))
+        hlo = jax.jit(streaming._update, static_argnums=0).lower(
+            spec, state, ts, val, mask, wargs).as_text()
+        assert "scatter" not in hlo
 
     @pytest.mark.parametrize("agg", ["min", "max"])
     @pytest.mark.parametrize("seed,interval", [(62, 600_000), (63, 60_000),
                                                (64, 2_500_000)])
-    def test_extreme_modes_agree(self, agg, seed, interval):
+    def test_extreme_modes_agree(self, agg, seed, interval, kernel_forms):
         """scan / segment / subblock extreme forms answer identically —
         interval sweep covers windows smaller than, comparable to, and
         much wider than the 32-point sub-block granule."""
-        from opentsdb_tpu.ops import downsample as ds_mod
         rng = np.random.default_rng(seed)
         ts = np.full((3, 128), np.iinfo(np.int64).max, np.int64)
         val = np.zeros((3, 128), np.float64)
@@ -446,15 +390,13 @@ class TestExtremeScanPath:
             mask[i, :k] = True
         windows = FixedWindows.for_range(START, START + 5_000_000, interval)
         spec, wargs = windows.split()
+        kernel_forms(extreme="scan")
         _, want, wmask = downsample(ts, val, mask, agg, spec, wargs,
                                     FILL_NONE)
         for mode in ("segment", "subblock"):
-            ds_mod.set_extreme_mode(mode)
-            try:
-                _, got, gmask = downsample(ts, val, mask, agg, spec, wargs,
-                                           FILL_NONE)
-            finally:
-                ds_mod.set_extreme_mode("scan")
+            kernel_forms(extreme=mode)
+            _, got, gmask = downsample(ts, val, mask, agg, spec, wargs,
+                                       FILL_NONE)
             np.testing.assert_array_equal(np.asarray(gmask),
                                           np.asarray(wmask))
             m = np.asarray(wmask)
@@ -462,11 +404,10 @@ class TestExtremeScanPath:
                                           np.asarray(want)[m])
 
     @pytest.mark.parametrize("agg", ["min", "max"])
-    def test_subblock_extreme_dense_ties(self, agg):
+    def test_subblock_extreme_dense_ties(self, agg, kernel_forms):
         """Dense rows where window edges land exactly on sub-block
         boundaries and all values equal in a window — boundary masks and
         the interior reset-scan must not double-count or miss lanes."""
-        from opentsdb_tpu.ops import downsample as ds_mod
         s, n = 2, 128
         ts = np.full((s, n), np.iinfo(np.int64).max, np.int64)
         val = np.zeros((s, n), np.float64)
@@ -482,14 +423,12 @@ class TestExtremeScanPath:
         mask[1, :100] = True
         windows = FixedWindows.for_range(START, START + 700, 32)
         spec, wargs = windows.split()
+        kernel_forms(extreme="scan")
         _, want, wmask = downsample(ts, val, mask, agg, spec, wargs,
                                     FILL_NONE)
-        ds_mod.set_extreme_mode("subblock")
-        try:
-            _, got, gmask = downsample(ts, val, mask, agg, spec, wargs,
-                                       FILL_NONE)
-        finally:
-            ds_mod.set_extreme_mode("scan")
+        kernel_forms(extreme="subblock")
+        _, got, gmask = downsample(ts, val, mask, agg, spec, wargs,
+                                   FILL_NONE)
         np.testing.assert_array_equal(np.asarray(gmask), np.asarray(wmask))
         m = np.asarray(wmask)
         np.testing.assert_array_equal(np.asarray(got)[m],
@@ -623,36 +562,30 @@ class TestSearchModeShapeGuard:
     timeout)."""
 
     def test_long_rows_demote_dense_modes(self):
-        from opentsdb_tpu.ops.downsample import _effective_search_mode
         from opentsdb_tpu.ops import downsample as ds_mod
-        # this test pins the SHAPE rules; the platform guard (tested in
-        # TestPlatformModeGuard) would demote everything on CPU first
-        guard_before = ds_mod._PLATFORM_MODE_GUARD
-        ds_mod.set_platform_mode_guard(False)
         cases = {
-            # (mode, n) -> expected effective mode
-            ("compare_all", 65536): "compare_all",   # headline: stays
-            ("compare_all", 1 << 20): "scan",        # 1M-pt chunk: demote
-            ("hier", 65536): "hier",
+            # (form, n) -> is it a candidate at 514 edges
+            ("compare_all", 65536): True,      # headline: stays
+            ("compare_all", 1 << 20): False,   # 1M-pt chunk: demote
+            ("hier", 65536): True,
             # 1M-pt rows x 514 edges: 16.8M compare cells/row exceeds
             # _HIER_CELL_CAP — the config-1 shape (109M cells/row) ran
             # 18x slower on the host lane and failed scoped-vmem compile
             # on the chip (r04b), so wide hier matrices demote
-            ("hier", 1 << 20): "scan",
-            ("hier", 1 << 24): "scan",     # 16M-pt rows: demote
+            ("hier", 1 << 20): False,
+            ("hier", 1 << 24): False,     # 16M-pt rows: demote
         }
-        try:
-            for (mode, n), want in cases.items():
-                ds_mod.set_search_mode(mode)
-                try:
-                    got = _effective_search_mode(1024, n, 514)
-                finally:
-                    ds_mod.set_search_mode("scan")
-                assert got == want, (mode, n, got, want)
-        finally:
-            ds_mod.set_platform_mode_guard(guard_before)
+        for (mode, n), want in cases.items():
+            got = mode in ds_mod._search_candidates(n, 514)
+            assert got == want, (mode, n, got, want)
+        # and the chip's pick never leaves the candidates
+        for n in (65536, 1 << 20, 1 << 24):
+            assert ds_mod._effective_search_mode(1024, n, 514, "tpu") \
+                in ds_mod._search_candidates(n, 514)
+        assert ds_mod._effective_search_mode(1024, 1 << 20, 514,
+                                             "tpu") == "scan"
 
-    def test_demoted_search_still_correct(self):
+    def test_demoted_search_still_correct(self, kernel_forms, monkeypatch):
         """A (tiny-N, huge-W) shape under compare_all answers identically
         to scan — through the demotion path."""
         from opentsdb_tpu.ops import downsample as ds_mod
@@ -669,15 +602,14 @@ class TestSearchModeShapeGuard:
             mask[i, :k] = True
         windows = FixedWindows.for_range(START, START + 5_000_000, 1_000)
         spec, wargs = windows.split()      # ~5000 windows, N=256
-        ratio = ds_mod._SEARCH_DEMOTE_RATIO
-        ds_mod._SEARCH_DEMOTE_RATIO = 1    # force demotion at this shape
-        try:
-            ds_mod.set_search_mode("compare_all")
-            _, got, gm = downsample(ts, val, mask, "sum", spec, wargs,
-                                    FILL_NONE)
-        finally:
-            ds_mod._SEARCH_DEMOTE_RATIO = ratio
-            ds_mod.set_search_mode("scan")
+        # force demotion at this shape
+        monkeypatch.setattr(ds_mod, "_SEARCH_DEMOTE_RATIO", 1)
+        assert "compare_all" not in ds_mod._search_candidates(
+            n, spec.count + 1)
+        kernel_forms(search="compare_all")
+        _, got, gm = downsample(ts, val, mask, "sum", spec, wargs,
+                                FILL_NONE)
+        kernel_forms(search="scan")
         _, want, wm = downsample(ts, val, mask, "sum", spec, wargs,
                                  FILL_NONE)
         np.testing.assert_array_equal(np.asarray(gm), np.asarray(wm))
@@ -685,31 +617,18 @@ class TestSearchModeShapeGuard:
         np.testing.assert_allclose(np.asarray(got)[m], np.asarray(want)[m])
 
 
-class TestPlatformModeGuard:
-    """Dense search forms are accelerator winners only: with the platform
-    guard on (the production default; conftest disables it suite-wide so
-    CPU CI still exercises the dense kernels), any CPU execution — the
-    host lane or a CPU-only process — takes the binary search (r04b chip
-    session: hier 18x slower than scan end-to-end on the config-1 host
-    lane)."""
+class TestSearchPlatformRule:
+    """Dense search forms are accelerator winners only: any CPU execution
+    — the host lane or a CPU-only process — takes the binary search (r04b
+    chip session: hier 18x slower than scan end-to-end on the config-1
+    host lane).  The rule is part of the chooser, not a switch."""
 
-    def _guarded(self, fn):
+    def test_cpu_backend_takes_the_binary_search(self):
         from opentsdb_tpu.ops import downsample as ds_mod
-        ds_mod.set_platform_mode_guard(True)
-        try:
-            return fn(ds_mod)
-        finally:
-            ds_mod.set_platform_mode_guard(False)
-            ds_mod.set_search_mode("scan")
-
-    def test_cpu_backend_demotes_dense_modes(self):
-        # this suite runs on the CPU platform, so the default backend is
-        # cpu and the guard demotes even outside a host_lane context
-        def check(ds_mod):
-            for mode in ("compare_all", "hier"):
-                ds_mod.set_search_mode(mode)
-                assert ds_mod._effective_search_mode(8, 65536, 514) == "scan"
-        self._guarded(check)
+        # this suite runs on the CPU platform, so the ambient platform
+        # is cpu even outside a host_lane context
+        assert ds_mod._effective_search_mode(8, 65536, 514) == "scan"
+        assert ds_mod._effective_search_mode(8, 65536, 514, "cpu") == "scan"
 
     def test_host_lane_context_reports_cpu(self):
         from opentsdb_tpu.ops import hostlane
@@ -717,17 +636,14 @@ class TestPlatformModeGuard:
         with hostlane.host_lane(True):
             assert hostlane.execution_platform() == "cpu"
 
-    def test_guard_off_keeps_dense_modes(self):
+    def test_tpu_platform_keeps_dense_forms(self):
         from opentsdb_tpu.ops import downsample as ds_mod
-        ds_mod.set_search_mode("hier")
-        try:
-            assert ds_mod._effective_search_mode(8, 65536, 514) == "hier"
-        finally:
-            ds_mod.set_search_mode("scan")
+        assert ds_mod._effective_search_mode(8, 65536, 514, "tpu") == "hier"
 
-    def test_guarded_query_answers_identically(self):
-        """End-to-end: the same downsample under guard+dense-mode equals
-        the scan answer (the guard changes strategy, never values)."""
+    def test_dense_form_answers_identically(self, kernel_forms):
+        """End-to-end: the same downsample under the CPU backend's own
+        pick (binary search) equals the hier answer (the platform rule
+        changes strategy, never values)."""
         rng = np.random.default_rng(7)
         s, n = 2, 512
         ts = np.sort(rng.choice(10_000_000, size=(s, n), replace=False),
@@ -738,12 +654,9 @@ class TestPlatformModeGuard:
         spec, wargs = windows.split()
         _, want, wm = downsample(ts, val, mask, "sum", spec, wargs,
                                  FILL_NONE)
-
-        def run_guarded(ds_mod):
-            ds_mod.set_search_mode("hier")
-            return downsample(ts, val, mask, "sum", spec, wargs, FILL_NONE)
-
-        _, got, gm = self._guarded(run_guarded)
+        kernel_forms(search="hier")
+        _, got, gm = downsample(ts, val, mask, "sum", spec, wargs,
+                                FILL_NONE)
         np.testing.assert_array_equal(np.asarray(gm), np.asarray(wm))
         m = np.asarray(wm)
         np.testing.assert_allclose(np.asarray(got)[m], np.asarray(want)[m],
@@ -759,26 +672,17 @@ class TestWideGridGuards:
         from opentsdb_tpu.ops import downsample as ds_mod
         # headline shape: everything eligible
         assert ds_mod._subblock_edges_fit(65536, 514)
-        ds_mod.set_extreme_mode("subblock")
-        try:
-            assert ds_mod._use_subblock_extreme(65536, 513)
-            # config-2 chunk: 64k-pt chunk against a 1M-window grid
-            assert not ds_mod._use_subblock_extreme(65536, 1 << 20)
-        finally:
-            ds_mod.set_extreme_mode("scan")
-        ds_mod.set_search_mode("hier")
-        try:
-            assert ds_mod._effective_search_mode(1, 65536, 1 << 20) == "scan"
-            assert ds_mod._effective_search_mode(1, 65536, 514) == "hier"
-        finally:
-            ds_mod.set_search_mode("scan")
+        assert "subblock" in ds_mod._extreme_candidates(65536, 513)
+        # config-2 chunk: 64k-pt chunk against a 1M-window grid
+        assert "subblock" not in ds_mod._extreme_candidates(65536, 1 << 20)
+        assert ds_mod._effective_search_mode(1, 65536, 1 << 20,
+                                             "tpu") == "scan"
+        assert ds_mod._effective_search_mode(1, 65536, 514, "tpu") == "hier"
 
-    def test_wide_grid_all_modes_answer(self):
+    def test_wide_grid_all_modes_answer(self, kernel_forms):
         """A wide sparse grid (W >> N) under every new mode at once must
         answer identically to the defaults — through the demotion/
         fallback paths, without blowing memory."""
-        from opentsdb_tpu.ops import downsample as ds_mod
-        from opentsdb_tpu.ops import group_agg
         rng = np.random.default_rng(51)
         s, n = 2, 64
         ts = np.full((s, n), np.iinfo(np.int64).max, np.int64)
@@ -799,39 +703,28 @@ class TestWideGridGuards:
             _, out, om = downsample(ts, val, mask, agg, spec, wargs,
                                     FILL_NONE)
             want[agg] = (np.asarray(out), np.asarray(om))
-        ds_mod.set_scan_mode("subblock")
-        ds_mod.set_search_mode("hier")
-        ds_mod.set_extreme_mode("subblock")
-        group_agg.set_group_reduce_mode("sorted")
-        try:
-            for agg in ("sum", "min", "max", "avg"):
-                _, out, om = downsample(ts, val, mask, agg, spec, wargs,
-                                        FILL_NONE)
-                np.testing.assert_array_equal(np.asarray(om), want[agg][1])
-                m = want[agg][1]
-                np.testing.assert_allclose(np.asarray(out)[m],
-                                           want[agg][0][m],
-                                           rtol=1e-12, atol=1e-12)
-        finally:
-            ds_mod.set_scan_mode("flat")
-            ds_mod.set_search_mode("scan")
-            ds_mod.set_extreme_mode("scan")
-            group_agg.set_group_reduce_mode("segment")
+        kernel_forms(scan="subblock", search="hier", extreme="subblock",
+                     group="sorted")
+        for agg in ("sum", "min", "max", "avg"):
+            _, out, om = downsample(ts, val, mask, agg, spec, wargs,
+                                    FILL_NONE)
+            np.testing.assert_array_equal(np.asarray(om), want[agg][1])
+            m = want[agg][1]
+            np.testing.assert_allclose(np.asarray(out)[m],
+                                       want[agg][0][m],
+                                       rtol=1e-12, atol=1e-12)
         # subblock2 has NO edges-fit constraint (its remainder reads a
         # same-size prefix, not an [S, W, K] lane) — it must answer the
         # wide grid identically with the sub-block path ACTIVE
-        ds_mod.set_scan_mode("subblock2")
-        try:
-            for agg in ("sum", "avg"):
-                _, out, om = downsample(ts, val, mask, agg, spec, wargs,
-                                        FILL_NONE)
-                np.testing.assert_array_equal(np.asarray(om), want[agg][1])
-                m = want[agg][1]
-                np.testing.assert_allclose(np.asarray(out)[m],
-                                           want[agg][0][m],
-                                           rtol=1e-12, atol=1e-12)
-        finally:
-            ds_mod.set_scan_mode("flat")
+        kernel_forms(scan="subblock2")
+        for agg in ("sum", "avg"):
+            _, out, om = downsample(ts, val, mask, agg, spec, wargs,
+                                    FILL_NONE)
+            np.testing.assert_array_equal(np.asarray(om), want[agg][1])
+            m = want[agg][1]
+            np.testing.assert_allclose(np.asarray(out)[m],
+                                       want[agg][0][m],
+                                       rtol=1e-12, atol=1e-12)
 
 
 class TestNewModesAcrossWindowKinds:
@@ -857,8 +750,8 @@ class TestNewModesAcrossWindowKinds:
     @pytest.mark.parametrize("agg", ["sum", "avg", "min", "max", "dev"])
     @pytest.mark.parametrize("kind", ["edges", "all"])
     @pytest.mark.parametrize("scan_mode", ["subblock", "subblock2"])
-    def test_modes_agree_on_irregular_grids(self, agg, kind, scan_mode):
-        from opentsdb_tpu.ops import downsample as ds_mod
+    def test_modes_agree_on_irregular_grids(self, agg, kind, scan_mode,
+                                            kernel_forms):
         rng = np.random.default_rng(83)
         ts, val, mask = self._batch(rng)
         if kind == "edges":
@@ -869,16 +762,9 @@ class TestNewModesAcrossWindowKinds:
             windows = AllWindow(START + 5_000, START + 4_500_000)
         spec, wargs = windows.split()
         _, want, wm = downsample(ts, val, mask, agg, spec, wargs, FILL_NONE)
-        ds_mod.set_scan_mode(scan_mode)
-        ds_mod.set_search_mode("hier")
-        ds_mod.set_extreme_mode("subblock")
-        try:
-            _, got, gm = downsample(ts, val, mask, agg, spec, wargs,
-                                    FILL_NONE)
-        finally:
-            ds_mod.set_scan_mode("flat")
-            ds_mod.set_search_mode("scan")
-            ds_mod.set_extreme_mode("scan")
+        kernel_forms(scan=scan_mode, search="hier", extreme="subblock")
+        _, got, gm = downsample(ts, val, mask, agg, spec, wargs,
+                                FILL_NONE)
         np.testing.assert_array_equal(np.asarray(gm), np.asarray(wm))
         m = np.asarray(wm)
         np.testing.assert_allclose(np.asarray(got)[m], np.asarray(want)[m],
@@ -890,12 +776,9 @@ def test_compare_all_memory_cap_demotes():
     matrix would materialize huge (config 4's 64k-pt chunk against a
     16k-window grid attempted a multi-TB buffer on CPU)."""
     from opentsdb_tpu.ops import downsample as ds_mod
-    ds_mod.set_search_mode("compare_all")
-    try:
-        # headline: 65536 x 514 cells — stays
-        assert ds_mod._effective_search_mode(1024, 65536, 514) \
-            == "compare_all"
-        # config-4 chunk grid: 65536 x 16385 cells — demote
-        assert ds_mod._effective_search_mode(512, 65536, 16385) == "scan"
-    finally:
-        ds_mod.set_search_mode("scan")
+    # headline: 65536 x 514 cells — stays
+    assert "compare_all" in ds_mod._search_candidates(65536, 514)
+    # config-4 chunk grid: 65536 x 16385 cells — demote
+    assert "compare_all" not in ds_mod._search_candidates(65536, 16385)
+    assert ds_mod._effective_search_mode(512, 65536, 16385,
+                                         "tpu") != "compare_all"

@@ -636,7 +636,7 @@ class TestReplicationWire:
         health = a.get("/api/diag/health")
         assert "replication" in health["subsystems"]
         assert health["subsystems"]["replication"]["level"] == "ok"
-        assert len(health["subsystems"]) == 10
+        assert len(health["subsystems"]) == 9
 
 
 class TestFaultSites:

@@ -277,32 +277,6 @@ class TestSegDtypeGuards:
 class TestCacheCoherenceFixes:
     """PR 7 true positives surfaced by tools/lint/cache_coherence.py."""
 
-    def test_clear_dependent_caches_covers_every_mode_baked_program(
-            self, monkeypatch):
-        """_jitted_union_batch and _jitted_update_sliced bake the same
-        trace-time mode globals as their siblings but were missing from
-        _clear_dependent_caches — a set_segment_chunk_ratio (or any
-        set_*_mode) flip kept serving stale sliced-update/union-batch
-        kernels.  Fails pre-fix: the spies never see clear_cache()."""
-        from opentsdb_tpu.ops import downsample, pipeline, streaming
-
-        cleared = []
-
-        class Spy:
-            def __init__(self, name):
-                self.name = name
-
-            def clear_cache(self):
-                cleared.append(self.name)
-
-        monkeypatch.setattr(pipeline, "_jitted_union_batch",
-                            Spy("union_batch"))
-        monkeypatch.setattr(streaming, "_jitted_update_sliced",
-                            Spy("update_sliced"))
-        downsample._clear_dependent_caches()
-        assert "union_batch" in cleared
-        assert "update_sliced" in cleared
-
     def test_log_buffer_uninstall_detaches_from_root_logger(self):
         """The /logs ring-buffer handler used to outlive every server:
         installed on start, never detached.  Fails pre-fix:
@@ -450,44 +424,3 @@ class TestOrderingAtomicityTruePositives:
         s.append_batch(np.array([10_000, 20_000], dtype=np.int64),
                        np.array([1.0, 2.0]), False)
         assert len(s) == 2 and s._sorted
-
-    def test_failed_calibrator_construction_restores_global_installs(
-            self, tmp_path):
-        """OnlineCalibrator.__init__ armed the process-global
-        calibration-file redirect, then ran fallible config reads; a
-        raise there leaked the redirect with no instance whose
-        shutdown() could undo it.  Fails pre-fix: calibration_file()
-        still points at this constructor's path."""
-        from opentsdb_tpu.ops import calibrate, costmodel
-
-        prior_file = costmodel.calibration_file()
-        prior_hyst = costmodel.hysteresis()
-        cal_path = str(tmp_path / "cal.json")
-
-        class Cfg:
-            def get_int(self, key):
-                return 1
-
-            def get_bool(self, key):
-                return False
-
-            def get_string(self, key):
-                return cal_path
-
-            def get_float(self, key):
-                if key.endswith("hysteresis"):
-                    raise ValueError("could not parse hysteresis")
-                return 0.25
-
-        class FakeTsdb:
-            config = Cfg()
-            stats_hooks: dict = {}
-
-        try:
-            with pytest.raises(ValueError):
-                calibrate.OnlineCalibrator(FakeTsdb())
-            assert costmodel.calibration_file() == prior_file
-            assert costmodel.hysteresis() == prior_hyst
-        finally:
-            costmodel.set_calibration_file(prior_file)
-            costmodel.set_hysteresis(prior_hyst)

@@ -14,7 +14,6 @@ import pytest
 
 from opentsdb_tpu.core import TSDB
 from opentsdb_tpu.obs.registry import REGISTRY
-from opentsdb_tpu.ops import group_agg
 from opentsdb_tpu.tsd.http import HttpRequest
 from opentsdb_tpu.tsd.rpc_manager import RpcManager
 from opentsdb_tpu.utils.config import Config
@@ -142,13 +141,12 @@ def assert_answer(payload: list, want: dict, tag: str) -> None:
 
 @pytest.mark.parametrize("mode", MODES)
 @pytest.mark.parametrize("name", sorted(CLASSES))
-def test_every_group_form_answers_as_the_reference(served, name, mode):
+def test_every_group_form_answers_as_the_reference(served, name, mode,
+                                                   kernel_forms):
     tsdb, mgr, values = served
-    group_agg.set_group_reduce_mode(mode)
-    try:
-        payload = ask(mgr, uri_of(name))
-    finally:        # process-global: never leave it forced for another test
-        group_agg.set_group_reduce_mode("auto")
+    if mode != "auto":
+        kernel_forms(group=mode)
+    payload = ask(mgr, uri_of(name))
     _, tag, groups, _, _, _ = CLASSES[name]
     assert len(payload) == groups
     assert_answer(payload, reference(values, name), tag)

@@ -28,7 +28,6 @@ Exit code 0 = both contracts held every round.
 
 import argparse
 import json
-import math
 import os
 import random
 import signal
@@ -331,136 +330,6 @@ def run_phase(mode: str, rounds: int, rng, peer_port: int,
         recv.send_signal(signal.SIGTERM)
         recv.wait()
     return tally
-
-
-def _finite_positive(value) -> bool:
-    """Shared safety predicate for fitted constants: finite AND > 0.
-    (NaN, +/-inf, zero, negatives, and unparseable values all fail.)"""
-    try:
-        v = float(value)
-    except (TypeError, ValueError):
-        return False
-    return math.isfinite(v) and v > 0.0
-
-
-def run_autotune_stage(port: int, rounds: int) -> None:
-    """--autotune: one TSD with the online costmodel fitter armed
-    (short interval, low sample floor, exploration ON so losing modes
-    dispatch too) serves a mixed query load, then the stage asserts the
-    self-tuning loop's safety contract off the stats surfaces:
-
-      * at least one fit installed, and every live-fitted constant is
-        finite and strictly positive (a NaN/zero constant would poison
-        every later argmin);
-      * no feasibility-rejected mode was ever dispatched
-        (tsd.costmodel.infeasible stays absent/0 on /api/stats/
-        prometheus — the kernels' guards must hold under exploration);
-      * the daemon persists the calibration file at SIGTERM and the
-        persisted constants are finite and positive too.
-    """
-    import tempfile
-    calib = os.path.join(tempfile.mkdtemp(prefix="chaos_autotune_"),
-                         "calibration.json")
-    tsd = spawn_tsd(port, {
-        "tsd.costmodel.autotune.enable": "true",
-        "tsd.costmodel.autotune.interval": "1",
-        "tsd.costmodel.autotune.min_samples": "8",
-        "tsd.costmodel.autotune.epsilon": "0.5",
-        "tsd.costmodel.autotune.calibration_file": calib,
-        # only monolithic single-device dispatches enter the
-        # calibration ring, so the mesh route stays off here
-        "tsd.query.mesh.enable": "false",
-        # the fitter needs ring entries from MONOLITHIC dispatches;
-        # partial-aggregate rewrites skip the predicted-vs-actual
-        # ledger by design (their stage breakdown doesn't describe a
-        # block-decomposed execution) — the --cache stage owns the
-        # cache's own gates
-        "tsd.query.cache.enable": "false",
-    }, role="autotune")
-    try:
-        for host, value in (("a", 1), ("b", 2), ("c", 3)):
-            seed_host(port, host, value)
-        # mixed shapes: grouped downsamples (avg + an extreme) over
-        # varying ranges so several strategy buckets land in the ring
-        metrics = ["sum:10s-avg:chaos.m{host=*}",
-                   "max:10s-max:chaos.m{host=*}",
-                   "sum:30s-avg:chaos.m"]
-        fits = 0.0
-        for i in range(max(rounds, 12) * 3):
-            mq = metrics[i % len(metrics)]
-            span = 60 + 60 * (i % 5)
-            url = ("http://127.0.0.1:%d/api/query?start=%d&end=%d&m=%s"
-                   % (port, BASE - 1, BASE + span, mq))
-            try:
-                with urllib.request.urlopen(url, timeout=30):
-                    pass    # urlopen raises on any non-2xx
-            except urllib.error.HTTPError as e:
-                print("[autotune] query %d (%s) -> %d"
-                      % (i, mq, e.code), flush=True)
-                raise SystemExit(1)
-            time.sleep(0.1)
-        # the fit-polling budget starts AFTER the load phase: the
-        # query loop pays jit compiles (and exploration keeps clearing
-        # the caches), which can easily exceed a minute on a CI CPU
-        deadline = time.time() + 60
-        while time.time() < deadline:
-            stats = json.loads(urllib.request.urlopen(
-                "http://127.0.0.1:%d/api/stats" % port,
-                timeout=30).read())
-            by_name = {}
-            for rec in stats:
-                by_name.setdefault(rec["metric"], []).append(rec)
-            fits = sum(r["value"]
-                       for r in by_name.get(
-                           "tsd.costmodel.autotune.fits", []))
-            if fits >= 1:
-                break
-            time.sleep(0.5)
-        if fits < 1:
-            print("[autotune] no costmodel fit installed within the "
-                  "deadline — the loop is not closing", flush=True)
-            raise SystemExit(1)
-        constants = [r for name, rs in by_name.items()
-                     if name.startswith("tsd.costmodel.calibration.")
-                     for r in rs]
-        if not constants:
-            print("[autotune] fit reported but no live constants on "
-                  "/api/stats", flush=True)
-            raise SystemExit(1)
-        for r in constants:
-            if not _finite_positive(r["value"]):
-                print("[autotune] non-positive/NaN/inf live constant: "
-                      "%r" % r, flush=True)
-                raise SystemExit(1)
-        prom = urllib.request.urlopen(
-            "http://127.0.0.1:%d/api/stats/prometheus" % port,
-            timeout=30).read().decode()
-        for line in prom.splitlines():
-            if line.startswith("tsd_costmodel_infeasible") \
-                    and not line.startswith("#"):
-                if float(line.rsplit(" ", 1)[1]) != 0.0:
-                    print("[autotune] feasibility-rejected mode "
-                          "DISPATCHED: %s" % line, flush=True)
-                    raise SystemExit(1)
-        print("[autotune] %d fits, %d live constants positive, no "
-              "infeasible dispatches" % (int(fits), len(constants)),
-              flush=True)
-    finally:
-        tsd.send_signal(signal.SIGTERM)
-        tsd.wait()
-    if not os.path.exists(calib):
-        print("[autotune] calibration file %s not persisted at "
-              "shutdown" % calib, flush=True)
-        raise SystemExit(1)
-    with open(calib) as fh:
-        persisted = json.load(fh)
-    for plat, table in persisted.items():
-        for term, v in table.items():
-            if not _finite_positive(v):
-                print("[autotune] persisted %s.%s is non-positive/NaN/"
-                      "inf: %r" % (plat, term, v), flush=True)
-                raise SystemExit(1)
-    print("[autotune] persisted calibration OK: %s" % calib, flush=True)
 
 
 def run_cache_stage(port: int, rounds: int) -> None:
@@ -1795,11 +1664,6 @@ def main():
     ap.add_argument("--san", action="store_true",
                     help="arm tsdbsan in every spawned TSD and fail on "
                          "error-level race/inversion findings")
-    ap.add_argument("--autotune", action="store_true",
-                    help="run the costmodel self-tuning stage: a TSD "
-                         "with the online fitter (and exploration) "
-                         "armed must install finite positive constants "
-                         "and never dispatch an infeasible mode")
     ap.add_argument("--cache", action="store_true",
                     help="run the partial-aggregate cache stage: a "
                          "cache-enabled TSD must answer byte-identical "
@@ -1853,7 +1717,7 @@ def main():
                          "resolve to retained slow-query captures")
     ap.add_argument("--stages-only", action="store_true",
                     help="run only the requested stage(s) "
-                         "(--overload/--autotune), skipping the "
+                         "(--overload/--cache/...), skipping the "
                          "standard 2-TSD fault-proxy phases — the CI "
                          "wrappers use this to gate stages separately")
     args = ap.parse_args()
@@ -1866,8 +1730,6 @@ def main():
         run_tenants_stage(args.port + 11, args.rounds)
     if args.latattr:
         run_latattr_stage(args.port + 15, args.rounds)
-    if args.autotune:
-        run_autotune_stage(args.port + 2, args.rounds)
     if args.cache:
         run_cache_stage(args.port + 5, args.rounds)
     if args.spill:
@@ -1875,10 +1737,10 @@ def main():
     if args.rollup:
         run_rollup_stage(args.port + 9, args.rounds)
     if args.stages_only:
-        if not (args.overload or args.autotune or args.cache
+        if not (args.overload or args.cache
                 or args.spill or args.rollup or args.tenants
                 or args.failover or args.latattr):
-            ap.error("--stages-only needs --overload, --autotune, "
+            ap.error("--stages-only needs --overload, "
                      "--cache, --spill, --rollup, --tenants, "
                      "--latattr and/or --failover")
         print("chaos soak stages PASSED (standard phases skipped: "

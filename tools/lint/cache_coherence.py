@@ -2,14 +2,12 @@
 read-set mutation must reach its registered invalidator.
 
 PR 6's review pass fixed three independent instances of one bug class —
-global state mutated without dropping the caches derived from it
-(`set_hysteresis` not clearing the jit mode caches, `reload_calibration`
-callers having to "remember the second half", `OnlineCalibrator`
-leaking process-global installs past shutdown).  Stale caches in this
-codebase produce *wrong answers*, not slow ones: a compiled program
-bakes mode policy in at trace time and keeps serving the old policy
-forever.  This analyzer makes the invalidation discipline a checked
-contract.
+global state mutated without dropping the caches derived from it (the
+kernel-mode setters and calibration layers of the time, gone since
+PR 29).  Stale caches in this codebase produce *wrong answers*, not
+slow ones: a block served after its points were rewritten, a handler
+left on the root logger.  This analyzer makes the invalidation
+discipline a checked contract.
 
 Model (three registries, one rule):
 
@@ -33,11 +31,9 @@ Model (three registries, one rule):
       bare-name / self / module-alias calls; attribute-devirtualized
       calls are deliberately excluded so read-sets stay tight).  A
       read of ANOTHER cache's backing global imports that cache's
-      read-set instead (read-through): the jit pipelines read the
-      cost-table cache `_COSTS`, so a mutation of `_LIVE` obligates
-      BOTH `reload_calibration` (the table's invalidator) and the jit
-      `clear_cache` set — which `reload_calibration` reaches
-      transitively.  Mutable = assigned under a `global` declaration,
+      read-set instead (read-through): a mutation behind one cache
+      obligates the invalidators of every cache reading through it.
+      Mutable = assigned under a `global` declaration,
       or mutated in place (`.clear()/.update()/[k] = ...`) on a module
       global, anywhere in a function body.
 
@@ -47,11 +43,10 @@ Model (three registries, one rule):
       non-exceptional path (statement walk in the resource_leak style:
       a `return` that crosses an undischarged obligation reports, and
       so does falling off the end).  Invalidators are recognized
-      TRANSITIVELY through single entry points: `set_scan_mode` is
-      coherent because it calls `_clear_dependent_caches`, and
-      `install_live_calibration` because it calls
-      `reload_calibration` — so deleting the cache-drop inside the
-      entry point fails every mutation site routed through it.
+      TRANSITIVELY through single entry points: a setter is coherent
+      because it calls the one function that drops the dependent
+      caches — so deleting the cache-drop inside the entry point
+      fails every mutation site routed through it.
       Exemptions: `__init__` bodies (pre-publication construction),
       the cache's own backing globals (fills/drops are the
       invalidator's business, checked by the gutted rule below), and
@@ -60,7 +55,7 @@ Model (three registries, one rule):
 
   paired installs
       `# global-install[: <uninstaller>] paired-with: <func>` marks a
-      process-global install site (live calibration layers, logging
+      process-global install site (logging
       handlers, compile-log subscriptions, patched factories).  The
       pairing function must exist (same class, then module), must call
       the named uninstaller, and must be reachable from a

@@ -80,7 +80,6 @@ Seeded contracts (the repo's real load-bearing orderings):
     response-write  before permit-release      (tsd/rpcs.py)
     wal-close       before flightrec-shutdown  (core/tsdb.py shutdown)
     spill-close     before flightrec-shutdown  (core/tsdb.py shutdown)
-    epoch-bump      before jit-cache-splice    (ops/downsample.py)
 
 Suppressions, SARIF, baseline and --changed-only all inherit from the
 runner; fixture/test scopes override the analyzed directories through

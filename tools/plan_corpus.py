@@ -4,8 +4,8 @@ PLAN_CORPUS.json.
 Every entry explains one query (optionally under what-if overrides)
 against a deterministic in-process TSDB profile and records the
 routing verdict — path, plan fingerprint, and the full discrete
-provenance (shapes, chosen kernel modes, lane/cache verdicts,
-calibration layer; never raw milliseconds) — via the SAME
+provenance (shapes, chosen kernel modes, lane/cache verdicts;
+never raw milliseconds) — via the SAME
 plan_decision() the executor dispatches on (query/plandecision.py).
 
 The committed PLAN_CORPUS.json is byte-pinned by a tier-1 test
@@ -159,7 +159,7 @@ ENTRIES = [
     # fingerprint (must equal resident_big's)
     ("resident_big_forced_modes", "base", "sum:30s-avg:corpus.big",
      BASE, BASE + 6000,
-     {"force_scan": "flat", "calibration": "default"}, False),
+     {"force_scan": "flat"}, False),
     ("rate_resident", "base", "sum:rate:30s-avg:corpus.big",
      BASE, BASE + 6000, {}, False),
     ("extreme_resident", "base", "max:30s-max:corpus.big",

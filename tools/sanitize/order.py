@@ -30,8 +30,8 @@ cached so a session pays for one tree walk at most):
                         observed all session — uncovered path or a
                         probe left behind after the tagged site moved.
                         Events with no probe (catch-up-pull,
-                        rejoin-ready, epoch-bump, jit-cache-splice,
-                        wal-close, spill-close, flightrec-shutdown,
+                        rejoin-ready, wal-close, spill-close,
+                        flightrec-shutdown,
                         permit-release) are exempt: they fire on
                         rejoin/shutdown paths a normal session never
                         takes, and an always-on gap report is noise.
