@@ -17,12 +17,33 @@ no index per stored point (`_gather_windows`).
 Design:
 
   * One entry per metric: every series' normalized (ts, val) columns
-    concatenated whole into two 1-D device buffers (padded to pow2
-    length, >= 1024, to bound the batch program's recompiles and to keep
-    the buffer a whole number of tile rows), plus host-side row offsets.
+    concatenated whole into 1-D device buffers (padded to pow2 length,
+    >= 1024, to bound the batch program's recompiles and to keep the
+    buffer a whole number of tile rows), plus host-side row offsets.
     The tail padding guarantees nothing to a reader: a row that runs
     past the data's or the buffer's end does so beyond its length, under
     the mask.
+  * What is pinned is what the chip reads.  A TPU has no 64-bit lanes:
+    XLA:TPU holds an int64 as two uint32 and a float64 as two float32,
+    and a program handed a 64-bit parameter cuts the WHOLE parameter
+    into its halves at entry (`X64SplitHigh` / `X64SplitLow`).  With
+    int64 + float64 buffers pinned, every batch assembly re-derived the
+    halves of all 2^26 elements before it copied the few tile rows it
+    came for: the four longest device operations of both one-chip
+    benchmark cells, 0.80 / 0.94 s of a 5 s trace and 26-27 % of the
+    chip's busy time (PERF_LEDGER.jsonl, PR 29, `breakdown.device_ops`;
+    PERF.md section 6, PR 30).  So an entry pins the halves themselves,
+    made once at build (`_pin_columns`): the timestamps' low and high
+    words, cut on the host, and the values' two float32 parts, cut by
+    the device's own arithmetic from one float64 upload that is then
+    dropped.  The batch that leaves is the same (int32 | int64 ts,
+    float64 val, bool mask), bit for bit.  Two float32 hold 48 bits of
+    a double; on a backend with real 64-bit floats (the CPU) an entry
+    with a value they cannot hold keeps the float64 buffer instead —
+    decided per entry by the split program's own exactness check, and
+    never true on a TPU, whose float64 is such a pair.  So does, on
+    every backend, an entry with a NaN or a value under 2^-74, whose
+    second half the TPU's arithmetic would flush (`_PAIR_FLOOR`).
   * Consistency is by content-version, not locks: `Series.snapshot()`
     captures (data, version) atomically; at query time every requested
     series' version is read and compared with the snapshot's, and the
@@ -67,7 +88,9 @@ PAD_TS = np.iinfo(np.int64).max
 # the parity test pins the two (clean-batch detection and pad sorting
 # both depend on the exact value).
 I32_PAD_TS = np.int32(2**31 - 2)
-_BYTES_PER_POINT = 16  # int64 ts + float64 val
+# two uint32 words of the timestamp + two float32 parts of the value (or
+# the one float64): what an entry pins, and what the budget counts
+_BYTES_PER_POINT = 16
 
 
 def _pad_pow2(n: int, floor: int = 8) -> int:
@@ -90,8 +113,14 @@ class _Entry:
     offsets: np.ndarray  # [S+1] int64 start offsets into the buffers
     ts_host: np.ndarray  # host [P] int64: the timestamps as pinned, for
     #                      the window bounds of every row in one pass
-    ts_dev: object     # device [P] int64 (pow2-padded, pads PAD_TS)
-    val_dev: object    # device [P] float64
+    pinned: tuple      # what the chip reads (`_pin_columns`): device [P]
+    #                    uint32 low words of the timestamps, [P] uint32
+    #                    high words (pow2-padded, pads PAD_TS's own words
+    #                    0xFFFFFFFF / 0x7FFFFFFF), and the values' parts:
+    #                    [P] float32 first and second half of each double
+    #                    as the device itself splits it — or the one [P]
+    #                    float64 where those two would not give every
+    #                    value back.  16 bytes a point either way.
     nbytes: int = 0
     tick: int = 0      # LRU clock
     stale: bool = field(default=False)
@@ -281,8 +310,7 @@ class DeviceSeriesCache:
             entry.tick = self._tick
             self.hits += 1
         self._emit_hit()
-        return _gather_windows(entry.ts_dev, entry.val_dev,
-                               starts, lengths, n, ts_base)
+        return _gather_windows(entry.pinned, starts, lengths, n, ts_base)
 
     def bounds_for(self, store, metric: int, series_list, start_ms: int,
                    end_ms: int) -> WindowBounds | None:
@@ -468,7 +496,7 @@ class DeviceSeriesCache:
                        series_objs=series_list,
                        versions=np.asarray(versions, np.int64),
                        offsets=offsets, ts_host=ts_buf,
-                       ts_dev=_to_device(ts_buf), val_dev=_to_device(val_buf),
+                       pinned=_pin_columns(ts_buf, val_buf),
                        nbytes=p * _BYTES_PER_POINT)
         ekey = (id(store), metric)
         with self._lock:
@@ -551,17 +579,93 @@ def _to_device(arr: np.ndarray):
 # series row, so there is no second form and no crossover.
 _TILE = 128
 
-# compiled gather programs keyed by (padded N, compaction flag) — the
-# closure reads only module constants (PAD_TS / I32_PAD_TS / _TILE), so
+# compiled programs of this module: the batch assembly keyed by (padded
+# N, compaction flag), the build-time value split under "split" — the
+# closures read only module constants (PAD_TS / I32_PAD_TS / _TILE), so
 # there is nothing to invalidate
 # cache: gather-programs invalidated-by: none
 _GATHER_CACHE: dict = {}
 
 
+def _join_values(parts):
+    """float64 from the pinned parts of a value: the first part widened,
+    each further part added where it is not zero (so a part of zeros
+    changes no bit of the sum, the sign of a zero included).  The one
+    recombination: the build's exactness check and the batch assembly
+    both run it."""
+    import jax.numpy as jnp
+
+    val = parts[0].astype(jnp.float64)
+    for part in parts[1:]:
+        val = jnp.where(part == 0, val, val + part.astype(jnp.float64))
+    return val
+
+
+def _split_program():
+    """The jitted float64[P] -> (hi float32[P], lo float32[P], exact)
+    run once per build: the two 32-bit parts of every value as THIS
+    backend's own arithmetic makes them, and whether `_join_values` gives
+    every value back.  On a TPU a float64 IS such a pair, so `hi` is its
+    first half as stored, `lo` its second, and `exact` holds; on a
+    backend with real 64-bit floats it holds for values of 48 bits or
+    fewer (every integer gauge) and fails for the rest."""
+    import jax
+    import jax.numpy as jnp
+
+    fn = _GATHER_CACHE.get("split")
+    if fn is not None:
+        return fn
+
+    def split(v):
+        hi = v.astype(jnp.float32)
+        lo = jnp.where(jnp.isfinite(hi),
+                       (v - hi.astype(jnp.float64)).astype(jnp.float32), 0)
+        return hi, lo, jnp.all(_join_values((hi, lo)) == v)
+    # memoized in _GATHER_CACHE just above: constructed once a process
+    fn = jax.jit(split)  # tsdblint: disable=jax-jit-per-call
+    _GATHER_CACHE["split"] = fn
+    return fn
+
+
+# Below this magnitude a double's second float32 half can be a float32
+# denormal, which the TPU's arithmetic flushes to zero where its copies
+# do not (measured: PERF.md section 6, PR 30): 2^-74, since the half is
+# a multiple of the double's last bit, 2^-52 of its leading one, and a
+# float32 is normal down to 2^-126.
+_PAIR_FLOOR = 2.0 ** -74
+
+
+def _pin_values(val_buf: np.ndarray) -> tuple:
+    """Host float64 values -> their pinned parts on the device: uploaded
+    once as float64, split by the device's own program, and the upload
+    dropped — unless the two parts would not give every value back bit
+    for bit, where the upload itself stays as the only part: a backend
+    with real 64-bit floats holding a value of more than 48 bits (the
+    program's own check), a NaN, or a value under `_PAIR_FLOOR`."""
+    val_dev = _to_device(np.ascontiguousarray(val_buf, np.float64))
+    size = np.abs(val_buf)
+    if size[size < _PAIR_FLOOR].any():      # one of them is not zero
+        return (val_dev,)
+    hi, lo, exact = _split_program()(val_dev)
+    return (hi, lo) if bool(exact) else (val_dev,)
+
+
+def _pin_columns(ts_buf: np.ndarray, val_buf: np.ndarray) -> tuple:
+    """Host int64 timestamps and float64 values -> what is pinned:
+    (ts_lo uint32, ts_hi uint32, value parts), each on the device and as
+    long as the input.  The timestamps' two words are cut on the host:
+    exact, and nothing 64 bits wide is uploaded for them."""
+    parts = _pin_values(val_buf)
+    words = np.ascontiguousarray(ts_buf, "<i8").view("<u4").reshape(-1, 2)
+    return (_to_device(np.ascontiguousarray(words[:, 0])),
+            _to_device(np.ascontiguousarray(words[:, 1])), parts)
+
+
 def _gather_program(n: int, compact: bool):
-    """The jitted (tb, vb, starts, lengths, base) -> (ts, val, mask)
-    batch assembly for padded row length `n`, memoized per (n, compact);
-    jit itself specializes it per buffer length and row count."""
+    """The jitted (ts_lo, ts_hi, value parts, starts, lengths, base) ->
+    (ts, val, mask) batch assembly for padded row length `n`, memoized
+    per (n, compact); jit itself specializes it per buffer length, row
+    count and the parts' types."""
     import jax
     import jax.numpy as jnp
 
@@ -573,9 +677,9 @@ def _gather_program(n: int, compact: bool):
     # its first tile: lane offset <= _TILE - 1
     tiles = (n + 2 * _TILE - 2) // _TILE
 
-    def gather(tb, vb, st, ln, base):
+    def gather(tl, th, vals, st, ln, base):
         m = jnp.arange(n, dtype=jnp.int64)[None, :] < ln[:, None]
-        st = st.astype(jnp.int32 if tb.shape[0] < 2**31 else jnp.int64)
+        st = st.astype(jnp.int32 if tl.shape[0] < 2**31 else jnp.int64)
         lane = st % _TILE
         # laid [tiles, S]: the [S, tiles] matrix's flatten takes XLA:TPU
         # tens of seconds to compile at S = 100 000
@@ -600,13 +704,16 @@ def _gather_program(n: int, compact: bool):
                 k //= 2
             return x[:, :n]
 
+        # the 64-bit forms exist on the [S, n] result alone
+        stamps = (copy_rows(th).astype(jnp.int64) << 32) \
+            | copy_rows(tl).astype(jnp.int64)
         if compact:
-            off = jnp.clip(copy_rows(tb) - base, 0, I32_PAD_TS) \
-                .astype(jnp.int32)
+            off = jnp.clip(stamps - base, 0, I32_PAD_TS).astype(jnp.int32)
             ts = jnp.where(m, off, I32_PAD_TS)
         else:
-            ts = jnp.where(m, copy_rows(tb), PAD_TS)
-        val = jnp.where(m, copy_rows(vb), 0.0)
+            ts = jnp.where(m, stamps, PAD_TS)
+        val = jnp.where(
+            m, _join_values(tuple(copy_rows(v) for v in vals)), 0.0)
         return ts, val, m
     # memoized per (N, compaction) in _GATHER_CACHE just above — the
     # wrapper is constructed once per padded batch shape, not per call
@@ -615,18 +722,21 @@ def _gather_program(n: int, compact: bool):
     return fn
 
 
-def _gather_windows(ts_buf, val_buf, starts, lengths, n: int,
+def _gather_windows(pinned: tuple, starts, lengths, n: int,
                     ts_base: int | None = None):
-    """One-dispatch on-device batch assembly from the pinned buffers.
+    """One-dispatch on-device batch assembly from what `_pin_columns`
+    pinned.
 
     out[i, j] = buf[starts[i] + j] masked to j < lengths[i]; pads mirror
     build_batch (PAD_TS timestamps keep rows sorted for the prefix path).
     Every row is one contiguous run of the buffer (series are
     concatenated whole at build), so it is copied as whole 128-element
-    tile rows and shifted to its start — no per-point index.  `starts`
-    may be anything where `lengths` is 0, and a row may run past the
-    buffer's end beyond its length: neither is read under the mask.
-    Compiled once per (buffer length, S, N) — buffer and N pow2-padded.
+    tile rows and shifted to its start — no per-point index — from each
+    32-bit buffer, and the int64 / float64 the caller gets is put
+    together on the [S, n] result.  `starts` may be anything where
+    `lengths` is 0, and a row may run past the buffer's end beyond its
+    length: neither is read under the mask.  Compiled once per (buffer
+    length, S, N) — buffer and N pow2-padded.
 
     With `ts_base`, timestamps come back as int32 offsets from the base
     (the compaction fused into this program — the query dispatch already
@@ -637,4 +747,4 @@ def _gather_windows(ts_buf, val_buf, starts, lengths, n: int,
 
     base = jnp.asarray(0 if ts_base is None else ts_base, jnp.int64)
     return _gather_program(n, ts_base is not None)(
-        ts_buf, val_buf, jnp.asarray(starts), jnp.asarray(lengths), base)
+        *pinned, jnp.asarray(starts), jnp.asarray(lengths), base)

@@ -370,30 +370,88 @@ def _gather_cases(p=8192):
 
 GATHER_CASES = _gather_cases()
 
+T0 = 1_356_998_400_000
+
+
+def _fill(kind, rng, data):
+    """(timestamps, values) of `data` stored points of one kind: what the
+    pinned 32-bit halves must carry without losing a bit."""
+    ts = T0 + np.cumsum(rng.integers(0, 2_000_000, data))
+    val = rng.normal(size=data) * 1e6
+    if kind == "doubles":
+        # 53-bit mantissas and signed zeros; some stamps lie before the
+        # ts_base the test passes and some 2^31 ms past it, so the int32
+        # clip is hit at both ends
+        val[::97] = -0.0
+    elif kind == "integer_gauges":
+        val = rng.integers(0, 101, data).astype(np.float64)
+        val[::97] = -0.0    # the two-float32 form keeps a zero's sign too
+    elif kind == "negative_values":
+        val = -np.abs(val)
+        val[::3] = -rng.integers(1, 2**40, len(val[::3])) / 4.0
+    elif kind == "magnitudes_to_1e15":
+        val = rng.choice([-1.0, 1.0], data) \
+            * 10.0 ** rng.uniform(-15, 15, data)
+        val[::5] = rng.integers(-10**15, 10**15, len(val[::5]))
+        val[:6] = [1e15, -1e15, 2.0**48, 2.0**48 + 1, 2.0**53 - 1, 1e-15]
+    elif kind == "under_the_pair_floor":
+        # magnitudes whose second float32 half would be a denormal, down
+        # to the doubles' own denormals: such an entry keeps its float64
+        val = rng.choice([-1.0, 1.0], data) \
+            * 10.0 ** rng.uniform(-320, -22, data)
+        val[:4] = [5e-324, -5e-324, 2.0 ** -75, -1e-30]
+    elif kind == "stamps_across_2_32":
+        # the low word wraps (a multiple of 2^32 ms) and changes sign as
+        # an int32 (2^31 inside a word) in the middle of the data
+        ts = 316 * 2**32 - data // 2 + np.arange(data)
+        ts[data // 4:] += 2**31 - data // 4
+        ts = np.sort(ts)
+    elif kind == "stamps_around_the_epoch":
+        # 2^32 ms itself (1970-02-19), a high word of 0 and of 1
+        ts = 2**32 - data // 2 + np.arange(data) * 3
+    else:
+        raise AssertionError(kind)
+    return ts.astype(np.int64), val
+
+
+FILL_KINDS = ["doubles", "integer_gauges", "negative_values",
+              "magnitudes_to_1e15", "under_the_pair_floor",
+              "stamps_across_2_32", "stamps_around_the_epoch"]
+
+
+def _join_on_host(pinned):
+    """The pinned halves put together with numpy: (int64 ts, float64 val)."""
+    ts_lo, ts_hi, parts = pinned
+    ts = (np.asarray(ts_hi).astype(np.int64) << 32) \
+        | np.asarray(ts_lo).astype(np.int64)
+    val = np.asarray(parts[0]).astype(np.float64)
+    for part in parts[1:]:
+        part = np.asarray(part).astype(np.float64)
+        val = np.where(part == 0, val, val + part)   # keeps a zero's sign
+    return ts, val
+
 
 class TestGatherParity:
     """_gather_windows against a plain per-element numpy reference,
     bit for bit on ts, val and mask, pads included."""
 
-    @pytest.mark.parametrize("ts_base", [None, 1_356_998_400_000 + 1_234],
+    @pytest.mark.parametrize("ts_base", [None, T0 + 1_234],
                              ids=["int64", "ts_base"])
+    @pytest.mark.parametrize("kind", FILL_KINDS)
     @pytest.mark.parametrize("case", sorted(GATHER_CASES))
-    def test_gather_equals_the_per_element_reference(self, case, ts_base):
-        import jax.numpy as jnp
+    def test_gather_equals_the_per_element_reference(self, case, kind,
+                                                     ts_base):
         from opentsdb_tpu.storage.device_cache import (
-            I32_PAD_TS, PAD_TS, _gather_windows)
+            I32_PAD_TS, PAD_TS, _gather_windows, _pin_columns)
         p, data, n, starts, lengths = GATHER_CASES[case]
         starts = np.asarray(starts, np.int64)
         lengths = np.asarray(lengths, np.int64)
         rng = np.random.default_rng(sorted(GATHER_CASES).index(case))
         ts_buf = np.full(p, PAD_TS, np.int64)
-        # some stamps lie before the base and some 2^31 ms past it: the
-        # int32 clip is hit at both ends
-        ts_buf[:data] = 1_356_998_400_000 + np.cumsum(
-            rng.integers(0, 2_000_000, data))
         val_buf = np.zeros(p)
-        val_buf[:data] = rng.normal(size=data) * 1e6
-        val_buf[:data:97] = -0.0
+        ts_buf[:data], val_buf[:data] = _fill(kind, rng, data)
+        if kind == "stamps_around_the_epoch" and ts_base is not None:
+            ts_base = 2**32 - 1_000
 
         j = np.arange(n)
         mask = j[None, :] < lengths[:, None]
@@ -406,8 +464,7 @@ class TestGatherParity:
         val = np.where(mask, val_buf[at], 0.0)
 
         got_ts, got_val, got_mask = (np.asarray(a) for a in _gather_windows(
-            jnp.asarray(ts_buf), jnp.asarray(val_buf), starts, lengths, n,
-            ts_base))
+            _pin_columns(ts_buf, val_buf), starts, lengths, n, ts_base))
         assert got_ts.dtype == ts.dtype and got_ts.shape == ts.shape
         assert got_val.dtype == np.float64 and got_mask.dtype == np.bool_
         np.testing.assert_array_equal(got_mask, mask)
@@ -415,3 +472,90 @@ class TestGatherParity:
         # bit-equal, signed zeros included
         np.testing.assert_array_equal(got_val.view(np.int64),
                                       val.view(np.int64))
+
+
+class TestPinnedHalves:
+    """What `_pin_columns` pins gives the 64-bit columns back exactly."""
+
+    def test_pad_stamps_pin_as_their_own_words(self):
+        """PAD_TS is 0x7FFFFFFF / 0xFFFFFFFF in the pinned words, and a
+        pad comes back from the gather as PAD_TS or, compacted, as
+        I32_PAD_TS — the sentinels the prefix path sorts by."""
+        from opentsdb_tpu.storage.device_cache import (
+            I32_PAD_TS, PAD_TS, _gather_windows, _pin_columns)
+        pinned = _pin_columns(np.full(1024, PAD_TS, np.int64),
+                              np.zeros(1024))
+        assert np.asarray(pinned[0]).dtype == np.uint32
+        assert np.asarray(pinned[1]).dtype == np.uint32
+        assert (np.asarray(pinned[0]) == 0xFFFFFFFF).all()
+        assert (np.asarray(pinned[1]) == 0x7FFFFFFF).all()
+        starts, lengths = np.array([0, 500]), np.array([8, 8])
+        ts, _, _ = _gather_windows(pinned, starts, lengths, 8)
+        assert np.asarray(ts).dtype == np.int64
+        assert (np.asarray(ts) == PAD_TS).all()
+        ts, _, _ = _gather_windows(pinned, starts, lengths, 8, ts_base=T0)
+        assert np.asarray(ts).dtype == np.int32
+        assert (np.asarray(ts) == I32_PAD_TS).all()
+
+    @pytest.mark.parametrize("kind", FILL_KINDS)
+    def test_pinned_columns_join_to_the_input(self, kind):
+        from opentsdb_tpu.storage.device_cache import _pin_columns
+        ts_buf, val_buf = _fill(kind, np.random.default_rng(7), 3000)
+        pinned = _pin_columns(ts_buf, val_buf)
+        assert all(len(np.asarray(b)) == 3000
+                   for b in (*pinned[:2], *pinned[2]))
+        ts, val = _join_on_host(pinned)
+        np.testing.assert_array_equal(ts, ts_buf)
+        np.testing.assert_array_equal(val.view(np.int64),
+                                      val_buf.view(np.int64))
+
+    def test_values_of_48_bits_pin_as_two_float32(self):
+        """Integer gauges — every benchmark cell's data — take the form
+        the chip reads on every backend; only a value that two float32
+        cannot hold keeps the 64-bit buffer (never on a TPU, whose
+        float64 is such a pair)."""
+        from opentsdb_tpu.storage.device_cache import _pin_values
+        parts = _pin_values(np.arange(-500, 524, dtype=np.float64) * 0.25)
+        assert [np.asarray(b).dtype for b in parts] == [np.float32] * 2
+        import jax
+        if jax.default_backend() == "cpu":
+            parts = _pin_values(np.full(1024, 0.1))
+            assert [np.asarray(b).dtype for b in parts] == [np.float64]
+        # a second half in float32's denormal range, which a TPU's
+        # arithmetic would flush: the float64 stays, on every backend
+        tiny = np.arange(1024, dtype=np.float64)
+        tiny[7] = 2.0 ** -75
+        parts = _pin_values(tiny)
+        assert [np.asarray(b).dtype for b in parts] == [np.float64]
+
+    @pytest.mark.parametrize("values", ["integers", "fractions"])
+    def test_a_built_entry_joins_to_the_snapshot(self, values):
+        """`_build_guarded`'s buffers, fetched and joined on the host, are
+        every series' snapshot concatenated, bit for bit, with PAD_TS /
+        0.0 behind; 16 bytes a point are accounted as before."""
+        from opentsdb_tpu.storage.device_cache import PAD_TS
+        tsdb = TSDB(Config(BASE_CONF))
+        rng = np.random.default_rng(3)
+        for host in "abc":
+            for i in range(50):
+                v = float(rng.integers(-100, 100)) if values == "integers" \
+                    else float(rng.normal() * 1e3)
+                tsdb.add_point("dc.m", BASE + i * 10, v, {"host": host})
+        metric = tsdb.metrics.get_id("dc.m")
+        cache = DeviceSeriesCache(max_bytes=1 << 30)
+        entry = cache._build(tsdb.store, metric)
+        snaps = [s.snapshot(True) for s in entry.series_objs]
+        want_ts = np.concatenate([t for t, _, _ in snaps])
+        want_val = np.concatenate([v for _, v, _ in snaps])
+        ts, val = _join_on_host(entry.pinned)
+        total = len(want_ts)
+        assert len(ts) == len(val) == 1024 and entry.nbytes == 1024 * 16
+        np.testing.assert_array_equal(ts[:total], want_ts)
+        np.testing.assert_array_equal(ts, entry.ts_host)
+        assert (ts[total:] == PAD_TS).all()
+        np.testing.assert_array_equal(val[:total].view(np.int64),
+                                      want_val.view(np.int64))
+        assert (val[total:].view(np.int64) == 0).all()
+        pinned_bytes = sum(np.asarray(b).nbytes
+                           for b in (*entry.pinned[:2], *entry.pinned[2]))
+        assert pinned_bytes == entry.nbytes

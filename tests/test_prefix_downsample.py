@@ -535,13 +535,12 @@ class TestPrecompactedBatches:
         """The device cache's ts_base gather must emit exactly this
         contract: int32 dtype, offsets from base, pads at the clip
         ceiling."""
-        from opentsdb_tpu.storage.device_cache import _gather_windows
-        import jax.numpy as jnp
+        from opentsdb_tpu.storage.device_cache import (_gather_windows,
+                                                       _pin_columns)
         buf_ts = np.array([START + 10, START + 20, START + 30, START + 40],
                           np.int64)
         buf_val = np.array([1.0, 2.0, 3.0, 4.0])
-        ts, val, m = _gather_windows(jnp.asarray(buf_ts),
-                                     jnp.asarray(buf_val),
+        ts, val, m = _gather_windows(_pin_columns(buf_ts, buf_val),
                                      np.array([0, 2]), np.array([2, 1]),
                                      4, ts_base=START)
         ts = np.asarray(ts)

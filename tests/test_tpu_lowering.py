@@ -138,16 +138,26 @@ def _compile_all() -> None:
 
     for name, (n, compact) in zip(GATHER_NAMES, GATHER_CASES):
         try:
+            # what `_pin_columns` pins on a TPU: two uint32 words of each
+            # timestamp, the two float32 halves of each value
             text = _gather_program(n, compact).lower(
-                on_chip((GATHER_BUFFER,), jnp.int64),
-                on_chip((GATHER_BUFFER,), jnp.float64),
+                on_chip((GATHER_BUFFER,), jnp.uint32),
+                on_chip((GATHER_BUFFER,), jnp.uint32),
+                (on_chip((GATHER_BUFFER,), jnp.float32),
+                 on_chip((GATHER_BUFFER,), jnp.float32)),
                 on_chip((GATHER_ROWS,), jnp.int64),
                 on_chip((GATHER_ROWS,), jnp.int64),
                 on_chip((), jnp.int64)).compile().as_text()
             sizes = re.findall(r" gather\(.*slice_sizes=\{([0-9,]*)\}", text)
+            # every X64Split / X64Combine by the shape of its result
+            x64 = re.findall(
+                r"= \w+\[([0-9,]*)\]\S* custom-call\(.*"
+                r"custom_call_target=\"(X64\w+)\"", text)
             print(json.dumps({"case": name, "ok": True,
                               "gather_slice_sizes": sorted(sizes),
-                              "whiles": len(re.findall(r" while\(", text))}),
+                              "whiles": len(re.findall(r" while\(", text)),
+                              "x64_calls": sorted(
+                                  "%s[%s]" % (t, dims) for dims, t in x64)}),
                   flush=True)
         except Exception as e:  # noqa: BLE001 — the verdict under test
             print(json.dumps({"case": name, "ok": False,
@@ -179,13 +189,31 @@ def test_mesh_program_lowers_for_v5e_2x2(verdicts, case):
 
 @pytest.mark.parametrize("case", GATHER_NAMES)
 def test_cache_gather_copies_tile_rows_on_a_v5e(verdicts, case):
-    """Four gathers (int64 and float64 buffers, two 32-bit halves each),
+    """Four gathers (the timestamps' two words, the values' two halves),
     every one of whole 128-element tile rows; none with one index per
     point, and no loop with a step per series row."""
     verdict = verdicts[case]
     assert verdict["ok"], verdict.get("error")
     assert verdict["gather_slice_sizes"] == ["1,128"] * 4, verdict
     assert verdict["whiles"] == 0, verdict
+
+
+@pytest.mark.parametrize("case", GATHER_NAMES)
+def test_cache_gather_splits_no_pinned_buffer_on_a_v5e(verdicts, case):
+    """No 64-bit form of a pinned buffer exists in the program: every
+    X64Split is of the [rows] start / length vectors or the scalar base,
+    every X64Combine of the [rows, N] batch that leaves — none has
+    GATHER_BUFFER elements, and at least one was seen (the pattern still
+    reads this compiler's text)."""
+    verdict = verdicts[case]
+    assert verdict["ok"], verdict.get("error")
+    calls = verdict["x64_calls"]
+    assert any(c.startswith("X64Split") for c in calls), verdict
+    assert any(c.startswith("X64Combine") for c in calls), verdict
+    n = int(case.split(":")[1][1:])
+    allowed = {"[]", "[%d]" % GATHER_ROWS, "[%d,%d]" % (GATHER_ROWS, n)}
+    assert {c[c.index("["):] for c in calls} <= allowed, verdict
+    assert not any(str(GATHER_BUFFER) in c for c in calls), verdict
 
 
 if __name__ == "__main__":
