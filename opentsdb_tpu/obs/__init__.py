@@ -96,6 +96,16 @@ METRICS_SCHEMA: dict[str, MetricSpec] = {
         "skipped; full = at least one had.  On the mesh dense means "
         "every shard's rows.  Tiled and lane-folded executions run "
         "one contribution program a tile and count nothing here."),
+    "tsd.query.rate_lane": _m(
+        "counter", ("lane",),
+        "Grouped rate dispatches answered by one device program, by "
+        "the lane that program's rate took to find each window's "
+        "previous value (ops/rate.py): shift = no row of the [S, W] "
+        "grid had a hole between two present windows, so the previous "
+        "value is the window before; scan = at least one had, and a "
+        "prefix scan and two per-cell gathers skip it.  Counted where "
+        "tsd.query.contrib_lane is, from the same fetch; on the mesh "
+        "shift means every shard's rows."),
     "tsd.http.response_bytes": _m(
         "counter", ("route",),
         "Response body bytes written, by registered route."),

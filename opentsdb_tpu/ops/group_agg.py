@@ -30,7 +30,7 @@ import jax.numpy as jnp
 
 from opentsdb_tpu.ops.aggregators import Aggregator
 from opentsdb_tpu.ops.downsample import parse_percentile_name
-from opentsdb_tpu.ops.rate import _prev_valid_index
+from opentsdb_tpu.ops.rate import _no_interior_hole, _prev_valid_index
 from opentsdb_tpu.ops.union_agg import interpolate, _next_valid
 
 _I64_MAX = jnp.iinfo(jnp.int64).max
@@ -48,6 +48,9 @@ def _seg_dtype(num: int):
 MOMENT_AGGS = frozenset({
     "sum", "zimsum", "pfsum", "count", "avg", "min", "mimmin", "max",
     "mimmax", "dev", "squareSum"})
+
+
+_EXTREME_AGGS = ("min", "mimmin", "max", "mimmax")
 
 
 def is_moment_agg(name: str) -> bool:
@@ -241,18 +244,6 @@ def _run_folds(mode: str, gid, num_groups: int, s: int, rows_sorted: bool):
         else _SortedGroups(gid, num_groups, s, rows_sorted)
 
 
-def _no_interior_hole(mask):
-    """mask[S, W] -> bool[]: every row is one contiguous run of True, or
-    all False.  A run starts where a True follows a False (or sits in
-    column 0); a row with at most one start has no hole between two
-    present windows.  One fused elementwise pass and a row reduction —
-    no scan, no gather, no 64-bit arithmetic."""
-    starts = mask[:, 1:] & ~mask[:, :-1]
-    rises = mask[:, 0].astype(jnp.int32) \
-        + jnp.sum(starts, axis=1, dtype=jnp.int32)
-    return jnp.all(rises <= 1)
-
-
 def grid_contributions(grid_ts, val, mask, agg: Aggregator):
     """Per-series contribution + participation at every grid slot.
 
@@ -349,7 +340,7 @@ def moment_group_reduce(agg_name: str, contrib, participate, gid,
     s, w = contrib.shape
     g = num_groups
     num = g * w
-    extremes = agg_name in ("min", "mimmin", "max", "mimmax")
+    extremes = agg_name in _EXTREME_AGGS
     mode = _effective_group_reduce_mode(s, w, g, extremes=extremes,
                                         row_groups=row_groups)
 
@@ -543,6 +534,46 @@ def ordered_group_reduce(agg_name: str, contrib, participate, gid,
     return out, cnt
 
 
+# shape: mask[S,W] bool, gid[S] any
+def group_presence(mask, gid, num_groups: int, extremes: bool = False,
+                   rows_sorted: bool = False, row_groups: bool = False):
+    """[S, W] actual-value mask + gid[S] -> bool[G, W]: group g has a
+    member present in window w (the out-mask rule of every grouped
+    tail; rows with gid outside [0, G) belong to no group).
+
+    The pass rides the form the reduce beside it took — `extremes` is
+    moment_group_reduce's own flag, so the chooser answers as it did
+    there — or a pick of sorted / matmul, made to keep a dispatch
+    scatter-free, would get the scatter back through its mask (review
+    r5; on the chip a [S*W] -> [G*W] scatter was the longest operation
+    of a sum tail, PERF.md section 6, PR 33).  sorted / rows: the run
+    folds.  matmul: the reduce's one-hot contraction over 0/1 operands
+    in float32, exact while a count stays under 2^24 (_matmul_feasible
+    caps S far below).  segment, the CPU's pick: segment_sum.
+    """
+    s, w = mask.shape
+    g = num_groups
+    mode = _effective_group_reduce_mode(s, w, g, extremes=extremes,
+                                        row_groups=row_groups)
+    if mode in ("sorted", "rows"):
+        # same fold machinery as the reduce (XLA CSEs the repeated
+        # argsort/bounds)
+        sg = _run_folds(mode, gid, g, s, rows_sorted)
+        return sg.sum(mask.astype(jnp.float64)) > 0
+    if mode == "matmul":
+        onehot_t = (gid[None, :] == jnp.arange(g, dtype=gid.dtype)[:, None])
+        present = jnp.dot(onehot_t.astype(jnp.float32),
+                          mask.astype(jnp.float32),
+                          preferred_element_type=jnp.float32)
+        return present > 0
+    dt = _seg_dtype(g * w + w)
+    cols = jnp.arange(w, dtype=dt)[None, :]
+    seg = (gid.astype(dt)[:, None] * w + cols).reshape(-1)
+    present = jax.ops.segment_sum(mask.reshape(-1).astype(jnp.int32), seg,
+                                  num_segments=g * w)
+    return present.reshape(g, w) > 0
+
+
 # shape: grid_ts[W] i64, val[S,W] any, mask[S,W] bool, gid[S] any
 def grid_group_aggregate(grid_ts, val, mask, gid, num_groups: int,
                          agg: Aggregator, rows_sorted: bool = False,
@@ -572,28 +603,8 @@ def grid_group_aggregate(grid_ts, val, mask, gid, num_groups: int,
     else:
         out, _ = ordered_group_reduce(agg.name, contrib, participate, gid,
                                       num_groups)
-    s, w = val.shape
-    # same extremes flag as moment_group_reduce's own decision: the mask
-    # pass must ride the form the reduce actually took, or a pick
-    # of matmul (excluded for extremes) would put the segment scatter
-    # back into a dispatch the sorted mode was chosen to keep
-    # scatter-free (review r5)
-    extreme_agg = agg.name in ("min", "mimmin", "max", "mimmax")
-    mask_mode = _effective_group_reduce_mode(
-        s, w, num_groups,
-        extremes=is_moment_agg(agg.name) and extreme_agg,
-        row_groups=row_groups)
-    if mask_mode in ("sorted", "rows"):
-        # same fold machinery as the reduce (XLA CSEs the repeated
-        # argsort/bounds)
-        sg = _run_folds(mask_mode, gid, num_groups, s, rows_sorted)
-        out_mask = sg.sum(mask.astype(jnp.float64)) > 0
-    else:
-        dt = _seg_dtype(num_groups * w + w)
-        cols = jnp.arange(w, dtype=dt)[None, :]
-        seg = (gid.astype(dt)[:, None] * w + cols).reshape(-1)
-        present = jax.ops.segment_sum(
-            mask.reshape(-1).astype(jnp.int32), seg,
-            num_segments=num_groups * w)
-        out_mask = present.reshape(num_groups, w) > 0
+    out_mask = group_presence(mask, gid, num_groups,
+                              extremes=agg.name in _EXTREME_AGGS,
+                              rows_sorted=rows_sorted,
+                              row_groups=row_groups)
     return grid_ts, out, out_mask, dense

@@ -68,11 +68,11 @@ def _pipeline(spec: PipelineSpec, ts, val, mask, wargs):
         grid = jnp.asarray(wts)
         if spec.rate is not None:
             grid_b = jnp.broadcast_to(grid[None, :], v.shape)
-            _, v, m = rate(grid_b, v, m, spec.rate, all_int=False)
+            _, v, m, _ = rate(grid_b, v, m, spec.rate, all_int=False)
         return grid_aggregate(grid, v, m, agg, int_mode=False)
     if spec.rate is not None:
-        work_ts, work_val, work_mask = rate(ts, val, mask, spec.rate,
-                                            all_int=spec.int_mode)
+        work_ts, work_val, work_mask, _ = rate(ts, val, mask, spec.rate,
+                                               all_int=spec.int_mode)
         return union_aggregate(work_ts, work_val, work_mask, agg,
                                int_mode=False, tile_cells=spec.tile_cells)
     return union_aggregate(ts, val, mask, agg, int_mode=spec.int_mode,
@@ -137,7 +137,7 @@ def _rollup_avg_pipeline(spec: PipelineSpec, ts_s, val_s, mask_s,
     if spec.rate is not None:
         agg = Aggregator(agg.name, PREV, agg.reduce)
         grid_b = jnp.broadcast_to(grid[None, :], v.shape)
-        _, v, m = rate(grid_b, v, m, spec.rate, all_int=False)
+        _, v, m, _ = rate(grid_b, v, m, spec.rate, all_int=False)
     return grid_aggregate(grid, v, m, agg, int_mode=False)
 
 
@@ -166,24 +166,32 @@ def _group_pipeline(spec: PipelineSpec, num_groups: int, ts, val, mask, gid,
     return _grid_tail(spec, num_groups, wts, v, m, gid)
 
 
+# The fourth return of every grouped program: one int32 scalar whose
+# bits say which lane each `lax.cond` of the tail took on the device.
+LANE_DENSE = 1      # grid_contributions skipped interpolation
+LANE_SHIFT = 2      # rate's previous point was a shift (0 without rate)
+
+
 def _grid_tail(spec: PipelineSpec, num_groups: int, wts, v, m, gid):
     """Shared pipeline tail: (rate ->) grouped cross-series aggregation on
     an already-downsampled [S, W] grid.  Also the finish stage of the
     streaming executor (ops.streaming hands it the accumulated grid).
-    Like every grouped program here it returns grid_group_aggregate's
-    four: the answer's triple and `dense`, the contribution lane the
-    device took (ops/group_agg.py::grid_contributions)."""
+    Like every grouped program here it returns four: the answer's triple
+    and `lanes`, the lanes the device took (LANE_DENSE:
+    ops/group_agg.py::grid_contributions, LANE_SHIFT: ops/rate.py)."""
     from opentsdb_tpu.ops.group_agg import grid_group_aggregate
     agg = get_agg(spec.aggregator)
+    grid = jnp.asarray(wts)
+    lanes = jnp.int32(0)
     if spec.rate is not None:
         agg = Aggregator(agg.name, PREV, agg.reduce)
-    grid = jnp.asarray(wts)
-    if spec.rate is not None:
         grid_b = jnp.broadcast_to(grid[None, :], v.shape)
-        _, v, m = rate(grid_b, v, m, spec.rate, all_int=False)
-    return grid_group_aggregate(grid, v, m, gid, num_groups, agg,
-                                rows_sorted=spec.rows_sorted,
-                                row_groups=spec.row_groups)
+        _, v, m, shift = rate(grid_b, v, m, spec.rate, all_int=False)
+        lanes = LANE_SHIFT * shift.astype(jnp.int32)
+    wts, out, out_mask, dense = grid_group_aggregate(
+        grid, v, m, gid, num_groups, agg, rows_sorted=spec.rows_sorted,
+        row_groups=spec.row_groups)
+    return wts, out, out_mask, lanes + LANE_DENSE * dense.astype(jnp.int32)
 
 
 def _downsample_grid(step: DownsampleStep, ts, val, mask, wargs):
@@ -241,7 +249,7 @@ def _stacked_group_pipeline(spec: PipelineSpec, num_groups: int, ts, val,
     (stacked along axis 0), and inside the vmap the kernels trace on
     the per-member [S, N] shapes, so the mode choosers pick exactly
     what a solo dispatch of the same member would.  Per-member results
-    come back batched ([Q, W], [Q, G, W], [Q, G, W], [Q]) for host-side
+    come back batched ([Q, W], [Q, G, W], [Q, G, W], lanes[Q]) for host-side
     unpack; on integer data a member's slice is bitwise what its solo
     dispatch would produce (integer-exact f64 accumulation is
     reassociation-proof — the same contract the rollup lanes pin).
@@ -262,7 +270,7 @@ _jitted_lane_partials = jax.jit(_lane_partials, static_argnums=0)
 
 def run_grid_tail(spec: PipelineSpec, wts, v, m, gid, num_groups: int):
     """Finish a streamed query: grid [S, W] -> (wts, out[G, W],
-    mask[G, W], dense[])."""
+    mask[G, W], lanes[])."""
     return _jitted_grid_tail(spec, num_groups, wts, v, m, gid)
 
 
@@ -270,10 +278,10 @@ def run_grid_tail(spec: PipelineSpec, wts, v, m, gid, num_groups: int):
 def run_stacked_group_pipeline(spec: PipelineSpec, ts, val, mask, gid,
                                num_groups: int, wargs: dict):
     """Q stacked grouped pipelines -> (wts[Q, W], out[Q, G, W],
-    mask[Q, G, W], dense[Q]) — the batcher's one-launch form of
+    mask[Q, G, W], lanes[Q]) — the batcher's one-launch form of
     run_group_pipeline; `wargs` values carry a leading member axis.
-    Under the vmap grid_contributions' cond is a select (both branches
-    run); dense[q] still says which answer member q got."""
+    Under the vmap the tail's conds are selects (both branches run);
+    lanes[q] still says which answers member q got."""
     if spec.downsample is None:
         raise ValueError("grouped pipeline requires a downsample step")
     return _jitted_stacked_group(spec, num_groups, ts, val, mask, gid,
@@ -297,7 +305,7 @@ def run_lane_partials(spec: WindowSpec, ts, val, mask, wargs: dict):
 def run_group_pipeline(spec: PipelineSpec, ts, val, mask, gid,
                        num_groups: int, wargs: dict | None = None):
     """Execute the grouped pipeline -> (wts[W], out[G, W], out_mask[G, W],
-    dense[]).
+    lanes[]).
 
     Requires a downsample step (the shared grid is what makes the segmented
     cross-series reduce possible); union-timestamp queries keep the
@@ -311,7 +319,6 @@ def run_group_pipeline(spec: PipelineSpec, ts, val, mask, gid,
 def _group_rollup_avg(spec: PipelineSpec, num_groups: int, ts_s, val_s,
                       mask_s, ts_c, val_c, mask_c, gid, wargs):
     """Grouped rollup-avg read: sum/count lane division, then the grid tail."""
-    from opentsdb_tpu.ops.group_agg import grid_group_aggregate
     step = spec.downsample
     wts, sums, msum = downsample(ts_s, val_s, mask_s, "sum", step.window_spec,
                                  wargs, FILL_NONE)
@@ -323,15 +330,7 @@ def _group_rollup_avg(spec: PipelineSpec, num_groups: int, ts_s, val_s,
     live = jnp.arange(v.shape[-1]) < nwin
     v, m = apply_fill(v, ok, live[None, :], step.fill_policy,
                       step.fill_value)
-    grid = jnp.asarray(wts)
-    agg = get_agg(spec.aggregator)
-    if spec.rate is not None:
-        agg = Aggregator(agg.name, PREV, agg.reduce)
-        grid_b = jnp.broadcast_to(grid[None, :], v.shape)
-        _, v, m = rate(grid_b, v, m, spec.rate, all_int=False)
-    return grid_group_aggregate(grid, v, m, gid, num_groups, agg,
-                                rows_sorted=spec.rows_sorted,
-                                row_groups=spec.row_groups)
+    return _grid_tail(spec, num_groups, wts, v, m, gid)
 
 
 _jitted_group_rollup_avg = jax.jit(_group_rollup_avg, static_argnums=(0, 1))
@@ -341,7 +340,7 @@ def run_group_rollup_avg_pipeline(spec: PipelineSpec, ts_s, val_s, mask_s,
                                   ts_c, val_c, mask_c, gid, num_groups: int,
                                   wargs: dict | None = None):
     """Grouped rollup-avg pipeline -> (wts[W], out[G, W], out_mask[G, W],
-    dense[])."""
+    lanes[])."""
     return _jitted_group_rollup_avg(spec, num_groups, ts_s, val_s, mask_s,
                                     ts_c, val_c, mask_c, gid, wargs or {})
 

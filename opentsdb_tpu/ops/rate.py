@@ -9,9 +9,23 @@ point; the first point of a span yields no output, matching how
 AggregationIterator consumes the synthetic time-zero rate as interpolation
 state only (AggregationIterator.java:448-459).
 
-Vectorized form: for each row of a [S, N] sorted batch, the "previous valid
-point" is found with a prefix-max scan over masked positions, so gaps from
-FILL_NONE downsampling are skipped exactly like the iterator would.
+Vectorized form: for each row of a [S, N] sorted batch, slot k needs its
+"previous valid point".  Two lanes find it, chosen on the device by the
+mask itself (one `lax.cond`, no key or mode), and everything after is one
+shared body:
+
+* shift — every row's valid slots are one contiguous run, or none
+  (`_no_interior_hole`: a regular-cadence grid whatever its padding, a
+  series born late or ended early).  The previous valid point of a valid
+  slot is then the slot before it or nothing, so `ts`, `val` and `mask`
+  move one column right: no scan, no gather.
+* scan — a row with a hole between two valid slots (FILL_NONE
+  downsampling of a host down mid-range): a prefix-max scan over masked
+  positions and two per-slot gathers skip the gap exactly like the
+  iterator would.
+
+On a mask that passes the predicate the lanes agree bit for bit in the
+rate and its mask on every slot.
 """
 
 from __future__ import annotations
@@ -54,22 +68,64 @@ def _prev_valid_index(mask):
     return prev
 
 
+def _no_interior_hole(mask):
+    """mask[S, W] -> bool[]: every row is one contiguous run of True, or
+    all False.  A run starts where a True follows a False (or sits in
+    column 0); a row with at most one start has no hole between two
+    present windows.  One fused elementwise pass and a row reduction —
+    no scan, no gather, no 64-bit arithmetic."""
+    starts = mask[:, 1:] & ~mask[:, :-1]
+    rises = mask[:, 0].astype(jnp.int32) \
+        + jnp.sum(starts, axis=1, dtype=jnp.int32)
+    return jnp.all(rises <= 1)
+
+
+def _prev_by_scan(operand):
+    """The scan lane: (prev_ts, prev_val, has_prev) on any mask."""
+    ts, val, mask = operand
+    n = ts.shape[1]
+    prev = _prev_valid_index(mask)
+    safe_prev = jnp.clip(prev, 0, n - 1)
+    return (jnp.take_along_axis(ts, safe_prev, axis=1),
+            jnp.take_along_axis(val, safe_prev, axis=1), prev >= 0)
+
+
+def _prev_by_shift(operand):
+    """The shift lane: the same three where `_no_interior_hole(mask)`
+    holds.  Column 0 has no previous point and repeats itself, which is
+    what the scan lane's clipped index reads there."""
+    def right(x):
+        return jnp.concatenate([x[:, :1], x[:, :-1]], axis=1)
+
+    ts, val, mask = operand
+    has_prev = jnp.concatenate(
+        [jnp.zeros_like(mask[:, :1]), mask[:, :-1]], axis=1)
+    return right(ts), right(val), has_prev
+
+
 # shape: ts[S,N] any, val[S,N] any, mask[S,N] bool
 def rate(ts, val, mask, options: RateOptions, all_int: bool = False):
     """Compute rates over a [S, N] sorted batch.
 
-    Returns (ts, rate_values[S, N] float, mask[S, N]): slot k holds the rate
-    between point k and its previous valid point, masked off for first points
-    (and dropped resets).  Timestamps are unchanged (rate sits at the latter
-    point's timestamp).
+    Returns (ts, rate_values[S, N] float, mask[S, N], shift[]): slot k
+    holds the rate between point k and its previous valid point, masked
+    off for first points (and dropped resets).  Timestamps are unchanged
+    (rate sits at the latter point's timestamp).  `shift` is the lane the
+    device took to find the previous points (module docstring), a bool
+    scalar: the served path hands it back beside the answer for
+    `tsd.query.rate_lane{lane}`.
     """
-    s, n = ts.shape
-    prev = _prev_valid_index(mask)
-    has_prev = prev >= 0
-    safe_prev = jnp.clip(prev, 0, n - 1)
-    prev_ts = jnp.take_along_axis(ts, safe_prev, axis=1)
-    prev_val = jnp.take_along_axis(val, safe_prev, axis=1)
+    shift = _no_interior_hole(mask)
+    prev = lax.cond(shift, _prev_by_shift, _prev_by_scan, (ts, val, mask))
+    out, out_mask = _rate_from_prev(ts, val, mask, prev, options, all_int)
+    return ts, out, out_mask, shift
 
+
+def _rate_from_prev(ts, val, mask, prev, options: RateOptions,
+                    all_int: bool):
+    """Both lanes' shared body: (rate[S, N], mask[S, N]) from each slot's
+    previous valid point (prev_ts, prev_val, has_prev)."""
+    prev_ts, prev_val, has_prev = prev
     dt_sec = (ts - prev_ts).astype(jnp.float64) / 1000.0
     dt_sec = jnp.where(dt_sec == 0, jnp.inf, dt_sec)
 
@@ -102,5 +158,4 @@ def rate(ts, val, mask, options: RateOptions, all_int: bool = False):
     else:
         out = diff / dt_sec
 
-    out = jnp.where(out_mask, out, jnp.nan)
-    return ts, out, out_mask
+    return jnp.where(out_mask, out, jnp.nan), out_mask

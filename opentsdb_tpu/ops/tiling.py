@@ -201,7 +201,7 @@ def _tile_contrib(spec, wts, v, m):
     if spec.rate is not None:
         agg = Aggregator(agg.name, PREV, agg.reduce)
         grid_b = jnp.broadcast_to(grid[None, :], v.shape)
-        _, v, m = rate(grid_b, v, m, spec.rate, all_int=False)
+        _, v, m, _ = rate(grid_b, v, m, spec.rate, all_int=False)
     contrib, participate, _dense = grid_contributions(
         grid, v.astype(jnp.float64), m, agg)
     return contrib, participate, m
@@ -210,15 +210,8 @@ def _tile_contrib(spec, wts, v, m):
 def _group_presence(num_groups: int, mask, gid):
     """[S, W] actual-value mask + gid[S] -> [G, W] any-member-present —
     the resident tail's out-mask rule, window-local."""
-    from opentsdb_tpu.ops.group_agg import _seg_dtype
-    s, w = mask.shape
-    dt = _seg_dtype(num_groups * w + w)
-    cols = jnp.arange(w, dtype=dt)[None, :]
-    seg = (gid.astype(dt)[:, None] * w + cols).reshape(-1)
-    present = jax.ops.segment_sum(
-        mask.reshape(-1).astype(jnp.int32), seg,
-        num_segments=num_groups * w)
-    return present.reshape(num_groups, w) > 0
+    from opentsdb_tpu.ops.group_agg import group_presence
+    return group_presence(mask, gid, num_groups)
 
 
 _jitted_tile_contrib = jax.jit(_tile_contrib, static_argnums=0)

@@ -247,23 +247,27 @@ def _local_grid_tail(spec, num_groups: int, wts, v, m, gid):
     """
     from opentsdb_tpu.ops.aggregators import Aggregator, get_agg, PREV
     from opentsdb_tpu.ops.group_agg import (
-        _seg_dtype, grid_contributions, is_moment_agg,
+        _EXTREME_AGGS, grid_contributions, group_presence, is_moment_agg,
         moment_group_reduce, ordered_group_reduce)
+    from opentsdb_tpu.ops.pipeline import LANE_DENSE, LANE_SHIFT
     from opentsdb_tpu.ops.rate import rate
 
     g = num_groups
     agg = get_agg(spec.aggregator)
+    grid = jnp.asarray(wts)
+    shift = jnp.bool_(False)
     if spec.rate is not None:
         agg = Aggregator(agg.name, PREV, agg.reduce)
-    grid = jnp.asarray(wts)
-    if spec.rate is not None:
         grid_b = jnp.broadcast_to(grid[None, :], v.shape)
-        _, v, m = rate(grid_b, v, m, spec.rate, all_int=False)
+        _, v, m, shift = rate(grid_b, v, m, spec.rate, all_int=False)
     vf = v.astype(jnp.float64)
     contrib, participate, dense = grid_contributions(grid, vf, m, agg)
-    # each shard took the lane its own rows allow; the answer's lane is
-    # dense iff every shard's was
-    dense = lax.psum((~dense).astype(jnp.int32), _BOTH) == 0
+    # each shard took the lanes its own rows allow; the answer's lane is
+    # the cheaper one iff every shard's was (one psum for both)
+    missed = lax.psum(jnp.stack([~dense, ~shift]).astype(jnp.int32), _BOTH)
+    lanes = LANE_DENSE * (missed[0] == 0).astype(jnp.int32)
+    if spec.rate is not None:
+        lanes = lanes + LANE_SHIFT * (missed[1] == 0).astype(jnp.int32)
     if is_moment_agg(agg.name):
         out, _ = moment_group_reduce(
             agg.name, contrib, participate, gid, g,
@@ -281,14 +285,10 @@ def _local_grid_tail(spec, num_groups: int, wts, v, m, gid):
         p_all = lax.all_gather(participate, _BOTH, axis=0, tiled=True)
         g_all = lax.all_gather(gid, _BOTH, axis=0, tiled=True)
         out, _ = ordered_group_reduce(agg.name, c_all, p_all, g_all, g)
-    w = v.shape[1]
-    dt = _seg_dtype(g * w + w)
-    cols = jnp.arange(w, dtype=dt)[None, :]
-    seg = (gid.astype(dt)[:, None] * w + cols).reshape(-1)
-    present = jax.ops.segment_sum(m.reshape(-1).astype(jnp.int32), seg,
-                                  num_segments=g * w)
-    out_mask = lax.psum(present, _BOTH).reshape(g, w) > 0
-    return wts, out, out_mask, dense
+    present = group_presence(m, gid, g, extremes=agg.name in _EXTREME_AGGS,
+                             rows_sorted=spec.rows_sorted)
+    out_mask = lax.psum(present.astype(jnp.int32), _BOTH) > 0
+    return wts, out, out_mask, lanes
 
 
 @lru_cache(maxsize=128)
@@ -298,8 +298,8 @@ def sharded_query_pipeline(mesh: Mesh, spec, num_groups: int):
     fn(ts, val, mask, gid, wargs) with rows sharded over every chip
     (dim 0 split across both mesh axes, time dim intact so downsample/rate
     stay row-local); returns replicated (wts[W], out[G, W], out_mask[G, W],
-    dense[]) identical to ops.pipeline.run_group_pipeline's single-device
-    answer (dense: every shard's rows took the dense contribution lane).
+    lanes[]) identical to ops.pipeline.run_group_pipeline's single-device
+    answer (a lane bit is set iff every shard's rows took that lane).
 
     `spec` is a PipelineSpec (hashable) — the builder is lru_cached so a
     dashboard re-issuing the same query shape reuses the compiled program.
@@ -439,7 +439,7 @@ def _stream_update_sliced_fn(mesh: Mesh, window_spec, wc: int,
 def _stream_finish_fn(mesh: Mesh, window_spec, pipeline_spec,
                       num_groups: int):
     """Jitted shard_map'd stream finish: per-chip moment state -> replicated
-    (wts[W], out[G, W], out_mask[G, W], dense[]) via the collective grid
+    (wts[W], out[G, W], out_mask[G, W], lanes[]) via the collective grid
     tail."""
     from opentsdb_tpu.ops import streaming
 
@@ -538,7 +538,7 @@ class ShardedStreamAccumulator:
         return int(np.asarray(self.state["oob"]))
 
     def finish_tail(self, pipeline_spec, gid: np.ndarray, num_groups: int):
-        """Replicated (wts[W], out[G, W], out_mask[G, W], dense[]) for the
+        """Replicated (wts[W], out[G, W], out_mask[G, W], lanes[]) for the
         query."""
         fn = _stream_finish_fn(self.mesh, self.window_spec, pipeline_spec,
                                num_groups)

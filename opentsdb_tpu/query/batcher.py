@@ -163,7 +163,7 @@ class DispatchBatcher:
     def submit(self, spec, ts, val, mask, gid, g_pad: int, wargs: dict,
                host_small: bool, deadline=None):
         """Execute one batch-routed plan; returns ((out_ts, out_val,
-        out_mask, dense), info) where the outputs are the member's own
+        out_mask, lanes), info) where the outputs are the member's own
         host-unpacked slice (np arrays when stacked, device arrays on
         the solo fallback) and ``info`` carries the batch verdict for
         span annotation.  Raises the member's own deadline error if it
@@ -338,13 +338,13 @@ class DispatchBatcher:
         wargs = {k: np.stack([np.asarray(m.wargs[k]) for m in padded])
                  for k in live[0].wargs}
         with host_lane(host_small):
-            wts, out_val, out_mask, dense = run_stacked_group_pipeline(
+            wts, out_val, out_mask, lanes = run_stacked_group_pipeline(
                 spec, ts, val, mask, gid, g_pad, wargs)
         # host-side unpack: one transfer per output, then row views
         wts = np.asarray(wts)
         out_val = np.asarray(out_val)
         out_mask = np.asarray(out_mask)
-        dense = np.asarray(dense)
+        lanes = np.asarray(lanes)
         with self._lock:
             self.stacked_dispatches += 1
             self.stacked_members += q
@@ -361,7 +361,7 @@ class DispatchBatcher:
                             points=int(ts.shape[2]),
                             groups=int(g_pad),
                             hostSmall=bool(host_small))
-        return [(wts[i], out_val[i], out_mask[i], dense[i], q)
+        return [(wts[i], out_val[i], out_mask[i], lanes[i], q)
                 for i in range(q)]
 
     # -- stats ----------------------------------------------------------- #

@@ -30,7 +30,7 @@ from opentsdb_tpu.ops.pipeline import (
     PipelineSpec, DownsampleStep, run_pipeline, run_group_pipeline,
     run_union_batch_pipeline,
     run_group_rollup_avg_pipeline, run_grid_tail, build_batch,
-    build_batch_direct, PAD_TS)
+    build_batch_direct, PAD_TS, LANE_DENSE, LANE_SHIFT)
 from opentsdb_tpu.ops.streaming import (
     StreamAccumulator, STREAMABLE_DS, is_sketch_ds, lanes_for)
 from opentsdb_tpu.query import filters as query_filters
@@ -765,7 +765,7 @@ class QueryRunner:
             # predicted-vs-actual ring skips lane-served executions like
             # rewrites/tiled runs (the monolithic stage breakdown does
             # not describe them).
-            out_ts, out_val, out_mask, dense = self._run_lane_serve(
+            out_ts, out_val, out_mask, lanes = self._run_lane_serve(
                 spec, seg, lane_plan, series_list, gid, g_pad, windows,
                 window_spec, budget, fix, psp)
             self.exec_stats["rollupLane"] = 1.0
@@ -782,13 +782,13 @@ class QueryRunner:
                 tsdb, spec, seg, series_list, gid, g_pad, window_spec,
                 wargs, ds_fn, lanes_for([ds_fn]), sketchable, fix,
                 tiled_plan, budget, store=store)
-            dense = None    # no one lane: a contribution program a tile
+            lanes = None    # no one lane: a contribution program a tile
             obs_trace.annotate(psp, tiling=tile_stats)
             self.exec_stats["tiledExecution"] = 1.0
             self._bump("spillBytes", float(tile_stats["spillBytes"]))
             self._bump("tiledTiles", float(tile_stats["tiles"]))
         elif agg_plan is not None:
-            out_ts, out_val, out_mask, dense = self._run_agg_rewrite(
+            out_ts, out_val, out_mask, lanes = self._run_agg_rewrite(
                 spec, agg_plan, series_list, gid, g_pad, windows,
                 window_spec, host_small, budget)
         elif pd.path == "batched":
@@ -804,7 +804,7 @@ class QueryRunner:
             from opentsdb_tpu.query.limits import active_deadline
             ts, val, mask, _ = build_batch_direct(
                 series_list, seg.start_ms, seg.end_ms, fix)
-            (out_ts, out_val, out_mask, dense), batch_info = \
+            (out_ts, out_val, out_mask, lanes), batch_info = \
                 tsdb.dispatch_batcher.submit(
                     spec, ts, val, mask, gid, g_pad, wargs,
                     host_small, deadline=active_deadline())
@@ -818,7 +818,7 @@ class QueryRunner:
             # Beyond the threshold the batch never materializes: bounded
             # chunks are copied straight out of the store into the device
             # accumulator (SaltScanner overlap analog, VERDICT r1 #4).
-            out_ts, out_val, out_mask, dense = self._stream_grouped(
+            out_ts, out_val, out_mask, lanes = self._stream_grouped(
                 spec, seg, series_list, n_max, gid, g_pad, window_spec,
                 wargs, sketch=sketchable)
         elif seg.kind == "rollup_avg":
@@ -837,7 +837,7 @@ class QueryRunner:
                         tsdb.config.fix_duplicates))
             tc, vc, mc, _ = build_batch(cnt_windows)
             with host_lane(host_small):
-                out_ts, out_val, out_mask, dense = \
+                out_ts, out_val, out_mask, lanes = \
                     run_group_rollup_avg_pipeline(
                         spec, ts, val, mask, tc, vc, mc, gid, g_pad,
                         wargs)
@@ -866,7 +866,7 @@ class QueryRunner:
                 else:
                     d_ts, d_val, d_mask, d_gid = shard_rows(
                         mesh, ts, val, mask, gid, pad_gid_value=g_pad)
-                out_ts, out_val, out_mask, dense = fn(
+                out_ts, out_val, out_mask, lanes = fn(
                     d_ts, d_val, d_mask, d_gid, wargs)
             else:
                 if n_groups == len(gid) and pd.path in pdn.ROW_GROUP_PATHS:
@@ -874,7 +874,7 @@ class QueryRunner:
                     # dispatch: row i is group i
                     spec = replace(spec, row_groups=True)
                 with host_lane(host_small):
-                    out_ts, out_val, out_mask, dense = run_group_pipeline(
+                    out_ts, out_val, out_mask, lanes = run_group_pipeline(
                         spec, ts, val, mask, gid, g_pad, wargs)
 
         # the arm above returned (dispatch enqueued; results may still
@@ -917,15 +917,21 @@ class QueryRunner:
                 fields["batch"] = batch_info
             recorder.record("plan", **fields)
         with obs_trace.timed_stage("extract"):
-            out_ts, out_val, out_mask, dense = self._materialize_answer(
-                out_ts, out_val, out_mask, dense)
-            if dense is not None:
-                # which contribution lane the device took
-                # (ops/group_agg.py grid_contributions)
+            out_ts, out_val, out_mask, lanes = self._materialize_answer(
+                out_ts, out_val, out_mask, lanes)
+            if lanes is not None:
+                # which lanes the device took (ops/pipeline.py's word)
                 REGISTRY.counter(
                     "tsd.query.contrib_lane", "Grouped dispatches by "
                     "the contribution lane the device took").labels(
-                        lane="dense" if dense else "full").inc()
+                        lane="dense" if lanes & LANE_DENSE
+                        else "full").inc()
+                if spec.rate is not None:
+                    REGISTRY.counter(
+                        "tsd.query.rate_lane", "Grouped rate dispatches "
+                        "by the lane that found the previous points"
+                    ).labels(lane="shift" if lanes & LANE_SHIFT
+                             else "scan").inc()
             # device->host materialization is where an async dispatch
             # actually blocks (tracing syncs earlier via device_wait,
             # in which case this delta is ~0)
@@ -1053,15 +1059,15 @@ class QueryRunner:
         return (np.asarray(v)[:, :count], np.asarray(m)[:, :count])
 
     @staticmethod
-    def _materialize_answer(out_ts, out_val, out_mask, dense):
-        """Host copies of a grouped dispatch's answer and of the lane
-        scalar its program made, in ONE fetch: device_get starts every
+    def _materialize_answer(out_ts, out_val, out_mask, lanes):
+        """Host copies of a grouped dispatch's answer and of the lanes
+        word its program made, in ONE fetch: device_get starts every
         copy before it waits for the first, where an np.asarray each in
         turn pays a blocking transfer's latency (~0.4 ms on the chip,
         PERF.md section 6, PR 28).  Host arrays and None pass through.
         (`_materialize` prefix: the sanctioned device->host result
         materialization of the extract stage.)"""
-        return jax.device_get((out_ts, out_val, out_mask, dense))
+        return jax.device_get((out_ts, out_val, out_mask, lanes))
 
     def _run_agg_rewrite(self, spec, plan, series_list, gid, g_pad,
                          windows, window_spec, host_small, budget):

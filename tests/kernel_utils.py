@@ -1,4 +1,5 @@
-"""Helpers to build padded [series, time] batches for kernel tests."""
+"""Helpers to build padded [series, time] batches for kernel tests, and
+to read what a traced kernel holds."""
 
 import numpy as np
 
@@ -28,3 +29,17 @@ def collect(ts, val, mask):
     mask = np.asarray(mask)
     return [(int(t), float(v)) for t, v, m in zip(ts.ravel(), val.ravel(),
                                                   mask.ravel()) if m]
+
+
+def primitives(jaxpr) -> set:
+    """Every primitive name of a jaxpr, sub-jaxprs (cond branches, scans,
+    nested jits) included."""
+    out = set()
+    for eqn in jaxpr.eqns:
+        out.add(eqn.primitive.name)
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    out |= primitives(inner)
+    return out

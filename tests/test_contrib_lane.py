@@ -3,7 +3,10 @@ and a rate query through the /api/query handler bump
 `tsd.query.contrib_lane{lane=dense}` over a store with no hole and
 `{lane=full}` over the same store with a gap planted in one series, on
 every route that answers from one grouped device program; both answer
-equal to a plain numpy reference written here."""
+equal to a plain numpy reference written here.  The rate query also
+bumps `tsd.query.rate_lane{lane=shift}` over the hole-free store and
+`{lane=scan}` with the gap planted (ops/rate.py's two lanes); the sum
+query, which runs no rate, bumps neither."""
 
 import json
 
@@ -103,7 +106,10 @@ def reference(kind: str, gap: bool) -> dict:
 
 def lanes() -> dict:
     c = REGISTRY.counter("tsd.query.contrib_lane")
-    return {lane: c.labels(lane=lane).get() for lane in ("dense", "full")}
+    r = REGISTRY.counter("tsd.query.rate_lane")
+    return dict(
+        {lane: c.labels(lane=lane).get() for lane in ("dense", "full")},
+        **{lane: r.labels(lane=lane).get() for lane in ("shift", "scan")})
 
 
 def ask(tsdb, mgr, kind: str):
@@ -131,8 +137,10 @@ def served(request, route):
     return (route, request.param) + build(route, request.param)
 
 
-def test_the_counter_is_declared():
-    kind, labels, _ = METRICS_SCHEMA["tsd.query.contrib_lane"]
+@pytest.mark.parametrize("name", ["tsd.query.contrib_lane",
+                                  "tsd.query.rate_lane"])
+def test_the_counter_is_declared(name):
+    kind, labels, _ = METRICS_SCHEMA[name]
     assert (kind, tuple(labels)) == ("counter", ("lane",))
 
 
@@ -153,8 +161,11 @@ def test_the_lane_is_counted_and_the_answer_is_the_reference(
         and event["path"] == "agg_rewrite"), served_paths(tsdb)
     assert event["windows"] == 128 and event["series"] == HOSTS
     bumped = {lane: n - before[lane] for lane, n in lanes().items()}
-    assert bumped == ({"dense": 0, "full": 1} if gap
-                      else {"dense": 1, "full": 0}), (event["path"], bumped)
+    ran_rate = int(kind == "rate")
+    assert bumped == ({"dense": 0, "full": 1, "shift": 0, "scan": ran_rate}
+                      if gap else
+                      {"dense": 1, "full": 0, "shift": ran_rate, "scan": 0}
+                      ), (event["path"], bumped)
     want = reference(kind, gap)
     assert sorted(r["tags"]["region"] for r in body) == sorted(want)
     for result in body:
@@ -163,3 +174,21 @@ def test_the_lane_is_counted_and_the_answer_is_the_reference(
         assert len(ref) == (119 if kind == "rate" else 120)
         for t, v in result["dps"].items():
             assert v == pytest.approx(ref[int(t)], rel=1e-9, abs=1e-12)
+
+
+def test_both_lane_counters_are_exported_under_the_names_a_reader_keys_on():
+    """What a `counter_ratio` layer file of the benchmark would read
+    (benchmark/daemon.py parses this text): the counters' Prometheus
+    names and their `lane` label."""
+    tsdb, mgr = build("resident", gap=False)
+    ask(tsdb, mgr, "rate")
+    q = mgr.handle_http(HttpRequest(method="GET",
+                                    uri="/api/stats/prometheus",
+                                    headers={}), remote="127.0.0.1:9")
+    assert q.response.status == 200
+    text = q.response.body.decode()
+    for name, lane in (("tsd_query_rate_lane_total", "shift"),
+                       ("tsd_query_contrib_lane_total", "dense")):
+        line = next(ln for ln in text.splitlines()
+                    if ln.startswith('%s{lane="%s"}' % (name, lane)))
+        assert float(line.split()[-1]) >= 1

@@ -127,7 +127,7 @@ class TestDownsample:
 class TestRate:
     def run_rate(self, series, options=RateOptions(), all_int=False):
         ts, val, mask = batch(series)
-        rts, rout, rmask = rate(ts, val, mask, options, all_int)
+        rts, rout, rmask, _ = rate(ts, val, mask, options, all_int)
         return collect(rts, rout, rmask)
 
     def test_simple_rate(self):
@@ -161,7 +161,7 @@ class TestRate:
         ts = np.array([[0, 1000, 2000, 3000]], dtype=np.int64)
         val = np.array([[0.0, 99.0, 20.0, 30.0]])
         mask = np.array([[True, False, True, True]])
-        _, out, omask = rate(ts, val, mask, RateOptions())
+        _, out, omask, _ = rate(ts, val, mask, RateOptions())
         got = collect(ts, out, omask)
         # Gap at 1000 skipped: rate at 2000 spans 0->2000 = 20/2 = 10.
         assert got == [(2000, 10.0), (3000, 10.0)]

@@ -13,6 +13,7 @@ from opentsdb_tpu.ops.group_agg import (_no_interior_hole,
                                         grid_group_aggregate)
 from opentsdb_tpu.ops.rate import _prev_valid_index
 from opentsdb_tpu.ops.union_agg import interpolate, _next_valid
+from tests.kernel_utils import primitives
 
 
 def _full_reference(grid_ts, val, mask, agg):
@@ -223,18 +224,6 @@ def test_predicate_is_the_row_run_count(case):
         r <= 1 for r in runs)
 
 
-def _primitives(jaxpr) -> set:
-    out = set()
-    for eqn in jaxpr.eqns:
-        out.add(eqn.primitive.name)
-        for v in eqn.params.values():
-            for sub in (v if isinstance(v, (list, tuple)) else (v,)):
-                inner = getattr(sub, "jaxpr", sub)
-                if hasattr(inner, "eqns"):
-                    out |= _primitives(inner)
-    return out
-
-
 def test_predicate_holds_no_gather_scan_or_sort():
     """What the lane's condition costs a grid WITH a hole: one
     elementwise pass and a row reduction over bool[S, W], in 32 bits."""
@@ -242,7 +231,7 @@ def test_predicate_holds_no_gather_scan_or_sort():
     import jax.numpy as jnp
     jaxpr = jax.make_jaxpr(_no_interior_hole)(
         jnp.zeros((4000, 128), bool))
-    prims = _primitives(jaxpr.jaxpr)
+    prims = primitives(jaxpr.jaxpr)
     for p in prims:
         assert not any(word in p for word in (
             "gather", "scatter", "scan", "while", "sort", "cum")), prims
@@ -294,3 +283,110 @@ class TestSubblock2Boundaries:
         np.testing.assert_array_equal(
             np.asarray(ds._edge_subblock2_builder(s, n, idx)(di)),
             np.asarray(ds._edge_prefix_builder(s, n, idx)(di)))
+
+
+# --------------------------------------------------------------------- #
+# The out-mask: "group g has a member present in window w", in the form #
+# the reduce beside it took                                             #
+# --------------------------------------------------------------------- #
+
+PRESENCE_GRIDS = ("empty_groups", "out_of_range_gid", "nan_under_mask",
+                  "padded_groups", "unsorted_gid")
+
+
+def _presence_grid(case: str):
+    """(grid_ts, val, mask, gid, G, rows_sorted) with values integer so
+    every form sums them exactly."""
+    rng = np.random.default_rng(33)
+    s, w, g = 24, 16, 8
+    grid_ts = np.arange(w, dtype=np.int64) * 60_000
+    val = rng.integers(0, 100, (s, w)).astype(np.float64)
+    mask = np.zeros((s, w), bool)
+    for r in range(s):                      # hole-free rows, ragged edges
+        lo = int(rng.integers(0, 6))
+        mask[r, lo:int(rng.integers(lo + 1, w + 1))] = True
+    mask[:, 11] = False                     # a window nobody holds
+    mask[:, 12:] &= (np.arange(s) % 3 == 0)[:, None]
+    gid = np.sort(rng.integers(0, 5, s)).astype(np.int64)
+    sorted_ = True
+    if case == "empty_groups":
+        gid = np.where(gid >= 2, gid + 2, gid)      # 2 and 3 have no member
+    elif case == "out_of_range_gid":
+        gid[-5:] = g                        # the planner's padding rows
+        mask[-5:] = True                    # ... present, and in no group
+    elif case == "nan_under_mask":
+        val[mask & (rng.random((s, w)) < 0.3)] = np.nan
+        val[gid == 1] = np.nan              # a group holding only NaN
+    elif case == "padded_groups":
+        g = 16                              # G padded past the live groups
+    else:
+        assert case == "unsorted_gid"
+        gid = rng.permutation(gid)
+        sorted_ = False
+    return grid_ts, val, mask, gid, g, sorted_
+
+
+def _present_reference(mask, gid, g):
+    want = np.zeros((g, mask.shape[1]), bool)
+    for row, grp in zip(mask, gid):
+        if 0 <= grp < g:
+            want[grp] |= row
+    return want
+
+
+@pytest.mark.parametrize("aggname", ["sum", "min", "p99", "median"])
+@pytest.mark.parametrize("case", PRESENCE_GRIDS)
+@pytest.mark.parametrize("form", ["matmul", "sorted", "segment"])
+def test_out_mask_is_any_member_present_in_every_form(form, case, aggname,
+                                                      kernel_forms):
+    import jax.numpy as jnp
+    if form != "segment":                   # segment: the CPU's own pick
+        kernel_forms(group=form)
+    grid_ts, val, mask, gid, g, sorted_ = _presence_grid(case)
+    agg = get_agg(aggname)
+    extremes = aggname == "min"
+    s, w = mask.shape
+    took = group_agg._effective_group_reduce_mode(s, w, g,
+                                                  extremes=extremes)
+    # extremes have no matmul form: their pin falls back to segment
+    assert took == ("segment" if form == "matmul" and extremes else form)
+    _, _, out_mask, _ = grid_group_aggregate(
+        jnp.asarray(grid_ts), jnp.asarray(val), jnp.asarray(mask),
+        jnp.asarray(gid), g, agg, rows_sorted=sorted_)
+    want = _present_reference(mask, gid, g)
+    np.testing.assert_array_equal(np.asarray(out_mask), want)
+    alone = group_agg.group_presence(
+        jnp.asarray(mask), jnp.asarray(gid), g, extremes=extremes,
+        rows_sorted=sorted_)
+    np.testing.assert_array_equal(np.asarray(alone), want)
+
+
+@pytest.mark.parametrize("aggname", ["sum", "p99"])
+def test_matmul_out_mask_holds_no_scatter(aggname, kernel_forms):
+    """Under the form the chip picks for a narrow group-by the whole
+    grouped tail of a moment aggregator indexes no cell: no scatter, and
+    outside grid_contributions' full branch no gather."""
+    import jax
+    import jax.numpy as jnp
+    kernel_forms(group="matmul")
+    grid_ts, val, mask, gid, g, _ = _presence_grid("empty_groups")
+    presence = primitives(jax.make_jaxpr(
+        lambda m, i: group_agg.group_presence(m, i, g))(
+            jnp.asarray(mask), jnp.asarray(gid)).jaxpr)
+    assert "dot_general" in presence
+    assert not [p for p in presence if "scatter" in p or "gather" in p
+                or "sort" in p or p.startswith("cum")], presence
+    whole = primitives(jax.make_jaxpr(
+        lambda t, v, m, i: grid_group_aggregate(
+            t, v, m, i, g, get_agg(aggname), rows_sorted=True))(
+                jnp.asarray(grid_ts), jnp.asarray(val), jnp.asarray(mask),
+                jnp.asarray(gid)).jaxpr)
+    assert not [p for p in whole if "scatter" in p], whole
+
+
+def test_presence_counts_stay_exact_at_the_widest_matmul_shape():
+    """0/1 sums in float32 are exact below 2^24 terms; the matmul form's
+    own gate keeps S under it at every G."""
+    assert all(
+        not group_agg._matmul_feasible(1 << 24, g)
+        for g in (1, 2, 16, group_agg._MATMUL_MAX_GROUPS))
