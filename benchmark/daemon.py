@@ -1,9 +1,14 @@
 """Starting, talking to and stopping the one TSD daemon — the benchmark's
-only JAX child.  Client / start / wait_ready / stop are chip_smoke.py's
-(PR 21), copied; the parent process never imports jax."""
+only JAX child.  Client / start / wait_ready are chip_smoke.py's (PR 21),
+copied; the parent process never imports jax.
+
+Whatever ends the runner, the daemon does not outlive it: it is started
+in a session of its own with a parent-death signal, and stop() ends the
+whole session's process group and then sees the port refuse."""
 
 from __future__ import annotations
 
+import ctypes
 import http.client
 import json
 import os
@@ -67,12 +72,40 @@ def free_port() -> int:
         return s.getsockname()[1]
 
 
+PR_SET_PDEATHSIG = 1        # <linux/prctl.h>
+
+
+def die_with_parent(parent: int, libc) -> None:
+    """A preexec_fn: SIGKILL for this process when the thread that
+    created it ends, however it ends — a SIGKILL of the runner included,
+    which no handler sees.  Not SIGTERM: the graceful path is stop()'s to
+    take while the runner lives; a daemon whose runner is gone has no
+    result to protect, and inside an XLA compile of minutes its graceful
+    path outlasts any patience (the drain gives up after 35 s, the
+    interpreter then joins the compiling thread), holding the chip and
+    the machine for the runs that follow.  prctl(2) is Linux's, the only
+    platform a cell runs on; the kernel counts the creating THREAD as the
+    parent, so the daemon is started from the runner's main thread (the
+    writers, which a thread that ends early starts, look at their
+    parent's pid instead: loadgen._end_with).  `parent` is the creator's
+    pid, to close the race with a creator that died before the call;
+    `libc` was loaded before the fork, so that the child does nothing
+    between fork and exec but call into it."""
+    if libc.prctl(PR_SET_PDEATHSIG, signal.SIGKILL, 0, 0, 0) != 0:
+        raise OSError(ctypes.get_errno(), "prctl(PR_SET_PDEATHSIG)")
+    if os.getppid() != parent:
+        os.kill(os.getpid(), signal.SIGKILL)
+
+
 def start(port: int, out_dir: str, env: dict, tsd_config: dict,
           traced: bool) -> subprocess.Popen:
     """`python -m opentsdb_tpu.tools.tsd_main`, as users start it; a
     traced run starts the benchmark's launcher, which arms jax.profiler
-    and then calls the same main() unchanged."""
+    and then calls the same main() unchanged.  The daemon leads a session
+    (and so a process group) of its own, which stop() ends as a whole,
+    and carries the parent-death signal across its exec."""
     assert_no_jax()
+    runner, libc = os.getpid(), ctypes.CDLL(None, use_errno=True)
     conf = os.path.join(out_dir, "tsd.conf")
     with open(conf, "w") as fh:
         for key, value in tsd_config.items():
@@ -85,7 +118,9 @@ def start(port: int, out_dir: str, env: dict, tsd_config: dict,
         return subprocess.Popen(
             [sys.executable, *entry, "--port", str(port),
              "--bind", "127.0.0.1", "--config", conf],
-            cwd=REPO, env=env, stdout=log, stderr=subprocess.STDOUT)
+            cwd=REPO, env=env, stdout=log, stderr=subprocess.STDOUT,
+            start_new_session=True,
+            preexec_fn=lambda: die_with_parent(runner, libc))
 
 
 def wait_ready(proc: subprocess.Popen, port: int, timeout: float) -> None:
@@ -103,16 +138,39 @@ def wait_ready(proc: subprocess.Popen, port: int, timeout: float) -> None:
                        % (port, timeout))
 
 
-def stop(proc: subprocess.Popen) -> int | None:
-    """SIGTERM (the graceful path), then wait until it has ended."""
+def _signal_group(proc: subprocess.Popen, signum: int) -> None:
+    """The daemon leads its own process group (start_new_session)."""
+    try:
+        os.killpg(proc.pid, signum)
+    except (ProcessLookupError, PermissionError):
+        pass                # nothing is left of the group
+
+
+def stop(proc: subprocess.Popen, port: int,
+         patience: float = 120.0) -> int | None:
+    """SIGTERM to the daemon's group (the graceful path), `patience`
+    seconds for it to end, SIGKILL to whatever is left of the group, and
+    then the port has to refuse a connection: nothing the run started
+    is left to serve a later one.  Returns the daemon's exit code."""
     if proc.poll() is None:
-        proc.send_signal(signal.SIGTERM)
+        _signal_group(proc, signal.SIGTERM)
         try:
-            proc.wait(timeout=120)
+            proc.wait(timeout=patience)
         except subprocess.TimeoutExpired:
-            proc.kill()
-            proc.wait()
-    return proc.returncode
+            pass
+    _signal_group(proc, signal.SIGKILL)
+    proc.wait()
+    give_up = time.monotonic() + 10.0
+    while True:
+        try:
+            with socket.create_connection(("127.0.0.1", port), 1.0):
+                pass
+        except OSError:
+            return proc.returncode
+        if time.monotonic() > give_up:
+            raise BenchFailure("port %d still answers after the daemon's "
+                               "group was killed" % port)
+        time.sleep(0.1)
 
 
 def device_section(client: Client) -> dict:

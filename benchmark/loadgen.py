@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import json
 import multiprocessing
+import os
 import threading
 import time
 from concurrent.futures import ProcessPoolExecutor
@@ -25,8 +26,22 @@ from benchmark.tsbs import CADENCE_S, EPOCH_S, TAG_KEYS
 _W: dict = {}
 
 
-def _writer_init(port: int, values_path: str, tags: list[dict],
-                 metric: str) -> None:
+def _end_with(runner: int) -> None:
+    """Ends this writer within half a second of its runner's end, however
+    the runner ended (a SIGKILL leaves it nothing else to go by).  A look
+    at the parent's pid, not the daemon's parent-death signal: the kernel
+    ties that signal to the THREAD that started the process, the executor
+    starts its workers inside submit() — from the loader thread, which
+    ends with the load — and starting them all beforehand from the main
+    thread cost every run 6 s of set-up on the chip (PR 34)."""
+    while os.getppid() == runner:
+        time.sleep(0.5)
+    os._exit(1)
+
+
+def _writer_init(runner: int, port: int, values_path: str,
+                 tags: list[dict], metric: str) -> None:
+    threading.Thread(target=_end_with, args=(runner,), daemon=True).start()
     _W["client"] = Client(port)
     _W["values"] = np.load(values_path, mmap_mode="r")
     _W["tail"] = [',"tags":{%s}}' % ",".join(
@@ -89,15 +104,26 @@ def host_edges(hosts: int, writers: int) -> np.ndarray:
     return np.linspace(0, hosts, writers + 1).astype(int)
 
 
+def end_workers() -> None:
+    """Ends every multiprocessing child of this process: the writers,
+    whether or not a pool has come to own them yet."""
+    workers = multiprocessing.active_children()
+    for proc in workers:
+        proc.terminate()
+    for proc in workers:
+        proc.join(10.0)
+
+
 class Writers:
-    """A pool of writer processes (spawned: the parent has threads)."""
+    """A pool of writer processes (spawned: the parent has threads), each
+    of which ends with the runner (_end_with)."""
 
     def __init__(self, processes: int, port: int, values_path: str,
                  tags: list[dict], metric: str):
         self.pool = ProcessPoolExecutor(
             processes, mp_context=multiprocessing.get_context("spawn"),
             initializer=_writer_init,
-            initargs=(port, values_path, tags, metric))
+            initargs=(os.getpid(), port, values_path, tags, metric))
 
     def load(self, hosts: int, columns: int) -> int:
         """The retained store, through POST /api/put: bodies of 50 hosts
@@ -122,8 +148,13 @@ class Writers:
                 stop_at))
         return futures
 
-    def close(self) -> None:
-        self.pool.shutdown(wait=True, cancel_futures=True)
+    def close(self, cut: bool = False) -> None:
+        """`cut`: the run is being ended from outside.  What is queued is
+        cancelled either way; a cut run does not wait for a body that is
+        in flight (to a daemon that may be gone) but ends the workers."""
+        self.pool.shutdown(wait=not cut, cancel_futures=True)
+        if cut:
+            end_workers()
 
 
 # --------------------------------------------------------------------- #

@@ -11,6 +11,14 @@ reference after the window, stops the daemon with SIGTERM, and prints as
 its last line the JSON object the driver reads.  Earlier lines (prefixed
 `#`) say what that line has no key for.
 
+Whatever ends a run, nothing it started outlives it: SIGTERM, SIGINT and
+SIGHUP raise `Cut` in the main thread, so the same `finally` that ends a
+failed run ends the daemon and the writers, and the runner exits with
+128 + the signal's number, prints no result line and says on stderr
+where it was cut; a SIGKILL, which nothing can handle, is covered by the
+parent-death signal the daemon carries (daemon.die_with_parent) and the
+look each writer takes at its parent's pid (loadgen._end_with).
+
 Nothing about a cell, a request class or a metric is written here: the
 cell is workloads/<cell>.json, its deployment configs/<config>.json, its
 traffic traffic/<mix>.json, each per-layer metric layers/<metric>.json
@@ -32,6 +40,7 @@ import argparse  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
+import signal  # noqa: E402
 import subprocess  # noqa: E402
 import sys  # noqa: E402
 import threading  # noqa: E402
@@ -126,6 +135,9 @@ class Warmer:
     def __init__(self, client: daemon.Client):
         self.client = client
         self.compiles = daemon.compile_total(daemon.counters(client))
+        # per class, what sizes a mix against an empty compile cache: the
+        # seconds of its first send and of all, tsd.jax.compiles across them
+        self.by_class: dict[str, dict] = {}
 
     def compiled(self) -> int:
         now = daemon.compile_total(daemon.counters(self.client))
@@ -133,11 +145,24 @@ class Warmer:
         return int(delta)
 
     def send(self, req: dict) -> int:
+        t = time.monotonic()
         status, body = self.client.request("GET", req["path"])
+        took = time.monotonic() - t
         if status != 200:
             raise BenchFailure("warm-up %s -> %d: %s"
                                % (req["cls"], status, body[:400]))
-        return self.compiled()
+        compiled = self.compiled()
+        if req["cls"] not in self.by_class:     # said at once: a run that
+            # is cut in warm-up has then said how far it came
+            say("warm-up, first send of %s: %.3f s, %d compiles"
+                % (req["cls"], took, compiled))
+        seen = self.by_class.setdefault(req["cls"], {
+            "first_s": round(took, 3), "first_compiles": compiled,
+            "sends": 0, "all_s": 0.0, "compiles": 0})
+        seen["sends"] += 1
+        seen["all_s"] = round(seen["all_s"] + took, 3)
+        seen["compiles"] += compiled
+        return compiled
 
 
 def warm_sequential(warmer: Warmer, gen: traffic.Generator, mix: dict,
@@ -287,6 +312,28 @@ def parse_rehearse(text: str | None) -> dict | None:
     return {k: int(v) for k, v in (kv.split("=") for kv in text.split(","))}
 
 
+SIGNALS = (signal.SIGTERM, signal.SIGINT, signal.SIGHUP)
+
+
+class Cut(BaseException):
+    """The run is being ended from outside.  Not an Exception: no
+    `except Exception` on the way up may swallow it."""
+
+    def __init__(self, signum: int):
+        super().__init__(signal.Signals(signum).name)
+        self.signum = signum
+
+
+def ignore_signals() -> None:
+    for signum in SIGNALS:
+        signal.signal(signum, signal.SIG_IGN)
+
+
+def raise_cut(signum: int, frame) -> None:
+    ignore_signals()        # the way out is taken once, and to its end
+    raise Cut(signum)
+
+
 def main(argv: list[str] | None = None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -312,11 +359,17 @@ def main(argv: list[str] | None = None) -> int:
         print("benchmark/run.py needs the repository around it (no "
               "opentsdb_tpu/ beside benchmark/)", file=sys.stderr)
         return 2
+    for signum in SIGNALS:
+        signal.signal(signum, raise_cut)
     try:
         return run(args)
     except BenchFailure as e:
         print("benchmark: " + str(e), file=sys.stderr, flush=True)
         return 1
+    except Cut as cut:      # before there was a run to end
+        print("benchmark: cut by %s before set-up began" % cut,
+              file=sys.stderr, flush=True)
+        return 128 + cut.signum
 
 
 class Run:
@@ -332,6 +385,7 @@ class Run:
                                                 cell.name)
         self.env = child_env(bool(self.rehearse), cell.chips)
         self.phases: dict[str, float] = {}
+        self.doing = "start"        # the step a cut run was in
         self.proc = self.writers = self.gen = self.cycle = self.due = None
         self.records: list = []
         self.refs: dict = {}
@@ -408,14 +462,21 @@ class Run:
             self.values_path, fleet.tags, fleet.metric)
         t_load = time.monotonic()
         loaded: dict = {}
-        loader = threading.Thread(target=lambda: loaded.update(
-            n=self.writers.load(fleet.hosts, fleet.retained),
-            s=time.monotonic() - t_load), daemon=True)
+
+        def load_store() -> None:
+            try:
+                loaded.update(n=self.writers.load(fleet.hosts, fleet.retained),
+                              s=time.monotonic() - t_load)
+            except Exception as e:      # the main thread says it, below; a
+                loaded["error"] = e     # cut run's pool breaks under it
+
+        loader = threading.Thread(target=load_store, daemon=True)
         loader.start()
         self.timed("reference", self.draw_traffic)
         loader.join()
         if "n" not in loaded:
-            raise BenchFailure("loading the store failed (see daemon.log)")
+            raise BenchFailure("loading the store failed (see daemon.log): "
+                               "%r" % loaded.get("error"))
         self.phases["ingest"] = loaded["s"]
         sent = fleet.hosts * fleet.retained
         ctr = daemon.counters(self.client)
@@ -489,6 +550,9 @@ class Run:
                 break
         say("warm-up sends: %s; concurrent rounds: %d" % (
             json.dumps(sends), rounds))
+        say("warm-up by class (sequential sends; seconds and "
+            "tsd.jax.compiles across them): %s"
+            % json.dumps(warmer.by_class))
 
     # -- the window --------------------------------------------------- #
 
@@ -557,15 +621,25 @@ class Run:
                 self.cursors, len(self.fleet.ts)))
 
     def close(self) -> None:
-        """Never leave the daemon holding the chip, whatever failed."""
-        if self.writers is not None:
+        """Never leave the daemon holding the chip or a writer its
+        connection, whatever failed.  A run that is cut ends the daemon
+        first (a writer's body in flight then fails at once) and gives
+        it 20 s of its graceful path, not 120, before the kill."""
+        cut = isinstance(sys.exc_info()[1], Cut)
+        if self.writers is not None and not cut:
             self.writers.close()
-        if self.proc is None:
-            return
-        rc = daemon.stop(self.proc)
-        if rc != 0 and sys.exc_info()[0] is None:
-            raise BenchFailure("daemon shutdown was not graceful: rc=%s"
-                               % rc)
+        try:
+            if self.proc is not None:
+                rc = daemon.stop(self.proc, self.port,
+                                 20.0 if cut else 120.0)
+                if rc != 0 and sys.exc_info()[0] is None:
+                    raise BenchFailure("daemon shutdown was not graceful: "
+                                       "rc=%s" % rc)
+        finally:
+            if self.writers is not None and cut:
+                self.writers.close(cut=True)
+            elif cut:       # cut while the pool was starting them
+                loadgen.end_workers()
 
 
 def run_readers(run: Run, t0: float, trace_every: int) -> list:
@@ -662,17 +736,30 @@ def run(args) -> int:
         cell.mix["readers"]["rate_per_s"] = float(args.sweep.split(",")[0])
     this = Run(args, cell)
     try:
-        this.start()
-        this.load()
-        this.timed("warmup", this.warm)
-        if args.sweep:
-            sweep(this)
-            return 0
-        ctx = this.window()
-        this.client.close()
-    finally:
-        this.close()
-    line = result_line(this, ctx)
+        try:
+            this.start()
+            this.doing = "load"
+            this.load()
+            this.doing = "warmup"
+            this.timed("warmup", this.warm)
+            this.doing = "window"
+            if args.sweep:
+                sweep(this)
+                return 0
+            ctx = this.window()
+            this.client.close()
+        finally:
+            this.close()
+        this.doing = "judging"
+        line = result_line(this, ctx)
+    except Cut as cut:
+        print("benchmark: cut by %s %.1f s after its start, in %s; "
+              "phases so far: %s; nothing it started is left running"
+              % (cut, time.monotonic() - T_START, this.doing,
+                 ", ".join("%s %.1f" % kv for kv in this.phases.items())
+                 or "nothing"), file=sys.stderr, flush=True)
+        return 128 + cut.signum
+    ignore_signals()        # a result is printed whole or not at all
     print(json.dumps(line), flush=True)
     for name, c in line["compared"].items():
         print("compared %s = %s (limit %s)" % (name, c["value"], c["limit"]),

@@ -166,6 +166,11 @@ def check_metrics(bench: dict) -> None:
         assert set(m) - {"workloads"} == {"name", "unit", "better",
                                           "source", "layer", "moves"}
         assert m["moves"] in e2e_names and one_line(m["layer"])
+        # a per-layer metric names its cells, whether the metric it moves
+        # lists its own or is every cell's (setup_s): without a list every
+        # cell a later PR adds would have to report it, and enter() could
+        # append an entering cell's name to nothing
+        assert isinstance(m.get("workloads"), list), m["name"]
         spec = readers.load_layer(bench["root"], m["name"])
         assert (spec["name"], spec["layer"], spec["unit"], spec["moves"]) \
             == (m["name"], m["layer"], m["unit"], m["moves"])
@@ -278,6 +283,9 @@ def enter(src: dict, dst: dict, cell: str, bounds: dict) -> None:
         kept = []
         for m in src[section]:
             if "workloads" not in m:        # every cell's, in both indexes
+                # (an end-to-end metric alone: check_metrics gives every
+                # per-layer metric a list, so a cell is never left off one)
+                assert section == "end_to_end", m["name"]
                 kept.append(m)
                 if m["name"] not in known:
                     dst[section].append(dict(m))

@@ -1,9 +1,11 @@
-"""`dense_lane_share`: the per-layer metric that reads the program's
-`tsd.query.contrib_lane{lane}` counter — a data file read by the
+"""`dense_lane_share` and `rate_shift_share`: the per-layer metrics that
+read the program's lane counters, `tsd.query.contrib_lane{lane}` (PR 28)
+and `tsd.query.rate_lane{lane}` (PR 33) — each a data file read by the
 `counter_ratio` reader from two counter snapshots in the shape
-benchmark/daemon.counters() gives them, one added `per_layer` entry, and
-a traced CPU rehearsal in which the daemon's own counter feeds it (the
-rehearsal fleet, like TSBS's, has no hole: 100)."""
+benchmark/daemon.counters() gives them, one added `per_layer` entry found
+by its name, wherever later PRs' entries put it, and a traced CPU
+rehearsal in which the daemon's own counters feed both (the rehearsal
+fleet, like TSBS's, has no hole: 100)."""
 
 import json
 import os
@@ -20,37 +22,53 @@ from index_checks import REPO  # noqa: E402
 from benchmark import readers  # noqa: E402
 
 ROOT = os.path.join(REPO, "benchmark")
-NAME = "dense_lane_share"
-DENSE = "tsd_query_contrib_lane_total{lane=dense}"
-FULL = "tsd_query_contrib_lane_total{lane=full}"
+# metric -> the counter's sample of the lane it is the share of, and of
+# the other lane
+LANES = {
+    "dense_lane_share": ("tsd_query_contrib_lane_total{lane=dense}",
+                         "tsd_query_contrib_lane_total{lane=full}"),
+    "rate_shift_share": ("tsd_query_rate_lane_total{lane=shift}",
+                         "tsd_query_rate_lane_total{lane=scan}"),
+}
+SCAN_CELLS = ["heavy-replay", "heavy-replay-solo", "heavy-replay-mesh4",
+              "fleet-replay-100k"]
 OTHER = {"tsd_http_requests_total{route=api/query,status=200}": 40,
          "tsd_query_group_reduce_total{mode=sorted}": 40}
+by_name = pytest.mark.parametrize("name", sorted(LANES))
 
 
-def value(before: dict, after: dict):
-    spec = readers.load_layer(ROOT, NAME)
+def value(name: str, before: tuple, after: tuple):
+    """The metric from (fast lane, other lane) counts at the window's
+    two ends; None = the sample is not exported."""
+    def snapshot(counts):
+        return dict(OTHER, **{key: n for key, n in zip(LANES[name], counts)
+                              if n is not None})
+    spec = readers.load_layer(ROOT, name)
     assert spec["reader"]["kind"] == "counter_ratio"
-    return readers.read(ROOT, spec, {"ctr_before": dict(OTHER, **before),
-                                     "ctr_after": dict(OTHER, **after)})
+    return readers.read(ROOT, spec, {"ctr_before": snapshot(before),
+                                     "ctr_after": snapshot(after)})
 
 
+@by_name
 @pytest.mark.parametrize("before,after,want", [
-    ({DENSE: 10, FULL: 2}, {DENSE: 13, FULL: 3}, 75.0),
-    ({}, {DENSE: 3, FULL: 1}, 75.0),         # born inside the window
-    ({DENSE: 5}, {DENSE: 12}, 100.0),        # `full` never exported
-    ({FULL: 5}, {FULL: 9}, 0.0),
-], ids=["dense3_full1", "first_seen_in_window", "all_dense", "all_full"])
-def test_the_share_is_dense_over_both_lanes(before, after, want):
-    assert value(before, after) == pytest.approx(want)
+    ((10, 2), (13, 3), 75.0),
+    ((None, None), (3, 1), 75.0),           # born inside the window
+    ((5, None), (12, None), 100.0),         # the other lane never exported
+    ((None, 5), (None, 9), 0.0),
+], ids=["fast3_other1", "first_seen_in_window", "all_fast", "all_other"])
+def test_the_share_is_the_fast_lane_over_both_lanes(name, before, after,
+                                                    want):
+    assert value(name, before, after) == pytest.approx(want)
 
 
-@pytest.mark.parametrize("snap", [{}, {DENSE: 7, FULL: 1}],
+@by_name
+@pytest.mark.parametrize("counts", [(None, None), (7, 1)],
                          ids=["no_such_counter", "no_dispatch_in_window"])
-def test_nothing_is_reported_without_a_counted_dispatch(snap):
-    """The parent commit has no such counter; a window may hold no
-    grouped dispatch: None, never an exception, and the line leaves the
+def test_nothing_is_reported_without_a_counted_dispatch(name, counts):
+    """A program from before the counter has none; a window may hold no
+    such dispatch: None, never an exception, and the line leaves the
     metric out."""
-    assert value(snap, dict(snap)) is None
+    assert value(name, counts, counts) is None
 
 
 @pytest.fixture(scope="module")
@@ -64,30 +82,49 @@ def test_the_live_index_with_the_new_entry_holds_to_every_rule(index,
     check(index)
 
 
-def test_the_entry_is_the_layer_files_and_lists_every_scan_cell(index):
-    entry = index["per_layer"][-1]          # appended, nothing moved
-    spec = readers.load_layer(ROOT, NAME)
-    assert entry["name"] == spec["name"] == NAME
+@by_name
+def test_the_entry_is_the_layer_files_and_lists_the_scan_cells(index,
+                                                               name):
+    entry = next(m for m in index["per_layer"] if m["name"] == name)
+    others = [m for m in index["per_layer"] if m is not entry]
+    spec = readers.load_layer(ROOT, name)
+    assert entry["name"] == spec["name"] == name
     for key in ("layer", "unit", "moves"):
         assert entry[key] == spec[key]
     assert (entry["source"], entry["better"]) == ("program_counter",
                                                   "higher")
     scan = next(m for m in index["end_to_end"]
                 if m["name"] == "scan_mpts_per_s")
-    assert entry["workloads"] == scan["workloads"]
+    # the four cells that were there when the entries were made, first and
+    # in the index's order; a cell a later PR adds is on the list if its
+    # traced run prints the metric, and reports scan_mpts_per_s if so
+    assert entry["workloads"][:len(SCAN_CELLS)] == SCAN_CELLS
+    assert set(entry["workloads"]) <= set(scan["workloads"])
     # the layer's name is the one its other metrics carry
-    assert entry["layer"] in {m["layer"] for m in index["per_layer"][:-1]}
+    assert entry["layer"] in {m["layer"] for m in others}
 
 
-def test_a_traced_rehearsal_reads_100_from_the_daemons_counter(tmp_path):
+@pytest.fixture(scope="module")
+def traced_line(tmp_path_factory):
+    """One traced rehearsal: its profile is asked for with SIGUSR1 and
+    ended with SIGUSR2 sent to the daemon's pid, which leads a session
+    of its own (benchmark/daemon.py) and is signalled all the same."""
+    out = tmp_path_factory.mktemp("lanes") / "out"
     proc = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "run.py"), "--out",
-         str(tmp_path / "out"), "--workload", "heavy-replay-solo",
-         "--seed", "2147483777", "--seconds", "4", "--trace", "1",
-         "--rehearse", "hosts=40,hours=2"],
+        [sys.executable, os.path.join(ROOT, "run.py"), "--out", str(out),
+         "--workload", "heavy-replay-solo", "--seed", "2147483777",
+         "--seconds", "4", "--trace", "1", "--rehearse", "hosts=40,hours=2"],
         cwd=REPO, capture_output=True, text=True, timeout=600)
     assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-3000:]
-    line = json.loads(proc.stdout.strip().splitlines()[-1])
+    for marker in ("started", "stopped"):       # the profile was taken
+        assert os.path.getsize(out / "trace" / marker) > 0
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
+@by_name
+def test_a_traced_rehearsal_reads_100_from_the_daemons_counter(traced_line,
+                                                               name):
+    line = traced_line
     assert line["correct"] is True and line["failed"] == 0
     assert line["device"]["platform"] == "cpu"      # a rehearsal, no chip
-    assert line["metrics"][NAME] == {"value": 100.0, "unit": "%"}
+    assert line["metrics"][name] == {"value": 100.0, "unit": "%"}

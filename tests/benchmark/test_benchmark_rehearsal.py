@@ -148,8 +148,10 @@ def test_a_fifth_cell_is_added_by_files_alone(tmp_path):
         "reader": {"kind": "latattr", "route": "api/query",
                    "phases": ["plan"]}}))
     b["workloads"].append(cell)
-    for m in b["end_to_end"]:
-        if m["name"] == "scan_mpts_per_s":
+    # its name goes on the list of each metric it reports: the end-to-end
+    # one, and the per-layer one every cell reports
+    for m in b["end_to_end"] + b["per_layer"]:
+        if m["name"] in ("scan_mpts_per_s", "compiles_in_window"):
             m["workloads"].append("p99-only")
     b["per_layer"].append({
         "name": "p99_plan_ms", "unit": "ms", "better": "lower",
@@ -164,6 +166,10 @@ def test_a_fifth_cell_is_added_by_files_alone(tmp_path):
                                                         "setup_s"}
     line = last_line(run_cell(tmp_path, *common, "--trace", "1"))
     assert line["correct"] and line["metrics"]["p99_plan_ms"]["value"] > 0
+    assert set(line["metrics"]) == {"p99_plan_ms", "compiles_in_window"}
+    index = ic.load_index(str(tmp_path), "BENCHMARK.json")
+    for check in ic.CHECKS:
+        check(index)
 
 
 def test_a_configuration_of_another_fleet_size_is_added_by_files_alone(
