@@ -81,7 +81,13 @@ METRICS_SCHEMA: dict[str, MetricSpec] = {
         "counter", ("stage",),
         "Cumulative wall milliseconds of the planner's host stages, "
         "tracing on or off: scan (resolve + group, or their memo), "
-        "count (per-row point counts, budget), extract, assemble."),
+        "count (per-row point counts, budget), extract, assemble; on "
+        "the streamed route, inside latattr's dispatch and summed over "
+        "a request's chunks, stream_pack (the host's fill of one "
+        "[S, n] chunk out of the store), stream_upload (the chunk's "
+        "three arrays handed to the device and its fold enqueued) and "
+        "stream_wait (the reads that wait for folds in flight: the "
+        "every-16th-chunk backpressure and the out-of-slice audit)."),
     "tsd.query.group_reduce": _m(
         "counter", ("mode",),
         "Grouped dispatches of the monolithic pipeline, by the "
@@ -106,6 +112,34 @@ METRICS_SCHEMA: dict[str, MetricSpec] = {
         "prefix scan and two per-cell gathers skip it.  Counted where "
         "tsd.query.contrib_lane is, from the same fetch; on the mesh "
         "shift means every shard's rows."),
+    # -- the streamed fold (query/planner.py _stream_grouped) ----------- #
+    "tsd.query.stream.requests": _m(
+        "counter", (),
+        "Grouped segments answered by the streamed fold: the batch was "
+        "never materialized, bounded [S, n] chunks went from the store "
+        "into a device-resident [S, W] moment state.  Over "
+        "tsd.http.requests of api/query, the share of requests on the "
+        "route."),
+    "tsd.query.stream.chunks": _m(
+        "counter", (),
+        "Chunks the streamed fold folded into its device state (a "
+        "chunk no series had a point for is skipped and not counted)."),
+    "tsd.query.stream.points": _m(
+        "counter", (),
+        "Stored points (mask-true cells of the chunks) the streamed "
+        "fold handed to the device."),
+    "tsd.query.stream.upload_bytes": _m(
+        "counter", (),
+        "Bytes of the chunk arrays (int64 timestamps, float64 values, "
+        "bool mask) the streamed fold uploaded, padding included: over "
+        "tsd.query.stream.points, 17 is the floor."),
+    "tsd.query.stream.fold": _m(
+        "counter", ("lane",),
+        "Chunks of the streamed fold by the update each took "
+        "(ops/streaming.py): sliced = merged into the [w0, w0 + wc) "
+        "window slice its points span, O(S*wc); full = its span "
+        "overflowed the slice sized from the first chunk (or the grid "
+        "is not fixed), so the whole [S, W] state was merged."),
     "tsd.http.response_bytes": _m(
         "counter", ("route",),
         "Response body bytes written, by registered route."),

@@ -1564,24 +1564,32 @@ class QueryRunner:
         use_slice = window_spec.kind == "fixed"
         first_ms = int(np.asarray(wargs["first"])) if use_slice else 0
         interval = window_spec.interval_ms
+        # what the route did, for /api/stats/prometheus: chunks folded (a
+        # skipped empty chunk is not one) by the fold each took, the
+        # mask-true points handed to the device, and the bytes uploaded
+        # for them, padding included
+        folded = {"sliced": 0, "full": 0}
+        points = upload_bytes = 0
         for chunk_i in range(n_chunks_total):
-            ts = np.full((s_rows, n_chunk), PAD_TS, np.int64)
-            val = np.zeros((s_rows, n_chunk), np.float64)
-            mask = np.zeros((s_rows, n_chunk), bool)
-            tmin = tmax = None
-            for i, series in enumerate(series_list):
-                t, fv = series.window_chunk(seg.start_ms, seg.end_ms,
-                                            cursors[i], n_chunk, fix)
-                m = len(t)
-                if m:
-                    ts[i, :m] = t
-                    val[i, :m] = fv
-                    mask[i, :m] = True
-                    cursors[i] = int(t[-1])
-                    tmin = int(t[0]) if tmin is None else min(tmin,
-                                                              int(t[0]))
-                    tmax = int(t[-1]) if tmax is None else max(tmax,
-                                                               int(t[-1]))
+            with obs_trace.timed_stage("stream_pack"):
+                ts = np.full((s_rows, n_chunk), PAD_TS, np.int64)
+                val = np.zeros((s_rows, n_chunk), np.float64)
+                mask = np.zeros((s_rows, n_chunk), bool)
+                tmin = tmax = None
+                for i, series in enumerate(series_list):
+                    t, fv = series.window_chunk(seg.start_ms, seg.end_ms,
+                                                cursors[i], n_chunk, fix)
+                    m = len(t)
+                    if m:
+                        ts[i, :m] = t
+                        val[i, :m] = fv
+                        mask[i, :m] = True
+                        points += m
+                        cursors[i] = int(t[-1])
+                        tmin = int(t[0]) if tmin is None else min(
+                            tmin, int(t[0]))
+                        tmax = int(t[-1]) if tmax is None else max(
+                            tmax, int(t[-1]))
             if tmin is None:
                 # a pointless chunk folds nothing: skip it — and, when
                 # the accumulator doesn't exist yet, WITHOUT creating
@@ -1603,11 +1611,14 @@ class QueryRunner:
             if acc.window_slice is not None and tmin is not None \
                     and (tmax - tmin) // interval + 2 <= acc.window_slice:
                 w0 = (tmin - first_ms) // interval
-            if use_sharded:
-                acc.update(ts, val, mask, w0=w0)
-            else:
-                acc.update(jnp.asarray(ts), jnp.asarray(val),
-                           jnp.asarray(mask), w0=w0)
+            with obs_trace.timed_stage("stream_upload"):
+                if use_sharded:
+                    acc.update(ts, val, mask, w0=w0)
+                else:
+                    acc.update(jnp.asarray(ts), jnp.asarray(val),
+                               jnp.asarray(mask), w0=w0)
+            folded["full" if w0 is None else "sliced"] += 1
+            upload_bytes += ts.nbytes + val.nbytes + mask.nbytes
             if (chunk_i + 1) % 16 == 0:
                 # Backpressure: updates enqueue asynchronously, and a long
                 # scan would otherwise stage hundreds of chunk transfers
@@ -1616,23 +1627,51 @@ class QueryRunner:
                 # enqueued so far, which bounds what is in flight; the
                 # cadence keeps the host-pack / device-fold overlap in
                 # between.
-                np.asarray(acc.state["n"][:1, :1])
+                with obs_trace.timed_stage("stream_wait"):
+                    np.asarray(acc.state["n"][:1, :1])
 
         if acc is None:     # zero chunks (empty range): empty state
             acc = make_acc(None)
-        if acc.oob_count():
+        with obs_trace.timed_stage("stream_wait"):
+            # the one read every sliced scan ends on: it waits for the
+            # folds still in flight
+            oob = acc.oob_count()
+        self._count_stream(folded, points, upload_bytes)
+        if oob:
             # w0 = floor((chunk_min - first)/interval) with wc >= the
             # chunk's span makes this impossible; a nonzero count means
             # dropped points, never serve a wrong answer
             raise RuntimeError(
                 "internal: %d points fell outside their declared "
-                "streaming window slice" % acc.oob_count())
+                "streaming window slice" % oob)
         if use_sharded:
             return acc.finish_tail(spec, gid, g_pad)
         step = spec.downsample
         wts, v, m = acc.finish(step.function, step.fill_policy,
                                step.fill_value)
         return run_grid_tail(spec, wts, v, m, jnp.asarray(gid), g_pad)
+
+    @staticmethod
+    def _count_stream(folded: dict[str, int], points: int,
+                      upload_bytes: int) -> None:
+        """One streamed request's counters (tsd.query.stream.*)."""
+        REGISTRY.counter(
+            "tsd.query.stream.requests", "Grouped segments answered by "
+            "the streamed fold").inc()
+        REGISTRY.counter(
+            "tsd.query.stream.chunks", "Chunks the streamed fold folded "
+            "into its device state").inc(sum(folded.values()))
+        REGISTRY.counter(
+            "tsd.query.stream.points", "Stored points the streamed fold "
+            "handed to the device").inc(points)
+        REGISTRY.counter(
+            "tsd.query.stream.upload_bytes", "Bytes of the chunk arrays "
+            "the streamed fold uploaded, padding included").inc(
+                upload_bytes)
+        for lane, n in folded.items():
+            REGISTRY.counter(
+                "tsd.query.stream.fold", "Chunks of the streamed fold, "
+                "by the update each took").labels(lane=lane).inc(n)
 
     # Cap on groups fused into one batched union dispatch (the tile
     # budget divides by the batch size, so bigger fusions trade tile

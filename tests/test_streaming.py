@@ -985,3 +985,71 @@ class TestSketchDriftBound:
         1/(2K) allowance."""
         from opentsdb_tpu.ops.streaming import SKETCH_K
         assert self._drift(1) <= 0.5 / SKETCH_K
+
+
+class TestStreamedProgramsPinned:
+    """The streamed route's jitted programs — `_update`, `_update_sliced`,
+    `_finish` and the shared `_grid_tail` — lower, at one small shape, to
+    the text they lowered to at PR 34's tree (6ed9b6c; the hashes were
+    taken there and on the tree that added this test, and were equal).
+    XLA's compile-cache key is the program: while these hold, a daemon of
+    this tree loads the cache entries a daemon of that one wrote, and a
+    benchmark pair of the two compares like with like.  A PR that means
+    to change one of these programs changes its hash here, and says so;
+    a PR that only instruments the host side around them (PR 35's stages
+    and counters in `_stream_grouped`) must leave them."""
+
+    PINNED = {
+        "_update":
+            "cae13741bcaf18fc4d089d64cd7dfe9bc7fb17912daf6e2f5a554dd41de7b798",
+        "_update_sliced":
+            "269fb7a88518e33f421d90d31bc8dad3699b66d2fbc23012eab210666fd40689",
+        "_finish":
+            "8a13f21e24e69071d6d7ffa973efce99b65501005b7aafe2f7928e91b84584e2",
+        "_grid_tail":
+            "e327773d02797d3c96203d3eecd763cdc3c53cd0223bcf4e6f88a43ef9b37850",
+    }
+
+    @staticmethod
+    def _lowered(name):
+        from opentsdb_tpu.ops import pipeline, streaming
+        from opentsdb_tpu.ops.streaming import lanes_for
+        # heavy-cold-scan's region classes in small: 12 h of 10-minute
+        # windows (72), 8 series, chunks of 64
+        windows = FixedWindows.for_range(START, START + 43_200_000 - 1,
+                                         600_000)
+        spec, wargs = windows.split()
+        s, n = 8, 64
+        ts = np.full((s, n), PAD, np.int64)
+        val = np.zeros((s, n), np.float64)
+        mask = np.zeros((s, n), bool)
+        lanes = lanes_for(["avg"])
+        full = StreamAccumulator.create(s, spec, wargs, lanes=lanes)
+        if name == "_update":
+            return streaming._jitted_update.lower(
+                full.spec, full.state, ts, val, mask, full.wargs)
+        if name == "_update_sliced":
+            sliced = StreamAccumulator.create(s, spec, wargs, lanes=lanes,
+                                              window_slice=16)
+            assert sliced.window_slice == 64 < spec.count
+            return streaming._jitted_update_sliced.lower(
+                sliced.spec, sliced.window_slice, sliced.state, ts, val,
+                mask, sliced.wargs, 3)
+        if name == "_finish":
+            return streaming._jitted_finish.lower(
+                full.spec, "avg", FILL_NONE, full.state, full.wargs, 0.0)
+        wts, v, m = full.finish("avg")
+        pspec = pipeline.PipelineSpec(
+            aggregator="sum", downsample=pipeline.DownsampleStep("avg", spec))
+        return pipeline._jitted_grid_tail.lower(
+            pspec, 4, wts, v, m, np.arange(s, dtype=np.int32) % 3)
+
+    @pytest.mark.parametrize("name", sorted(PINNED))
+    def test_lowers_to_the_text_pinned_at_pr34(self, name):
+        import hashlib
+        text = self._lowered(name).as_text()
+        assert hashlib.sha256(text.encode()).hexdigest() == \
+            self.PINNED[name], (
+                "%s lowers to another program than at 6ed9b6c (%d "
+                "characters of text): a daemon of this tree compiles it "
+                "anew where that one's cache entry was" % (name, len(text)))
