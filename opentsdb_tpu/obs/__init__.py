@@ -83,10 +83,13 @@ METRICS_SCHEMA: dict[str, MetricSpec] = {
         "tracing on or off: scan (resolve + group, or their memo), "
         "count (per-row point counts, budget), extract, assemble; on "
         "the streamed route, inside latattr's dispatch and summed over "
-        "a request's chunks, stream_pack (the host's fill of one "
-        "[S, n] chunk out of the store), stream_upload (the chunk's "
-        "three arrays handed to the device and its fold enqueued) and "
-        "stream_wait (the reads that wait for folds in flight: the "
+        "a request's chunks, stream_pack (the chunk packer: every "
+        "series' window bounds once a request, then the host's fill "
+        "of each [S, n] chunk out of the store by bulk copies), "
+        "stream_upload (the chunk's three arrays handed to the device "
+        "and its fold enqueued) and stream_wait (the waits on the "
+        "device: for the upload out of a chunk buffer before it is "
+        "refilled, and the reads that wait for folds in flight, the "
         "every-16th-chunk backpressure and the out-of-slice audit)."),
     "tsd.query.group_reduce": _m(
         "counter", ("mode",),
@@ -140,6 +143,17 @@ METRICS_SCHEMA: dict[str, MetricSpec] = {
         "window slice its points span, O(S*wc); full = its span "
         "overflowed the slice sized from the first chunk (or the grid "
         "is not fixed), so the whole [S, W] state was merged."),
+    "tsd.query.stream.rows": _m(
+        "counter", ("lane",),
+        "Series rows of the chunks the streamed fold folded, by the "
+        "lane that filled each (storage/chunk_pack.py): bulk = the "
+        "series' version still was what it was when the scan took its "
+        "window bounds, so the row was copied with all the others, "
+        "out of views, without its lock; cursor = the series moved "
+        "mid-scan (an append, a delete, a dedup) and the row was read "
+        "by the locked timestamp cursor (Series.window_chunk) from "
+        "then on.  bulk + cursor = series x tsd.query.stream.chunks; "
+        "a store nobody writes to reads bulk only."),
     "tsd.http.response_bytes": _m(
         "counter", ("route",),
         "Response body bytes written, by registered route."),

@@ -690,14 +690,19 @@ class StreamAccumulator:
                                              with_oob=wc is not None),
                                  wc)
 
-    def update(self, ts, val, mask, w0: int | None = None) -> None:
+    def update(self, ts, val, mask, w0: int | None = None) -> tuple:
         """Fold one [S, n] chunk in (async — returns at enqueue).
 
         `w0`: index of the first grid window this chunk's points can
         touch (host-known for time-ordered chunking).  With a
         window_slice-enabled accumulator this routes to the sliced
         update — the chunk must fit in [w0, w0 + window_slice); points
-        outside are counted in oob_count() rather than folded."""
+        outside are counted in oob_count() rather than folded.
+
+        Host arrays are uploaded here; the three device arrays are
+        handed back, so a caller that reuses its host buffers can wait
+        for the transfers (storage/chunk_pack.py)."""
+        ts, val, mask = jnp.asarray(ts), jnp.asarray(val), jnp.asarray(mask)
         if w0 is not None and self.window_slice is not None:
             self.state = _jitted_update_sliced(
                 self.spec, self.window_slice, self.state, ts, val, mask,
@@ -705,6 +710,7 @@ class StreamAccumulator:
         else:
             self.state = _jitted_update(self.spec, self.state, ts, val,
                                         mask, self.wargs)
+        return ts, val, mask
 
     def oob_count(self) -> int:
         """Valid points sliced updates missed (w0 contract violations);

@@ -65,8 +65,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from opentsdb_tpu.ops.downsample import pad_pow2
-from opentsdb_tpu.ops.pipeline import PAD_TS, run_grid_tail
+from opentsdb_tpu.ops.pipeline import run_grid_tail
 from opentsdb_tpu.ops.streaming import StreamAccumulator
+from opentsdb_tpu.storage.chunk_pack import ChunkPacker
 
 # Per-cell byte weights for plan sizing.  Spill entries hold contrib
 # (f64) + participate (bool) + actual mask (bool) per (series, window)
@@ -290,9 +291,11 @@ def _stream_tile(tsdb, seg, tile_series, window_spec, wargs, lanes,
 
     Device-cache fast path first: a metric pinned in HBM whose padded
     [S_tile, N] batch fits the cache's batch budget serves in one
-    on-device gather.  Otherwise the chunked streaming loop — per-series
-    timestamp cursors, one [S_tile, n_chunk] compile, async overlap,
-    the same sliced-update sizing the resident streamed path uses."""
+    on-device gather.  Otherwise the chunked streaming loop — chunks
+    filled by the same packer as the resident streamed path's
+    (storage/chunk_pack.py: bulk copies, the timestamp cursor for rows
+    that moved mid-scan), one [S_tile, n_chunk] compile, async overlap,
+    the same sliced-update sizing."""
     from opentsdb_tpu.ops.pipeline import run_downsample_grid
 
     s = len(tile_series)
@@ -313,30 +316,15 @@ def _stream_tile(tsdb, seg, tile_series, window_spec, wargs, lanes,
     use_slice = window_spec.kind == "fixed"
     first_ms = int(np.asarray(wargs["first"])) if use_slice else 0
     interval = window_spec.interval_ms
-    max_len = max((sr.window_count(seg.start_ms, seg.end_ms, fix)
-                   for sr in tile_series), default=0)
-    n_chunks_total = -(-max_len // n_chunk) if max_len else 0
-    cursors: list = [None] * s
+    packer = ChunkPacker(tile_series, seg.start_ms, seg.end_ms, n_chunk, s,
+                         fix)
+    n_chunks_total = -(-packer.max_len // n_chunk)
     acc = None
     for chunk_i in range(n_chunks_total):
-        ts = np.full((s, n_chunk), PAD_TS, np.int64)
-        val = np.zeros((s, n_chunk), np.float64)
-        mask = np.zeros((s, n_chunk), bool)
-        tmin = tmax = None
-        for i, series in enumerate(tile_series):
-            t, fv = series.window_chunk(seg.start_ms, seg.end_ms,
-                                        cursors[i], n_chunk, fix)
-            m = len(t)
-            if m:
-                ts[i, :m] = t
-                val[i, :m] = fv
-                mask[i, :m] = True
-                cursors[i] = int(t[-1])
-                tmin = int(t[0]) if tmin is None else min(tmin, int(t[0]))
-                tmax = int(t[-1]) if tmax is None else max(tmax,
-                                                           int(t[-1]))
-        if tmin is None:
+        chunk = packer.fill()
+        if chunk is None:
             continue
+        ts, val, mask, tmin, tmax, _ = chunk
         if acc is None:
             wslice = None
             if use_slice:
@@ -348,11 +336,11 @@ def _stream_tile(tsdb, seg, tile_series, window_spec, wargs, lanes,
         if acc.window_slice is not None \
                 and (tmax - tmin) // interval + 2 <= acc.window_slice:
             w0 = (tmin - first_ms) // interval
-        acc.update(jnp.asarray(ts), jnp.asarray(val), jnp.asarray(mask),
-                   w0=w0)
+        packer.uploaded(acc.update(ts, val, mask, w0=w0))
         if (chunk_i + 1) % 16 == 0:
             # backpressure: drain the async queue (see _stream_grouped)
             np.asarray(acc.state["n"][:1, :1])
+    packer.close()
     if acc is None:
         acc = StreamAccumulator.create(s, window_spec, wargs,
                                        sketch=sketch, lanes=lanes)

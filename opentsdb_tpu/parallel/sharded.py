@@ -506,8 +506,10 @@ class ShardedStreamAccumulator:
                 mesh, window_spec, self.window_slice, keys)
 
     def update(self, ts: np.ndarray, val: np.ndarray,
-               mask: np.ndarray, w0: int | None = None) -> None:
-        """Fold one [num_series, n] host chunk (async — returns at enqueue).
+               mask: np.ndarray, w0: int | None = None) -> tuple:
+        """Fold one [num_series, n] host chunk (async — returns at enqueue)
+        and hand back the three row-sharded device arrays uploaded for it
+        (see StreamAccumulator.update).
 
         Rows are padded to the sharded row count (callers may pack chunks
         at `s_pad` rows directly to skip the copy); padding rows carry
@@ -526,9 +528,10 @@ class ShardedStreamAccumulator:
             self.state = self._update_sliced(self.state, d_ts, d_val,
                                              d_mask, self.wargs,
                                              jnp.asarray(w0, jnp.int64))
-            return
-        self.state = self._update(self.state, d_ts, d_val, d_mask,
-                                  self.wargs)
+        else:
+            self.state = self._update(self.state, d_ts, d_val, d_mask,
+                                      self.wargs)
+        return d_ts, d_val, d_mask
 
     def oob_count(self) -> int:
         """Valid points sliced folds missed (w0 contract violations);

@@ -30,11 +30,12 @@ from opentsdb_tpu.ops.pipeline import (
     PipelineSpec, DownsampleStep, run_pipeline, run_group_pipeline,
     run_union_batch_pipeline,
     run_group_rollup_avg_pipeline, run_grid_tail, build_batch,
-    build_batch_direct, PAD_TS, LANE_DENSE, LANE_SHIFT)
+    build_batch_direct, LANE_DENSE, LANE_SHIFT)
 from opentsdb_tpu.ops.streaming import (
     StreamAccumulator, STREAMABLE_DS, is_sketch_ds, lanes_for)
 from opentsdb_tpu.query import filters as query_filters
 from opentsdb_tpu.rollup.config import NoSuchRollupForInterval, RollupQuery
+from opentsdb_tpu.storage.chunk_pack import ChunkPacker
 from opentsdb_tpu.storage.memstore import Series, SeriesKey
 from opentsdb_tpu.uid import NoSuchUniqueName
 from opentsdb_tpu.utils import datetime_util as DT
@@ -1483,11 +1484,16 @@ class QueryRunner:
         host packs chunk k+1 while the device reduces chunk k (JAX async
         dispatch = the ScannerCB overlap, SaltScanner.java:463).
 
-        Each chunk is copied straight out of the store (window_chunk) —
-        the full range is NEVER materialized on the host, so host RAM
-        stays O(store + chunk).  Like the reference's scanner over live
-        HBase rows, the pass has no snapshot isolation: writes landing
-        mid-query may or may not be seen (SaltScanner.java:269).
+        Each chunk is copied straight out of the store by the chunk
+        packer (storage/chunk_pack.py): every series' window bounds are
+        taken once, under its lock, and a chunk is two bulk copies over
+        all rows into buffers the packer reuses; a row whose series
+        moved mid-scan falls back to the locked timestamp-cursor read
+        (window_chunk).  The full range is NEVER materialized on the
+        host, so host RAM stays O(store + chunk).  Like the reference's
+        scanner over live HBase rows, the pass has no snapshot
+        isolation: writes landing mid-query may or may not be seen
+        (SaltScanner.java:269).
         """
         import jax.numpy as jnp
         tsdb = self.tsdb
@@ -1555,10 +1561,6 @@ class QueryRunner:
                 s, window_spec, wargs, sketch=sketch, lanes=lanes,
                 window_slice=wslice)
 
-        # timestamp cursors, not index offsets: monotone progression means
-        # no pre-existing point is ever streamed twice even when an out-of-
-        # order write shifts buffer positions mid-query (see window_chunk)
-        cursors: list[int | None] = [None] * s
         n_chunks_total = -(-max_len // n_chunk)
         self._bump("streamedChunks", n_chunks_total)
         use_slice = window_spec.kind == "fixed"
@@ -1570,27 +1572,17 @@ class QueryRunner:
         # for them, padding included
         folded = {"sliced": 0, "full": 0}
         points = upload_bytes = 0
+        with obs_trace.timed_stage("stream_pack"):
+            # every series' window bounds, once, under its lock
+            packer = ChunkPacker(series_list, seg.start_ms, seg.end_ms,
+                                 n_chunk, s_rows, fix)
         for chunk_i in range(n_chunks_total):
+            with obs_trace.timed_stage("stream_wait"):
+                # the buffers about to be refilled: their upload is done
+                packer.reclaim()
             with obs_trace.timed_stage("stream_pack"):
-                ts = np.full((s_rows, n_chunk), PAD_TS, np.int64)
-                val = np.zeros((s_rows, n_chunk), np.float64)
-                mask = np.zeros((s_rows, n_chunk), bool)
-                tmin = tmax = None
-                for i, series in enumerate(series_list):
-                    t, fv = series.window_chunk(seg.start_ms, seg.end_ms,
-                                                cursors[i], n_chunk, fix)
-                    m = len(t)
-                    if m:
-                        ts[i, :m] = t
-                        val[i, :m] = fv
-                        mask[i, :m] = True
-                        points += m
-                        cursors[i] = int(t[-1])
-                        tmin = int(t[0]) if tmin is None else min(
-                            tmin, int(t[0]))
-                        tmax = int(t[-1]) if tmax is None else max(
-                            tmax, int(t[-1]))
-            if tmin is None:
+                chunk = packer.fill()
+            if chunk is None:
                 # a pointless chunk folds nothing: skip it — and, when
                 # the accumulator doesn't exist yet, WITHOUT creating
                 # it, so the window_slice sizing below sees the first
@@ -1598,6 +1590,8 @@ class QueryRunner:
                 # first chunk used to pin window_slice=None and every
                 # later chunk paid the full-grid O(S*W) fold)
                 continue
+            ts, val, mask, tmin, tmax, m = chunk
+            points += m
             if acc is None:
                 wslice = None
                 if use_slice:
@@ -1608,15 +1602,13 @@ class QueryRunner:
                     wslice = 2 * ((tmax - tmin) // interval + 2)
                 acc = make_acc(wslice)
             w0 = None
-            if acc.window_slice is not None and tmin is not None \
+            if acc.window_slice is not None \
                     and (tmax - tmin) // interval + 2 <= acc.window_slice:
                 w0 = (tmin - first_ms) // interval
             with obs_trace.timed_stage("stream_upload"):
-                if use_sharded:
-                    acc.update(ts, val, mask, w0=w0)
-                else:
-                    acc.update(jnp.asarray(ts), jnp.asarray(val),
-                               jnp.asarray(mask), w0=w0)
+                # both accumulators upload the host chunk themselves and
+                # hand the device arrays back: the packer's gate
+                packer.uploaded(acc.update(ts, val, mask, w0=w0))
             folded["full" if w0 is None else "sliced"] += 1
             upload_bytes += ts.nbytes + val.nbytes + mask.nbytes
             if (chunk_i + 1) % 16 == 0:
@@ -1636,7 +1628,12 @@ class QueryRunner:
             # the one read every sliced scan ends on: it waits for the
             # folds still in flight
             oob = acc.oob_count()
-        self._count_stream(folded, points, upload_bytes)
+            # and the chunk buffers go to the next scan once the last
+            # uploads have read them
+            packer.close()
+        self._count_stream(folded, points, upload_bytes,
+                           {"bulk": packer.rows_bulk,
+                            "cursor": packer.rows_cursor})
         if oob:
             # w0 = floor((chunk_min - first)/interval) with wc >= the
             # chunk's span makes this impossible; a nonzero count means
@@ -1653,7 +1650,7 @@ class QueryRunner:
 
     @staticmethod
     def _count_stream(folded: dict[str, int], points: int,
-                      upload_bytes: int) -> None:
+                      upload_bytes: int, rows: dict[str, int]) -> None:
         """One streamed request's counters (tsd.query.stream.*)."""
         REGISTRY.counter(
             "tsd.query.stream.requests", "Grouped segments answered by "
@@ -1672,6 +1669,11 @@ class QueryRunner:
             REGISTRY.counter(
                 "tsd.query.stream.fold", "Chunks of the streamed fold, "
                 "by the update each took").labels(lane=lane).inc(n)
+        for lane, n in rows.items():
+            REGISTRY.counter(
+                "tsd.query.stream.rows", "Series rows of the folded "
+                "chunks, by the lane that filled each").labels(
+                    lane=lane).inc(n)
 
     # Cap on groups fused into one batched union dispatch (the tile
     # budget divides by the batch size, so bigger fusions trade tile

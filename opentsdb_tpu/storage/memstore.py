@@ -288,6 +288,27 @@ class Series:
                                                 fix_duplicates)
             return hi - lo
 
+    def window_views(self, start_ms: int, end_ms: int,
+                     fix_duplicates: bool = True
+                     ) -> tuple[np.ndarray, np.ndarray, int]:
+        """(ts, float_vals, version) of [start_ms, end_ms] as VIEWS of the
+        live buffers, taken under one lock hold — the streaming scan's
+        bulk read (storage/chunk_pack.py), which copies out of them
+        without the lock.
+
+        Such a reader must re-read `version` AFTER each copy and drop
+        what it copied when the version has moved: every mutation that
+        moves stored points bumps the version BEFORE it moves them (an
+        append writes past the views, and bumps it before any sort or
+        dedup its point makes needful), so an unchanged version says
+        the copy saw the points as they were when the bounds were taken.
+        A grown buffer is a new array; the views keep the old one alive.
+        """
+        with self._lock:
+            lo, hi = self._window_bounds_locked(start_ms, end_ms,
+                                                fix_duplicates)
+            return self._ts[lo:hi], self._val[lo:hi], self._version
+
     def window_stats(self, start_ms: int, end_ms: int,
                      fix_duplicates: bool = True) -> tuple[int, bool]:
         """(point count, every value integer-typed) for the range,
@@ -343,7 +364,10 @@ class Series:
                      fix_duplicates: bool = True
                      ) -> tuple[np.ndarray, np.ndarray]:
         """Copy up to `limit` window points with timestamp > `after_ts`
-        (None = from the window start) — the streaming scan's cursor read.
+        (None = from the window start) — the streaming scan's cursor read,
+        since storage/chunk_pack.py the lane for rows that moved mid-scan
+        (a row whose version still is what it was when the scan took its
+        bounds is copied in bulk out of window_views()).
 
         The cursor is a TIMESTAMP, not an index: concurrent out-of-order
         writes (or the dedup a normalize performs) shift buffer positions
@@ -379,13 +403,15 @@ class Series:
         with self._lock:
             if n > len(self._ts):
                 self._grow_locked(n)
+            # before the stored points move: window_views()' lock-free
+            # readers tell a torn copy by it
+            self._version += 1
             self._ts[:n] = ts
             self._val[:n] = val
             self._ival[:n] = ival
             self._isint[:n] = isint
             self._n = n
             self._sorted = sorted_flag
-            self._version += 1
 
     def arrays(self) -> tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
         """Copies of the full (ts, float_vals, int_vals, is_int) columns."""
@@ -406,12 +432,13 @@ class Series:
             if removed <= 0:
                 return 0
             keep = n - hi
+            # before the stored points move (see restore_arrays)
+            self._version += 1
             self._ts[lo:lo + keep] = self._ts[hi:n]
             self._val[lo:lo + keep] = self._val[hi:n]
             self._ival[lo:lo + keep] = self._ival[hi:n]
             self._isint[lo:lo + keep] = self._isint[hi:n]
             self._n = n - removed
-            self._version += 1
             return removed
 
     @property

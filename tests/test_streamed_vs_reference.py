@@ -89,6 +89,8 @@ def stream_counters() -> dict:
            for k in ("requests", "chunks", "points", "upload_bytes")}
     for lane in ("sliced", "full"):
         out[lane] = _counter("tsd.query.stream.fold", lane=lane)
+    for lane in ("bulk", "cursor"):
+        out[lane] = _counter("tsd.query.stream.rows", lane=lane)
     for stage in ("stream_pack", "stream_upload", "stream_wait"):
         out[stage] = _counter("tsd.query.stage_ms", stage=stage)
     return out
@@ -239,6 +241,18 @@ def test_one_streamed_query_moves_its_counters_and_stages(cold):
                  'tsd_query_stream_fold_total{lane="full"}',
                  "tsd_query_stream_requests_total "):
         assert line in text, line
+
+
+@pytest.mark.parametrize("cls", CLASSES)
+def test_a_quiet_store_fills_every_row_by_the_bulk_lane(cold, cls):
+    """Nothing writes while the request scans: each of its five chunks'
+    rows is copied in bulk, none by the cursor read."""
+    fleet, mgr, requests = cold
+    _, rose = ask(mgr, requests[cls])
+    assert rose["bulk"] == HOSTS * rose["chunks"] == HOSTS * 5
+    assert rose["cursor"] == 0
+    assert 'tsd_query_stream_rows_total{lane="bulk"}' in \
+        REGISTRY.prometheus_text()
 
 
 def test_the_stages_are_spans_under_the_pipeline_and_explain_is_unmoved(
