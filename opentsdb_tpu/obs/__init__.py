@@ -4,16 +4,16 @@ Three layers, one package (docs/observability.md):
 
   * obs/trace.py     span-tree tracer threaded through rpc_manager ->
                      QueryRpc -> planner -> cluster fan-out; spans carry
-                     wall + device time and ride /api/stats/query plus
-                     the inline showStats summary.
+                     wall time and ride /api/stats/query plus the
+                     inline showStats summary.
   * obs/registry.py  thread-safe counters / gauges / log-bucketed
                      latency histograms (obs/histogram.py) with a
                      Prometheus text-exposition endpoint
                      (/api/stats/prometheus).
   * obs/jaxprof.py   per-kernel compile accounting (the SHARED
                      compile-log capture tsdbsan's JaxSanitizer also
-                     subscribes to), device-cache gauges, and costmodel
-                     predicted-vs-actual feedback per query segment.
+                     subscribes to), device-cache gauges, and the
+                     costmodel's per-segment decisions.
 
 obs/selfreport.py closes the dogfooding loop: the daemon ingests its own
 tsd.* metrics into its own memstore every tsd.stats.interval seconds, so
@@ -64,6 +64,15 @@ METRICS_SCHEMA: dict[str, MetricSpec] = {
     "tsd.http.latency_ms": _m(
         "histogram", ("route",),
         "End-to-end HTTP request latency in milliseconds."),
+    "tsd.http.edge_ms": _m(
+        "counter", ("route", "stage"),
+        "Cumulative wall milliseconds of a request outside its handler, "
+        "added on the event loop: queue (the whole body read to the "
+        "handler's start on a responder thread), resume (the handler's "
+        "last latattr mark to the loop's resumption) and write (the "
+        "response encoded, written and drained).  With latattr's phases "
+        "they cover a request from its last byte in to its last byte "
+        "out."),
     "tsd.http.errors": _m(
         "gauge", ("family",),
         "HTTP error responses by family (4xx client / 5xx server)."),
@@ -81,7 +90,15 @@ METRICS_SCHEMA: dict[str, MetricSpec] = {
         "counter", ("stage",),
         "Cumulative wall milliseconds of the planner's host stages, "
         "tracing on or off: scan (resolve + group, or their memo), "
-        "count (per-row point counts, budget), extract, assemble; on "
+        "count (per-row point counts, budget), consult (the routing "
+        "verdict and its cache consults); inside latattr's dispatch "
+        "rewrite (a partial-aggregate rewrite whole: rw_pieces, the "
+        "per-piece narrowing and delta dispatches, rw_assemble, the "
+        "grid's concatenation or host materialization, and tail, the "
+        "grid tail's enqueue) and enqueue (the resident and mesh "
+        "programs' calls, row sharding included); fetch (the answer's "
+        "device-to-host copy, where a request waits for the device), "
+        "extract, assemble; on "
         "the streamed route, inside latattr's dispatch and summed over "
         "a request's chunks, stream_pack (the chunk packer: every "
         "series' window bounds once a request, then the host's fill "
@@ -340,15 +357,6 @@ METRICS_SCHEMA: dict[str, MetricSpec] = {
     # -- JAX / costmodel (obs/jaxprof.py, query/planner.py) ------------- #
     "tsd.jax.compiles": _m(
         "counter", ("kernel",), "XLA compilations per jitted kernel."),
-    "tsd.costmodel.segments": _m(
-        "counter", ("kind",),
-        "Query segments with predicted-vs-actual accounting."),
-    "tsd.costmodel.predicted_ms": _m(
-        "counter", ("kind",),
-        "Costmodel-predicted device milliseconds, summed."),
-    "tsd.costmodel.actual_ms": _m(
-        "counter", ("kind",),
-        "Measured device milliseconds, summed."),
     "tsd.costmodel.infeasible": _m(
         "counter", ("axis",),
         "Strategy decisions outside the feasible candidate set "

@@ -1,5 +1,5 @@
-"""JAX profiling hooks: compile accounting, device gauges, costmodel
-predicted-vs-actual feedback.
+"""JAX profiling hooks: compile accounting, device gauges, the costmodel's
+per-segment decisions.
 
 Compile capture — THE shared source.  `jax_log_compiles` emits one
 "Compiling <kernel> ..." log record per XLA compilation, synchronously
@@ -10,16 +10,13 @@ JaxSanitizer (tools/sanitize/jax_san.py) subscribe to the same capture,
 so the profiler and the sanitizer can never disagree about what
 compiled — one regex, one handler, one event stream.
 
-Costmodel feedback.  ops/costmodel.py predicts per-stage dispatch
-costs from its static per-unit table; `record_segment()` keeps a ring
-of (shape, chosen modes, feature vector, predicted, actual) per query
-segment plus running totals in the metrics registry, for an operator
-to read at /api/stats/query (nothing in the daemon reads it back).
-`segment_decisions()` recomputes the per-axis strategy decisions
-through the same choosers the kernels consult (the trace annotates
-them per segment), and `stage_breakdown()` apportions a fused
-dispatch's measured device time across downsample/rate/groupby/
-aggregate children (tagged estimated).
+Costmodel decisions.  `segment_decisions()` recomputes the per-axis
+strategy decisions through the same choosers the kernels consult (the
+trace annotates them per segment, the explain engine reports them), and
+`stage_breakdown()` predicts one grouped dispatch's seconds per logical
+stage from ops/costmodel.py's table (admission, plan decision, rollup
+and agg-cache pricing read it).  Nothing here times the device: that is
+the device trace's.
 """
 
 from __future__ import annotations
@@ -27,7 +24,6 @@ from __future__ import annotations
 import logging
 import re
 import threading
-from collections import deque
 
 from opentsdb_tpu.obs.registry import REGISTRY
 
@@ -230,14 +226,8 @@ def device_report() -> dict:
 
 
 # --------------------------------------------------------------------- #
-# Costmodel predicted-vs-actual                                         #
+# Costmodel decisions and predictions                                   #
 # --------------------------------------------------------------------- #
-
-SEGMENT_RING = 256
-
-_seg_lock = threading.Lock()
-# guarded-by: _seg_lock
-_segments: deque = deque(maxlen=SEGMENT_RING)
 
 
 def segment_decisions(platform: str, s: int, n: int, w: int, g: int,
@@ -280,36 +270,6 @@ def segment_decisions(platform: str, s: int, n: int, w: int, g: int,
     return out
 
 
-def segment_features(platform: str, s: int, n: int, w: int, g: int,
-                     has_rate: bool,
-                     decisions: dict[str, dict]) -> dict[str, float]:
-    """The per-unit-cost feature vector of one dispatch under its CHOSEN
-    modes: unit counts per costmodel term, summed across the pipeline
-    stages.  `dot(features, costmodel.costs(platform))` is the
-    dispatch's predicted seconds."""
-    from opentsdb_tpu.ops import costmodel as cm
-    s = max(int(s), 1)
-    n = max(int(n), 1)
-    w = max(int(w), 1)
-    g = max(int(g), 1)
-    e = w + 1
-    features: dict[str, float] = {}
-
-    def add(fv: dict[str, float]) -> None:
-        for term, units in fv.items():
-            features[term] = features.get(term, 0.0) + units
-
-    add(cm.features_search(decisions["search"]["mode"], s, n, e))
-    if "extreme" in decisions:
-        add(cm.features_extreme(decisions["extreme"]["mode"], s, n, e))
-    else:
-        add(cm.features_scan(decisions["scan"]["mode"], s, n, e))
-    add(cm.features_group(decisions["group"]["mode"], s, w, g))
-    # rate + final aggregate: elementwise passes over the [*, W] grids
-    add({"elem_f64": float(g * w + (s * w if has_rate else 0))})
-    return features
-
-
 def stage_breakdown(platform: str, s: int, n: int, w: int, g: int,
                     ds_function: str | None, has_rate: bool,
                     decisions: dict[str, dict] | None = None
@@ -317,8 +277,8 @@ def stage_breakdown(platform: str, s: int, n: int, w: int, g: int,
     """Predicted seconds per logical pipeline stage for one grouped
     dispatch, using the costmodel's table under the modes the
     kernels actually chose (`decisions`; recomputed here when absent).
-    Approximate by design — this is the PREDICTED side of the
-    predicted-vs-actual ledger, not a timer."""
+    Approximate by design: a prediction for pricing a route, not a
+    timer."""
     from opentsdb_tpu.ops import costmodel as cm
     s = max(int(s), 1)
     n = max(int(n), 1)
@@ -345,55 +305,3 @@ def stage_breakdown(platform: str, s: int, n: int, w: int, g: int,
     out["aggregate"] = g * w * elem
     return out
 
-
-def record_segment(kind: str, s: int, n: int, w: int, g: int,
-                   predicted_s: float, actual_ms: float,
-                   platform: str | None = None,
-                   modes: dict[str, str] | None = None,
-                   features: dict[str, float] | None = None,
-                   aggregator: str | None = None) -> None:
-    """One executed query segment's predicted-vs-actual device cost.
-    Lands in the in-process ring (`segments()`) and the registry
-    running totals.  Entries carrying `platform` + `features` (the
-    planner always sends both) hold what a re-anchoring of the table
-    would regress: actualMs against the feature vector."""
-    entry = {
-        "kind": kind, "series": int(s), "points": int(n),
-        "windows": int(w), "groups": int(g),
-        "predictedMs": round(predicted_s * 1e3, 4),
-        "actualMs": round(actual_ms, 4),
-    }
-    if platform is not None:
-        entry["platform"] = platform
-    if aggregator is not None:
-        # the group axis's extremes flag keys on this
-        entry["aggregator"] = aggregator
-    if modes is not None:
-        entry["modes"] = dict(modes)
-    if features is not None:
-        entry["features"] = {t: float(u) for t, u in features.items()}
-    with _seg_lock:
-        _segments.append(entry)
-    REGISTRY.counter(
-        "tsd.costmodel.segments",
-        "Query segments with predicted-vs-actual accounting").labels(
-            kind=kind).inc()
-    REGISTRY.counter(
-        "tsd.costmodel.predicted_ms",
-        "Costmodel-predicted device milliseconds, summed").labels(
-            kind=kind).inc(predicted_s * 1e3)
-    REGISTRY.counter(
-        "tsd.costmodel.actual_ms",
-        "Measured device milliseconds, summed").labels(
-            kind=kind).inc(actual_ms)
-
-
-def segments() -> list[dict]:
-    """The predicted-vs-actual ring, oldest first."""
-    with _seg_lock:
-        return list(_segments)
-
-
-def clear_segments() -> None:
-    with _seg_lock:
-        _segments.clear()

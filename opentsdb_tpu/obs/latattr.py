@@ -25,6 +25,17 @@ for EVERY request, tracing on or off:
   them.  tools/trace_gaps.py reads both: what the host did while the
   chip idled.  With no profile running nothing is created.
 
+* ``Edges`` — the three stretches of an HTTP request outside its
+  handler, stamped by the event loop (tsd/server.py) and the handler
+  (RpcManager.handle_http), counted always into
+  ``tsd.http.edge_ms{route, stage}``: ``queue`` (the whole body read to
+  the handler's start), ``resume`` (the handler's last mark to the
+  loop's resumption) and ``write`` (the response encoded, written and
+  drained).  With the phases they cover a request from its last byte
+  in to its last byte out.  Under a profile ``write`` is a ``tsd.phase``
+  event on the loop thread (stat ``resume_ms``), and the request's
+  first ``tsd.phase`` event carries ``queue_ms``.
+
 * ``LatencyAttribution`` — the aggregation engine.  Finished stamps
   fold into bounded streaming per-phase ``LogHistogram``s keyed by
   (route arm, plan fingerprint, clamped tenant), with exemplar trace
@@ -116,7 +127,8 @@ class PhaseStamps:
     the ambient ``_tls`` stamps."""
 
     __slots__ = ("t0", "_prev", "_prev_cpu", "deltas", "cpu", "phase",
-                 "route", "fingerprint", "tenant", "trace_id", "_ann")
+                 "route", "fingerprint", "tenant", "trace_id", "queue_ms",
+                 "_ann")
 
     def __init__(self, trace_id: str | None = None):
         now = time.perf_counter()
@@ -130,6 +142,9 @@ class PhaseStamps:
         self.fingerprint: str | None = None     # set by the planner
         self.tenant: str | None = None          # set by admission
         self.trace_id = trace_id
+        # the executor queue's wait before the handler started (Edges):
+        # a stat of the first tsd.phase event
+        self.queue_ms: float | None = None
         # the open tsd.phase annotation; None while no profile runs
         self._ann = open_annotation("tsd.phase")
 
@@ -155,6 +170,9 @@ class PhaseStamps:
         meta = {"phase": phase, "cpu_ms": cpu_s * 1e3}
         if self.trace_id is not None:
             meta["trace_id"] = self.trace_id
+        if self.queue_ms is not None:
+            meta["queue_ms"] = self.queue_ms
+            self.queue_ms = None
         close_annotation(self._ann, **meta)
         self._ann = None
 
@@ -168,6 +186,48 @@ class PhaseStamps:
 
     def total_ms(self) -> float:
         return (self._prev - self.t0) * 1e3
+
+
+# --------------------------------------------------------------------- #
+# The edges outside the handler                                         #
+# --------------------------------------------------------------------- #
+
+EDGES = ("queue", "resume", "write")
+
+
+class Edges:
+    """One HTTP request's stamps outside its handler.  The event loop
+    makes it when it has the whole body (``queued``) and writes the
+    response; the responder thread sets ``entered`` at the handler's
+    start and ``returned`` at its last mark, with the clamped ``route``
+    and the ``trace_id``.  Each field has one writer, and the loop reads
+    the handler's fields only after awaiting the handler's future."""
+
+    __slots__ = ("queued", "entered", "returned", "route", "trace_id")
+
+    def __init__(self):
+        self.queued = time.perf_counter()
+        self.entered: float | None = None
+        self.returned: float | None = None
+        self.route = "other"
+        self.trace_id: str | None = None
+
+    def written(self, resumed: float, ann) -> None:
+        """The response is out (or its write failed): close the loop's
+        ``write`` event ``ann``, opened at ``resumed``, and add the three
+        edges to tsd.http.edge_ms."""
+        now = time.perf_counter()
+        resume_ms = (resumed - self.returned) * 1e3
+        meta = {"phase": "write", "resume_ms": resume_ms}
+        if self.trace_id is not None:
+            meta["trace_id"] = self.trace_id
+        close_annotation(ann, **meta)
+        fam = REGISTRY.counter(
+            "tsd.http.edge_ms", "Cumulative wall milliseconds of a "
+            "request outside its handler")
+        for stage, ms in zip(EDGES, ((self.entered - self.queued) * 1e3,
+                                     resume_ms, (now - resumed) * 1e3)):
+            fam.labels(route=self.route, stage=stage).inc(ms)
 
 
 # --------------------------------------------------------------------- #

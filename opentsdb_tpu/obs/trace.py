@@ -9,23 +9,11 @@ no-ops at near-zero cost when no trace is active — library callers of
 QueryRunner.run() and the sanitizer's steady-state loops see no
 behavior change.
 
-Span times:
-
-  * ``wallMs``   start-to-finish wall time of the stage.
-  * ``deviceMs`` time spent waiting on device results inside the stage
-    (`device_wait()`: a block_until_ready at the stage boundary,
-    enabled by ``tsd.trace.device_time``).  JAX dispatch is
-    asynchronous, so this is queue+execute time for work the stage
-    enqueued — the honest observable without per-kernel device
-    profiling.  Stage children of a fused dispatch carry device time
-    APPORTIONED from the measured total by the costmodel's per-stage
-    predictions and say so (``estimated`` tag) — XLA fuses
-    downsample/rate/groupby/aggregate into one kernel, so per-stage
-    device truth does not exist at runtime.
-
-This module is a registered tsdbsan SANCTIONED_SITES entry: the
-device_wait sync is the trace path's one deliberate device->host
-rendezvous, and it must never count as a hidden hot-path sync.
+Span times: ``wallMs``, start-to-finish wall time of the stage.  The
+tracer syncs nothing: JAX dispatch stays asynchronous, and where a
+request waits for the device is latattr's ``device_wait`` phase and the
+planner's ``fetch`` stage (``timed_stage`` below); per-kernel device
+time is the device trace's (benchmark `--trace 1`, tools/trace_gaps.py).
 
 While a ``jax.profiler`` trace runs, every stack-managed span
 (``Trace.span()``/``stage()``, ``begin()``/``end()``) is also a
@@ -62,8 +50,7 @@ def _new_trace_id() -> str:
 class Span:
     """One named stage; a node in the trace tree."""
 
-    __slots__ = ("name", "tags", "children", "start", "wall_ms",
-                 "device_ms", "_ann")
+    __slots__ = ("name", "tags", "children", "start", "wall_ms", "_ann")
 
     def __init__(self, name: str, **tags):
         self.name = name
@@ -71,7 +58,6 @@ class Span:
         self.children: list[Span] = []
         self.start = time.perf_counter()
         self.wall_ms: float | None = None
-        self.device_ms = 0.0
         self._ann = None        # begin()'s open tsd.span annotation
 
     def finish(self) -> None:
@@ -93,7 +79,6 @@ class Span:
         out: dict = {
             "name": self.name,
             "wallMs": round(wall, 3),
-            "deviceMs": round(self.device_ms, 3),
         }
         if self.tags:
             # a stats scrape can render while another thread (the
@@ -115,10 +100,8 @@ class Span:
 class Trace:
     """One request's span tree + the id that names it across hosts."""
 
-    def __init__(self, name: str, trace_id: str | None = None,
-                 device_time: bool = True):
+    def __init__(self, name: str, trace_id: str | None = None):
         self.trace_id = trace_id or _new_trace_id()
-        self.device_time = device_time
         self.root = Span(name)
         # the span stack of the OWNING thread; cross-thread work uses
         # explicit Span.child() handles instead
@@ -242,18 +225,3 @@ def end(span: Span | None) -> None:
         tr._stack.pop()
     span.finish()
 
-
-def device_wait(span: Span | None, outputs) -> float:
-    """Block until `outputs` (a jax array or pytree) are ready,
-    attributing the wait to `span` as device time.  Returns the wait in
-    ms.  No-ops (0.0) when untraced or device timing is off — the
-    dispatch then stays fully asynchronous, exactly as before."""
-    tr = active()
-    if span is None or tr is None or not tr.device_time:
-        return 0.0
-    import jax
-    t0 = time.perf_counter()
-    jax.block_until_ready(outputs)
-    dt = (time.perf_counter() - t0) * 1e3
-    span.device_ms += dt
-    return dt

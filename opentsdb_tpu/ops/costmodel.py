@@ -39,9 +39,8 @@ below; CPU anchors are the dev box):
 
 Every `predict_*` is a LINEAR form: a dot product of a per-form feature
 vector (unit counts — gather rounds, scanned elements, scattered cells;
-`features_*` below) with the per-unit cost table.  obs/jaxprof.py
-records each executed query segment's prediction next to its measured
-device time (`tsd.costmodel.{predicted_ms, actual_ms}`).
+`features_*` below) with the per-unit cost table.  What a dispatch
+really took on the device is the device trace's (benchmark `--trace 1`).
 
 Reference being outperformed: the per-datapoint iterator stack
 (/root/reference/src/core/AggregationIterator.java:514,

@@ -49,10 +49,9 @@ product against ``COST_TERMS`` (spill write/read MB, per-tile dispatch
 overhead) per the linearity contract, `tsd/admission.py` prices the
 tiled plan with the same vector instead of shedding it, and every
 tiled pipeline span carries a ``tiling`` annotation (tile count, spill
-bytes).  Tiled executions are deliberately EXCLUDED
-from the predicted-vs-actual ring, like partial-aggregate rewrites: the
-monolithic stage breakdown does not describe a tiled execution
-(pinned by tests/test_tiling.py).
+bytes).  A tiled pipeline span carries no ``costmodel`` decisions, like
+a partial-aggregate rewrite's: they describe the monolithic program,
+which a tiled execution does not run (pinned by tests/test_tiling.py).
 """
 
 from __future__ import annotations

@@ -33,9 +33,8 @@ the admission gate shows no other query in flight: an uncontended
 query never pays coalesce latency), seals the bucket at
 ``tsd.query.batch.max_q`` members / ``tsd.query.batch.max_mb`` of
 stacked operands, dispatches once, and distributes the host-unpacked
-slices.  Batched executions are EXCLUDED from the predicted-vs-actual
-ring like rewrites/tiled runs (a stacked launch's measured time
-describes no single member's prediction).
+slices.  A batched pipeline span carries the plan's ``costmodel``
+decisions beside its ``batch`` tag.
 
 Deadlines stay per-member: a member whose deadline expires or cancels
 while waiting leaves the bucket WITHOUT poisoning its siblings — the

@@ -721,9 +721,11 @@ class QueryRunner:
             batch_ok=batcher is not None and batcher.enabled,
             batch_factor=tsdb.config.get_float(
                 "tsd.query.batch.amortize_factor"))
-        pd = pdn.plan_decision(
-            tsdb, ctx, _ExecConsults(tsdb, ctx, seg, sub, windows,
-                                     store, series_list, fix, scan.bounds))
+        with obs_trace.timed_stage("consult"):
+            pd = pdn.plan_decision(
+                tsdb, ctx, _ExecConsults(tsdb, ctx, seg, sub, windows,
+                                         store, series_list, fix,
+                                         scan.bounds))
         if pd.lane_note is not None:
             obs_trace.annotate(psp, rollup=pd.lane_note)
         if pd.agg_note is not None:
@@ -762,10 +764,7 @@ class QueryRunner:
             # Standing fast path: serve the downsample grid from the
             # rollup lane's mergeable partials (storage/rollup.py) —
             # the raw points are never fetched, never streamed.  Exact
-            # by derivation; annotated on the span's `rollup` tag; the
-            # predicted-vs-actual ring skips lane-served executions like
-            # rewrites/tiled runs (the monolithic stage breakdown does
-            # not describe them).
+            # by derivation; annotated on the span's `rollup` tag.
             out_ts, out_val, out_mask, lanes = self._run_lane_serve(
                 spec, seg, lane_plan, series_list, gid, g_pad, windows,
                 window_spec, budget, fix, psp)
@@ -775,9 +774,7 @@ class QueryRunner:
         elif tiled_plan is not None:
             # Out-of-core: series-tiled streaming with partial-grid
             # spill, window-striped tail replay (ops/tiling.py).  The
-            # decision + pool traffic ride the span's `tiling` tag; the
-            # predicted-vs-actual ring skips tiled executions like rewrites
-            # (the monolithic stage breakdown does not describe them).
+            # decision + pool traffic ride the span's `tiling` tag.
             from opentsdb_tpu.ops import tiling
             (out_ts, out_val, out_mask), tile_stats = tiling.run_tiled(
                 tsdb, spec, seg, series_list, gid, g_pad, window_spec,
@@ -797,11 +794,8 @@ class QueryRunner:
             # dispatch-bound plan rendezvouses with concurrent
             # compatible plans and executes as one stacked [Q, S, N]
             # kernel with host-side unpack — the per-dispatch floor is
-            # paid once per bucket instead of once per query.  The
-            # predicted-vs-actual ring skips batched executions like rewrites/
-            # tiled runs (a stacked launch's measured time describes
-            # no single member), so the span carries the decisions
-            # directly.
+            # paid once per bucket instead of once per query.  The span
+            # carries the decisions with the batch.
             from opentsdb_tpu.query.limits import active_deadline
             ts, val, mask, _ = build_batch_direct(
                 series_list, seg.start_ms, seg.end_ms, fix)
@@ -857,44 +851,37 @@ class QueryRunner:
                 from opentsdb_tpu.parallel.sharded import (
                     n_devices, shard_rows_device)
                 self.exec_stats["meshDevices"] = float(n_devices(mesh))
-                fn = sharded_query_pipeline(mesh, spec, g_pad)
-                if cached is not None:
-                    # cache hit under the mesh: re-lay the device batch
-                    # out across the chips (ICI scatter) instead of a
-                    # fresh host upload
-                    d_ts, d_val, d_mask, d_gid = shard_rows_device(
-                        mesh, ts, val, mask, gid, pad_gid_value=g_pad)
-                else:
-                    d_ts, d_val, d_mask, d_gid = shard_rows(
-                        mesh, ts, val, mask, gid, pad_gid_value=g_pad)
-                out_ts, out_val, out_mask, lanes = fn(
-                    d_ts, d_val, d_mask, d_gid, wargs)
+                with obs_trace.timed_stage("enqueue"):
+                    fn = sharded_query_pipeline(mesh, spec, g_pad)
+                    if cached is not None:
+                        # cache hit under the mesh: re-lay the device
+                        # batch out across the chips (ICI scatter)
+                        # instead of a fresh host upload
+                        d_ts, d_val, d_mask, d_gid = shard_rows_device(
+                            mesh, ts, val, mask, gid, pad_gid_value=g_pad)
+                    else:
+                        d_ts, d_val, d_mask, d_gid = shard_rows(
+                            mesh, ts, val, mask, gid, pad_gid_value=g_pad)
+                    out_ts, out_val, out_mask, lanes = fn(
+                        d_ts, d_val, d_mask, d_gid, wargs)
             else:
                 if n_groups == len(gid) and pd.path in pdn.ROW_GROUP_PATHS:
                     # one member a group, the whole batch in this one
                     # dispatch: row i is group i
                     spec = replace(spec, row_groups=True)
-                with host_lane(host_small):
+                with obs_trace.timed_stage("enqueue"), host_lane(host_small):
                     out_ts, out_val, out_mask, lanes = run_group_pipeline(
                         spec, ts, val, mask, gid, g_pad, wargs)
 
         # the arm above returned (dispatch enqueued; results may still
-        # be device-resident) — the true sync lands in device_wait at
-        # the asarray boundary below
+        # be device-resident) — the wait lands in fetch, below
         latattr.mark("dispatch")
-        if psp is not None:
-            obs_trace.device_wait(psp, (out_ts, out_val, out_mask))
-            if agg_plan is None and tiled_plan is None \
-                    and lane_plan is None and pd.path != "batched":
-                # rewritten, tiled, lane-served AND batched segments
-                # skip the predicted-vs-actual ledger: the monolithic
-                # stage breakdown does not describe a block-decomposed,
-                # tiled, lane-derived, or stacked-multi-member
-                # execution, and pairing its prediction with a partial
-                # (or shared) actual would poison the ring
-                self._trace_pipeline_stages(
-                    psp, sub, seg, len(gid), n_max, window_spec.count,
-                    n_groups, host_small, decisions=pd.decisions)
+        if psp is not None and pd.decisions is not None \
+                and pd.path != "batched":
+            # the decisions describe the monolithic program (rewritten,
+            # tiled and lane-served segments ran others and have none),
+            # and a batched one annotated its own
+            self._annotate_decisions(psp, pd.decisions)
         obs_trace.end(psp)
         recorder = getattr(tsdb, "flightrec", None)
         if recorder is not None:
@@ -917,9 +904,10 @@ class QueryRunner:
             if batch_info is not None:
                 fields["batch"] = batch_info
             recorder.record("plan", **fields)
-        with obs_trace.timed_stage("extract"):
+        with obs_trace.timed_stage("fetch"):
             out_ts, out_val, out_mask, lanes = self._materialize_answer(
                 out_ts, out_val, out_mask, lanes)
+        with obs_trace.timed_stage("extract"):
             if lanes is not None:
                 # which lanes the device took (ops/pipeline.py's word)
                 REGISTRY.counter(
@@ -933,9 +921,8 @@ class QueryRunner:
                         "by the lane that found the previous points"
                     ).labels(lane="shift" if lanes & LANE_SHIFT
                              else "scan").inc()
-            # device->host materialization is where an async dispatch
-            # actually blocks (tracing syncs earlier via device_wait,
-            # in which case this delta is ~0)
+            # device->host materialization (fetch, above) is where an
+            # async dispatch actually blocks
             latattr.mark("device_wait")
             stamps, rows = extract_grid(
                 out_ts, out_val[:n_groups], out_mask[:n_groups],
@@ -958,46 +945,12 @@ class QueryRunner:
             "queries").inc(n_groups)
         return results
 
-    def _trace_pipeline_stages(self, span, sub: TSSubQuery, seg: Segment,
-                               s: int, n: int, w: int, g: int,
-                               host_small: bool = False,
-                               decisions: dict | None = None) -> None:
-        """Logical stage children of the fused dispatch span + the
-        costmodel predicted-vs-actual ledger entry.
-
-        XLA fuses downsample/rate/groupby/aggregate into one kernel, so
-        per-stage device truth does not exist at runtime; the measured
-        device wait is APPORTIONED across the stages by the
-        costmodel's per-stage predictions and the children say so
-        (`estimated` tag).  The span is also annotated with every
-        kernel-axis DECISION (chosen form, per-candidate predicted ms),
-        and the (shape, modes, feature vector, predicted, actual) tuple
-        lands in obs.jaxprof's segment ring."""
-        from opentsdb_tpu.obs import jaxprof
-        from opentsdb_tpu.ops.hostlane import execution_platform
-        ds = sub.downsample_spec
-        ds_fn = seg.ds_function or (ds.function if ds is not None else None)
-        # per-SEGMENT platform: the exec_stats hostLane flag is sticky
-        # across a run's segments and would misattribute later
-        # device-dispatched segments as cpu, filling the ring with
-        # cpu-predicted vs device-actual pairs
-        platform = "cpu" if host_small else execution_platform()
-        # DISPATCH shapes: build_batch pads the point axis to pow2 and
-        # the group count dispatches as g_pad — the kernels' mode
-        # choosers see the padded values, so the decision report and
-        # the ring's feature vectors must too (n=1000 would report
-        # 'flat' while the n=1024 kernel picked a sub-block form).
-        # The streamed path still approximates: it dispatches chunk-
-        # sized batches while one entry covers the whole range.
-        n = pad_pow2(max(int(n), 1))
-        g = pad_pow2(max(int(g), 1))
-        if decisions is None:
-            # direct callers without a PlanDecision in hand; the
-            # grouped executor passes plan_decision()'s reports through
-            # so the span, the fingerprint, and the ring all describe
-            # ONE recomputation
-            decisions = jaxprof.segment_decisions(
-                platform, s, n, w, g, ds_fn, aggregator=sub.aggregator)
+    @staticmethod
+    def _annotate_decisions(span, decisions: dict) -> None:
+        """The pipeline span's `costmodel` tag: every kernel-axis
+        decision (chosen form, per-candidate predicted ms) of the plan's
+        one recomputation (plan_decision), which the fingerprint and
+        explain describe too."""
         obs_trace.annotate(span, costmodel=decisions)
         for axis, report in decisions.items():
             if not report["feasible"]:
@@ -1008,33 +961,6 @@ class QueryRunner:
                     "tsd.costmodel.infeasible",
                     "Strategy decisions outside the feasible candidate "
                     "set (must stay 0)").labels(axis=axis).inc()
-        breakdown = jaxprof.stage_breakdown(platform, s, n, w, g, ds_fn,
-                                            bool(sub.rate),
-                                            decisions=decisions)
-        total_pred = sum(breakdown.values()) or 1.0
-        for stage_name in ("downsample", "rate", "groupby", "aggregate"):
-            share = breakdown.get(stage_name)
-            if share is None:
-                continue
-            child = span.child(stage_name, estimated=True)
-            child.device_ms = round(span.device_ms * share / total_pred, 3)
-            child.wall_ms = child.device_ms
-        tr = obs_trace.active()
-        if tr is None or not tr.device_time:
-            # wall-only tracing: span.device_ms is 0 by CONFIG, not by
-            # measurement — recording predicted>0/actual=0 pairs would
-            # poison the ring
-            return
-        jaxprof.record_segment(
-            seg.kind, s, n, w, g, sum(breakdown.values()), span.device_ms,
-            platform=platform,
-            modes={axis: r["mode"] for axis, r in decisions.items()},
-            features=jaxprof.segment_features(platform, s, n, w, g,
-                                              bool(sub.rate), decisions),
-            aggregator=sub.aggregator)
-        self._bump("deviceTimeMs", round(span.device_ms, 3))
-        self._bump("costmodelPredictedMs",
-                   round(sum(breakdown.values()) * 1e3, 3))
 
     @staticmethod
     def _host_window_ids(windows, tsb):
@@ -1095,67 +1021,68 @@ class QueryRunner:
         s = len(series_list)
         pieces_v: list = []
         pieces_m: list = []
-        with host_lane(host_small):
-            for piece in plan.pieces:
-                if piece.cached is not None:
-                    # cached entries hold their FULL row set; narrow
-                    # to this query's rows unless they already match
-                    # (the exact-repeat hot path serves zero-copy)
-                    v, m = piece.cached
-                    rows = piece.rows
-                    identity = (v.shape[0] == len(rows)
-                                and np.array_equal(
-                                    rows, np.arange(len(rows))))
-                    if not identity and piece.tier == "agg_device":
-                        rdev = jnp.asarray(rows)
-                        v = jnp.take(v, rdev, axis=0)
-                        m = jnp.take(m, rdev, axis=0)
-                    elif not identity:
-                        v = v[rows]
-                        m = m[rows]
+        with obs_trace.timed_stage("rewrite"), host_lane(host_small):
+            with obs_trace.timed_stage("rw_pieces"):
+                for piece in plan.pieces:
+                    if piece.cached is not None:
+                        # cached entries hold their FULL row set; narrow
+                        # to this query's rows unless they already match
+                        # (the exact-repeat hot path serves zero-copy)
+                        v, m = piece.cached
+                        rows = piece.rows
+                        identity = (v.shape[0] == len(rows)
+                                    and np.array_equal(
+                                        rows, np.arange(len(rows))))
+                        if not identity and piece.tier == "agg_device":
+                            rdev = jnp.asarray(rows)
+                            v = jnp.take(v, rdev, axis=0)
+                            m = jnp.take(m, rdev, axis=0)
+                        elif not identity:
+                            v = v[rows]
+                            m = m[rows]
+                        pieces_v.append(v)
+                        pieces_m.append(m)
+                        self._bump("aggCacheHitWindows", piece.count)
+                        continue
+                    budget.check_deadline()
+                    # delta fetch composes with the device series cache:
+                    # pinned HBM columns serve the piece's [S, n] batch as
+                    # an on-device gather (zero host copy); cold/stale
+                    # falls back to the host build.  Either source hands
+                    # the SAME values at the same pow2-padded shape to the
+                    # same program, so the block's bits do not depend on
+                    # which one answered.
+                    batch = None
+                    if tsdb.device_cache is not None:
+                        batch = tsdb.device_cache.batch_for(
+                            plan.store, plan.metric, series_list,
+                            piece.fetch_lo, piece.fetch_hi, fix,
+                            build=False)
+                    if batch is not None:
+                        ts, val, mask = batch
+                    else:
+                        ts, val, mask, _ = build_batch_direct(
+                            series_list, piece.fetch_lo, piece.fetch_hi,
+                            fix)
+                    sub_win = FixedWindows(interval, piece.first_ms,
+                                           piece.count)
+                    wspec, wargs = sub_win.split()
+                    sub_step = DownsampleStep(step0.function, wspec,
+                                              step0.fill_policy,
+                                              step0.fill_value)
+                    _wts, v, m = run_downsample_grid(sub_step, ts, val,
+                                                     mask, wargs)
+                    self._bump("aggCacheComputedWindows", piece.count)
+                    if piece.block is not None:
+                        vn, mn = self._materialize_agg_piece(v, m,
+                                                             piece.count)
+                        tsdb.agg_cache.store_block(plan, piece,
+                                                   series_list, vn, mn)
+                    # edge pieces stay padded here; the host assembly
+                    # slices to piece.count after materializing (an eager
+                    # jnp slice would dispatch — and recompile — per call)
                     pieces_v.append(v)
                     pieces_m.append(m)
-                    self._bump("aggCacheHitWindows", piece.count)
-                    continue
-                budget.check_deadline()
-                # delta fetch composes with the device series cache:
-                # pinned HBM columns serve the piece's [S, n] batch as
-                # an on-device gather (zero host copy); cold/stale
-                # falls back to the host build.  Either source hands
-                # the SAME values at the same pow2-padded shape to the
-                # same program, so the block's bits do not depend on
-                # which one answered.
-                batch = None
-                if tsdb.device_cache is not None:
-                    batch = tsdb.device_cache.batch_for(
-                        plan.store, plan.metric, series_list,
-                        piece.fetch_lo, piece.fetch_hi, fix,
-                        build=False)
-                if batch is not None:
-                    ts, val, mask = batch
-                else:
-                    ts, val, mask, _ = build_batch_direct(
-                        series_list, piece.fetch_lo, piece.fetch_hi,
-                        fix)
-                sub_win = FixedWindows(interval, piece.first_ms,
-                                       piece.count)
-                wspec, wargs = sub_win.split()
-                sub_step = DownsampleStep(step0.function, wspec,
-                                          step0.fill_policy,
-                                          step0.fill_value)
-                _wts, v, m = run_downsample_grid(sub_step, ts, val,
-                                                 mask, wargs)
-                self._bump("aggCacheComputedWindows", piece.count)
-                if piece.block is not None:
-                    vn, mn = self._materialize_agg_piece(v, m,
-                                                         piece.count)
-                    tsdb.agg_cache.store_block(plan, piece,
-                                               series_list, vn, mn)
-                # edge pieces stay padded here; the host assembly
-                # slices to piece.count after materializing (an eager
-                # jnp slice would dispatch — and recompile — per call)
-                pieces_v.append(v)
-                pieces_m.append(m)
             w = windows.count
             wp = window_spec.count
             # Device concatenation only for the all-cached all-device
@@ -1169,27 +1096,29 @@ class QueryRunner:
             device_ok = all(p.cached is not None
                             and p.tier == "agg_device"
                             for p in plan.pieces)
-            if device_ok:
-                pad = [jnp.zeros((s, wp - w), jnp.float64)] \
-                    if wp > w else []
-                mpad = [jnp.zeros((s, wp - w), bool)] if wp > w else []
-                v_full = jnp.concatenate(pieces_v + pad, axis=1)
-                m_full = jnp.concatenate(pieces_m + mpad, axis=1)
-            else:
-                v_full = np.zeros((s, wp), np.float64)
-                m_full = np.zeros((s, wp), bool)
-                col = 0
-                for v, m, piece in zip(pieces_v, pieces_m, plan.pieces):
-                    v_full[:, col:col + piece.count], \
-                        m_full[:, col:col + piece.count] = \
-                        self._materialize_agg_piece(v, m, piece.count)
-                    col += piece.count
-            # the monolithic grid's timestamps: first + i * interval
-            # over the padded window count, int64 (window_timestamps)
-            wts = (windows.first_window_ms
-                   + np.arange(wp, dtype=np.int64) * interval)
-            out = run_grid_tail(spec, jnp.asarray(wts), v_full, m_full,
-                                jnp.asarray(gid), g_pad)
+            with obs_trace.timed_stage("rw_assemble"):
+                if device_ok:
+                    pad = [jnp.zeros((s, wp - w), jnp.float64)] \
+                        if wp > w else []
+                    mpad = [jnp.zeros((s, wp - w), bool)] if wp > w else []
+                    v_full = jnp.concatenate(pieces_v + pad, axis=1)
+                    m_full = jnp.concatenate(pieces_m + mpad, axis=1)
+                else:
+                    v_full = np.zeros((s, wp), np.float64)
+                    m_full = np.zeros((s, wp), bool)
+                    col = 0
+                    for v, m, piece in zip(pieces_v, pieces_m, plan.pieces):
+                        v_full[:, col:col + piece.count], \
+                            m_full[:, col:col + piece.count] = \
+                            self._materialize_agg_piece(v, m, piece.count)
+                        col += piece.count
+            with obs_trace.timed_stage("tail"):
+                # the monolithic grid's timestamps: first + i * interval
+                # over the padded window count, int64 (window_timestamps)
+                wts = (windows.first_window_ms
+                       + np.arange(wp, dtype=np.int64) * interval)
+                out = run_grid_tail(spec, jnp.asarray(wts), v_full, m_full,
+                                    jnp.asarray(gid), g_pad)
         if plan.cached_windows:
             self.exec_stats["aggCacheHit"] = 1.0
         return out
@@ -1735,13 +1664,6 @@ class QueryRunner:
                 bt, bv, bm = (np.asarray(bt), np.asarray(bv),
                               np.asarray(bm))
                 outs = [(bt[i], bv[i], bm[i]) for i in range(len(chunk))]
-            if psp is not None:
-                obs_trace.device_wait(psp, outs)
-                # the union pipeline is one fused aggregate (+rate)
-                # kernel — a single estimated child, full device share
-                child = psp.child("aggregate", estimated=True)
-                child.device_ms = round(psp.device_ms, 3)
-                child.wall_ms = child.device_ms
             obs_trace.end(psp)
             for (group_key, members, *_), (o_ts, o_val, o_mask) \
                     in zip(chunk, outs):

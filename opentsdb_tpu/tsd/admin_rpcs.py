@@ -151,10 +151,6 @@ class StatsRpc(TelnetRpc, HttpRpc):
                 raise BadRequestError("Query stats are not enabled",
                                       status=404)
             payload = self.stats_registry.snapshot()
-            # the costmodel predicted-vs-actual segment ring rides the
-            # query-stats payload
-            from opentsdb_tpu.obs import jaxprof
-            payload["costmodelSegments"] = jaxprof.segments()
             query.send_reply(query.serializer.format_query_stats_v1(
                 payload))
             return

@@ -228,12 +228,15 @@ class RpcManager:
         deadline (query/limits.py) for every QueryBudget, retry policy,
         and admission wait downstream, and bound to the server's
         cancellation handle so a client disconnect flips its token."""
+        entered = time.perf_counter()
+        # the loop's stamps of this request (tsd/server.py; None for a
+        # caller that is not the event loop)
+        edges = getattr(request, "edges", None)
         cfg = self.tsdb.config
         trace = None
         if cfg.get_bool("tsd.trace.enable"):
             trace = obs_trace.Trace(
-                "http", trace_id=request.header(obs_trace.TRACE_HEADER),
-                device_time=cfg.get_bool("tsd.trace.device_time"))
+                "http", trace_id=request.header(obs_trace.TRACE_HEADER))
             trace.root.tags["method"] = request.method
             trace.root.tags["path"] = request.path
             obs_trace.activate(trace)
@@ -250,6 +253,8 @@ class RpcManager:
         if getattr(self.tsdb, "latattr", None) is not None:
             stamps = latattr.PhaseStamps(
                 trace_id=trace.trace_id if trace is not None else None)
+            if edges is not None:
+                stamps.queue_ms = (entered - edges.queued) * 1e3
             latattr.activate(stamps)
         start = time.perf_counter()
         try:
@@ -272,6 +277,14 @@ class RpcManager:
             # the handler wall time
             stamps.mark("flush", last=True)
             stamps.route = route
+        if edges is not None:
+            # the handler's part ends at its last mark; what follows it
+            # here is the loop's `resume`
+            edges.entered = entered
+            edges.returned = time.perf_counter()
+            edges.route = route
+            edges.trace_id = trace.trace_id if trace is not None else None
+        if stamps is not None:
             self.tsdb.latattr.observe(stamps)
         status = query.response.status if query.response is not None else 0
         REGISTRY.counter(

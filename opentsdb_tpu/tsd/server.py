@@ -20,6 +20,7 @@ import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
 
+from opentsdb_tpu.obs import latattr
 from opentsdb_tpu.tsd.http import (
     BadRequestError, HttpQuery, HttpResponse, parse_http_head)
 from opentsdb_tpu.tsd.rpc_manager import RpcManager
@@ -482,6 +483,8 @@ class TSDServer:
             request.cancel_handle = handle
             self._active_handles.add(handle)
             watcher = None
+            # the edges outside the handler (obs/latattr.py): queued now
+            edges = request.edges = latattr.Edges()
             try:
                 fut = loop.run_in_executor(
                     self._executor, self.rpc_manager.handle_http, request,
@@ -514,12 +517,17 @@ class TSDServer:
                             # this one executed: keep its bytes
                             buffer = chunk
                 query = await fut
-                keep_alive = (request.version != "HTTP/1.0"
-                              and (request.header("connection")
-                                   or "").lower() != "close")
-                response = query.response or HttpResponse(status=500)
-                writer.write(response.to_bytes(keep_alive))
-                await writer.drain()
+                resumed = time.perf_counter()
+                ann = latattr.open_annotation("tsd.phase")
+                try:
+                    keep_alive = (request.version != "HTTP/1.0"
+                                  and (request.header("connection")
+                                       or "").lower() != "close")
+                    response = query.response or HttpResponse(status=500)
+                    writer.write(response.to_bytes(keep_alive))
+                    await writer.drain()
+                finally:
+                    edges.written(resumed, ann)
             finally:
                 if watcher is not None:
                     buffer = await self._drain_watcher(watcher, buffer)

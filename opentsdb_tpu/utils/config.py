@@ -185,11 +185,6 @@ CONFIG_SCHEMA: dict[str, ConfigEntry] = {
         "(scan/pipeline stages, cluster fan-out with retry/breaker "
         "annotations) surfaced inline via showStats and in the "
         "/api/stats/query ring."),
-    "tsd.trace.device_time": _e(
-        "bool", True, "Record per-stage device time on traced requests "
-        "by syncing on stage outputs at stage boundaries "
-        "(block_until_ready; a sanctioned sync site).  False keeps "
-        "spans wall-time-only and dispatches fully asynchronous."),
     "tsd.stats.interval": _e(
         "int", "0", "Seconds between self-report passes writing the "
         "daemon's own tsd.* metrics into its local store through the "

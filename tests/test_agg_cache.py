@@ -472,7 +472,7 @@ def test_cache_hit_speedup_at_scale():
     vs cold at a compute-dominated shape — the aligned dashboard
     repeat (full block coverage), the same measurement the committed
     BENCH_AGG_CACHE.json artifact records via
-    tools/bench_agg_cache.py (which also reports trace-span device
+    tools/bench_agg_cache.py (which also reports the fetch stage's
     ms)."""
     import statistics
     import time

@@ -516,22 +516,28 @@ class TestBatchedRouting:
         finally:
             tsdb.shutdown()
 
-    def test_batched_runs_skip_the_calibration_ring(self):
-        """Like rewrites/tiled/lane serves: a stacked launch's
-        measured time describes no single member's feature vector, so
-        batched executions never land in the predicted-vs-actual
-        ring."""
-        from opentsdb_tpu.obs import jaxprof
-        tsdb, mgr = _manager(**{"tsd.trace.enable": "true",
-                                "tsd.trace.device_time": "true"})
+    def test_a_batched_pipeline_span_carries_its_decisions(self):
+        """The batched arm annotates the plan's costmodel decisions
+        itself, beside its batch tag, and no tracer sync runs."""
+        tsdb, mgr = _manager(**{"tsd.trace.enable": "true"})
         feed(tsdb, "bt.ring")
         try:
-            q = "start=%d&end=%d&m=sum:30s-avg:bt.ring" % (
+            q = "start=%d&end=%d&m=sum:30s-avg:bt.ring&show_stats" % (
                 BASE // 1000, BASE // 1000 + 100 * 15)
-            before = len(jaxprof.segments())
-            status, _ = ask(mgr, "/api/query?" + q)
+            status, rep = ask(mgr, "/api/query?" + q)
             assert status == 200
-            assert len(jaxprof.segments()) == before
+            tree = [e for e in rep if "statsSummary" in e][0][
+                "statsSummary"]["trace"]
+
+            def find(node):
+                out = [node] if node["name"] == "pipeline" else []
+                for c in node.get("spans", []):
+                    out += find(c)
+                return out
+            (pipe,) = find(tree)
+            assert "batch" in pipe["tags"]
+            assert "group" in pipe["tags"]["costmodel"]
+            assert "deviceMs" not in pipe
         finally:
             tsdb.shutdown()
 

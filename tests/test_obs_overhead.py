@@ -12,7 +12,7 @@ batch time per arm — scheduler noise only ever adds time, so min-of-3
 is the stable estimator — with a small absolute floor so a
 microsecond-level baseline cannot fail on jitter alone.
 
-If this starts failing, profile obs/trace.py's stage()/device_wait()
+If this starts failing, profile obs/trace.py's stage()/timed_stage()
 before even thinking about relaxing the bound: a tracer nobody can
 afford to leave on observes nothing.
 """

@@ -89,35 +89,46 @@ class TestSpanTree:
         assert r.status == 200
         tree = self._trace_of(r)
         names = span_names(tree)
-        for stage in ("scan", "pipeline", "downsample", "groupby",
-                      "aggregate", "extract", "serialize"):
+        for stage in ("scan", "count", "consult", "pipeline", "fetch",
+                      "extract", "assemble", "serialize"):
             assert stage in names, "missing %s in %s" % (stage, names)
-        # every span carries wall + device time
+        # every span carries its wall time and nothing that claims to be
+        # the device's: the tracer syncs nothing
         def walk(node):
             assert isinstance(node["wallMs"], float)
-            assert isinstance(node["deviceMs"], float)
+            assert "deviceMs" not in node
+            assert "estimated" not in node.get("tags", {})
             for c in node.get("spans", []):
                 walk(c)
         walk(tree)
-        # the fused dispatch's stage children are honest about being
-        # costmodel-apportioned
-        for child in find_spans(tree, "downsample"):
-            assert child["tags"]["estimated"] is True
+        # the fused dispatch's stages are the costmodel's decisions on
+        # the pipeline span, not children apportioned from a sync
+        (pipe,) = find_spans(tree, "pipeline")
+        assert {"search", "group"} <= set(pipe["tags"]["costmodel"])
+        assert not {"downsample", "groupby", "aggregate"} & names
         assert re.fullmatch(r"[0-9a-f]{16}", tree["traceId"])
 
-    def test_rate_query_gets_a_rate_span(self, manager):
+    def test_rate_query_takes_a_rate_lane(self, manager):
+        from opentsdb_tpu.obs.registry import REGISTRY
+        fam = REGISTRY.counter("tsd.query.rate_lane")
+        before = sum(fam.labels(lane=lane).get()
+                     for lane in ("shift", "scan"))
         r = http(manager, "GET",
                  "/api/query?start=%d&end=%d"
                  "&m=sum:30s-avg:rate:obs.cpu&show_stats"
                  % (BASE, BASE + 300))
-        assert "rate" in span_names(self._trace_of(r))
+        assert "pipeline" in span_names(self._trace_of(r))
+        assert sum(fam.labels(lane=lane).get()
+                   for lane in ("shift", "scan")) == before + 1
 
-    def test_union_query_traces_pipeline_and_aggregate(self, manager):
+    def test_union_query_traces_pipeline(self, manager):
         r = http(manager, "GET",
                  "/api/query?start=%d&end=%d&m=sum:obs.cpu&show_stats"
                  % (BASE, BASE + 300))
-        names = span_names(self._trace_of(r))
-        assert {"scan", "pipeline", "aggregate", "serialize"} <= names
+        tree = self._trace_of(r)
+        assert {"scan", "pipeline", "serialize"} <= span_names(tree)
+        (pipe,) = find_spans(tree, "pipeline")
+        assert pipe["tags"]["union"] is True and "spans" not in pipe
 
     def test_trace_id_header_is_adopted(self, manager):
         r = http(manager, "GET",
@@ -141,18 +152,6 @@ class TestSpanTree:
         payload = json.loads(r.body)
         summary = [e for e in payload if "statsSummary" in e][0]
         assert "trace" not in summary["statsSummary"]
-
-    def test_costmodel_segments_recorded(self, manager):
-        from opentsdb_tpu.obs import jaxprof
-        jaxprof.clear_segments()
-        http(manager, "GET",
-             "/api/query?start=%d&end=%d&m=sum:30s-avg:obs.cpu"
-             % (BASE, BASE + 300))
-        segs = jaxprof.segments()
-        assert segs, "a traced grouped dispatch must record its segment"
-        seg = segs[-1]
-        assert seg["kind"] == "raw" and seg["series"] == 2
-        assert seg["predictedMs"] > 0 and seg["actualMs"] >= 0
 
 
 class TestPrometheus:

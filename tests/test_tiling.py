@@ -164,17 +164,25 @@ class TestTiledExecution:
         tag = tiled[0]["tags"]["tiling"]
         assert tag["tiles"] >= 2 and tag["spillBytes"] > 0
 
-    def test_tiled_runs_excluded_from_calibration_ring(self):
-        """PR 9 precedent, pinned: the monolithic stage breakdown does
-        not describe a tiled execution, so no predicted-vs-actual pair
-        may land in the ring for a tiled pipeline."""
-        from opentsdb_tpu.obs import jaxprof
+    def test_tiled_pipeline_span_carries_no_monolithic_decisions(self):
+        """PR 9 precedent, pinned: the costmodel's decisions describe
+        the monolithic program, which a tiled execution does not run,
+        so its pipeline span carries the tiling tag and no costmodel
+        tag."""
+        from opentsdb_tpu.obs import trace as obs_trace
         t = _mk_tsdb(1)
-        jaxprof.clear_segments()
-        _, st = _run(t, "sum:10s-sum:til.m{g=*}")
+        tr = obs_trace.Trace("tiled")
+        obs_trace.activate(tr)
+        try:
+            _, st = _run(t, "sum:10s-sum:til.m{g=*}")
+        finally:
+            obs_trace.deactivate()
+            tr.finish()
         assert st.get("tiledExecution") == 1.0
-        assert jaxprof.segments() == [], \
-            "tiled execution leaked into the calibration ring"
+        pipes = [sp for sp in tr.root.children if sp.name == "pipeline"]
+        assert pipes and all("tiling" in sp.tags for sp in pipes)
+        assert not any("costmodel" in sp.tags for sp in pipes), \
+            "a tiled execution must not carry the monolithic decisions"
 
     def test_spill_write_fault_surfaces_as_retryable_and_heals(self):
         from opentsdb_tpu.query.limits import QueryException

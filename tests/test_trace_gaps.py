@@ -119,6 +119,43 @@ def test_phase_cpu_seconds_and_overlaps_on_one_line_are_counted():
         {"plan": 1.0, "dispatch": 0.5})
 
 
+# the edges outside the handler: the parse event's queue_ms puts `queue`
+# before it, the loop line's write event's resume_ms puts `resume` before
+# the write
+EDGED_REQUEST = [[phase(1.0, 2.0, "parse", queue_ms=1000.0),
+                  phase(2.0, 3.0, "plan"), phase(3.0, 5.0, "dispatch"),
+                  phase(5.0, 6.0, "serialize"), phase(6.0, 6.5, "flush")],
+                 [phase(7.0, 9.0, "write", resume_ms=500.0)]]
+
+
+def test_the_edges_outside_the_handler_take_their_idle_seconds():
+    out = trace_gaps.reduce_planes(planes(EDGED_REQUEST))
+    # idle 0-2, 4-7, 8-10: queue 0-1, parse 1-2, dispatch 4-5,
+    # serialize 5-6, flush 6-6.5, resume 6.5-7, write 8-9, nobody 9-10
+    assert out["idle_by_phase_s"] == pytest.approx({
+        "queue": 1.0, "parse": 1.0, "dispatch": 1.0, "serialize": 1.0,
+        "flush": 0.5, "resume": 0.5, "write": 1.0, "no_request": 1.0})
+    assert sum(out["idle_by_phase_s"].values()) == pytest.approx(7.0)
+    assert out["phase_s"]["queue"] == pytest.approx(1.0)
+    assert out["phase_s"]["resume"] == pytest.approx(0.5)
+    assert out["phase_s"]["write"] == pytest.approx(2.0)
+    # six tsd.phase events; the edges placed from stats are not events
+    assert out["phase_events"] == 6
+
+
+def test_the_loops_write_events_overlap_without_counting():
+    """The event loop serves many requests: two writes overlapping on
+    its line are not a fault, two phases on a handler's line are."""
+    loop_line = [phase(7.0, 9.0, "write", resume_ms=0.0),
+                 phase(8.0, 9.5, "write", resume_ms=0.0)]
+    out = trace_gaps.reduce_planes(planes(ONE_REQUEST + [loop_line]))
+    assert out["overlapping_phase_events"] == 0
+    # idle 8-9: both writes and serialize live, a third each; 9-9.5:
+    # one write and serialize, a half each
+    assert out["idle_by_phase_s"]["write"] == pytest.approx(
+        2 / 3 + 0.25, rel=1e-9)
+
+
 def test_a_device_with_no_op_line_is_read_from_its_modules():
     pl = planes(ONE_REQUEST)
     pl[0]["lines"] = [ln for ln in pl[0]["lines"]

@@ -51,10 +51,6 @@ SANCTIONED_SITES: list[tuple[str, str]] = [
     ("opentsdb_tpu/tsd/serializers.py", ""),
     ("opentsdb_tpu/query/planner.py", "_materialize"),
     ("opentsdb_tpu/ops/hostlane.py", ""),
-    # the tracer's device_wait: per-stage device timing is a DELIBERATE
-    # stage-boundary rendezvous (tsd.trace.device_time) — the one sync
-    # the trace path is allowed
-    ("opentsdb_tpu/obs/trace.py", ""),
 ]
 
 _tls = threading.local()

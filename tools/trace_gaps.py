@@ -9,7 +9,11 @@ benchmark_out/<cell>/trace/plugins/profile/*/).  While a profile runs
 the daemon writes a `tsd.phase` event per latattr phase interval (stats
 `phase`, `cpu_ms`, `trace_id`) and a `tsd.span` event per tracer span
 (stat `name`) on the handler threads' /host:CPU lines, on the device
-planes' clock (opentsdb_tpu/obs/latattr.py, obs/trace.py).
+planes' clock (opentsdb_tpu/obs/latattr.py, obs/trace.py).  The edges
+outside the handler come in too: the event loop's `write` events (stat
+`resume_ms`: the `resume` before it ends where the write starts), and a
+request's first event's `queue_ms` (the `queue` before the handler
+started ends where that event starts).
 
 Per device plane: busy = the union of the `XLA Ops` intervals (else
 `XLA Modules`), the window = the extent of all events on all planes,
@@ -34,6 +38,11 @@ import re
 import sys
 
 DEVICE_PLANE = re.compile(r"^/device:(TPU|GPU):\d+$")
+# the stats that place an edge outside the handler: it ends where the
+# event that carries it starts
+EDGE_STATS = {"queue_ms": "queue", "resume_ms": "resume"}
+# phases of the event loop's thread, which serves many requests at once
+LOOP_PHASES = {"write"}
 HOST_PLANE = "/host:CPU"
 OPS_LINE, MODULES_LINE = "XLA Ops", "XLA Modules"
 NO_REQUEST = "no_request"
@@ -106,7 +115,7 @@ def reduce_planes(planes: list[dict], top: int = 10) -> dict:
     duration_ns[, stats dict])]}]}] -> the summary, in seconds.  Pure
     arithmetic (tested on its own); load() builds `planes`."""
     lo = hi = None
-    phases, span_lines, overlaps = [], [], 0
+    phases, loop, edges, span_lines, overlaps = [], [], [], [], 0
     phase_s: dict[str, float] = {}
     phase_cpu_s: dict[str, float] = {}
     for plane in planes:
@@ -118,10 +127,17 @@ def reduce_planes(planes: list[dict], top: int = 10) -> dict:
                 if plane["name"] != HOST_PLANE or not rest:
                     continue
                 if name == "tsd.phase" and "phase" in rest[0]:
-                    mine.append((s, s + d, str(rest[0]["phase"])))
-                    _add(phase_s, mine[-1][2], d * 1e-9)
-                    _add(phase_cpu_s, mine[-1][2],
-                         float(rest[0].get("cpu_ms", 0.0)) * 1e-3)
+                    stats, ph = rest[0], str(rest[0]["phase"])
+                    (loop if ph in LOOP_PHASES else mine).append(
+                        (s, s + d, ph))
+                    _add(phase_s, ph, d * 1e-9)
+                    _add(phase_cpu_s, ph,
+                         float(stats.get("cpu_ms", 0.0)) * 1e-3)
+                    for key, edge in EDGE_STATS.items():
+                        if key in stats:
+                            ns = float(stats[key]) * 1e6
+                            edges.append((s - ns, s, edge))
+                            _add(phase_s, edge, ns * 1e-9)
                 elif name == "tsd.span" and "name" in rest[0]:
                     spans.append((s, s + d, str(rest[0]["name"])))
             mine.sort()
@@ -130,6 +146,9 @@ def reduce_planes(planes: list[dict], top: int = 10) -> dict:
             phases += mine
             if spans:
                 span_lines.append(spans)
+    phases += loop
+    n_events = len(phases)
+    phases += edges
     devices = {}
     for plane in planes:
         if not DEVICE_PLANE.match(plane["name"]):
@@ -172,7 +191,7 @@ def reduce_planes(planes: list[dict], top: int = 10) -> dict:
         }
     n = len(devices)
     out = {"window_s": (hi - lo) * 1e-9 if n else 0.0, "device_count": n,
-           "phase_events": len(phases), "overlapping_phase_events": overlaps,
+           "phase_events": n_events, "overlapping_phase_events": overlaps,
            "phase_s": _ranked(phase_s), "phase_cpu_s": _ranked(phase_cpu_s),
            "devices": devices}
     if n:       # the mean over devices, as trace_reduce.py's busy_s
