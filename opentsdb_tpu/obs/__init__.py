@@ -94,7 +94,8 @@ METRICS_SCHEMA: dict[str, MetricSpec] = {
         "verdict and its cache consults); inside latattr's dispatch "
         "rewrite (a partial-aggregate rewrite whole: rw_pieces, the "
         "per-piece narrowing and delta dispatches, rw_assemble, the "
-        "grid's concatenation or host materialization, and tail, the "
+        "grid put together by placement programs or, on the host lane, "
+        "host copies, and tail, the "
         "grid tail's enqueue) and enqueue (the resident and mesh "
         "programs' calls, row sharding included); fetch (the answer's "
         "device-to-host copy, where a request waits for the device), "
@@ -108,6 +109,18 @@ METRICS_SCHEMA: dict[str, MetricSpec] = {
         "device: for the upload out of a chunk buffer before it is "
         "refilled, and the reads that wait for folds in flight, the "
         "every-16th-chunk backpressure and the out-of-slice audit)."),
+    "tsd.query.rewrite.assembly": _m(
+        "counter", ("lane",),
+        "Partial-aggregate rewrites (query/planner.py _run_agg_rewrite), "
+        "by the lane that put the [S, Wp] grid together: device = one "
+        "placement program a piece on the device (ops/pipeline.py "
+        "assemble_grid), the pieces never copied to the host; host = "
+        "the host lane's copies of every piece into a host grid."),
+    "tsd.query.rewrite.pieces": _m(
+        "counter", ("kind",),
+        "Pieces of partial-aggregate rewrites, by kind: cached = a block "
+        "served from either tier of the cache; computed = an edge piece "
+        "or a missing block downsampled for the request."),
     "tsd.query.group_reduce": _m(
         "counter", ("mode",),
         "Grouped dispatches of the monolithic pipeline, by the "

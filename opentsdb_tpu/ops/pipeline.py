@@ -203,6 +203,39 @@ def _downsample_grid(step: DownsampleStep, ts, val, mask, wargs):
                       wargs, step.fill_policy, step.fill_value)
 
 
+# shape: grid_v[S,W] f64, grid_m[S,W] bool, piece_v[S,P] any, piece_m[S,P] bool, at[2] i32
+def _place_piece(grid_v, grid_m, piece_v, piece_m, at):
+    """Write the first at[1] columns of one [S, pw] piece into the
+    [S, Wp] grid from column at[0] on; every other column keeps what it
+    held.  Offset and count are traced, so the program compiles once
+    per (S, Wp, pw) wherever the piece falls.  dynamic_update_slice
+    clamps a window that would run past the grid's edge, which would
+    shift the piece: the write goes through the window the clamp gives,
+    the piece rolled by the clamp's distance.  Selects and copies only:
+    the values keep their bits."""
+    s, wp = grid_v.shape
+    pw = piece_v.shape[1]
+    off, count = at[0], at[1]
+    start = jnp.minimum(off, wp - pw)
+    shift = off - start
+    col = jnp.arange(pw, dtype=at.dtype)
+    take = ((col >= shift) & (col < shift + count))[None, :]
+    at0 = (jnp.zeros((), at.dtype), start)
+    old_v = jax.lax.dynamic_slice(grid_v, at0, (s, pw))
+    old_m = jax.lax.dynamic_slice(grid_m, at0, (s, pw))
+    new_v = jnp.where(take, jnp.roll(piece_v.astype(grid_v.dtype), shift,
+                                     axis=1), old_v)
+    new_m = jnp.where(take, jnp.roll(piece_m.astype(bool), shift, axis=1),
+                      old_m)
+    return (jax.lax.dynamic_update_slice(grid_v, new_v, at0),
+            jax.lax.dynamic_update_slice(grid_m, new_m, at0))
+
+
+def _blank_grid(s: int, wp: int):
+    """The grid a rewrite's pieces are placed into: 0 and False."""
+    return jnp.zeros((s, wp), jnp.float64), jnp.zeros((s, wp), bool)
+
+
 def _lane_partials(spec: WindowSpec, ts, val, mask, wargs):
     """Mergeable per-(series, window) partials — the rollup-lane block
     builder (storage/rollup.py): one dispatch computes the sum, count,
@@ -265,6 +298,8 @@ _jitted_stacked_group = jax.jit(_stacked_group_pipeline,
                                 static_argnums=(0, 1))
 _jitted_grid_tail = jax.jit(_grid_tail, static_argnums=(0, 1))
 _jitted_downsample_grid = jax.jit(_downsample_grid, static_argnums=0)
+_jitted_place_piece = jax.jit(_place_piece)
+_jitted_blank_grid = jax.jit(_blank_grid, static_argnums=(0, 1))
 _jitted_lane_partials = jax.jit(_lane_partials, static_argnums=0)
 
 
@@ -292,6 +327,27 @@ def run_stacked_group_pipeline(spec: PipelineSpec, ts, val, mask, gid,
 def run_downsample_grid(step: DownsampleStep, ts, val, mask, wargs: dict):
     """One downsample-only dispatch -> (wts[W], v[S, W], mask[S, W])."""
     return _jitted_downsample_grid(step, ts, val, mask, wargs)
+
+
+def assemble_grid(pieces, s: int, wp: int):
+    """The [S, Wp] grid of window-contiguous pieces, put together on
+    the device: `pieces` is [(v[S, pw], mask[S, pw], count)] in window
+    order, each piece's first `count` columns taken (a piece's width is
+    its own padded one, never over Wp).  Columns past the pieces hold 0
+    and False.  A device array never leaves the device, a host array is
+    uploaded as it is.  A dispatch costs the handler a fraction of a
+    millisecond on the chip's host, so there are as few as there can be:
+    one for the blank grid, one upload of every piece's (offset, count),
+    one placement a piece."""
+    pieces = list(pieces)
+    ats, col = [], 0
+    for _pv, _pm, count in pieces:
+        ats.append(np.array([col, count], np.int32))
+        col += count
+    v, m = _jitted_blank_grid(s, wp)
+    for (pv, pm, _count), at in zip(pieces, jax.device_put(ats)):
+        v, m = _jitted_place_piece(v, m, pv, pm, at)
+    return v, m
 
 
 # shape: ts[S,N] any, val[S,N] f64, mask[S,N] bool

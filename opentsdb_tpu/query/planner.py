@@ -1012,8 +1012,8 @@ class QueryRunner:
         import jax.numpy as jnp
         from opentsdb_tpu.ops.hostlane import host_lane
         from opentsdb_tpu.ops.pipeline import (
-            DownsampleStep, build_batch_direct, run_downsample_grid,
-            run_grid_tail)
+            DownsampleStep, assemble_grid, build_batch_direct,
+            run_downsample_grid, run_grid_tail)
         tsdb = self.tsdb
         fix = tsdb.config.fix_duplicates
         step0 = spec.downsample
@@ -1074,44 +1074,46 @@ class QueryRunner:
                                                      mask, wargs)
                     self._bump("aggCacheComputedWindows", piece.count)
                     if piece.block is not None:
+                        # a fresh full block goes to the host tier once
                         vn, mn = self._materialize_agg_piece(v, m,
                                                              piece.count)
                         tsdb.agg_cache.store_block(plan, piece,
                                                    series_list, vn, mn)
-                    # edge pieces stay padded here; the host assembly
-                    # slices to piece.count after materializing (an eager
-                    # jnp slice would dispatch — and recompile — per call)
+                    # pieces stay padded: the assembly takes each one's
+                    # first piece.count columns
                     pieces_v.append(v)
                     pieces_m.append(m)
-            w = windows.count
+            n_cached = sum(p.cached is not None for p in plan.pieces)
+            pieces_c = REGISTRY.counter(
+                "tsd.query.rewrite.pieces", "Pieces of partial-aggregate "
+                "rewrites, by kind")
+            pieces_c.labels(kind="cached").inc(n_cached)
+            pieces_c.labels(kind="computed").inc(
+                len(plan.pieces) - n_cached)
             wp = window_spec.count
-            # Device concatenation only for the all-cached all-device
-            # repeat (stable piece shapes -> the concat compiles once
-            # per family).  Everything else assembles on the HOST:
-            # sliding windows change the edge pieces' shapes every
-            # refresh, and a jnp.concatenate would recompile per
-            # distinct shape combination (measured ~0.5s/slide) while
-            # np writes cost microseconds; the grid upload itself is
-            # [S, Wp] — tiny next to the point data the cache avoids.
-            device_ok = all(p.cached is not None
-                            and p.tier == "agg_device"
-                            for p in plan.pieces)
+            counts = [p.count for p in plan.pieces]
             with obs_trace.timed_stage("rw_assemble"):
-                if device_ok:
-                    pad = [jnp.zeros((s, wp - w), jnp.float64)] \
-                        if wp > w else []
-                    mpad = [jnp.zeros((s, wp - w), bool)] if wp > w else []
-                    v_full = jnp.concatenate(pieces_v + pad, axis=1)
-                    m_full = jnp.concatenate(pieces_m + mpad, axis=1)
-                else:
+                if host_small:
+                    # the host lane's device is the CPU, where the
+                    # device-tier blocks are not: copy every piece into
+                    # a host grid
                     v_full = np.zeros((s, wp), np.float64)
                     m_full = np.zeros((s, wp), bool)
                     col = 0
-                    for v, m, piece in zip(pieces_v, pieces_m, plan.pieces):
-                        v_full[:, col:col + piece.count], \
-                            m_full[:, col:col + piece.count] = \
-                            self._materialize_agg_piece(v, m, piece.count)
-                        col += piece.count
+                    for v, m, count in zip(pieces_v, pieces_m, counts):
+                        v_full[:, col:col + count], \
+                            m_full[:, col:col + count] = \
+                            self._materialize_agg_piece(v, m, count)
+                        col += count
+                else:
+                    # one placement program a piece width, traced
+                    # offsets: no compile per slide, no host round trip
+                    v_full, m_full = assemble_grid(
+                        zip(pieces_v, pieces_m, counts), s, wp)
+            REGISTRY.counter(
+                "tsd.query.rewrite.assembly", "Partial-aggregate "
+                "rewrites, by the lane that assembled the grid").labels(
+                    lane="host" if host_small else "device").inc()
             with obs_trace.timed_stage("tail"):
                 # the monolithic grid's timestamps: first + i * interval
                 # over the padded window count, int64 (window_timestamps)

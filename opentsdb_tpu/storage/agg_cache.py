@@ -77,8 +77,8 @@ Host tier: every cached block, numpy, byte-budgeted
 (`tsd.query.cache.mb`, LRU).  Device tier: blocks that keep hitting
 (>= `tsd.query.cache.promote_hits`) get an HBM mirror beside
 storage/device_cache.py's column cache (`tsd.query.cache.device_mb`,
-own LRU) — when every piece of an assembled grid is device-resident
-the tail dispatch consumes it with zero host->device traffic.
+own LRU) — a device-tier block goes into the assembled grid without
+leaving the device (ops/pipeline.py assemble_grid).
 
 This module stays importable numpy-only (the device tier lazy-imports
 jax), like the rest of storage/.

@@ -286,7 +286,8 @@ def test_each_request_adds_up_and_its_stages_nest(daemon, uri):
         assert (stages["consult"] > 0) == (uri == REWRITE_URI)
 
 
-def test_under_a_profile_the_edges_are_on_the_trace(daemon, tmp_path):
+def test_under_a_profile_the_edges_are_on_the_trace(daemon, tmp_path,
+                                                    monkeypatch):
     """The loop thread's `write` events carry `resume_ms` and a trace id,
     the handler's `parse` event carries `queue_ms`; two connections whose
     writes interleave on the loop each keep their own event; and
@@ -297,6 +298,14 @@ def test_under_a_profile_the_edges_are_on_the_trace(daemon, tmp_path):
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     ids = ("hc-prof-1", "hc-prof-2")
+    # `get` waits for the route's write edge to move, which the OTHER
+    # request's write may do first: wait for both ids' own writes
+    done, real = [], latattr.Edges.written
+
+    def written(self, resumed, ann):
+        real(self, resumed, ann)
+        done.append(self.trace_id)
+    monkeypatch.setattr(latattr.Edges, "written", written)
     jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
     try:
         threads = [threading.Thread(target=get, args=(
@@ -305,6 +314,10 @@ def test_under_a_profile_the_edges_are_on_the_trace(daemon, tmp_path):
             t.start()
         for t in threads:
             t.join(60)
+        give_up = time.monotonic() + 10.0
+        while not set(ids) <= set(done):
+            assert time.monotonic() < give_up, done
+            time.sleep(0.001)
     finally:
         jax.profiler.stop_trace()
     (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"

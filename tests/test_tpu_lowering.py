@@ -58,6 +58,10 @@ GATHER_CASES = [(n, compact) for n in (256, 2048, 8192)
 GATHER_NAMES = ["gather:n%d:%s" % (n, "ts_base" if compact else "int64")
                 for n, compact in GATHER_CASES]
 
+# a rewritten request's grid placement at the 4000-host cells' [S, Wp]
+PLACE_WIDTHS = (8, 16, 32)
+PLACE_NAMES = ["place:pw%d" % pw for pw in PLACE_WIDTHS]
+
 
 def _compile_all() -> None:
     """Child: compile every case for v5e 2x2, one JSON line each."""
@@ -163,6 +167,24 @@ def _compile_all() -> None:
             print(json.dumps({"case": name, "ok": False,
                               "error": str(e)[:400]}), flush=True)
 
+    from opentsdb_tpu.ops.pipeline import _jitted_place_piece
+    for name, pw in zip(PLACE_NAMES, PLACE_WIDTHS):
+        try:
+            text = _jitted_place_piece.lower(
+                on_chip((GATHER_ROWS, 128), jnp.float64),
+                on_chip((GATHER_ROWS, 128), jnp.bool_),
+                on_chip((GATHER_ROWS, pw), jnp.float64),
+                on_chip((GATHER_ROWS, pw), jnp.bool_),
+                on_chip((2,), jnp.int32)).compile().as_text()
+            print(json.dumps({
+                "case": name, "ok": True,
+                "whiles": len(re.findall(r" while\(", text)),
+                "gathers": len(re.findall(r" gather\(", text))}),
+                flush=True)
+        except Exception as e:  # noqa: BLE001 — the verdict under test
+            print(json.dumps({"case": name, "ok": False,
+                              "error": str(e)[:400]}), flush=True)
+
 
 @pytest.fixture(scope="module")
 def verdicts():
@@ -214,6 +236,16 @@ def test_cache_gather_splits_no_pinned_buffer_on_a_v5e(verdicts, case):
     allowed = {"[]", "[%d]" % GATHER_ROWS, "[%d,%d]" % (GATHER_ROWS, n)}
     assert {c[c.index("["):] for c in calls} <= allowed, verdict
     assert not any(str(GATHER_BUFFER) in c for c in calls), verdict
+
+
+@pytest.mark.parametrize("case", PLACE_NAMES)
+def test_grid_placement_compiles_for_a_v5e(verdicts, case):
+    """The placement program at the 4000-host cells' [4000, 128] grid
+    and each edge-piece width: slices, selects and updates, with no
+    loop and no gather."""
+    verdict = verdicts[case]
+    assert verdict["ok"], verdict.get("error")
+    assert verdict["whiles"] == 0 and verdict["gathers"] == 0, verdict
 
 
 if __name__ == "__main__":
