@@ -39,30 +39,55 @@ def _end_with(runner: int) -> None:
     os._exit(1)
 
 
-def _writer_init(runner: int, port: int, values_path: str,
-                 tags: list[dict], metric: str) -> None:
-    threading.Thread(target=_end_with, args=(runner,), daemon=True).start()
-    _W["client"] = Client(port)
-    _W["values"] = np.load(values_path, mmap_mode="r")
-    _W["tail"] = [',"tags":{%s}}' % ",".join(
-        '"%s":"%s"' % (k, t[k]) for k in TAG_KEYS) for t in tags]
-    _W["metric"] = metric
-    _W["vstr"] = [str(v) for v in range(101)]
+VSTR = [str(v) for v in range(101)]      # every value a gauge can take
 
 
-def _put(h0: int, h1: int, c0: int, c1: int) -> int:
-    """POST one body: hosts [h0, h1) x columns [c0, c1).  Returns the
-    points acked; anything but a full ack is a failure."""
-    vstr = _W["vstr"]
+def tag_tails(tags: list[dict]) -> list[str]:
+    """Each host's tags as the end of one JSON point."""
+    return [',"tags":{%s}}' % ",".join('"%s":"%s"' % (k, t[k])
+                                       for k in TAG_KEYS) for t in tags]
+
+
+def put_body(values, tails: list[str], metric: str, h0: int, h1: int,
+             c0: int, c1: int) -> bytes:
+    """One /api/put body of one metric: hosts [h0, h1) x columns [c0, c1)
+    of its [hosts, points] `values`, host after host, each in time
+    order."""
     head = ['{"metric":"%s","timestamp":%d,"value":'
-            % (_W["metric"], EPOCH_S + CADENCE_S * c) for c in range(c0, c1)]
+            % (metric, EPOCH_S + CADENCE_S * c) for c in range(c0, c1)]
     parts = []
     for h in range(h0, h1):
-        tail = _W["tail"][h]
-        row = _W["values"][h, c0:c1].tolist()
-        parts.append(",".join([a + vstr[v] + tail
+        tail = tails[h]
+        row = values[h, c0:c1].tolist()
+        parts.append(",".join([a + VSTR[v] + tail
                                for a, v in zip(head, row)]))
-    body = ("[" + ",".join(parts) + "]").encode()
+    return ("[" + ",".join(parts) + "]").encode()
+
+
+def load_jobs(hosts: int, columns: int, metrics: int) -> list[tuple]:
+    """The retained store as (metric, h0, h1, c0, c1) bodies of 50 hosts
+    x 720 columns (~36k points, ~8 MB), as PR 21 wrote them, one metric
+    after another."""
+    return [(f, h0, min(h0 + 50, hosts), c0, min(c0 + 720, columns))
+            for f in range(metrics)
+            for c0 in range(0, columns, 720)
+            for h0 in range(0, hosts, 50)]
+
+
+def _writer_init(runner: int, port: int, values_path: str,
+                 tags: list[dict], metrics: list[str]) -> None:
+    threading.Thread(target=_end_with, args=(runner,), daemon=True).start()
+    _W["client"] = Client(port)
+    _W["values"] = np.load(values_path, mmap_mode="r")  # [metric, host, col]
+    _W["tail"] = tag_tails(tags)
+    _W["metrics"] = metrics
+
+
+def _put(f: int, h0: int, h1: int, c0: int, c1: int) -> int:
+    """POST one body: metric f, hosts [h0, h1) x columns [c0, c1).
+    Returns the points acked; anything but a full ack is a failure."""
+    body = put_body(_W["values"][f], _W["tail"], _W["metrics"][f], h0, h1,
+                    c0, c1)
     status, reply = _W["client"].request(
         "POST", "/api/put?summary", body,
         {"Content-Type": "application/json"})
@@ -75,16 +100,17 @@ def _put(h0: int, h1: int, c0: int, c1: int) -> int:
     return summary["success"]
 
 
-def _load_job(job: tuple[int, int, int, int]) -> int:
+def _load_job(job: tuple[int, int, int, int, int]) -> int:
     return _put(*job)
 
 
 def _backfill(h0: int, h1: int, c0: int, c_end: int, cols: int,
               start_at: float, stop_at: float) -> list[tuple]:
-    """One closed-loop writer: its hosts' columns from c0 on, `cols` per
-    body, in time order, from `start_at` until `stop_at` or the data's
-    end.  No body is issued after `stop_at`; the one in flight finishes.
-    Returns (issued, acked, points, next column) per body."""
+    """One closed-loop writer: its hosts' columns of the first metric
+    from c0 on, `cols` per body, in time order, from `start_at` until
+    `stop_at` or the data's end.  No body is issued after `stop_at`; the
+    one in flight finishes.  Returns (issued, acked, points, next column)
+    per body."""
     while time.monotonic() < start_at:
         time.sleep(min(0.002, max(start_at - time.monotonic(), 0)))
     out = []
@@ -93,7 +119,7 @@ def _backfill(h0: int, h1: int, c0: int, c_end: int, cols: int,
         if issued >= stop_at:
             break
         c1 = min(c0 + cols, c_end)
-        points = _put(h0, h1, c0, c1)
+        points = _put(0, h0, h1, c0, c1)
         out.append((issued, time.monotonic(), points, c1))
         c0 = c1
     return out
@@ -119,19 +145,17 @@ class Writers:
     of which ends with the runner (_end_with)."""
 
     def __init__(self, processes: int, port: int, values_path: str,
-                 tags: list[dict], metric: str):
+                 tags: list[dict], metrics: list[str]):
         self.pool = ProcessPoolExecutor(
             processes, mp_context=multiprocessing.get_context("spawn"),
             initializer=_writer_init,
-            initargs=(os.getpid(), port, values_path, tags, metric))
+            initargs=(os.getpid(), port, values_path, tags, metrics))
 
-    def load(self, hosts: int, columns: int) -> int:
-        """The retained store, through POST /api/put: bodies of 50 hosts
-        x 720 columns (~36k points, ~8 MB), as PR 21 wrote them."""
-        jobs = [(h0, min(h0 + 50, hosts), c0, min(c0 + 720, columns))
-                for c0 in range(0, columns, 720)
-                for h0 in range(0, hosts, 50)]
-        return sum(self.pool.map(_load_job, jobs, chunksize=4))
+    def load(self, hosts: int, columns: int, metrics: int) -> int:
+        """The retained store of every metric, through POST /api/put
+        (load_jobs)."""
+        return sum(self.pool.map(_load_job, load_jobs(hosts, columns,
+                                                      metrics), chunksize=4))
 
     def backfill(self, hosts: int, body_points: int, cursors: list[int],
                  c_end: int, start_at: float, stop_at: float):

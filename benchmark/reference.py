@@ -67,11 +67,22 @@ def ref_aggregate(grid: np.ndarray, agg: str) -> np.ndarray:
     raise ValueError("reference has no aggregator %r" % agg)
 
 
-def ref_query(fleet: Fleet, req: dict
-              ) -> dict[str, tuple[np.ndarray, np.ndarray]]:
-    """{group tag value: (timestamps [W], values [W])} for one request
-    over the retained columns: filter hosts, cut the time range (end
-    inclusive), downsample, rate, then aggregate each group."""
+def ref_query(fleet: Fleet, req: dict) -> dict:
+    """{group tag value: (timestamps [W], values [W])} for a request of
+    one metric; for one of several (`req["metrics"]`, the fleet's first
+    n), the same per sub-query under (metric, group tag value)."""
+    if not req.get("metrics"):
+        return _ref_sub(fleet, req, fleet.values)
+    return {(name, group): answer for name in req["metrics"]
+            for group, answer in _ref_sub(
+                fleet, req, fleet.data[fleet.metrics.index(name)]).items()}
+
+
+def _ref_sub(fleet: Fleet, req: dict, values: np.ndarray
+             ) -> dict[str, tuple[np.ndarray, np.ndarray]]:
+    """One sub-query over one metric's [hosts, points] `values`, retained
+    columns only: filter hosts, cut the time range (end inclusive),
+    downsample, rate, then aggregate each group."""
     c0 = max(-(-(req["start"] - EPOCH_S) // CADENCE_S), 0)
     c1 = min((req["end"] - EPOCH_S) // CADENCE_S + 1, fleet.retained)
     ts = fleet.ts[c0:c1]
@@ -80,10 +91,10 @@ def ref_query(fleet: Fleet, req: dict
         groups: dict[str, list[int]] = {}
         for i, h in enumerate(rows):
             groups.setdefault(fleet.tags[h][req["group_by"]], []).append(i)
-        vals = fleet.values[rows, c0:c1]
+        vals = values[rows, c0:c1]
         members = {g: np.asarray(i) for g, i in groups.items()}
     else:
-        vals = fleet.values[:, c0:c1]
+        vals = values[:, c0:c1]
         members = fleet.members(req["group_by"])
     wts, grid = ref_downsample(ts, vals, req["interval_s"], req["ds_fn"])
     if req.get("rate"):
@@ -92,13 +103,17 @@ def ref_query(fleet: Fleet, req: dict
             for g, idx in members.items()}
 
 
-def parse_answer(payload: list, group_by: str) -> dict:
+def parse_answer(payload: list, group_by: str, by_metric: bool = False
+                 ) -> dict:
+    """An /api/query answer as ref_query keys it: by group tag value, or
+    with `by_metric` (a request of several metrics) by (metric, group)."""
     out = {}
     for r in payload:
         if "metric" not in r:
             continue            # statsSummary trailer
         items = sorted((int(k), v) for k, v in r["dps"].items())
-        out[r["tags"][group_by]] = (
+        group = r["tags"][group_by]
+        out[(r["metric"], group) if by_metric else group] = (
             np.array([k for k, _ in items], np.int64),
             np.array([v for _, v in items], np.float64))
     return out

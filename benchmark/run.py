@@ -39,6 +39,7 @@ T_START = time.monotonic()          # set-up counts from process start
 import argparse  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
+import resource  # noqa: E402
 import shutil  # noqa: E402
 import signal  # noqa: E402
 import subprocess  # noqa: E402
@@ -268,8 +269,8 @@ def judge(records, fleet: Fleet, refs: dict) -> list[str]:
                     want = refs[req["path"]] = reference.ref_query(fleet,
                                                                    req)
                 rec.groups = len(want)
-                why = reference.compare(
-                    reference.parse_answer(payload, req["group_by"]), want)
+                why = reference.compare(reference.parse_answer(
+                    payload, req["group_by"], bool(req.get("metrics"))), want)
         rec.ok = why is None
         rec.body = b""
         if why and len(wrong) < 10:
@@ -424,9 +425,10 @@ class Run:
                 (self.wr["backfill_hours"] + self.wr["warm_hours"]) * 3600
                 * scale["hours"] / cfg["retention_hours"]) // CADENCE_S
         self.fleet = self.timed("generate", Fleet, scale["hosts"], retained,
-                                self.extra, self.args.seed)
+                                self.extra, self.args.seed,
+                                cfg.get("metrics", 1))
         self.values_path = os.path.join(self.out_dir, "values.npy")
-        np.save(self.values_path, self.fleet.values.astype(np.int8))
+        np.save(self.values_path, self.fleet.data.astype(np.int8))
         self.timed("daemon_start", daemon.wait_ready, self.proc, self.port,
                    600.0)
         self.client = daemon.Client(self.port)
@@ -459,13 +461,14 @@ class Run:
         fleet, procs = self.fleet, self.mix.get("load_processes", 6)
         self.writers = loadgen.Writers(
             max(procs, self.wr["processes"] if self.wr else 0), self.port,
-            self.values_path, fleet.tags, fleet.metric)
+            self.values_path, fleet.tags, fleet.metrics)
         t_load = time.monotonic()
         loaded: dict = {}
 
         def load_store() -> None:
             try:
-                loaded.update(n=self.writers.load(fleet.hosts, fleet.retained),
+                loaded.update(n=self.writers.load(fleet.hosts, fleet.retained,
+                                                  len(fleet.metrics)),
                               s=time.monotonic() - t_load)
             except Exception as e:      # the main thread says it, below; a
                 loaded["error"] = e     # cut run's pool breaks under it
@@ -478,7 +481,7 @@ class Run:
             raise BenchFailure("loading the store failed (see daemon.log): "
                                "%r" % loaded.get("error"))
         self.phases["ingest"] = loaded["s"]
-        sent = fleet.hosts * fleet.retained
+        sent = len(fleet.metrics) * fleet.hosts * fleet.retained
         ctr = daemon.counters(self.client)
         added = daemon.counter_sum(ctr, "tsd_datapoints_added")
         if loaded["n"] != sent or added != sent:
@@ -489,9 +492,10 @@ class Run:
                                           "tsd_put_parser{parser=native}")):
             raise BenchFailure("the put path took the Python fallback "
                                "parser: is native/libtsdb_engine.so built?")
-        say("loaded %d points in %.1f s (%.3f Mpts/s, %d writer "
-            "processes, parser native)" % (
-                sent, loaded["s"], sent / loaded["s"] / 1e6, procs))
+        say("loaded %d points of %d metrics in %.1f s (%.3f Mpts/s, %d "
+            "writer processes, parser native)" % (
+                sent, len(fleet.metrics), loaded["s"],
+                sent / loaded["s"] / 1e6, procs))
 
     def backfill(self, c_end: int, t0: float, seconds: float):
         wr = self.wr
@@ -793,6 +797,12 @@ def report(cell: Cell, ctx: dict, phases: dict, setup_s: float) -> None:
             np.mean(lat) if lat else float("nan")))
     say("window %.2f s measured (%.0f s asked); %d requests, %d bodies" % (
         ctx["window_s"], ctx["seconds"], len(records), len(ctx["puts"])))
+    # read after the daemon and the writers were waited for: the
+    # largest child is the daemon
+    say("peak resident memory, MB: runner %.0f, largest child (the "
+        "daemon) %.0f" % tuple(resource.getrusage(who).ru_maxrss * 1024 / 1e6
+                              for who in (resource.RUSAGE_SELF,
+                                          resource.RUSAGE_CHILDREN)))
     # the traced run's end-to-end numbers: set beside a --trace 0 run's,
     # their difference is what tracing costs
     for name, spec in cell.mix.get("metrics", {}).items():

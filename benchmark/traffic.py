@@ -2,7 +2,8 @@
 (traffic/<mix>.json), and every request, arrival time and host choice is
 drawn here from --seed.  A new mix over the existing operators needs no
 code: classes name their reference by the fields chip_smoke.request_table
-used (group_by, interval_s, ds_fn, agg, rate)."""
+used (group_by, interval_s, ds_fn, agg, rate) and how many of the fleet's
+metrics they ask for (metrics)."""
 
 from __future__ import annotations
 
@@ -92,13 +93,24 @@ class Generator:
         start = (pool[int(rng.integers(len(pool)))] if pool
                  else self._draw_start(cls, rng))
         end = start + self._span(cls) - 1           # end is inclusive
-        m = cls["m"].replace("$metric", self.fleet.metric).replace(
-            "$hosts", "|".join(hosts or []))
+        # a class of `metrics` n > 1 asks for the fleet's first n metrics
+        # in one request, one m= each (TSBS's double-groupby-5 / -all)
+        count = cls.get("metrics", 1)
+        names = ([self.fleet.metric] if count == 1
+                 else self.fleet.metrics[:count])
+        if len(names) != count:
+            raise ValueError("class %s asks for %d metrics; the fleet has %d"
+                             % (cls["name"], count, len(names)))
+        if count > 1:
+            req["metrics"] = names
+        subs = "".join("&m=" + urllib.parse.quote(cls["m"].replace(
+            "$metric", name).replace("$hosts", "|".join(hosts or [])),
+            safe="") for name in names)
         req.update(
             start=start, end=end,
-            path="/api/query?start=%d&end=%d&m=%s" % (
-                start, end, urllib.parse.quote(m, safe="")),
-            points=(n or self.fleet.hosts) * (self._span(cls) // CADENCE_S),
+            path="/api/query?start=%d&end=%d%s" % (start, end, subs),
+            points=count * (n or self.fleet.hosts) * (self._span(cls)
+                                                      // CADENCE_S),
             **{k: cls[k] for k in ("group_by", "interval_s", "ds_fn", "agg")},
             rate=bool(cls.get("rate")))
         return req

@@ -70,17 +70,29 @@ def timestamps(points: int) -> np.ndarray:
 
 
 class Fleet:
-    """The generated deployment: tags, timestamps and values of one
-    metric, `retained` columns of which are loaded before the window
-    (the rest is what a backfill writes)."""
+    """The generated deployment: tags, timestamps and values of the first
+    `metrics` cpu fields in TSBS's own order (its GetCPUMetricsSlice(n)
+    takes them so), `retained` columns of which are loaded before the
+    window (the rest is what a backfill writes).  Field f's walk is
+    make_values(..., f) whatever `metrics` is, so the first field's data
+    is the same in a store of one metric or of ten."""
 
     def __init__(self, hosts: int, retained: int, extra: int, seed: int,
-                 field: int = 0):
-        self.metric = "cpu." + CPU_FIELDS[field]
+                 metrics: int = 1):
+        if not 1 <= metrics <= len(CPU_FIELDS):
+            raise ValueError("a fleet holds 1 to %d cpu metrics, not %r"
+                             % (len(CPU_FIELDS), metrics))
+        self.metrics = ["cpu." + f for f in CPU_FIELDS[:metrics]]
+        self.metric = self.metrics[0]
         self.tags = make_fleet(hosts, seed)
         self.retained = retained
         self.ts = timestamps(retained + extra)
-        self.values = make_values(hosts, retained + extra, seed, field)
+        # [metrics, hosts, points]; `values` is the first metric's
+        # [hosts, points], which lastpoint and the backfill read
+        self.data = np.empty((metrics, hosts, retained + extra), np.int64)
+        for f in range(metrics):
+            self.data[f] = make_values(hosts, retained + extra, seed, f)
+        self.values = self.data[0]
         self.index = {t["hostname"]: h for h, t in enumerate(self.tags)}
         self._members: dict[str, dict[str, np.ndarray]] = {}
 
