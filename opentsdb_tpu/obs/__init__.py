@@ -86,10 +86,15 @@ METRICS_SCHEMA: dict[str, MetricSpec] = {
     "tsd.query.groups": _m(
         "counter", (),
         "Groups answered by grouped downsample queries."),
+    "tsd.query.subqueries": _m(
+        "counter", (),
+        "Sub-queries (one m= or tsuid= each) the query runner ran: over "
+        "tsd.http.requests of api/query, the fan-out of a request."),
     "tsd.query.stage_ms": _m(
         "counter", ("stage",),
         "Cumulative wall milliseconds of the planner's host stages, "
-        "tracing on or off: scan (resolve + group, or their memo), "
+        "tracing on or off: subquery (one sub-query whole, the stages "
+        "below included), scan (resolve + group, or their memo), "
         "count (per-row point counts, budget), consult (the routing "
         "verdict and its cache consults); inside latattr's dispatch "
         "rewrite (a partial-aggregate rewrite whole: rw_pieces, the "
@@ -599,6 +604,16 @@ METRICS_SCHEMA: dict[str, MetricSpec] = {
         "gauge", (), "Device-cache batch gathers served from HBM."),
     "tsd.query.device_cache.misses": _m(
         "gauge", (), "Device-cache misses (cold/stale/over-budget)."),
+    "tsd.query.device_cache.miss_reason": _m(
+        "counter", ("reason",),
+        "Device-cache misses by their reason, one per miss: cold (no "
+        "entry, none built or the metric not admitted), building (no "
+        "entry, one being built), evicted (no entry, the last one was "
+        "evicted for the byte budget), stale (a requested series' "
+        "version moved since the snapshot), rows (a requested series "
+        "is not in the snapshot), batch (the [S, n] batch is over "
+        "tsd.query.device_cache.batch_mb).  They sum to "
+        "tsd.query.device_cache.misses."),
     "tsd.query.device_cache.builds": _m(
         "gauge", (), "Device-cache entry builds."),
     "tsd.query.device_cache.evictions": _m(

@@ -1864,10 +1864,19 @@ class QueryRunner:
         return [merged[k] for k in sorted(merged)]
 
     def run(self, query: TSQuery) -> list[QueryResult]:
+        """Every sub-query in turn, each one a `subquery` stage: its
+        wall time goes to tsd.query.stage_ms{stage=subquery} and, in a
+        traced request, to a span of its own around its plan, dispatch
+        and extraction."""
         self.exec_stats = {}
         out = []
-        for sub in query.queries:
-            out.extend(self.run_sub(query, sub))
+        subs = REGISTRY.counter(
+            "tsd.query.subqueries",
+            "Sub-queries (m= / tsuid=) run by the query runner").labels()
+        for i, sub in enumerate(query.queries):
+            subs.inc()
+            with obs_trace.timed_stage("subquery", index=i):
+                out.extend(self.run_sub(query, sub))
         return out
 
 

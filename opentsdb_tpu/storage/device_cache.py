@@ -69,6 +69,7 @@ from __future__ import annotations
 
 import logging
 import threading
+import time
 from dataclasses import dataclass, field
 
 import numpy as np
@@ -227,6 +228,9 @@ class DeviceSeriesCache:
         self._entries: dict[tuple, _Entry] = {}
         self._stale: dict[tuple, object] = {}  # key -> store  # guarded-by: _lock
         self._building: set[tuple] = set()  # guarded-by: _lock
+        # keys whose entry the byte budget evicted and nothing rebuilt
+        # since: a miss on one reads `evicted`, not `cold`
+        self._evicted: set[tuple] = set()  # guarded-by: _lock
         self._lock = threading.Lock()
         self._tick = 0  # guarded-by: _lock
         # stats (surfaced via /api/stats)
@@ -279,21 +283,23 @@ class DeviceSeriesCache:
         if bounds is None:
             with self._lock:
                 entry = self._entries.get(ekey)
-            if entry is None:
-                if not build:
-                    with self._lock:
-                        self._stale[ekey] = store
-                    self._count("misses")
+                absent = None if entry is not None \
+                    else self._absent_locked(ekey)
+                if absent is not None and not build:
+                    self._stale[ekey] = store
+            if absent is not None:
+                if not build or absent == "building":
+                    self._miss(absent)
                     return None
                 entry = self._build(store, metric)
                 if entry is None:
-                    self._count("misses")
+                    self._miss(absent)
                     return None
-            bounds = _bounds(entry, entry.rows_of(series_list), series_list,
-                             start_ms, end_ms)
+            rows = entry.rows_of(series_list)
+            bounds = _bounds(entry, rows, series_list, start_ms, end_ms)
             if bounds is None:
                 self._mark_stale(ekey, entry)
-                self._count("misses")
+                self._miss("rows" if rows is None else "stale")
                 return None
         entry, starts, lengths = bounds.entry, bounds.starts, bounds.lengths
         s = len(series_list)
@@ -303,7 +309,7 @@ class DeviceSeriesCache:
         # layout actually fits
         per_point = 13 if ts_base is not None else 17
         if s * n * per_point > self.batch_max_bytes:
-            self._count("misses")
+            self._miss("batch")
             return None
         with self._lock:
             self._tick += 1
@@ -411,12 +417,15 @@ class DeviceSeriesCache:
                 tier="device_series").inc()
 
     @staticmethod
-    def _emit_miss() -> None:
+    def _emit_miss(reason: str) -> None:
         from opentsdb_tpu.obs.registry import REGISTRY
         REGISTRY.counter(
             "tsd.query.cache.misses",
             "Query-cache misses, by tier").labels(
                 tier="device_series").inc()
+        REGISTRY.counter(
+            "tsd.query.device_cache.miss_reason",
+            "Device-cache misses, by reason").labels(reason=reason).inc()
 
     @staticmethod
     def _emit_evictions(n: int) -> None:
@@ -437,11 +446,19 @@ class DeviceSeriesCache:
             "Query-cache resident entries, by tier").labels(
                 tier="device_series").set(len(self))
 
-    def _count(self, name: str) -> None:
+    def _miss(self, reason: str) -> None:
         with self._lock:
-            setattr(self, name, getattr(self, name) + 1)
-        if name == "misses":
-            self._emit_miss()
+            self.misses += 1
+        self._emit_miss(reason)
+
+    def _absent_locked(self, ekey: tuple) -> str:
+        """Why a key has no entry: `building` (a build of it is under
+        way), `evicted` (the budget evicted it and nothing rebuilt it
+        since) or `cold` (never built, dropped by invalidate, or not
+        admitted)."""
+        if ekey in self._building:
+            return "building"
+        return "evicted" if ekey in self._evicted else "cold"
 
     def _mark_stale(self, ekey: tuple, entry: _Entry) -> None:
         with self._lock:
@@ -466,6 +483,7 @@ class DeviceSeriesCache:
                 self._building.discard(ekey)
 
     def _build_guarded(self, store, metric: int):
+        t0 = time.perf_counter()
         series_list = store.series_for_metric(metric)
         if not series_list:
             return None
@@ -507,17 +525,23 @@ class DeviceSeriesCache:
             entry.tick = self._tick
             self._entries[ekey] = entry
             self._stale.pop(ekey, None)
+            self._evicted.discard(ekey)
             self.builds += 1
         if evicted:
             self._emit_evictions(evicted)
         self._emit_bytes()
+        _LOG.info("pinned metric %d: %d series, %d points, %d MiB in "
+                  "%.2f s (%d evicted)", metric, len(series_list), total,
+                  entry.nbytes >> 20, time.perf_counter() - t0, evicted)
         return entry
 
     def _evict_for_locked(self, incoming_bytes: int) -> None:
         used = sum(e.nbytes for e in self._entries.values())
         while self._entries and used + incoming_bytes > self.max_bytes:
             victim = min(self._entries.values(), key=lambda e: e.tick)
-            self._entries.pop((id(victim.store), victim.metric))
+            vkey = (id(victim.store), victim.metric)
+            self._entries.pop(vkey)
+            self._evicted.add(vkey)
             used -= victim.nbytes
             self.evictions += 1
 
@@ -534,11 +558,22 @@ class DeviceSeriesCache:
             pending = list(self._stale.items())[:max_rebuilds]
             for ekey, _ in pending:
                 self._stale.pop(ekey, None)
+            # a key another build already holds is left to that build;
+            # the others are held as building from the moment their
+            # entry is dropped, so a miss in between reads `building`
+            pending = [(k, st) for k, st in pending
+                       if k not in self._building]
+            for ekey, _ in pending:
                 self._entries.pop(ekey, None)
+                self._building.add(ekey)
         done = 0
-        for (_, metric), st in pending:
-            if self._build(st, metric) is not None:
-                done += 1
+        for ekey, st in pending:
+            try:
+                if self._build_guarded(st, ekey[1]) is not None:
+                    done += 1
+            finally:
+                with self._lock:
+                    self._building.discard(ekey)
         return done
 
     def invalidate(self, metric: int | None = None) -> None:
@@ -547,11 +582,13 @@ class DeviceSeriesCache:
             if metric is None:
                 self._entries.clear()
                 self._stale.clear()
+                self._evicted.clear()
             else:
                 for ekey in [k for k in self._entries if k[1] == metric]:
                     self._entries.pop(ekey, None)
                 for ekey in [k for k in self._stale if k[1] == metric]:
                     self._stale.pop(ekey, None)
+                self._evicted = {k for k in self._evicted if k[1] != metric}
         self._emit_bytes()
 
     def collect_stats(self) -> dict:

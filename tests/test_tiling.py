@@ -179,7 +179,12 @@ class TestTiledExecution:
             obs_trace.deactivate()
             tr.finish()
         assert st.get("tiledExecution") == 1.0
-        pipes = [sp for sp in tr.root.children if sp.name == "pipeline"]
+        def walk(sp):
+            yield sp
+            for child in sp.children:
+                yield from walk(child)
+        # a sub-query's pipeline span sits under its subquery span
+        pipes = [sp for sp in walk(tr.root) if sp.name == "pipeline"]
         assert pipes and all("tiling" in sp.tags for sp in pipes)
         assert not any("costmodel" in sp.tags for sp in pipes), \
             "a tiled execution must not carry the monolithic decisions"
