@@ -20,6 +20,7 @@
 // C ABI only (driven from Python via ctypes).
 
 #include <algorithm>
+#include <charconv>
 #include <cmath>
 #include <cstdint>
 #include <cstdio>
@@ -1682,4 +1683,89 @@ EXPORT const int64_t* eng_telnet_spans(void* h) {
 
 EXPORT const int32_t* eng_telnet_point(void* h) {
     return static_cast<putparse::TelnetBatch*>(h)->line_point.data();
+}
+
+// ------------------------------------------------------------ answer text
+//
+// The points of a grouped /api/query answer as the JSON text json.dumps
+// writes for them (query/planner.py QueryResult.json_text): every finite
+// double as Python's float.__repr__ gives it.  Both that and
+// std::to_chars without a precision write the shortest digits that read
+// back to the same double, the nearest of them to it; only the layout
+// differs, and py_float_repr moves to_chars' scientific form into it.
+
+namespace answertext {
+
+// A finite double's repr: plain notation where its decimal exponent
+// lies in [-4, 16), "d[.ddd]e±XX" outside, which is to_chars'
+// scientific form as it stands.  Writes at most 24 bytes at `out`, the
+// mantissa first where to_chars put it; returns the end.
+inline char* py_float_repr(double v, char* out) {
+    char* end = std::to_chars(out, out + 24, v,
+                              std::chars_format::scientific).ptr;
+    char* e = end[-4] == 'e' ? end - 4 : end - 5;   // 2 or 3 digits
+    int exp10 = 0;
+    for (const char* d = e + 2; d < end; d++) exp10 = exp10 * 10 + (*d - '0');
+    if (e[1] == '-') exp10 = -exp10;
+    if (exp10 < -4 || exp10 >= 16) return end;
+    char* lead = out + (*out == '-');   // the first digit
+    char digits[20];
+    int n = 1;
+    digits[0] = *lead;
+    for (const char* d = lead + 2; d < e; d++) digits[n++] = *d;
+    int point = exp10 + 1;     // digits before the decimal point
+    char* w = lead;
+    if (point <= 0) {
+        *w++ = '0';
+        *w++ = '.';
+        for (int i = point; i < 0; i++) *w++ = '0';
+        std::memcpy(w, digits, n);
+        return w + n;
+    }
+    if (point < n) {
+        std::memcpy(w, digits, point);
+        w += point;
+        *w++ = '.';
+        std::memcpy(w, digits + point, n - point);
+        return w + (n - point);
+    }
+    std::memcpy(w, digits, n);
+    w += n;
+    for (int i = n; i < point; i++) *w++ = '0';
+    *w++ = '.';
+    *w++ = '0';
+    return w;
+}
+
+}  // namespace answertext
+
+// Rows `rows[0..nrows)` of the C-ordered [*, ncols] float64 `block`,
+// each as `piece[0] v0 piece[1] v1 ... piece[ncols-1] v(ncols-1)
+// piece[ncols]`, one after another into `out`; piece i is
+// pieces[piece_off[i] .. piece_off[i+1]).  offsets[i] is where row i
+// starts, offsets[nrows] the end.  Returns the bytes written, -1 where
+// `cap` would be exceeded, -2 at a NaN or an infinity.
+EXPORT int64_t eng_emit_rows(const double* block, int64_t ncols,
+                             const int64_t* rows, int64_t nrows,
+                             const char* pieces, const int64_t* piece_off,
+                             char* out, int64_t cap, int64_t* offsets) {
+    const int64_t row_max = piece_off[ncols + 1] + 24 * ncols;
+    int64_t pos = 0;
+    for (int64_t i = 0; i < nrows; i++) {
+        offsets[i] = pos;
+        if (cap - pos < row_max) return -1;
+        const double* v = block + rows[i] * ncols;
+        char* w = out + pos;
+        for (int64_t j = 0; j < ncols; j++) {
+            if (!std::isfinite(v[j])) return -2;
+            int64_t len = piece_off[j + 1] - piece_off[j];
+            std::memcpy(w, pieces + piece_off[j], len);
+            w = answertext::py_float_repr(v[j], w + len);
+        }
+        int64_t tail = piece_off[ncols + 1] - piece_off[ncols];
+        std::memcpy(w, pieces + piece_off[ncols], tail);
+        pos = (w + tail) - out;
+    }
+    offsets[nrows] = pos;
+    return pos;
 }

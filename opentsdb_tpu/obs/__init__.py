@@ -86,6 +86,14 @@ METRICS_SCHEMA: dict[str, MetricSpec] = {
     "tsd.query.groups": _m(
         "counter", (),
         "Groups answered by grouped downsample queries."),
+    "tsd.query.emit_groups": _m(
+        "counter", ("lane",),
+        "Results written into /api/query answers (tsd/serializers.py "
+        "format_query_v1), by the lane that wrote their points: native "
+        "= the finite rows of a grouped answer's [G, W] value block, "
+        "every row of one block in one call of the native library "
+        "(query/planner.py emit_texts); python = every other result, "
+        "through QueryResult.json_text or to_json."),
     "tsd.query.subqueries": _m(
         "counter", (),
         "Sub-queries (one m= or tsuid= each) the query runner ran: over "

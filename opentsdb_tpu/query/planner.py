@@ -35,6 +35,7 @@ from opentsdb_tpu.ops.streaming import (
     StreamAccumulator, STREAMABLE_DS, is_sketch_ds, lanes_for)
 from opentsdb_tpu.query import filters as query_filters
 from opentsdb_tpu.rollup.config import NoSuchRollupForInterval, RollupQuery
+from opentsdb_tpu.storage import native_engine
 from opentsdb_tpu.storage.chunk_pack import ChunkPacker
 from opentsdb_tpu.storage.memstore import Series, SeriesKey
 from opentsdb_tpu.uid import NoSuchUniqueName
@@ -81,20 +82,22 @@ class QueryResult:
     as `dps`; a downsampled answer holds them as two columns instead —
     `stamps`, one list shared by every group of the answer, and this
     group's `values` — and pairs them up only for a reader that asks.
+    Where the values are row `row` of the answer's [G, W] float64 grid,
+    `block` is that grid (extract_grid), for emit_texts() to write.
     `tags`, `aggregate_tags` and `tsuids` may be a memoised selection's
     own objects: replace them, never write into them.
     """
 
     __slots__ = ("metric", "tags", "aggregate_tags", "tsuids",
                  "annotations", "global_annotations", "index", "head",
-                 "stamps", "values", "_dps")
+                 "stamps", "values", "_dps", "block", "row")
 
     def __init__(self, metric: str, tags: dict[str, str],
                  aggregate_tags: list[str], tsuids: list[str],
                  dps: list[tuple[int, object]] | None = None,
                  annotations=(), global_annotations=(), index: int = 0,
                  head: str | None = None, stamps: list | None = None,
-                 values: list | None = None):
+                 values: list | None = None, block=None, row: int = 0):
         self.metric = metric
         self.tags = tags
         self.aggregate_tags = aggregate_tags
@@ -107,6 +110,7 @@ class QueryResult:
         # selection; whoever replaces one of the three sets it to None
         self.head = head
         self.stamps, self.values, self._dps = stamps, values, dps
+        self.block, self.row = block, row
 
     @property
     def dps(self) -> list[tuple[int, object]]:
@@ -116,7 +120,7 @@ class QueryResult:
 
     @dps.setter
     def dps(self, pairs: list[tuple[int, object]]) -> None:
-        self.stamps = self.values = None
+        self.stamps = self.values = self.block = None
         self._dps = pairs
 
     def _columns(self) -> tuple:
@@ -190,6 +194,42 @@ class QueryResult:
         form = self._memo(keys, "form", stamps, lambda col: ', "dps": {%s}}' % (
             ", ".join('"%d": %%r' % (t // scale) for t in col)))
         return self.head + form % tuple(values)
+
+
+def emit_texts(results: list, ms_resolution: bool = False) -> list:
+    """json_text() of each result that holds a row of a value block, has
+    a head and no annotations, and whose row is finite: the rows of one
+    block written by one native call (storage/native_engine.py
+    emit_rows).  None for every other result, and for all of them where
+    the native library is unavailable."""
+    texts = [None] * len(results)
+    blocks: dict[int, tuple] = {}
+    for i, r in enumerate(results):
+        if r.block is not None and r.head is not None and not r.annotations:
+            entry = blocks.get(id(r.block))
+            if entry is None:
+                entry = blocks[id(r.block)] = (r, [], [])
+            entry[1].append(i)
+            entry[2].append(r.row)
+    scale = 1 if ms_resolution else 1000
+    for first, at, rows in blocks.values():
+        block, stamps = first.block, first.stamps
+        if not stamps:
+            continue
+        rows = np.asarray(rows, np.int64)
+        finite = np.isfinite(block).all(axis=1)[rows]
+        if not finite.all():
+            at = [i for i, ok in zip(at, finite.tolist()) if ok]
+            rows = rows[finite]
+        keys = ['"%d": ' % (t // scale) for t in stamps]
+        pieces = ([', "dps": {' + keys[0]] + [", " + k for k in keys[1:]]
+                  + ["}}"])
+        dps = native_engine.emit_rows(block, rows, pieces)
+        if dps is None:
+            return texts
+        for i, text in zip(at, dps):
+            texts[i] = results[i].head + text
+    return texts
 
 
 class _Selection:
@@ -584,10 +624,12 @@ class QueryRunner:
 
     def _assemble_result(self, query: TSQuery, sub: TSSubQuery, members,
                          dps, global_notes, meta=None, stamps=None,
-                         values=None) -> QueryResult:
+                         values=None, block=None,
+                         row: int = 0) -> QueryResult:
         """`meta` is the selection's (tags, aggregateTags, tsuids, head)
         of the group where it has them: the answer shares them.  The
-        points are `dps`, or the columns `stamps` and `values`."""
+        points are `dps`, or the columns `stamps` and `values` (row
+        `row` of `block`, where extract_grid kept one)."""
         tsdb = self.tsdb
         if meta is None:
             group_tags, agg_tags = self._compute_tags(members)
@@ -611,7 +653,7 @@ class QueryRunner:
             annotations=annotations,
             global_annotations=global_notes,
             index=sub.index,
-            head=head, stamps=stamps, values=values,
+            head=head, stamps=stamps, values=values, block=block, row=row,
         )
 
     def _run_segment_grouped(self, query: TSQuery, sub: TSSubQuery,
@@ -924,7 +966,7 @@ class QueryRunner:
             # device->host materialization (fetch, above) is where an
             # async dispatch actually blocks
             latattr.mark("device_wait")
-            stamps, rows = extract_grid(
+            stamps, rows, block = extract_grid(
                 out_ts, out_val[:n_groups], out_mask[:n_groups],
                 seg.start_ms, seg.end_ms,
                 keep_nans=sub.fill_policy != "none")
@@ -933,10 +975,11 @@ class QueryRunner:
             meta = sel.meta(tsdb, sub.metric or (
                 tsdb.metrics.get_name(series_list[0].key.metric)))
             results: dict[tuple, QueryResult] = {}
-            for g, ts_col, values in zip(scan.groups.tolist(), stamps, rows):
+            for i, (g, ts_col, values) in enumerate(
+                    zip(scan.groups.tolist(), stamps, rows)):
                 results[sel.str_keys[g]] = self._assemble_result(
                     query, sub, members[g], None, global_notes, meta[g],
-                    ts_col, values)
+                    ts_col, values, block, i)
         REGISTRY.counter(
             "tsd.query.series", "Rows (member series) dispatched by "
             "grouped downsample queries").inc(len(gid))
@@ -1952,13 +1995,14 @@ def _fmt_pct(p: float) -> str:
 
 def extract_grid(out_ts: np.ndarray, out_val: np.ndarray,
                  out_mask: np.ndarray, start_ms: int, end_ms: int,
-                 keep_nans: bool = False) -> tuple[list, list]:
+                 keep_nans: bool = False) -> tuple[list, list, object]:
     """extract_dps for every row of a [G, W] float grid over one [W]
     timestamp vector, as columns: ([G] timestamp lists, [G] value
-    lists).  Where every row keeps the same columns (a grid with no
-    gap: the common case) all rows share ONE timestamp list and the
-    values convert at C speed in one call; rows that differ are
-    extracted one by one."""
+    lists, the [G, W'] float64 block of the values or None).  Where
+    every row keeps the same columns (a grid with no gap: the common
+    case) all rows share ONE timestamp list, the values convert at C
+    speed in one call and their block is kept for the serializer; rows
+    that differ are extracted one by one and have no block."""
     ts = out_ts.ravel()
     val = out_val.astype(np.float64, copy=False)
     keep = out_mask & ((ts >= start_ms) & (ts <= end_ms))[None, :]
@@ -1969,8 +2013,9 @@ def extract_grid(out_ts: np.ndarray, out_val: np.ndarray,
         pairs = [extract_dps(ts, val[i], out_mask[i], start_ms, end_ms,
                              False, keep_nans) for i in range(len(val))]
         return ([[t for t, _ in row] for row in pairs],
-                [[v for _, v in row] for row in pairs])
-    return [ts[cols].tolist()] * len(val), val[:, cols].tolist()
+                [[v for _, v in row] for row in pairs], None)
+    block = np.ascontiguousarray(val[:, cols])
+    return [ts[cols].tolist()] * len(val), block.tolist(), block
 
 
 def extract_dps(out_ts: np.ndarray, out_val: np.ndarray, out_mask: np.ndarray,

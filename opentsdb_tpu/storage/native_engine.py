@@ -15,6 +15,7 @@ entry point degrades to the pure-Python path when the toolchain is absent.
 
 from __future__ import annotations
 
+import codecs
 import ctypes
 import logging
 import os
@@ -108,6 +109,10 @@ def _configure(lib) -> None:
     lib.eng_telnet_spans.restype = _I64P
     lib.eng_telnet_point.argtypes = [ctypes.c_void_p]
     lib.eng_telnet_point.restype = ctypes.POINTER(_I32)
+    # a grouped answer's points as JSON text
+    lib.eng_emit_rows.argtypes = [_F64P, _I64, _I64P, _I64, ctypes.c_char_p,
+                                  _I64P, _U8P, _I64, _I64P]
+    lib.eng_emit_rows.restype = _I64
 
 
 def _load_library():
@@ -396,3 +401,40 @@ def parse_put_body(body: bytes):
         return None
     finally:
         lib.eng_put_free(handle)
+
+
+def emit_rows(block: np.ndarray, rows: np.ndarray,
+              pieces: list[str]) -> list[str] | None:
+    """Rows `rows` of the [G, W] float64 `block`, each as the text
+    pieces[0] v0 pieces[1] v1 ... v(W-1) pieces[W], every value written
+    as float.__repr__ writes it (eng_emit_rows), in one native pass over
+    the block.  Every value of those rows must be finite and every piece
+    ASCII.  None where the library is unavailable."""
+    lib = _load_library()
+    if lib is None:
+        return None
+    g, w = block.shape
+    if (block.dtype != np.float64 or not block.flags.c_contiguous
+            or len(pieces) != w + 1):
+        raise ValueError("emit_rows: a C-ordered float64 block and one "
+                         "piece more than it has columns")
+    rows = np.ascontiguousarray(rows, np.int64)
+    if len(rows) and (rows.min() < 0 or rows.max() >= g):
+        raise ValueError("emit_rows: a row outside the block")
+    text = "".join(pieces).encode("ascii")
+    piece_off = np.cumsum([0] + [len(p) for p in pieces], dtype=np.int64)
+    # a value's repr is at most 24 bytes: -2.2250738585072014e-308
+    cap = len(rows) * (len(text) + 24 * w)
+    out = np.empty(cap, np.uint8)
+    offsets = np.empty(len(rows) + 1, np.int64)
+    size = lib.eng_emit_rows(
+        block.ctypes.data_as(_F64P), w, rows.ctypes.data_as(_I64P),
+        len(rows), text, piece_off.ctypes.data_as(_I64P),
+        out.ctypes.data_as(_U8P), cap, offsets.ctypes.data_as(_I64P))
+    if size == -2:
+        raise ValueError("emit_rows: a NaN or an infinity in a row")
+    if size < 0:
+        raise RuntimeError("emit_rows: the text outgrew its bound")
+    written = codecs.ascii_decode(out[:size])[0]
+    ends = offsets.tolist()
+    return [written[a:b] for a, b in zip(ends, ends[1:])]

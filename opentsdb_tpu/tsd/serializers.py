@@ -13,7 +13,9 @@ from __future__ import annotations
 from opentsdb_tpu.models.tsquery import (
     TSQuery, TSSubQuery, parse_m_subquery, parse_tsuid_subquery,
     parse_rate_options, parse_percentiles)
+from opentsdb_tpu.obs.registry import REGISTRY
 from opentsdb_tpu.query.filters import build_filter, tags_to_filters
+from opentsdb_tpu.query.planner import emit_texts
 from opentsdb_tpu.tsd.http import BadRequestError, HttpQuery, RawJson
 
 
@@ -178,9 +180,17 @@ class HttpJsonSerializer(HttpSerializer):
         keys: dict = {}     # timestamps -> their key strings, shared
         plain = not (data_query.show_tsuids or data_query.show_query
                      or data_query.global_annotations)
-        for r in results:
-            text = (r.json_text(keys, data_query.ms_resolution)
-                    if plain and not r.annotations else None)
+        texts = (emit_texts(results, data_query.ms_resolution) if plain
+                 else [None] * len(results))
+        native = len(texts) - texts.count(None)
+        lanes = REGISTRY.counter(
+            "tsd.query.emit_groups", "Results written into /api/query "
+            "answers, by the lane that wrote their points")
+        lanes.labels(lane="native").inc(native)
+        lanes.labels(lane="python").inc(len(results) - native)
+        for r, text in zip(results, texts):
+            if text is None and plain and not r.annotations:
+                text = r.json_text(keys, data_query.ms_resolution)
             if text is not None:
                 out.append(RawJson(text))
                 continue
